@@ -109,4 +109,7 @@ def main(reps: int = 6, batch: int = 2048):
 
 
 if __name__ == "__main__":
+    from distkeras_tpu.utils import compile_cache
+
+    compile_cache.enable()
     main()

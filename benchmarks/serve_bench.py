@@ -1411,16 +1411,13 @@ def bench_multichip(tp_list=(1, 2), V=1024, D=256, H=8, Hk=4, L=4,
     return result
 
 
-def run_multichip(tp_list=(1, 2), smoke=False):
-    """bench_multichip with the dryrun_multichip respawn pattern: when
-    this process has fewer devices than max(tp_list) (one real chip, or
-    a plain CPU host), re-exec the bench in a subprocess with a forced
-    virtual CPU mesh — the env must be set before XLA initializes a
-    backend. Returns the bench's JSON dict either way."""
-    need = max(tp_list)
-    if len(jax.devices()) >= need:
-        return bench_multichip(tp_list=tp_list, smoke=smoke)
-
+def _respawn_on_virtual_cpu(need: int, args, what: str, timeout=1800):
+    """Run this script again with ``args`` in a child pinned to the CPU
+    backend with ``need`` virtual host devices (the flag must be set
+    before XLA initializes a backend) and return its JSON line — with a
+    ``ran_on`` label: these are the "multi-replica" numbers of a process
+    that saw fewer real devices than replicas, and they are CPU
+    numbers. The child never needs the chip this parent may hold."""
     import subprocess
 
     env = dict(os.environ)
@@ -1432,21 +1429,36 @@ def run_multichip(tp_list=(1, 2), smoke=False):
         flags + f" --xla_force_host_platform_device_count={need}"
     ).strip()
     env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, os.path.abspath(__file__), "--multichip",
-           "--tp-list", ",".join(map(str, tp_list))]
-    if smoke:
-        cmd.append("--smoke")
+    cmd = [sys.executable, os.path.abspath(__file__), *args]
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=1800)
+                          timeout=timeout)
     sys.stderr.write(proc.stderr)
     if proc.returncode != 0:
         raise RuntimeError(
-            f"multichip bench subprocess failed "
-            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}"
+            f"{what} subprocess failed "
+            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}\n"
+            f"{proc.stdout[-2000:]}"
         )
     line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    print(line, flush=True)
-    return json.loads(line)
+    result = {**json.loads(line),
+              "ran_on": f"cpu: {need} virtual host devices, child process"}
+    print(json.dumps(result), flush=True)
+    return result
+
+
+def run_multichip(tp_list=(1, 2), smoke=False):
+    """bench_multichip with the dryrun_multichip respawn pattern: when
+    this process has fewer devices than max(tp_list) (one real chip, or
+    a plain CPU host), re-exec the bench in a subprocess with a forced
+    virtual CPU mesh — the env must be set before XLA initializes a
+    backend. Returns the bench's JSON dict either way."""
+    need = max(tp_list)
+    if len(jax.devices()) >= need:
+        return bench_multichip(tp_list=tp_list, smoke=smoke)
+
+    args = ["--multichip", "--tp-list", ",".join(map(str, tp_list))]
+    return _respawn_on_virtual_cpu(
+        need, args + ["--smoke"] * smoke, "multichip bench")
 
 
 def bench_router(V=512, D=256, H=4, L=2, replicas=3, slots=2,
@@ -1858,35 +1870,10 @@ def run_router(smoke=False, replicas=3, checks=True):
         return bench_router(smoke=smoke, replicas=replicas,
                             checks=checks)
 
-    import subprocess
-
-    env = dict(os.environ)
-    flags = " ".join(
-        f for f in env.get("XLA_FLAGS", "").split()
-        if "xla_force_host_platform_device_count" not in f
-    )
-    env["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={replicas}"
-    ).strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, os.path.abspath(__file__), "--router",
-           "--replicas", str(replicas)]
-    if smoke:
-        cmd.append("--smoke")
-    if not checks:
-        cmd.append("--no-checks")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=1800)
-    sys.stderr.write(proc.stderr)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"router bench subprocess failed "
-            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}\n"
-            f"{proc.stdout[-2000:]}"
-        )
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    print(line, flush=True)
-    return json.loads(line)
+    return _respawn_on_virtual_cpu(
+        replicas, ["--router", "--replicas", str(replicas)]
+        + ["--smoke"] * smoke + ["--no-checks"] * (not checks),
+        "router bench")
 
 
 def bench_fleet_sim(V=256, D=64, H=2, L=2, slots=2,
@@ -2392,34 +2379,9 @@ def run_fleet_sim(smoke=False, checks=True, max_replicas=3):
         return bench_fleet_sim(smoke=smoke, checks=checks,
                                max_replicas=max_replicas)
 
-    import subprocess
-
-    env = dict(os.environ)
-    flags = " ".join(
-        f for f in env.get("XLA_FLAGS", "").split()
-        if "xla_force_host_platform_device_count" not in f
-    )
-    env["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={need}"
-    ).strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, os.path.abspath(__file__), "--fleet-sim"]
-    if smoke:
-        cmd.append("--smoke")
-    if not checks:
-        cmd.append("--no-checks")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=1800)
-    sys.stderr.write(proc.stderr)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fleet-sim subprocess failed "
-            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}\n"
-            f"{proc.stdout[-2000:]}"
-        )
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    print(line, flush=True)
-    return json.loads(line)
+    return _respawn_on_virtual_cpu(
+        need, ["--fleet-sim"] + ["--smoke"] * smoke
+        + ["--no-checks"] * (not checks), "fleet-sim")
 
 
 def bench_disagg(V=64, D=256, H=4, L=2, replicas=3, slots=3,
@@ -2835,35 +2797,10 @@ def run_disagg(smoke=False, replicas=3, checks=True):
         return bench_disagg(smoke=smoke, replicas=replicas,
                             checks=checks)
 
-    import subprocess
-
-    env = dict(os.environ)
-    flags = " ".join(
-        f for f in env.get("XLA_FLAGS", "").split()
-        if "xla_force_host_platform_device_count" not in f
-    )
-    env["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={replicas}"
-    ).strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, os.path.abspath(__file__), "--disagg",
-           "--replicas", str(replicas)]
-    if smoke:
-        cmd.append("--smoke")
-    if not checks:
-        cmd.append("--no-checks")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=2400)
-    sys.stderr.write(proc.stderr)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"disagg bench subprocess failed "
-            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}\n"
-            f"{proc.stdout[-2000:]}"
-        )
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    print(line, flush=True)
-    return json.loads(line)
+    return _respawn_on_virtual_cpu(
+        replicas, ["--disagg", "--replicas", str(replicas)]
+        + ["--smoke"] * smoke + ["--no-checks"] * (not checks),
+        "disagg bench", timeout=2400)
 
 
 def bench_live_update(V=256, D=128, H=4, L=2, replicas=3, slots=2,
@@ -3202,35 +3139,10 @@ def run_live_update(smoke=False, replicas=3, checks=True):
         return bench_live_update(smoke=smoke, replicas=replicas,
                                  checks=checks)
 
-    import subprocess
-
-    env = dict(os.environ)
-    flags = " ".join(
-        f for f in env.get("XLA_FLAGS", "").split()
-        if "xla_force_host_platform_device_count" not in f
-    )
-    env["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={replicas}"
-    ).strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, os.path.abspath(__file__), "--live-update",
-           "--replicas", str(replicas)]
-    if smoke:
-        cmd.append("--smoke")
-    if not checks:
-        cmd.append("--no-checks")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=2400)
-    sys.stderr.write(proc.stderr)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"live-update bench subprocess failed "
-            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}\n"
-            f"{proc.stdout[-2000:]}"
-        )
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    print(line, flush=True)
-    return json.loads(line)
+    return _respawn_on_virtual_cpu(
+        replicas, ["--live-update", "--replicas", str(replicas)]
+        + ["--smoke"] * smoke + ["--no-checks"] * (not checks),
+        "live-update bench", timeout=2400)
 
 
 def main():
@@ -3459,4 +3371,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from distkeras_tpu.utils import compile_cache
+
+    compile_cache.enable()
     main()

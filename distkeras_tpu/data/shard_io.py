@@ -27,37 +27,32 @@ import json
 import os
 import queue
 import threading
+import warnings
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from distkeras_tpu.data.dataset import PartitionedDataset
+from distkeras_tpu.utils import native
 
-_NATIVE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native", "libdk_dataio.so",
-)
 _native = None
 _native_tried = False
 
 
 def _load_native():
+    """The ctypes shard-IO library, (re)built when it does not match its
+    ``.c`` source (native/build.py · ensure_lib); None — with a warning
+    — when it cannot be built, and numpy does the reads and gathers."""
     global _native, _native_tried
     if _native is not None or _native_tried:
         return _native
     _native_tried = True
-    path = _NATIVE_PATH
-    if not os.path.exists(path):
-        try:  # auto-build like the transport plane
-            import sys
-
-            sys.path.insert(0, os.path.dirname(os.path.dirname(_NATIVE_PATH)))
-            from native.build import build_lib
-
-            build_lib("libdk_dataio.so", quiet=True)
-        except Exception:
-            return None
-    if not os.path.exists(path):
+    try:
+        path = native.ensure_lib("libdk_dataio.so")
+    except native.BuildError as e:
+        warnings.warn(
+            "native shard IO unavailable, using the numpy paths: "
+            f"{type(e).__name__}: {e}", RuntimeWarning, stacklevel=2)
         return None
     lib = ctypes.CDLL(path)
     lib.dk_pread.restype = ctypes.c_int

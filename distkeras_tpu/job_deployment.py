@@ -95,7 +95,13 @@ class Job:
         """Launch every process (host 0 first — it hosts the coordinator and
         the parameter server). Returns the Popen handles; with ``wait`` the
         call blocks and raises if any process exits nonzero
-        (reference: Job.run blocks on spark-submit)."""
+        (reference: Job.run blocks on spark-submit).
+
+        A chip belongs to one process at a time. This launcher never
+        initializes a JAX backend itself (the module imports none), so
+        its children are free to take their host's chips — keep the
+        calling script that way, and give a host with one chip one
+        process: several ``"local"`` entries are for CPU runs."""
         procs = []
         for pid in range(len(self.hosts)):
             cmd = self.command_for(pid)

@@ -262,7 +262,7 @@ class CausalSelfAttention(nn.Module):
 
     _DENSE_MAX_T = 512  # short sequences: one fused dense block is fastest
 
-    def _use_paged_kernel(self, T, G, hd, quant) -> bool:
+    def _use_paged_kernel(self, T, G, hd, quant, Hk=1) -> bool:
         """Resolve ``paged_kernel`` for this call shape: 'auto' defers
         to the kernel's own preferred() gate (TPU + tileable), 'pallas'
         forces it (interpret mode off-TPU), 'gather' keeps the XLA
@@ -275,9 +275,9 @@ class CausalSelfAttention(nn.Module):
 
         store = 1 if quant else jnp.dtype(self.dtype).itemsize
         return _pa.preferred(T, G, hd, self.page_block_size,
-                             store_itemsize=store)
+                             store_itemsize=store, Hk=Hk)
 
-    def _use_prefill_kernel(self, T, G, hd, L) -> bool:
+    def _use_prefill_kernel(self, T, G, hd, L, Hk=1) -> bool:
         """Resolve ``prefill_kernel`` for this call shape: 'auto'
         defers to the splash kernel's preferred() gate (TPU + tileable
         + a true chunk), 'splash' forces it (interpret mode off-TPU),
@@ -290,7 +290,7 @@ class CausalSelfAttention(nn.Module):
 
         if self.prefill_kernel == "splash":
             return True
-        return _sp.preferred(T, G, hd, L)
+        return _sp.preferred(T, G, hd, L, Hk)
 
     def _paged_attend(self, q, k, v, block_tables, seq_lens,
                       valid_lens=None):
@@ -367,7 +367,7 @@ class CausalSelfAttention(nn.Module):
         else:
             ck.value = put(ck.value, k)
             cv.value = put(cv.value, v)
-        if self._use_paged_kernel(T, H // Hk, hd, quant):
+        if self._use_paged_kernel(T, H // Hk, hd, quant, Hk):
             # Pallas paged attention: pages DMA'd straight off the block
             # table, int8 dequant fused in VMEM — the gathered [B, L]
             # view below never materializes (ops/paged_attention.py)
@@ -385,7 +385,7 @@ class CausalSelfAttention(nn.Module):
                     * view(vs.value)[..., None]).astype(self.dtype)
         else:
             keys, vals = view(ck.value), view(cv.value)
-        if self._use_prefill_kernel(T, G, hd, L):
+        if self._use_prefill_kernel(T, G, hd, L, Hk):
             # splash chunked prefill over the gathered view: identical
             # absolute-position masks, KV tiles beyond each row's
             # diagonal skipped (ops/splash_prefill.py); the dense
@@ -510,7 +510,7 @@ class CausalSelfAttention(nn.Module):
             cv.value = put(cv.value, v.astype(self.dtype))
             keys, vals = ck.value, cv.value
         idx.value = cur + (T if valid_lens is None else valid_lens)
-        if self._use_prefill_kernel(T, G, hd, L):
+        if self._use_prefill_kernel(T, G, hd, L, Hk):
             # splash chunked prefill over the slot cache leaves: same
             # per-row absolute-position masks as the dense attend below
             # (which stays the bit-parity reference), KV tiles beyond

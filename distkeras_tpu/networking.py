@@ -32,6 +32,7 @@ import socket
 import struct
 import threading
 import time
+import warnings
 from typing import Any, Optional
 
 import jax
@@ -39,6 +40,7 @@ import numpy as np
 from flax import serialization as flax_serialization
 
 from distkeras_tpu import telemetry
+from distkeras_tpu.utils import native
 
 
 def _to_host(tree):
@@ -76,30 +78,22 @@ def _tree_nbytes(tree) -> int:
 # Native data plane (ctypes; pure-Python fallback)
 # ---------------------------------------------------------------------------
 
-_NATIVE_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "native", "libdk_transport.so",
-)
 _native = None
+# why the native plane is off (None while it is on or untried): the
+# smoke (chip_smoke.py) treats a failed build as an error and prints this
+native_transport_error: Optional[str] = None
 
 
 def _load_native():
-    global _native
+    """The ctypes transport library, (re)built when it does not match
+    its ``.c`` source (native/build.py · ensure_lib); False — with a
+    warning, once — when it cannot be built or loaded, and the
+    pure-Python loops below carry the frames instead."""
+    global _native, native_transport_error
     if _native is not None:
         return _native
-    if not os.path.exists(_NATIVE_PATH):
-        try:
-            import sys
-
-            sys.path.insert(0, os.path.dirname(os.path.dirname(_NATIVE_PATH)))
-            from native.build import build
-
-            build(quiet=True)
-        except Exception:
-            _native = False
-            return False
     try:
-        lib = ctypes.CDLL(_NATIVE_PATH)
+        lib = ctypes.CDLL(native.ensure_lib("libdk_transport.so"))
         lib.dk_send_frame.argtypes = [
             ctypes.c_int, ctypes.c_char_p, ctypes.c_uint64
         ]
@@ -111,7 +105,12 @@ def _load_native():
         ]
         lib.dk_recv_exact.restype = ctypes.c_int
         _native = lib
-    except OSError:
+    except native.BuildError as e:
+        native_transport_error = f"{type(e).__name__}: {e}"
+        warnings.warn(
+            "native transport unavailable, using the pure-Python frame "
+            f"loops: {native_transport_error}", RuntimeWarning,
+            stacklevel=2)
         _native = False
     return _native
 

@@ -81,6 +81,25 @@ def _vma_zero(*arrays):
     return z
 
 
+def _typed_like(ct, primal):
+    """``ct`` carrying exactly ``primal``'s vma type, which a custom-VJP
+    bwd rule must return under ``shard_map``. The scan above types every
+    cotangent with the UNION of the inputs' varying axes; an input that
+    is replicated over some of them (the loss mask varies over sp only,
+    the activations over dp and sp) gets the psum over the extra axes —
+    the correct cotangent of a replicated value, and dead code when
+    nobody differentiates that input. Outside ``shard_map`` both sets are
+    empty and this is the identity."""
+    have, want = jax.typeof(ct).vma, jax.typeof(primal).vma
+    extra = tuple(sorted(have - want))
+    if extra:
+        ct = jax.lax.psum(ct, extra)
+    missing = tuple(sorted(want - have))
+    if missing:
+        ct = jax.lax.pcast(ct, missing, to="varying")
+    return ct
+
+
 def _logits(xc, kernel, bias, dtype):
     """One chunk's logits exactly as VocabHead computes them: bf16 (model
     dtype) operands on the MXU, f32 accumulation, f32 bias add."""
@@ -176,7 +195,9 @@ def _bwd(chunk, res, g):
     dx = dxs.reshape(nc * C, D)[: x.shape[0]]
     dw = dws.reshape(nc * C)[: x.shape[0]].astype(weights.dtype)
     # padded rows have weight 0 -> their dl is exactly 0; no correction
-    return dx, dk.astype(kernel.dtype), db.astype(bias.dtype), None, dw
+    return (_typed_like(dx, x), _typed_like(dk.astype(kernel.dtype), kernel),
+            _typed_like(db.astype(bias.dtype), bias), None,
+            _typed_like(dw, weights))
 
 
 fused_linear_softmax_ce.defvjp(_fwd, _bwd)

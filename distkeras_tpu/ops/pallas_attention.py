@@ -68,11 +68,7 @@ def _call_kwargs(block: int) -> dict:
     the cap is a budget, not an allocation, so raising it only for the
     big blocks leaves the proven 512-path compilation untouched."""
     if block > DEFAULT_BLOCK:
-        # CompilerParams was TPUCompilerParams before the rename; take
-        # whichever this jax ships so big blocks work on both
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams"))
-        return {"compiler_params": params_cls(
+        return {"compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024)}
     return {}
 
@@ -83,13 +79,8 @@ def _out_struct(shape, dtype, like):
     every output aval must state how it varies, and a plain
     ShapeDtypeStruct is rejected — which made the kernel unusable inside
     the sharded LM step (found the first time LMTrainer ran on real TPU
-    with the pallas auto-select, r5). Older jax has no ``jax.typeof``
-    (and no vma typing to satisfy): plain struct."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    with the pallas auto-select, r5)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 # ---------------------------------------------------------------------------

@@ -119,16 +119,9 @@ def _varying_zeros(q, shapes_fills, axis_name):
     over 'dp' too) — or scan rejects the carry types. The constants are
     pcast rather than derived from q data: a data-derived zero would let
     one non-finite element of q NaN-poison every accumulator."""
-    typeof = getattr(jax, "typeof", None)
-    pcast = getattr(jax.lax, "pcast", None)
-    if typeof is None or pcast is None:
-        # pre-vma jax: no varying-type system for scan to reject — the
-        # plain constants are the correct carries
-        return tuple(jnp.full(shape, fill, jnp.float32)
-                     for shape, fill in shapes_fills)
-    vma = tuple(sorted(getattr(typeof(q), "vma", None) or (axis_name,)))
+    vma = tuple(sorted(jax.typeof(q).vma or (axis_name,)))
     return tuple(
-        pcast(jnp.full(shape, fill, jnp.float32), vma, to="varying")
+        jax.lax.pcast(jnp.full(shape, fill, jnp.float32), vma, to="varying")
         for shape, fill in shapes_fills
     )
 

@@ -51,28 +51,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu.ops.paged_attention import VMEM_BUDGET, vmem_bytes
+
 _NEG_INF = -1e30
 
-# KV-axis tile: the largest power-of-two block that divides L (real-TPU
-# auto-select additionally requires L % 128 == 0 so the tile is
-# lane-aligned; interpret mode runs whatever divides)
-_KV_BLOCKS = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+# KV-axis tile: the largest power-of-two block up to 256 that divides L
+# (real-TPU auto-select additionally requires L % 128 == 0 so the tile
+# is lane-aligned; interpret mode runs whatever divides). 512 compiles
+# too, but the chip compiler's time grows faster than the tile: the
+# static per-head walk over its rows unrolls (v5e, H8/Hk2/hd256, T64:
+# 1.2 s at 128, 3.3 s at 256, 12 s at 512).
+_KV_BLOCKS = (256, 128, 64, 32, 16, 8, 4, 2, 1)
 
 
 def _interpret() -> bool:
     """Interpret mode off-TPU (CPU parity tests run the same program)."""
     return jax.default_backend() != "tpu"
-
-
-def _struct(shape, dtype, like):
-    """Output aval carrying ``like``'s vma type on vma-aware jax (the
-    sharded serving tick runs this under shard_map; see
-    paged_attention._struct)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def choose_kv_block(L: int) -> int:
@@ -83,34 +77,42 @@ def choose_kv_block(L: int) -> int:
     return L
 
 
-def supports(T: int, G: int, hd: int, L: int) -> bool:
-    """Shapes the kernel serves on real TPU: a true chunk (T > 1 — one
-    decode token is the dense attend's home turf), lane-aligned head
-    dim, a sublane-aligned ``[T*G, hd]`` query tile, and a
-    lane-aligned KV tile. Anything else keeps the dense reference —
-    conservative, never a mis-tile. Interpret mode (tests) may run any
-    shape by forcing ``prefill_kernel='splash'``."""
+def supports(T: int, G: int, hd: int, L: int, Hk: int = 1) -> bool:
+    """Shapes 'auto' sends to the kernel on a TPU, every one of which
+    the chip's compiler accepts (tests/test_chip_compile.py holds this
+    gate to the compiler for a described v5e): a true chunk (T > 1 —
+    one decode token is the dense attend's home turf), lane-aligned
+    head dim, a sublane-aligned ``[T*G, hd]`` query tile, a
+    lane-aligned KV tile, and buffers that fit the scoped VMEM limit
+    (the tiles hold all ``Hk`` local KV heads; see
+    paged_attention.vmem_bytes). Anything else keeps the dense
+    reference — conservative, never a mis-tile. Interpret mode (tests)
+    may run any shape by forcing ``prefill_kernel='splash'``."""
     return (T > 1 and hd % 128 == 0 and (T * G) % 8 == 0
-            and L % 128 == 0)
+            and L % 128 == 0
+            and vmem_bytes(T, G, Hk, hd, choose_kv_block(L))
+            <= VMEM_BUDGET)
 
 
-def preferred(T: int, G: int, hd: int, L: int) -> bool:
+def preferred(T: int, G: int, hd: int, L: int, Hk: int = 1) -> bool:
     """THE auto-select predicate (``prefill_kernel='auto'``): TPU
     backend and a supported shape — mirrors paged_attention.preferred
     so the engine's configured kernel label can't drift from what
     ran."""
     if jax.default_backend() != "tpu":
         return False
-    return supports(T, G, hd, L)
+    return supports(T, G, hd, L, Hk)
 
 
 def _kernel(starts_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
-            *, kb: int, T: int, G: int, nkv: int, scale: float):
-    """One (batch row, KV head, KV block) program: skip-or-score one
-    KV tile into the online-softmax state; finalize on the last
-    tile."""
+            *, kb: int, T: int, G: int, Hk: int, nkv: int, scale: float):
+    """One (batch row, KV block) program: skip-or-score one KV tile —
+    every local KV head of it, walked in a static loop (the block takes
+    the whole head axis; Mosaic admits a second-minor block dim only
+    when it is the array's own or a multiple of 8) — into the
+    online-softmax state; finalize on the last tile."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     TG = T * G
 
     @pl.when(j == 0)
@@ -126,37 +128,39 @@ def _kernel(starts_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
     # mask — their program issues no compute at all
     @pl.when(j * kb <= start + T - 1)
     def _():
-        q = q_ref[0, 0]          # [TG, hd]
-        kb_t = k_ref[0, :, 0, :]  # [kb, hd] — one KV tile of one head
-        vb_t = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, kb_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [TG, kb]
         # query row r = t * G + g sits at absolute position start + t;
         # key slot i of tile j is absolute position j * kb + i — the
         # gathered reference's mask, tile-local
         qpos = start + jax.lax.broadcasted_iota(
             jnp.int32, (TG, 1), 0) // G
         kpos = j * kb + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
-        s = jnp.where(kpos <= qpos, s, _NEG_INF)
-        m_old = m_s[:]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
-        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_s[:] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(vb_t.dtype), vb_t, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc[:] = acc[:] * corr + pv
+        visible = kpos <= qpos
+        for h in range(Hk):
+            q = q_ref[0, h]          # [TG, hd]
+            kb_t = k_ref[0, :, h, :]  # [kb, hd] — one head of the tile
+            vb_t = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, kb_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [TG, kb]
+            s = jnp.where(visible, s, _NEG_INF)
+            m_old = m_s[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new)
+            l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_s[h] = m_new
+            pv = jax.lax.dot_general(
+                p.astype(vb_t.dtype), vb_t, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc[h] = acc[h] * corr + pv
 
     @pl.when(j == nkv - 1)
     def _():
         # position 0 is visible to every real row, so l > 0; the
         # padding rows of a mixed tick normalize garbage nobody reads
-        o_ref[0, 0] = (acc[:] / jnp.maximum(l_s[:], 1e-30)).astype(
+        o_ref[0] = (acc[:] / jnp.maximum(l_s[:], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -193,35 +197,38 @@ def splash_prefill_attention(q, keys, vals, starts):
         B, Hk, TG, hd)
 
     kern = functools.partial(
-        _kernel, kb=kb, T=T, G=G, nkv=nkv, scale=1.0 / np.sqrt(hd),
+        _kernel, kb=kb, T=T, G=G, Hk=Hk, nkv=nkv,
+        scale=1.0 / np.sqrt(hd),
     )
 
-    def q_idx(b, h, j, starts_):
-        return (b, h, 0, 0)
+    def q_idx(b, j, starts_):
+        return (b, 0, 0, 0)
 
-    def kv_idx(b, h, j, starts_):
-        return (b, j, h, 0)
+    def kv_idx(b, j, starts_):
+        return (b, j, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hk, nkv),
+        grid=(B, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1, TG, hd), q_idx),
-            pl.BlockSpec((1, kb, 1, hd), kv_idx),
-            pl.BlockSpec((1, kb, 1, hd), kv_idx),
+            pl.BlockSpec((1, Hk, TG, hd), q_idx),
+            pl.BlockSpec((1, kb, Hk, hd), kv_idx),
+            pl.BlockSpec((1, kb, Hk, hd), kv_idx),
         ],
-        out_specs=pl.BlockSpec((1, 1, TG, hd), q_idx),
+        out_specs=pl.BlockSpec((1, Hk, TG, hd), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((TG, hd), jnp.float32),
-            pltpu.VMEM((TG, 1), jnp.float32),
-            pltpu.VMEM((TG, 1), jnp.float32),
+            pltpu.VMEM((Hk, TG, hd), jnp.float32),
+            pltpu.VMEM((Hk, TG, 1), jnp.float32),
+            pltpu.VMEM((Hk, TG, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=_struct((B, Hk, TG, hd), q.dtype, q),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, TG, hd), q.dtype,
+                                       vma=jax.typeof(q).vma),
         interpret=_interpret(),
+        name="splash_prefill",
     )(starts.astype(jnp.int32), qr, keys, vals)
     return out.reshape(B, Hk, T, G, hd).transpose(0, 2, 1, 3, 4).reshape(
         B, T, H, hd)

@@ -34,11 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-try:  # vma-aware shard_map (jax >= 0.6 exports it at top level)
-    from jax import shard_map
-except ImportError:  # older jax: the experimental module, same call shape
-    from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distkeras_tpu.ops import rules
 
@@ -195,6 +192,25 @@ def opt_state_specs(optimizer, params, param_specs):
     return tree_map_with_path(match, shapes)
 
 
+def lm_state_shardings(optimizer, mesh: Mesh, params,
+                       tp_axis: Optional[str] = None,
+                       ep_axis: Optional[str] = None):
+    """(params, opt_state) ``NamedSharding`` trees in the layout the LM
+    steps below keep their state in. A caller that places its freshly
+    initialised state this way before the first call hands the step the
+    very types the step returns, so the first call and every later one
+    are ONE compiled program; state left on a single device has a
+    different type (no mesh) and compiled the flagship step twice."""
+    pspec = lm_param_specs(params, tp_axis=tp_axis, ep_axis=ep_axis)
+    ospec = opt_state_specs(optimizer, params, pspec)
+
+    def named(spec):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), spec,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    return named(pspec), named(ospec)
+
+
 def make_lm_train_step(model, optimizer, mesh: Mesh,
                        dp_axis: str = "dp", sp_axis: str = "sp",
                        tp_axis: Optional[str] = None,
@@ -239,16 +255,6 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
             "always shards the sequence over sp_axis; use a size-1 axis "
             "for the unsharded-sequence case (e.g. make_mesh({'dp': n, "
             "'sp': 1}))"
-        )
-    if fused_ce and not hasattr(jax.lax, "pcast"):
-        # the fused loss NEEDS the pcast below: its transpose is the psum
-        # that makes the custom-VJP head grads a correct replicated
-        # gradient. On pre-vma jax there is no pcast — running anyway
-        # would train with silently-unsummed head grads.
-        raise NotImplementedError(
-            "fused_ce=True needs vma-aware jax (jax.lax.pcast) for "
-            "correct replicated head gradients under shard_map; pass "
-            "fused_ce=False on this jax"
         )
     sp_size = int(np.prod([s for a, s in zip(mesh.axis_names, mesh.devices.shape)
                            if a == sp_axis] or [1]))

@@ -107,25 +107,13 @@ from distkeras_tpu.utils.metrics import MetricsWriter
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax generations: the top-level export on newer
-    jax, the experimental module elsewhere. Replication/vma checking is
-    disabled either way — the serving bodies keep sampling on replicated
-    post-psum logits by construction, and the mesh-parity suite asserts
-    the streams, which is the check that matters (the training steps in
-    parallel/spmd.py keep strict checking; they differentiate, serving
-    doesn't)."""
-    try:
-        from jax import shard_map
-        try:
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-        except TypeError:  # a jax that renamed/dropped the kwarg
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with vma checking disabled — the serving bodies
+    keep sampling on replicated post-psum logits by construction, and
+    the mesh-parity suite asserts the streams, which is the check that
+    matters (the training steps in parallel/spmd.py keep strict
+    checking; they differentiate, serving doesn't)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _pack_i32(*arrs) -> np.ndarray:

@@ -43,7 +43,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-# the taxonomy above, for renderers and docs; append() accepts any
+# the kinds listed above, for renderers and docs; append() accepts any
 # action string so new actuators never need a telemetry release
 KNOWN_ACTIONS = frozenset({
     "scale_up", "scale_down", "rebalance", "drain", "undrain",

@@ -1741,7 +1741,10 @@ class LMTrainer(Trainer):
     def _train(self, dataset: PartitionedDataset, shuffle: bool = False) -> Model:
         from distkeras_tpu.data.shard_io import ShardedDataset
         from distkeras_tpu.parallel.mesh import make_mesh
-        from distkeras_tpu.parallel.spmd import make_lm_train_step
+        from distkeras_tpu.parallel.spmd import (
+            lm_state_shardings,
+            make_lm_train_step,
+        )
         from jax.sharding import NamedSharding
 
         # in-memory datasets (and small sharded corpora, which materialize)
@@ -1952,10 +1955,18 @@ class LMTrainer(Trainer):
         # The loop rebinds both, but the FIRST call would donate buffers
         # the caller may still own (self.params / user-passed init / the
         # restored checkpoint) and leave self.params a deleted tree if
-        # training raises mid-epoch — hand the loop device-local copies
-        # (one cheap D2D copy per train(), not per window)
-        params = jax.tree.map(jnp.copy, params)
-        opt_state = jax.tree.map(jnp.copy, opt_state)
+        # training raises mid-epoch — hand the loop copies of its own
+        # (one cheap D2D copy per train(), not per window), already in
+        # the step's layout on the mesh: state left on a single device
+        # is a different input type and the step compiled a second time
+        # when its own outputs came back in
+        p_sh, o_sh = lm_state_shardings(
+            optimizer, mesh, self.params,
+            tp_axis="tp" if tp > 1 else None,
+            ep_axis="ep" if moe else None,
+        )
+        params = jax.device_put(params, p_sh, may_alias=False)
+        opt_state = jax.device_put(opt_state, o_sh, may_alias=False)
         history: History = []
         for epoch in range(start_epoch, self.num_epoch):
             # keep losses on-device until the epoch ends so dispatches

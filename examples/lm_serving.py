@@ -377,4 +377,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from distkeras_tpu.utils import compile_cache
+
+    compile_cache.enable()
     main()

@@ -1,0 +1,177 @@
+"""The main path's Pallas kernels, compiled for a described (not
+attached) TPU v5e at the shapes ``chip_smoke.py`` runs them at.
+
+Interpret mode (what every other kernel test runs) says nothing about
+Mosaic's block rules or VMEM: both serving kernels once passed all their
+interpret-mode tests while the chip's compiler refused them at every
+shape. The TPU compiler is installed without a chip, so these compiles
+guard each later change at no chip time. Nothing here runs.
+
+The topology is described inside a fixture of this file and nowhere
+else: only one process may load the TPU library, every xdist worker
+imports every test file, and only the worker that is handed this file
+may make the call.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distkeras_tpu.ops import (paged_attention, pallas_attention,
+                               pallas_pair, splash_prefill)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(monkeypatch, one_chip):
+    """``compiled_text(fn, *shapes)`` for the described chip: kernels out
+    of interpret mode, the persistent cache off (an entry written here
+    cannot be read back without a chip, and the next run would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for mod in (pallas_attention, pallas_pair, paged_attention,
+                splash_prefill):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compiled_text(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compiled_text
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+
+def paged_shapes(B, T, H, Hk, hd, bs, quant, pages=512, max_blocks=64):
+    pool = ((pages, bs, Hk, hd), I8 if quant else BF16)
+    shapes = [((B, T, H, hd), BF16), pool, pool,
+              ((B, max_blocks), I32), ((B,), I32)]
+    if quant:
+        shapes += [((pages, bs, Hk), F32)] * 2
+    return shapes
+
+
+def splash_shapes(B, T, H, Hk, hd, L):
+    kv = ((B, L, Hk, hd), BF16)
+    return [((B, T, H, hd), BF16), kv, kv, ((B,), I32)]
+
+
+def grad_of(attend):
+    """fwd + bwd of one attention op as a single program."""
+    def loss(q, k, v):
+        out = attend(q, k, v)
+        return sum(jnp.sum(o.astype(F32)) for o in jax.tree.leaves(out))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def test_train_attention_fwd_and_grad(for_chip):
+    """The flagship train step's attention: B8 / T2048 / H8 / hd256."""
+    qkv = [((8, 2048, 8, 256), BF16)] * 3
+    block = pallas_attention.choose_block(2048, 256, itemsize=2)
+    text = for_chip(grad_of(functools.partial(
+        pallas_attention.pallas_causal_attention, block=block)), *qkv)
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_ring_pair_fwd_and_grad(for_chip, causal):
+    """One chunk pair of the ring (sp=2 of T2048): T1024 / hd256; the
+    diagonal pair is causal, the off-diagonal one is not."""
+    qkv = [((4, 1024, 8, 256), BF16)] * 3
+    text = for_chip(grad_of(functools.partial(
+        pallas_pair.pallas_pair_attention, causal=causal)), *qkv)
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("T,Hk,quant", [
+    (64, 2, False),  # the mixed tick's 64-token chunk, bf16 pool
+    (64, 2, True),   # ... and the int8 pool the smoke serves
+    (1, 1, True),    # a decode tick the gate admits: G = 8
+])
+def test_paged_attention(for_chip, T, Hk, quant):
+    assert paged_attention.supports(T, 8 // Hk, 256, 32,
+                                    1 if quant else 2, Hk)
+    text = for_chip(paged_attention.paged_attention,
+                    *paged_shapes(8, T, 8, Hk, 256, 32, quant))
+    assert "tpu_custom_call" in text and "%paged_attention" in text
+
+
+def test_splash_prefill(for_chip):
+    """The slot engine's 64-token chunk over its 2048-token cache."""
+    assert splash_prefill.supports(64, 4, 256, 2048, 2)
+    text = for_chip(splash_prefill.splash_prefill_attention,
+                    *splash_shapes(8, 64, 8, 2, 256, 2048))
+    assert "tpu_custom_call" in text and "%splash_prefill" in text
+
+
+# (kernel, T, H, Hk, hd): what supports() says must be what the compiler
+# says. The refusals are VMEM: the tiles hold all Hk heads of a chunk.
+GATE_CASES = [
+    ("paged", 150, 8, 2, 256),    # a whole prompt in one prefill
+    ("paged", 24, 8, 8, 128),     # MHA, hd128
+    ("paged", 1024, 8, 2, 256),   # refused: 16 MiB of tiles
+    ("splash", 2, 8, 2, 256),     # the smallest chunk
+    ("splash", 384, 8, 2, 256),   # near the budget
+    ("splash", 96, 32, 32, 128),  # wide MHA: the K/V tiles dominate
+    ("splash", 1024, 8, 8, 128),  # refused
+    ("splash", 512, 16, 16, 128),  # refused
+]
+
+
+@pytest.mark.parametrize("kernel,T,H,Hk,hd", GATE_CASES)
+def test_supports_agrees_with_the_compiler(for_chip, kernel, T, H, Hk, hd):
+    if kernel == "paged":
+        said = paged_attention.supports(T, H // Hk, hd, 32, 2, Hk)
+        fn = paged_attention.paged_attention
+        shapes = paged_shapes(2, T, H, Hk, hd, 32, False)
+    else:
+        said = splash_prefill.supports(T, H // Hk, hd, 2048, Hk)
+        fn = splash_prefill.splash_prefill_attention
+        shapes = splash_shapes(2, T, H, Hk, hd, 2048)
+    try:
+        for_chip(fn, *shapes)
+        compiles = True
+    except Exception as e:
+        assert "vmem" in str(e).lower(), e
+        compiles = False
+    assert said == compiles
+
+
+@pytest.mark.parametrize("T,G,hd,bs,itemsize", [
+    (64, 4, 64, 32, 2),   # head dim not lane-aligned
+    (1, 4, 256, 32, 1),   # T*G = 4: the smoke's own decode tick
+    (64, 4, 256, 16, 1),  # int8 pages in 16-row blocks
+])
+def test_shapes_the_paged_gate_keeps_off_the_kernel(T, G, hd, bs, itemsize):
+    """Alignment terms of the gate: conservative (the compiler accepts
+    some of these since the tiles took the whole head axis), never
+    measured, so they stay on the gathered attend."""
+    assert not paged_attention.supports(T, G, hd, bs, itemsize, 2)

@@ -89,7 +89,12 @@ def test_noncausal_rectangle_unchanged(
     v = jnp.asarray(rng.normal(size=(B, C, H, hd)), jnp.float32)
     o, lse = pallas_pair_attention(q, k, v, False, 32)
     ro, rlse = _dense(q, k, v, False)
+    # 1e-4, the tolerance this file's other f32 comparison of
+    # differently-ordered sums (the grads above) uses: the kernel adds
+    # the two 32-key blocks' softmax partials in sequence, the dense
+    # reference sums all 64 keys at once, and on the installed CPU
+    # backend 2 of the 32,768 outputs differ by 1.4e-5
     np.testing.assert_allclose(np.asarray(o), np.asarray(ro),
-                               rtol=1e-5, atol=1e-5)
+                               rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse),
-                               rtol=1e-5, atol=1e-5)
+                               rtol=1e-4, atol=1e-4)
