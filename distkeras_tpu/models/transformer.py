@@ -348,6 +348,7 @@ class CausalSelfAttention(nn.Module):
                 jnp.arange(T)[None, :] < valid_lens[:, None], blk, 0
             )
 
+        @jax.named_scope("cache_update")
         def put(cache, new):
             return cache.at[blk, off].set(new.astype(cache.dtype))
 
@@ -467,6 +468,7 @@ class CausalSelfAttention(nn.Module):
             q = apply_rope(q, pos)
             k = apply_rope(k, pos)
 
+        @jax.named_scope("cache_update")
         def put(cache, new):
             if valid_lens is not None:
                 # chunked mixed tick: scatter each row's VALID tokens at

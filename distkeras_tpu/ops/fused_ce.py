@@ -132,6 +132,7 @@ def fused_linear_softmax_ce(x, kernel, bias, labels, weights,
     return _fwd(x, kernel, bias, labels, weights, chunk)[0]
 
 
+@jax.named_scope("fused_ce")
 def _fwd(x, kernel, bias, labels, weights, chunk):
     xs, ls, ws, C = _chunked(x, labels, weights, chunk)
 
@@ -148,6 +149,7 @@ def _fwd(x, kernel, bias, labels, weights, chunk):
     return total, (x, kernel, bias, labels, weights)
 
 
+@jax.named_scope("fused_ce")
 def _bwd(chunk, res, g):
     x, kernel, bias, labels, weights = res
     xs, ls, ws, C = _chunked(x, labels, weights, chunk)

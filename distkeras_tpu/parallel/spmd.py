@@ -327,8 +327,9 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
             return local_sum / global_cnt
 
         local_obj, grads = jax.value_and_grad(objective)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         loss = jax.lax.psum(local_obj, (dp_axis, sp_axis))
         return params, opt_state, loss
 
@@ -427,8 +428,9 @@ def make_moe_lm_train_step(model, optimizer, mesh: Mesh,
         # objective = mean of local objectives; autodiff's vma transpose
         # already psums grads of the replicated params over (dp, ep)
         grads = rules.tree_scale(grads, 1.0 / n_shards)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         loss = jax.lax.pmean(local_ce, (dp_axis, ep_axis))
         return params, opt_state, loss
 

@@ -264,14 +264,15 @@ def _tick_fn(dm_slot, cfgs, ctx: Optional[_ShardCtx] = None):
                        out_kinds="crrr", donate=(1, 2, 3))
     def tick(params_only, cache, last_logits, rngs):
         recompiles.note("serve.tick")
-        toks, new_rngs = [], []
-        for s, (temp, top_k, top_p) in enumerate(cfgs):
-            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            new_rngs.append(rng)
+        with jax.named_scope("sample"):
+            toks, new_rngs = [], []
+            for s, (temp, top_k, top_p) in enumerate(cfgs):
+                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                new_rngs.append(rng)
         tok = jnp.stack(toks)  # [S]
         logits, vs = dm_slot.apply(
             {**params_only, "cache": cache}, tok[:, None],
@@ -336,14 +337,15 @@ def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
         fed, valid, smask = _unpack_i32(
             packed, ((S, chunk), (S,), (S,)))
         sample_mask = smask != 0
-        toks, new_rngs = [], []
-        for s, (temp, top_k, top_p) in enumerate(cfgs):
-            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
+        with jax.named_scope("sample"):
+            toks, new_rngs = [], []
+            for s, (temp, top_k, top_p) in enumerate(cfgs):
+                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
         sampled = jnp.stack(toks)  # [S]
         inputs = fed.at[:, 0].set(
             jnp.where(sample_mask, sampled, fed[:, 0])
@@ -382,14 +384,15 @@ def _paged_mixed_tick_fn(dm_paged, cfgs, chunk,
         tables, lens, fed, valid, smask = _unpack_i32(
             packed, ((S, MB), (S,), (S, chunk), (S,), (S,)))
         sample_mask = smask != 0
-        toks, new_rngs = [], []
-        for s, (temp, top_k, top_p) in enumerate(cfgs):
-            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
+        with jax.named_scope("sample"):
+            toks, new_rngs = [], []
+            for s, (temp, top_k, top_p) in enumerate(cfgs):
+                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
         sampled = jnp.stack(toks)
         inputs = fed.at[:, 0].set(
             jnp.where(sample_mask, sampled, fed[:, 0])
@@ -450,14 +453,15 @@ def _multi_tick_fn(dm_slot, cfgs, k, ctx: Optional[_ShardCtx] = None):
         def step(carry, _):
             cache, last, rngs, stopped, emitted = carry
             alive = ~stopped & (emitted < lim)
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
+            with jax.named_scope("sample"):
+                toks, new_rngs = [], []
+                for s, (temp, top_k, top_p) in enumerate(cfgs):
+                    rng, sub = jax.random.split(rngs[s])
+                    toks.append(
+                        sample_tokens(last[s][None], sub, temp,
+                                      top_k, top_p)[0]
+                    )
+                    new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
             tok = jnp.stack(toks)  # [S]
             valid = alive.astype(jnp.int32)
             logits, vs = dm_slot.apply(
@@ -503,14 +507,15 @@ def _paged_multi_tick_fn(dm_paged, cfgs, k,
         def step(carry, _):
             cache, last, rngs, stopped, emitted = carry
             alive = ~stopped & (emitted < lim)
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
+            with jax.named_scope("sample"):
+                toks, new_rngs = [], []
+                for s, (temp, top_k, top_p) in enumerate(cfgs):
+                    rng, sub = jax.random.split(rngs[s])
+                    toks.append(
+                        sample_tokens(last[s][None], sub, temp,
+                                      top_k, top_p)[0]
+                    )
+                    new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
             tok = jnp.stack(toks)  # [S]
             valid = alive.astype(jnp.int32)
             logits, vs = dm_paged.apply(
@@ -575,6 +580,7 @@ def _rewind_cursors(cache, rewind):
     )
 
 
+@jax.named_scope("sample")
 def _spec_accept(cfgs, k, onehot_q, full, rngs, valid, n_forced,
                  sample_mask, draft_toks, q_probs):
     """Rejection-sampling core shared by both verify ticks (traced).
@@ -887,14 +893,15 @@ def _paged_tick_fn(dm_paged, cfgs, ctx: Optional[_ShardCtx] = None):
         S = rngs.shape[0]
         MB = packed.shape[0] // S - 1
         tables, lens = _unpack_i32(packed, ((S, MB), (S,)))
-        toks, new_rngs = [], []
-        for s, (temp, top_k, top_p) in enumerate(cfgs):
-            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            new_rngs.append(rng)
+        with jax.named_scope("sample"):
+            toks, new_rngs = [], []
+            for s, (temp, top_k, top_p) in enumerate(cfgs):
+                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                new_rngs.append(rng)
         tok = jnp.stack(toks)  # [S]
         logits, vs = dm_paged.apply(
             {**params_only, "cache": cache}, tok[:, None],
@@ -985,6 +992,10 @@ class _SlotState:
     # restore uploads land, then flips to PREFILLING and streams its
     # uncached suffix like any other admission
     restoring: Optional[List[tuple]] = None
+    # tokens of this row the cache holds once every dispatched tick has
+    # run: advanced when a tick is planned (chunked engines; the mixed
+    # tick's work counters read it)
+    cursor: int = 0
     admit_seq: int = 0  # admission order: prefill budget is dealt FIFO
     admit_t: float = 0.0  # monotonic admission time (prefill span)
     # speculative decoding (engine.spec): the row's emitted-but-unfed
@@ -1020,11 +1031,16 @@ class _InflightTick:
     # per slot: None (idle at plan) | ("dec", st) | ("pre", st, take,
     # flipped) — flipped marks the prompt's last chunk landing
     rows: List[Optional[tuple]]
+    tick: int                       # the number this tick reconciles as
     plan_ms: float
-    dispatch_ms: float
+    upload_ms: float                # control-buffer transfer (or reuse)
+    dispatch_ms: float              # upload + the jitted call returning
     n_dec: int
     fed_tokens: int
     chunk: Optional[int]
+    # mixed ticks: what the dispatch computes against what was dealt
+    # (attended_tokens, key_positions, query_positions; see _tick_work)
+    work: Optional[dict] = None
     # multi-step decode: the window width this record dispatched (None
     # = ordinary one-token tick); ``acc`` doubles as its device [S]
     # per-row emitted counts
@@ -1034,6 +1050,56 @@ class _InflightTick:
     n_forced: Optional[np.ndarray] = None
     granted: Optional[np.ndarray] = None
     spec_set: Optional[set] = None
+
+
+class _Phase:
+    """One open bracket of :class:`_PhaseClock`; ``ms`` after exit."""
+
+    __slots__ = ("_clock", "_name", "_span", "_t0", "ms")
+
+    def __init__(self, clock, name, args):
+        self._clock, self._name = clock, name
+        self._span = jax.profiler.TraceAnnotation(clock.prefix + name, **args)
+        self.ms = 0.0
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._span.__exit__(*exc)
+        acc = self._clock.acc
+        acc[self._name] = acc.get(self._name, 0.0) + self.ms
+        return False
+
+
+class _PhaseClock:
+    """The one bracket the engine thread times its phases with:
+    ``with clock("plan", tick=n) as ph`` opens a
+    ``jax.profiler.TraceAnnotation("engine.plan", tick=n)`` — a span on
+    the engine thread's line of a profile, on the device trace's clock,
+    and nothing at all while no profiler session runs — and adds the
+    bracket's milliseconds (``ph.ms``) to the current period. A period
+    runs from the end of one tick's ``record`` to the end of the next
+    one's; :meth:`take` closes it and hands out what each phase took
+    in it, with the period's own length as ``loop``."""
+
+    def __init__(self, prefix: str):
+        self.prefix = prefix
+        self.acc: dict = {}
+        self._period_t0 = time.perf_counter()
+
+    def __call__(self, name: str, **args) -> _Phase:
+        return _Phase(self, name, args)
+
+    def take(self) -> dict:
+        now = time.perf_counter()
+        out, self.acc = self.acc, {}
+        out["loop"] = (now - self._period_t0) * 1e3
+        self._period_t0 = now
+        return out
 
 
 class ServingEngine:
@@ -1382,6 +1448,11 @@ class ServingEngine:
             )
         self._device = device if device is not None else jax.local_devices()[0]
         self._recompile_mark = recompiles.mark()
+        self._phase = _PhaseClock("engine.")
+        # what the mixed ticks computed against what they were dealt
+        self.attended_tokens_total = 0
+        self.query_positions_total = 0
+        self.useful_query_tokens_total = 0
         self._flight_ns = 0  # time spent building/recording snapshots
         self._tick_ns = 0    # total tick wall time (plan+device+stream)
         self.model = (model if max_len is None
@@ -1971,11 +2042,18 @@ class ServingEngine:
                     )
             raise
 
+    def _admit_phase(self) -> int:
+        with self._phase("admit", tick=self.ticks + 1):
+            return self._admit()
+
     def _step(self) -> bool:
-        self._drain_ctrl()
+        # ctrl, admit and idle brackets carry the number of the tick
+        # whose record closes the period they fall in
+        with self._phase("ctrl", tick=self.ticks + 1):
+            self._drain_ctrl()
         if self.pipeline:
             return self._pipelined_step()
-        n_prefills = self._admit()
+        n_prefills = self._admit_phase()
         occupied = any(st is not None for st in self._slots)
         if occupied:
             k = self._multi_gate()
@@ -1990,7 +2068,7 @@ class ServingEngine:
             # EOS'd / exhausted slots were freed while processing the
             # tick's tokens: refill them NOW so the next tick decodes
             # their replacement requests (same-tick refill)
-            n_prefills += self._admit()
+            n_prefills += self._admit_phase()
             if self.prefill_chunk is None:
                 # share of this step's device dispatches that were
                 # prefill passes (decode-latency pressure from arrival
@@ -2017,15 +2095,19 @@ class ServingEngine:
             defer: list = []
             while self._pending:
                 self._reconcile_spec(self._pending.popleft(), defer)
-            self._admit()
+            self._admit_phase()
             occupied = any(st is not None for st in self._slots)
             if occupied:
                 self._multi_gate()  # fallback accounting only ("spec")
                 self._pending.append(self._plan_dispatch_spec())
-            self._flush_emissions(defer)
+            if defer:
+                # the reconciled tick's tokens reach their consumers
+                # only now, inside the next tick's period
+                with self._phase("stream", tick=self.ticks, deferred=1):
+                    self._flush_emissions(defer)
             return (occupied or self.scheduler.depth() > 0
                     or bool(self._pending))
-        self._admit()
+        self._admit_phase()
         occupied = any(st is not None for st in self._slots)
         if occupied:
             k = self._multi_gate()
@@ -2050,7 +2132,8 @@ class ServingEngine:
         """Step until ``stop`` is set, dozing briefly when idle."""
         while not stop.is_set():
             if not self.step():
-                stop.wait(idle_sleep)
+                with self._phase("idle", tick=self.ticks + 1):
+                    stop.wait(idle_sleep)
 
     def drain(self, timeout: float = 120.0):
         """Step until queue and slots are empty (bench/test helper)."""
@@ -2526,7 +2609,7 @@ class ServingEngine:
         self._rngs = self._rngs.at[slot].set(jax.random.PRNGKey(req.seed))
         st = _SlotState(
             req=req, remaining=req.max_new_tokens, blocks=chain,
-            cached_tokens=cached,
+            cached_tokens=cached, cursor=cached,
             pending=np.asarray(req.prompt[cached:], np.int32),
             decoding=False, restoring=restoring or None,
             admit_seq=self._admit_seq, admit_t=now,
@@ -2852,90 +2935,107 @@ class ServingEngine:
         as idle — valid 0, no budget charge, RNG untouched; their
         restore uploads are issued here, BEFORE the tick's dispatch, so
         the transfer overlaps the in-flight compute."""
-        t_plan0 = time.perf_counter()
-        if self.host is not None:
-            self._issue_restores()
-        S = self.slots
-        cfgs = tuple(
-            (st.req.temperature, st.req.top_k, st.req.top_p)
-            if st else _IDLE_CFG
-            for st in self._slots
-        )
-        n_dec = sum(1 for st in self._slots if st and st.decoding)
-        pre = sorted(
-            ((s, st) for s, st in enumerate(self._slots)
-             if st and not st.decoding and st.restoring is None),
-            key=lambda p: p[1].admit_seq,
-        )
-        takes = self.scheduler.plan_prefill(
-            n_dec, [len(st.pending) for _, st in pre], self.prefill_chunk,
-            tiers=[st.req.tier for _, st in pre],
-        )
-        fed_tokens = sum(takes)
-        C = self.prefill_chunk if fed_tokens else 1
-        fed = np.zeros((S, C), np.int32)
-        valid = np.zeros((S,), np.int32)
-        sample_mask = np.zeros((S,), np.int32)
-        rows: List[Optional[tuple]] = [None] * S
-        for s, st in enumerate(self._slots):
-            if st is None:
-                # idle rows tick along like decoders (sampling greedily
-                # into the void at their parked cursor, as the unchunked
-                # tick always has)
-                valid[s] = 1
-                sample_mask[s] = 1
-            elif st.decoding:
-                valid[s] = 1
-                sample_mask[s] = 1
-                rows[s] = ("dec", st)
-            # else: PREFILLING rows are dealt below; RESTORING rows
-            # stay at valid 0 / sample 0 — the row writes nothing, its
-            # cursor holds at the cached span, and its RNG chain is
-            # untouched until its first real chunk
-        for (s, st), take in zip(pre, takes):
-            flipped = False
-            if take > 0:
-                fed[s, :take] = st.pending[:take]
-                valid[s] = take
-                st.pending = st.pending[take:]
-                if st.pending.size == 0:
-                    # last chunk dealt: this dispatch leaves the
-                    # prompt-final logits at the row's last valid token
-                    # — the NEXT tick samples its first token
-                    st.decoding = True
-                    flipped = True
-            # take == 0: starved this tick — valid stays 0, the row
-            # writes nothing and its cursor holds
-            rows[s] = ("pre", st, take, flipped)
-        if self.paged:
-            # REBIND, never mutate (aliasing hazard, see _decode_tick):
-            # live rows advance by what the dispatch consumes; idle rows
-            # stay parked at 0 on the trash block
-            adv = np.zeros((S,), np.int32)
-            for s, row in enumerate(rows):
-                if row is not None:
-                    adv[s] = 1 if row[0] == "dec" else valid[s]
-            packed = _pack_i32(self._block_tables, self._seq_lens, fed,
-                               valid, sample_mask)
-            self._seq_lens = self._seq_lens + adv
-        else:
-            packed = _pack_i32(fed, valid, sample_mask)
-        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        dev = self._upload(packed)
-        if self.paged:
-            tick = _paged_mixed_tick_fn(self._dm_paged, cfgs, C,
-                                        self._ctx)
-        else:
-            tick = _mixed_tick_fn(self._dm_slot, cfgs, C, self._ctx)
-        self._cache, self._last_logits, toks, self._rngs = tick(
-            self._params_only, self._cache, self._last_logits,
-            self._rngs, dev,
-        )
+        tick_no = self.ticks + len(self._pending) + 1
+        with self._phase("plan", tick=tick_no) as plan:
+            if self.host is not None:
+                self._issue_restores()
+            S = self.slots
+            cfgs = tuple(
+                (st.req.temperature, st.req.top_k, st.req.top_p)
+                if st else _IDLE_CFG
+                for st in self._slots
+            )
+            n_dec = sum(1 for st in self._slots if st and st.decoding)
+            pre = sorted(
+                ((s, st) for s, st in enumerate(self._slots)
+                 if st and not st.decoding and st.restoring is None),
+                key=lambda p: p[1].admit_seq,
+            )
+            takes = self.scheduler.plan_prefill(
+                n_dec, [len(st.pending) for _, st in pre], self.prefill_chunk,
+                tiers=[st.req.tier for _, st in pre],
+            )
+            fed_tokens = sum(takes)
+            C = self.prefill_chunk if fed_tokens else 1
+            fed = np.zeros((S, C), np.int32)
+            valid = np.zeros((S,), np.int32)
+            sample_mask = np.zeros((S,), np.int32)
+            rows: List[Optional[tuple]] = [None] * S
+            # work the model requires of this tick, against the S x C query
+            # positions the dispatch computes whatever was dealt: (query,
+            # key) pairs attended and K/V positions read, live rows only
+            attended = key_positions = 0
+            for s, st in enumerate(self._slots):
+                if st is None:
+                    # idle rows tick along like decoders (sampling greedily
+                    # into the void at their parked cursor, as the unchunked
+                    # tick always has)
+                    valid[s] = 1
+                    sample_mask[s] = 1
+                elif st.decoding:
+                    valid[s] = 1
+                    sample_mask[s] = 1
+                    rows[s] = ("dec", st)
+                    st.cursor += 1
+                    attended += st.cursor
+                    key_positions += st.cursor
+                # else: PREFILLING rows are dealt below; RESTORING rows
+                # stay at valid 0 / sample 0 — the row writes nothing, its
+                # cursor holds at the cached span, and its RNG chain is
+                # untouched until its first real chunk
+            for (s, st), take in zip(pre, takes):
+                flipped = False
+                if take > 0:
+                    fed[s, :take] = st.pending[:take]
+                    valid[s] = take
+                    st.pending = st.pending[take:]
+                    # query i of the chunk attends the cached span and the
+                    # chunk up to itself
+                    attended += take * st.cursor + take * (take + 1) // 2
+                    st.cursor += take
+                    key_positions += st.cursor
+                    if st.pending.size == 0:
+                        # last chunk dealt: this dispatch leaves the
+                        # prompt-final logits at the row's last valid token
+                        # — the NEXT tick samples its first token
+                        st.decoding = True
+                        flipped = True
+                # take == 0: starved this tick — valid stays 0, the row
+                # writes nothing and its cursor holds
+                rows[s] = ("pre", st, take, flipped)
+            if self.paged:
+                # REBIND, never mutate (aliasing hazard, see _decode_tick):
+                # live rows advance by what the dispatch consumes; idle rows
+                # stay parked at 0 on the trash block
+                adv = np.zeros((S,), np.int32)
+                for s, row in enumerate(rows):
+                    if row is not None:
+                        adv[s] = 1 if row[0] == "dec" else valid[s]
+                packed = _pack_i32(self._block_tables, self._seq_lens, fed,
+                                   valid, sample_mask)
+                self._seq_lens = self._seq_lens + adv
+            else:
+                packed = _pack_i32(fed, valid, sample_mask)
+        work = {"attended_tokens": attended,
+                "key_positions": key_positions, "query_positions": S * C}
+        with self._phase("upload", tick=tick_no) as upload:
+            dev = self._upload(packed)
+        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
+                         fed_tokens=fed_tokens, chunk=C,
+                         **work) as dispatch:
+            if self.paged:
+                tick = _paged_mixed_tick_fn(self._dm_paged, cfgs, C,
+                                            self._ctx)
+            else:
+                tick = _mixed_tick_fn(self._dm_slot, cfgs, C, self._ctx)
+            self._cache, self._last_logits, toks, self._rngs = tick(
+                self._params_only, self._cache, self._last_logits,
+                self._rngs, dev,
+            )
         return _InflightTick(
-            toks=toks, rows=rows, plan_ms=plan_ms,
-            dispatch_ms=(time.perf_counter() - t0) * 1e3,
-            n_dec=n_dec, fed_tokens=fed_tokens, chunk=C,
+            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
+            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
+            n_dec=n_dec, fed_tokens=fed_tokens, chunk=C, work=work,
         )
 
     def _reconcile(self, rec: _InflightTick):
@@ -2946,109 +3046,110 @@ class ServingEngine:
         EOS'd/exhausted rows, drop overrun tokens whose row finished in
         an earlier reconcile, and record telemetry + the flight
         snapshot."""
-        t_wait0 = time.perf_counter()
-        toks_host = np.asarray(rec.toks)  # forces completion of the tick
-        counts_host = (np.asarray(rec.acc) if rec.multi_k is not None
-                       else None)
-        wait_ms = (time.perf_counter() - t_wait0) * 1e3
-        t_stream0 = time.perf_counter()
-        self.ticks += 1
-        occupancy = sum(st is not None for st in self._slots)
-        self._occ_sum += occupancy
-        now = time.monotonic()
-        device_ms = rec.dispatch_ms + wait_ms
-        k = rec.multi_k or 1
-        # multi-step windows: one readback carries up to k tokens per
-        # row, each produced one scan step apart — attribute per-token
-        # timestamps across the window's device span so the per-tier
-        # ITL histograms see k gaps of ~device_ms/k, not one lump and
-        # k-1 zeros (no k-wide ITL spikes in the QoS stats)
-        step_s = (device_ms / 1e3) / k
-        window_t0 = now - (k - 1) * step_s
-        emitted = 0
-        overrun = 0
-        for s, row in enumerate(rec.rows):
-            if row is None:
-                continue
-            st = row[1]
-            if self._slots[s] is not st:
-                # late finish: this row's request completed while the
-                # tick was in flight (reconciled out of an earlier
-                # record) — its optimistically computed token is an
-                # overrun, dropped before any consumer sees it. RNG
-                # parity holds because the chain died with the request
-                # (the refill reseeds the slot's key).
-                if row[0] == "dec":
-                    overrun += (1 if counts_host is None
-                                else int(counts_host[s]))
-                continue
-            if row[0] == "pre":
-                if row[3]:  # the prompt's last chunk landed this tick
-                    req = st.req
-                    req.prefill_done_t = now
-                    prefill_ms = (now - st.admit_t) * 1e3
-                    self.tracer.record(
-                        req.trace_id, "prefill", st.admit_t,
-                        prefill_ms, slot=s,
-                        prompt_tokens=int(req.prompt.size),
-                        cached_tokens=st.cached_tokens,
-                        chunk=self.prefill_chunk,
-                        wv=self.weight_version,
-                    )
-                    self._m_prefill_ms.observe(prefill_ms)
-                continue
-            if counts_host is None:
-                e, _ = self._stream_row(s, st, [int(toks_host[s])], now)
-            else:
-                # the on-device stop mask already froze the row at its
-                # EOS (or at lim); n is exactly the tokens it emitted.
-                # _stream_row's own trim still applies — a pipelined
-                # window planned against a stale `remaining` can carry
-                # more device tokens than the row has budget left, the
-                # same optimism the late-EOS path drops — and the
-                # trimmed tail counts as overrun
-                n = int(counts_host[s])
-                times = [window_t0 + j * step_s for j in range(n)]
-                e, _ = self._stream_row(
-                    s, st, toks_host[s, :n].tolist(), now, times=times)
-                overrun += n - e
-            emitted += e
-        if overrun:
-            self.overrun_tokens += overrun
-            self._m_overrun.inc(overrun)
-        queue_depth = self.scheduler.depth()
-        self._m_ticks.inc()
-        self._m_tokens.inc(emitted)
-        self._m_occupancy.set(sum(st is not None for st in self._slots))
-        # serving_token_ms stays a PER-TOKEN series: a k-step window's
-        # device span covers k sampled tokens per live row
-        self._m_tick_ms.observe(device_ms / k)
-        self._m_device_wait.observe(wait_ms)
-        self.dispatches += 1
-        self._m_dispatches.inc()
-        self._m_tokens_per_dispatch.observe(emitted)
-        self._m_multi_k.set(k)
-        if rec.chunk is not None and rec.fed_tokens + rec.n_dec > 0:
-            self._m_prefill_frac.observe(
-                rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
-        if device_ms > 0:
-            self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
-        log_kw = ({"prefill_tokens": rec.fed_tokens}
-                  if rec.chunk is not None else {})
-        self.metrics.log(
-            step=self.ticks, occupancy=occupancy,
-            queue_depth=queue_depth,
-            token_ms=round(device_ms / k, 3), **log_kw,
-        )
+        with self._phase("wait", tick=rec.tick) as wait:
+            # forces completion of the tick
+            toks_host = np.asarray(rec.toks)
+            counts_host = (np.asarray(rec.acc) if rec.multi_k is not None
+                           else None)
+            # the device buffers are freed here, on the read's side of
+            # the boundary (a millisecond of this thread's time on a
+            # v5e, PR 25), not wherever the record happens to die
+            rec.toks = rec.acc = None
+        wait_ms = wait.ms
+        with self._phase("stream", tick=rec.tick) as stream:
+            self.ticks += 1
+            occupancy = sum(st is not None for st in self._slots)
+            self._occ_sum += occupancy
+            now = time.monotonic()
+            device_ms = rec.dispatch_ms + wait_ms
+            k = rec.multi_k or 1
+            # multi-step windows: one readback carries up to k tokens per
+            # row, each produced one scan step apart — attribute per-token
+            # timestamps across the window's device span so the per-tier
+            # ITL histograms see k gaps of ~device_ms/k, not one lump and
+            # k-1 zeros (no k-wide ITL spikes in the QoS stats)
+            step_s = (device_ms / 1e3) / k
+            window_t0 = now - (k - 1) * step_s
+            emitted = 0
+            overrun = 0
+            for s, row in enumerate(rec.rows):
+                if row is None:
+                    continue
+                st = row[1]
+                if self._slots[s] is not st:
+                    # late finish: this row's request completed while the
+                    # tick was in flight (reconciled out of an earlier
+                    # record) — its optimistically computed token is an
+                    # overrun, dropped before any consumer sees it. RNG
+                    # parity holds because the chain died with the request
+                    # (the refill reseeds the slot's key).
+                    if row[0] == "dec":
+                        overrun += (1 if counts_host is None
+                                    else int(counts_host[s]))
+                    continue
+                if row[0] == "pre":
+                    if row[3]:  # the prompt's last chunk landed this tick
+                        req = st.req
+                        req.prefill_done_t = now
+                        prefill_ms = (now - st.admit_t) * 1e3
+                        self.tracer.record(
+                            req.trace_id, "prefill", st.admit_t,
+                            prefill_ms, slot=s,
+                            prompt_tokens=int(req.prompt.size),
+                            cached_tokens=st.cached_tokens,
+                            chunk=self.prefill_chunk,
+                            wv=self.weight_version,
+                        )
+                        self._m_prefill_ms.observe(prefill_ms)
+                    continue
+                if counts_host is None:
+                    e, _ = self._stream_row(s, st, [int(toks_host[s])], now)
+                else:
+                    # the on-device stop mask already froze the row at its
+                    # EOS (or at lim); n is exactly the tokens it emitted.
+                    # _stream_row's own trim still applies — a pipelined
+                    # window planned against a stale `remaining` can carry
+                    # more device tokens than the row has budget left, the
+                    # same optimism the late-EOS path drops — and the
+                    # trimmed tail counts as overrun
+                    n = int(counts_host[s])
+                    times = [window_t0 + j * step_s for j in range(n)]
+                    e, _ = self._stream_row(
+                        s, st, toks_host[s, :n].tolist(), now, times=times)
+                    overrun += n - e
+                emitted += e
+            if overrun:
+                self.overrun_tokens += overrun
+                self._m_overrun.inc(overrun)
+            queue_depth = self.scheduler.depth()
+            self._m_ticks.inc()
+            self._m_tokens.inc(emitted)
+            self._m_occupancy.set(sum(st is not None for st in self._slots))
+            # serving_token_ms stays a PER-TOKEN series: a k-step window's
+            # device span covers k sampled tokens per live row
+            self._m_tick_ms.observe(device_ms / k)
+            self._m_device_wait.observe(wait_ms)
+            self.dispatches += 1
+            self._m_dispatches.inc()
+            self._m_tokens_per_dispatch.observe(emitted)
+            self._m_multi_k.set(k)
+            if rec.chunk is not None and rec.fed_tokens + rec.n_dec > 0:
+                self._m_prefill_frac.observe(
+                    rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
+            if device_ms > 0:
+                self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
+            log_kw = ({"prefill_tokens": rec.fed_tokens}
+                      if rec.chunk is not None else {})
+            self.metrics.log(
+                step=self.ticks, occupancy=occupancy,
+                queue_depth=queue_depth,
+                token_ms=round(device_ms / k, 3), **log_kw,
+            )
         self._record_tick(
-            plan_ms=rec.plan_ms, device_ms=device_ms,
-            stream_ms=(time.perf_counter() - t_stream0) * 1e3,
-            n_dec=rec.n_dec, prefill_tokens=rec.fed_tokens,
-            chunk=rec.chunk,
+            rec, device_ms=device_ms, stream_ms=stream.ms,
             emitted=emitted, occupancy=occupancy,
-            queue_depth=queue_depth,
-            device_wait_ms=wait_ms, dispatch_ms=rec.dispatch_ms,
-            overrun=overrun, multi_k=rec.multi_k,
+            queue_depth=queue_depth, device_wait_ms=wait_ms,
+            overrun=overrun,
         )
 
     def _stream_row(self, s: int, st: _SlotState, toks_row, now,
@@ -3204,108 +3305,113 @@ class ServingEngine:
         non-speculative mixed tick. Host-tier restore uploads are
         issued first, same as the plain mixed plan; RESTORING rows are
         planned idle."""
-        t_plan0 = time.perf_counter()
-        if self.host is not None:
-            self._issue_restores()
-        S, k = self.slots, self.spec_k
-        cfgs = tuple(
-            (st.req.temperature, st.req.top_k, st.req.top_p)
-            if st else _IDLE_CFG
-            for st in self._slots
-        )
-        pre = sorted(
-            ((s, st) for s, st in enumerate(self._slots)
-             if st and not st.decoding and st.restoring is None),
-            key=lambda p: p[1].admit_seq,
-        )
-        dec = [(s, st) for s, st in enumerate(self._slots)
-               if st and st.decoding]
-        # rows eligible to speculate: a host-known pending token, room
-        # for at least one draft, and a drafter able to propose (the
-        # n-gram index found a match / the draft model is caught up)
-        spec_rows, want = [], []
-        ngram_toks = {}
-        for s, st in dec:
-            if st.pending_tok is None:
-                continue  # transition row: samples its first token
-            w = min(k, st.remaining - 1)
-            if self.draft_kind == "ngram":
-                toks, found = _ngram_propose(st.history, k,
-                                             self.ngram_max)
-                ngram_toks[s] = toks
-                w = min(w, found)
-            elif st.draft_queue is not None and st.draft_queue.size > 2:
-                w = 0  # draft still consuming the prompt
-            if w > 0:
-                spec_rows.append((s, st))
-                want.append(w)
-        spec_set = {s for s, _ in spec_rows}
-        takes, widths = self.scheduler.plan_spec(
-            len(dec), [len(st.pending) for _, st in pre],
-            self.prefill_chunk, want,
-            tiers=[st.req.tier for _, st in pre],
-        )
-        fed_tokens = sum(takes)
-        W = max(self.prefill_chunk, k + 1) if fed_tokens else k + 1
-        fed = np.zeros((S, W), np.int32)
-        valid = np.zeros((S,), np.int32)
-        n_forced = np.zeros((S,), np.int32)
-        sample_mask = np.zeros((S,), np.int32)
-        draft_np = np.zeros((S, k), np.int32)
-        granted = np.zeros((S,), np.int32)
-        rows: List[Optional[tuple]] = [None] * S
-        for s, st in dec:
-            sample_mask[s] = 1
-            rows[s] = ("dec", st)
-            if st.pending_tok is not None:
-                fed[s, 0] = st.pending_tok
-                n_forced[s] = 1
-                valid[s] = 1
-        for (s, st), w in zip(spec_rows, widths):
-            valid[s] = 1 + w
-            granted[s] = w
-            if self.draft_kind == "ngram":
-                draft_np[s] = ngram_toks[s]
-        for (s, st), take in zip(pre, takes):
-            flipped = False
-            if take > 0:
-                fed[s, :take] = st.pending[:take]
-                valid[s] = take
-                n_forced[s] = take
-                st.pending = st.pending[take:]
-                if st.pending.size == 0:
-                    # last chunk dealt: the next tick is this row's
-                    # transition tick (samples its first token, which
-                    # becomes the pending token)
-                    st.decoding = True
-                    flipped = True
-            rows[s] = ("pre", st, take, flipped)
-        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        if self.draft_kind == "model":
-            q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
-        else:
-            q_probs = jnp.zeros((1,), jnp.float32)
-            draft_dev = jnp.asarray(draft_np)
-        onehot = self.draft_kind == "ngram"
-        if self.paged:
-            packed = _pack_i32(self._block_tables, self._seq_lens, fed,
-                               valid, n_forced, sample_mask)
-            tick = _paged_spec_verify_fn(self._dm_paged, cfgs, W, k,
-                                         onehot, self._ctx)
-        else:
-            packed = _pack_i32(fed, valid, n_forced, sample_mask)
-            tick = _spec_verify_fn(self._dm_slot, cfgs, W, k, onehot,
-                                   self._ctx)
-        dev = self._upload(packed)
-        (self._cache, self._last_logits, toks, acc,
-         self._rngs) = tick(
-            self._params_only, self._cache, self._last_logits,
-            self._rngs, dev, draft_dev, q_probs,
-        )
+        tick_no = self.ticks + len(self._pending) + 1
+        with self._phase("plan", tick=tick_no) as plan:
+            if self.host is not None:
+                self._issue_restores()
+            S, k = self.slots, self.spec_k
+            cfgs = tuple(
+                (st.req.temperature, st.req.top_k, st.req.top_p)
+                if st else _IDLE_CFG
+                for st in self._slots
+            )
+            pre = sorted(
+                ((s, st) for s, st in enumerate(self._slots)
+                 if st and not st.decoding and st.restoring is None),
+                key=lambda p: p[1].admit_seq,
+            )
+            dec = [(s, st) for s, st in enumerate(self._slots)
+                   if st and st.decoding]
+            # rows eligible to speculate: a host-known pending token, room
+            # for at least one draft, and a drafter able to propose (the
+            # n-gram index found a match / the draft model is caught up)
+            spec_rows, want = [], []
+            ngram_toks = {}
+            for s, st in dec:
+                if st.pending_tok is None:
+                    continue  # transition row: samples its first token
+                w = min(k, st.remaining - 1)
+                if self.draft_kind == "ngram":
+                    toks, found = _ngram_propose(st.history, k,
+                                                 self.ngram_max)
+                    ngram_toks[s] = toks
+                    w = min(w, found)
+                elif st.draft_queue is not None and st.draft_queue.size > 2:
+                    w = 0  # draft still consuming the prompt
+                if w > 0:
+                    spec_rows.append((s, st))
+                    want.append(w)
+            spec_set = {s for s, _ in spec_rows}
+            takes, widths = self.scheduler.plan_spec(
+                len(dec), [len(st.pending) for _, st in pre],
+                self.prefill_chunk, want,
+                tiers=[st.req.tier for _, st in pre],
+            )
+            fed_tokens = sum(takes)
+            W = max(self.prefill_chunk, k + 1) if fed_tokens else k + 1
+            fed = np.zeros((S, W), np.int32)
+            valid = np.zeros((S,), np.int32)
+            n_forced = np.zeros((S,), np.int32)
+            sample_mask = np.zeros((S,), np.int32)
+            draft_np = np.zeros((S, k), np.int32)
+            granted = np.zeros((S,), np.int32)
+            rows: List[Optional[tuple]] = [None] * S
+            for s, st in dec:
+                sample_mask[s] = 1
+                rows[s] = ("dec", st)
+                if st.pending_tok is not None:
+                    fed[s, 0] = st.pending_tok
+                    n_forced[s] = 1
+                    valid[s] = 1
+            for (s, st), w in zip(spec_rows, widths):
+                valid[s] = 1 + w
+                granted[s] = w
+                if self.draft_kind == "ngram":
+                    draft_np[s] = ngram_toks[s]
+            for (s, st), take in zip(pre, takes):
+                flipped = False
+                if take > 0:
+                    fed[s, :take] = st.pending[:take]
+                    valid[s] = take
+                    n_forced[s] = take
+                    st.pending = st.pending[take:]
+                    if st.pending.size == 0:
+                        # last chunk dealt: the next tick is this row's
+                        # transition tick (samples its first token, which
+                        # becomes the pending token)
+                        st.decoding = True
+                        flipped = True
+                rows[s] = ("pre", st, take, flipped)
+            if self.paged:
+                packed = _pack_i32(self._block_tables, self._seq_lens,
+                                   fed, valid, n_forced, sample_mask)
+            else:
+                packed = _pack_i32(fed, valid, n_forced, sample_mask)
+        with self._phase("upload", tick=tick_no) as upload:
+            dev = self._upload(packed)
+            if self.draft_kind != "model":
+                draft_dev = jnp.asarray(draft_np)
+        with self._phase("dispatch", tick=tick_no, n_dec=len(dec),
+                         fed_tokens=fed_tokens, chunk=W) as dispatch:
+            if self.draft_kind == "model":
+                q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
+            else:
+                q_probs = jnp.zeros((1,), jnp.float32)
+            onehot = self.draft_kind == "ngram"
+            if self.paged:
+                tick = _paged_spec_verify_fn(self._dm_paged, cfgs, W, k,
+                                             onehot, self._ctx)
+            else:
+                tick = _spec_verify_fn(self._dm_slot, cfgs, W, k, onehot,
+                                       self._ctx)
+            (self._cache, self._last_logits, toks, acc,
+             self._rngs) = tick(
+                self._params_only, self._cache, self._last_logits,
+                self._rngs, dev, draft_dev, q_probs,
+            )
         return _InflightTick(
-            toks=toks, rows=rows, plan_ms=plan_ms,
-            dispatch_ms=(time.perf_counter() - t0) * 1e3,
+            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
+            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
             n_dec=len(dec), fed_tokens=fed_tokens, chunk=W,
             acc=acc, n_forced=n_forced, granted=granted,
             spec_set=spec_set,
@@ -3322,107 +3428,105 @@ class ServingEngine:
         flushed after the NEXT dispatch; all scheduling state still
         settles here."""
         k = self.spec_k
-        t_wait0 = time.perf_counter()
-        toks_host = np.asarray(rec.toks)  # forces completion of the tick
-        acc_host = np.asarray(rec.acc)
-        wait_ms = (time.perf_counter() - t_wait0) * 1e3
-        if self.paged:
-            # REBIND, never mutate (aliasing hazard, see _decode_tick):
-            # each row keeps only its forced tokens plus the accepted
-            # prefix — the rejected-suffix rollback IS this arithmetic
-            self._seq_lens = self._seq_lens + (
-                rec.n_forced + acc_host).astype(np.int32)
-        t_stream0 = time.perf_counter()
-        self.ticks += 1
-        occupancy = sum(st is not None for st in self._slots)
-        self._occ_sum += occupancy
-        now = time.monotonic()
-        emitted = 0
-        proposed = int(rec.granted.sum())
-        accepted = 0
-        for s, row in enumerate(rec.rows):
-            if row is None:
-                continue
-            st = row[1]
-            if self._slots[s] is not st:
-                continue  # late finish (cannot happen at depth 1)
-            if row[0] == "pre":
-                if row[3]:
-                    req = st.req
-                    req.prefill_done_t = now
-                    prefill_ms = (now - st.admit_t) * 1e3
-                    self.tracer.record(
-                        req.trace_id, "prefill", st.admit_t,
-                        prefill_ms, slot=s,
-                        prompt_tokens=int(req.prompt.size),
-                        cached_tokens=st.cached_tokens,
-                        chunk=self.prefill_chunk,
-                        wv=self.weight_version,
-                    )
-                    self._m_prefill_ms.observe(prefill_ms)
-                continue
-            a = int(acc_host[s])
-            if rec.granted[s] > 0:
-                accepted += a
-                self._m_accept_len.observe(a)
-            toks_row = [int(t) for t in toks_host[s, :a + 1]]
-            e, done = self._stream_row(s, st, toks_row, now, defer)
-            emitted += e
-            if done:
-                continue
-            st.pending_tok = toks_row[-1]
-            if st.history is not None:
-                st.history = np.concatenate(
-                    [st.history, np.asarray(toks_row, np.int32)])
-            if self.draft_kind == "model":
-                lag = []
-                if s in rec.spec_set and a == k:
-                    # every proposal survived: the k-th was accepted
-                    # but never fed to the draft (only d_1..d_{k-1}
-                    # were) — it precedes the extra token in the queue
-                    lag.append(int(toks_host[s, k - 1]))
-                lag.append(st.pending_tok)
-                lag_np = np.asarray(lag, np.int32)
-                st.draft_queue = (
-                    np.concatenate([st.draft_queue, lag_np])
-                    if st.draft_queue.size else lag_np)
-                if s in rec.spec_set:
-                    st.draft_rewind = max(k - 1 - a, 0)
-        self.draft_tokens_proposed += proposed
-        self.draft_tokens_accepted += accepted
-        self._m_draft_tokens.inc(proposed)
-        self._m_accepted_tokens.inc(accepted)
-        queue_depth = self.scheduler.depth()
-        device_ms = rec.dispatch_ms + wait_ms
-        self._m_ticks.inc()
-        self._m_tokens.inc(emitted)
-        self._m_occupancy.set(sum(st is not None for st in self._slots))
-        self._m_tick_ms.observe(device_ms)
-        self._m_device_wait.observe(wait_ms)
-        self.dispatches += 1
-        self._m_dispatches.inc()
-        self._m_tokens_per_dispatch.observe(emitted)
-        if rec.fed_tokens + rec.n_dec > 0:
-            self._m_prefill_frac.observe(
-                rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
-        if device_ms > 0:
-            self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
-        self.metrics.log(
-            step=self.ticks, occupancy=occupancy,
-            queue_depth=queue_depth,
-            token_ms=round(device_ms, 3),
-            prefill_tokens=rec.fed_tokens,
-            draft_tokens=proposed, accepted_tokens=accepted,
-        )
+        with self._phase("wait", tick=rec.tick) as wait:
+            # forces completion of the tick
+            toks_host = np.asarray(rec.toks)
+            acc_host = np.asarray(rec.acc)
+            rec.toks = rec.acc = None  # freed here, as in _reconcile
+        wait_ms = wait.ms
+        with self._phase("stream", tick=rec.tick) as stream:
+            if self.paged:
+                # REBIND, never mutate (aliasing hazard, see _decode_tick):
+                # each row keeps only its forced tokens plus the accepted
+                # prefix — the rejected-suffix rollback IS this arithmetic
+                self._seq_lens = self._seq_lens + (
+                    rec.n_forced + acc_host).astype(np.int32)
+            self.ticks += 1
+            occupancy = sum(st is not None for st in self._slots)
+            self._occ_sum += occupancy
+            now = time.monotonic()
+            emitted = 0
+            proposed = int(rec.granted.sum())
+            accepted = 0
+            for s, row in enumerate(rec.rows):
+                if row is None:
+                    continue
+                st = row[1]
+                if self._slots[s] is not st:
+                    continue  # late finish (cannot happen at depth 1)
+                if row[0] == "pre":
+                    if row[3]:
+                        req = st.req
+                        req.prefill_done_t = now
+                        prefill_ms = (now - st.admit_t) * 1e3
+                        self.tracer.record(
+                            req.trace_id, "prefill", st.admit_t,
+                            prefill_ms, slot=s,
+                            prompt_tokens=int(req.prompt.size),
+                            cached_tokens=st.cached_tokens,
+                            chunk=self.prefill_chunk,
+                            wv=self.weight_version,
+                        )
+                        self._m_prefill_ms.observe(prefill_ms)
+                    continue
+                a = int(acc_host[s])
+                if rec.granted[s] > 0:
+                    accepted += a
+                    self._m_accept_len.observe(a)
+                toks_row = [int(t) for t in toks_host[s, :a + 1]]
+                e, done = self._stream_row(s, st, toks_row, now, defer)
+                emitted += e
+                if done:
+                    continue
+                st.pending_tok = toks_row[-1]
+                if st.history is not None:
+                    st.history = np.concatenate(
+                        [st.history, np.asarray(toks_row, np.int32)])
+                if self.draft_kind == "model":
+                    lag = []
+                    if s in rec.spec_set and a == k:
+                        # every proposal survived: the k-th was accepted
+                        # but never fed to the draft (only d_1..d_{k-1}
+                        # were) — it precedes the extra token in the queue
+                        lag.append(int(toks_host[s, k - 1]))
+                    lag.append(st.pending_tok)
+                    lag_np = np.asarray(lag, np.int32)
+                    st.draft_queue = (
+                        np.concatenate([st.draft_queue, lag_np])
+                        if st.draft_queue.size else lag_np)
+                    if s in rec.spec_set:
+                        st.draft_rewind = max(k - 1 - a, 0)
+            self.draft_tokens_proposed += proposed
+            self.draft_tokens_accepted += accepted
+            self._m_draft_tokens.inc(proposed)
+            self._m_accepted_tokens.inc(accepted)
+            queue_depth = self.scheduler.depth()
+            device_ms = rec.dispatch_ms + wait_ms
+            self._m_ticks.inc()
+            self._m_tokens.inc(emitted)
+            self._m_occupancy.set(sum(st is not None for st in self._slots))
+            self._m_tick_ms.observe(device_ms)
+            self._m_device_wait.observe(wait_ms)
+            self.dispatches += 1
+            self._m_dispatches.inc()
+            self._m_tokens_per_dispatch.observe(emitted)
+            if rec.fed_tokens + rec.n_dec > 0:
+                self._m_prefill_frac.observe(
+                    rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
+            if device_ms > 0:
+                self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
+            self.metrics.log(
+                step=self.ticks, occupancy=occupancy,
+                queue_depth=queue_depth,
+                token_ms=round(device_ms, 3),
+                prefill_tokens=rec.fed_tokens,
+                draft_tokens=proposed, accepted_tokens=accepted,
+            )
         self._record_tick(
-            plan_ms=rec.plan_ms, device_ms=device_ms,
-            stream_ms=(time.perf_counter() - t_stream0) * 1e3,
-            n_dec=rec.n_dec, prefill_tokens=rec.fed_tokens,
-            chunk=rec.chunk,
+            rec, device_ms=device_ms, stream_ms=stream.ms,
             emitted=emitted, occupancy=occupancy,
-            queue_depth=queue_depth,
+            queue_depth=queue_depth, device_wait_ms=wait_ms,
             draft_tokens=proposed, accepted_tokens=accepted,
-            device_wait_ms=wait_ms, dispatch_ms=rec.dispatch_ms,
         )
 
     def _decode_tick(self):
@@ -3431,45 +3535,51 @@ class ServingEngine:
         self._reconcile(self._plan_dispatch_decode())
 
     def _plan_dispatch_decode(self) -> _InflightTick:
-        t_plan0 = time.perf_counter()
-        cfgs = tuple(
-            (st.req.temperature, st.req.top_k, st.req.top_p)
-            if st else _IDLE_CFG
-            for st in self._slots
-        )
-        rows: List[Optional[tuple]] = [
-            ("dec", st) if st is not None else None
-            for st in self._slots
-        ]
-        n_dec = sum(1 for r in rows if r is not None)
-        if self.paged:
-            # the tick writes each live row's K/V at its cursor; advance
-            # the host-owned cursors (idle rows stay parked at 0 on the
-            # trash block). REBIND, never mutate: jnp.asarray can alias
-            # the numpy buffer zero-copy while the async tick still
-            # reads it — in-place writes would race the device
-            packed = _pack_i32(self._block_tables, self._seq_lens)
-            alive = np.fromiter(
-                (st is not None for st in self._slots), bool, self.slots
+        tick_no = self.ticks + len(self._pending) + 1
+        with self._phase("plan", tick=tick_no) as plan:
+            cfgs = tuple(
+                (st.req.temperature, st.req.top_k, st.req.top_p)
+                if st else _IDLE_CFG
+                for st in self._slots
             )
-            self._seq_lens = self._seq_lens + alive.astype(np.int32)
-        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        if self.paged:
-            tick = _paged_tick_fn(self._dm_paged, cfgs, self._ctx)
-            self._cache, self._last_logits, toks, self._rngs = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs, self._upload(packed),
-            )
-        else:
-            tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-            self._cache, self._last_logits, toks, self._rngs = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs
-            )
+            rows: List[Optional[tuple]] = [
+                ("dec", st) if st is not None else None
+                for st in self._slots
+            ]
+            n_dec = sum(1 for r in rows if r is not None)
+            if self.paged:
+                # the tick writes each live row's K/V at its cursor;
+                # advance the host-owned cursors (idle rows stay parked
+                # at 0 on the trash block). REBIND, never mutate:
+                # jnp.asarray can alias the numpy buffer zero-copy while
+                # the async tick still reads it — in-place writes would
+                # race the device
+                packed = _pack_i32(self._block_tables, self._seq_lens)
+                alive = np.fromiter(
+                    (st is not None for st in self._slots), bool,
+                    self.slots
+                )
+                self._seq_lens = self._seq_lens + alive.astype(np.int32)
+        with self._phase("upload", tick=tick_no) as upload:
+            # the slot tick takes no control buffer at all
+            dev = self._upload(packed) if self.paged else None
+        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
+                         fed_tokens=0) as dispatch:
+            if self.paged:
+                tick = _paged_tick_fn(self._dm_paged, cfgs, self._ctx)
+                self._cache, self._last_logits, toks, self._rngs = tick(
+                    self._params_only, self._cache, self._last_logits,
+                    self._rngs, dev,
+                )
+            else:
+                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
+                self._cache, self._last_logits, toks, self._rngs = tick(
+                    self._params_only, self._cache, self._last_logits,
+                    self._rngs
+                )
         return _InflightTick(
-            toks=toks, rows=rows, plan_ms=plan_ms,
-            dispatch_ms=(time.perf_counter() - t0) * 1e3,
+            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
+            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
             n_dec=n_dec, fed_tokens=0, chunk=None,
         )
 
@@ -3528,47 +3638,53 @@ class ServingEngine:
         are the only stop reasons — where :meth:`_complete` returns its
         whole block chain to the pool and zeroes its cursor in the same
         reconcile, the PR-7 worst-case-rollback discipline."""
-        t_plan0 = time.perf_counter()
-        S = self.slots
-        cfgs = tuple(
-            (st.req.temperature, st.req.top_k, st.req.top_p)
-            if st else _IDLE_CFG
-            for st in self._slots
-        )
-        rows: List[Optional[tuple]] = [
-            ("dec", st) if st is not None else None
-            for st in self._slots
-        ]
-        n_dec = sum(1 for r in rows if r is not None)
-        eos = np.full((S,), -1, np.int32)
-        lim = np.zeros((S,), np.int32)
-        for s, st in enumerate(self._slots):
-            if st is None:
-                continue
-            if st.req.eos_id is not None:
-                eos[s] = st.req.eos_id
-            lim[s] = min(k, st.remaining)
-        if self.paged:
-            packed = _pack_i32(self._block_tables, self._seq_lens,
-                               eos, lim)
-            # REBIND, never mutate (aliasing hazard, see _decode_tick)
-            self._seq_lens = self._seq_lens + lim
-            tick = _paged_multi_tick_fn(self._dm_paged, cfgs, k,
-                                        self._ctx)
-        else:
-            packed = _pack_i32(eos, lim)
-            tick = _multi_tick_fn(self._dm_slot, cfgs, k, self._ctx)
-        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        dev = self._upload(packed)
-        (self._cache, self._last_logits, toks, counts,
-         self._rngs) = tick(
-            self._params_only, self._cache, self._last_logits,
-            self._rngs, dev,
-        )
+        tick_no = self.ticks + len(self._pending) + 1
+        with self._phase("plan", tick=tick_no) as plan:
+            S = self.slots
+            cfgs = tuple(
+                (st.req.temperature, st.req.top_k, st.req.top_p)
+                if st else _IDLE_CFG
+                for st in self._slots
+            )
+            rows: List[Optional[tuple]] = [
+                ("dec", st) if st is not None else None
+                for st in self._slots
+            ]
+            n_dec = sum(1 for r in rows if r is not None)
+            eos = np.full((S,), -1, np.int32)
+            lim = np.zeros((S,), np.int32)
+            for s, st in enumerate(self._slots):
+                if st is None:
+                    continue
+                if st.req.eos_id is not None:
+                    eos[s] = st.req.eos_id
+                lim[s] = min(k, st.remaining)
+                # a row that stops short of lim completes at this
+                # window's reconcile: nothing reads its cursor again
+                st.cursor += int(lim[s])
+            if self.paged:
+                packed = _pack_i32(self._block_tables, self._seq_lens,
+                                   eos, lim)
+                # REBIND, never mutate (aliasing hazard, see
+                # _decode_tick)
+                self._seq_lens = self._seq_lens + lim
+                tick = _paged_multi_tick_fn(self._dm_paged, cfgs, k,
+                                            self._ctx)
+            else:
+                packed = _pack_i32(eos, lim)
+                tick = _multi_tick_fn(self._dm_slot, cfgs, k, self._ctx)
+        with self._phase("upload", tick=tick_no) as upload:
+            dev = self._upload(packed)
+        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
+                         fed_tokens=0, multi_k=k) as dispatch:
+            (self._cache, self._last_logits, toks, counts,
+             self._rngs) = tick(
+                self._params_only, self._cache, self._last_logits,
+                self._rngs, dev,
+            )
         return _InflightTick(
-            toks=toks, rows=rows, plan_ms=plan_ms,
-            dispatch_ms=(time.perf_counter() - t0) * 1e3,
+            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
+            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
             n_dec=n_dec, fed_tokens=0, chunk=None,
             multi_k=k, acc=counts,
         )
@@ -3711,122 +3827,148 @@ class ServingEngine:
                 self._m_device_peak.set(self._mem.device_peak_bytes)
         return self._mem.summary()
 
-    def _record_tick(self, *, plan_ms: float, device_ms: float,
-                     stream_ms: float, n_dec: int, prefill_tokens: int,
-                     chunk: Optional[int], emitted: int, occupancy: int,
-                     queue_depth: int,
+    def _record_tick(self, rec: _InflightTick, *, device_ms: float,
+                     stream_ms: float, emitted: int, occupancy: int,
+                     queue_depth: int, device_wait_ms: float,
                      draft_tokens: Optional[int] = None,
                      accepted_tokens: Optional[int] = None,
-                     device_wait_ms: Optional[float] = None,
-                     dispatch_ms: Optional[float] = None,
-                     overrun: int = 0,
-                     multi_k: Optional[int] = None):
-        """Post-tick runtime introspection + the flight snapshot. The
-        whole call is self-timed against tick wall time —
+                     overrun: int = 0):
+        """Post-tick runtime introspection + the flight snapshot — the
+        ``record`` phase, which closes the tick's period: the snapshot
+        gets what every phase took in it. The snapshot build is
+        self-timed against tick wall time —
         ``stats()["flight"]["overhead_frac"]`` is that ratio, and
         ``serve_bench --smoke`` asserts it stays under 5%."""
-        self._tick_ns += int((plan_ms + device_ms + stream_ms) * 1e6)
-        # runtime introspection runs with or without a recorder (the
-        # gauges are its output); only the snapshot build + ring append
-        # below counts as flight-recorder overhead
-        rec_total = recompiles.total()
-        oldest = self.scheduler.oldest_age_s()
-        sample_tick = self.ticks % self.MEM_SAMPLE_EVERY == 1
-        if sample_tick:
-            # gauge refreshes ride the slow cadence: SLO polls are
-            # ~1 s apart and ticks are ~ms, so a 32-tick-stale gauge
-            # is fresh to every scraper — and the hot path stays lean
-            mem = self._sample_memory()
-            self._m_recompiles.set(rec_total)
-            self._m_oldest_wait.set(round(oldest, 3))
-        else:
-            mem = None
-        # device-compute attribution: split this tick's device time
-        # evenly over the rows that were active — summed per request
-        # into the critical-path "device" phase (a finished row freed
-        # earlier in this step misses its final share; attribution,
-        # not accounting)
-        if device_ms > 0.0:
-            live = [st for st in self._slots if st is not None]
-            if live:
-                share = device_ms / len(live)
-                for st in live:
-                    st.req.device_ms_accum += share
-        t0 = time.perf_counter_ns()
-        if self.flight is not None:
-            # one flat dict, no rounding: this runs every tick and the
-            # smoke bound is 5% of a ~1 ms CPU tick — formatting is the
-            # renderer's job, not the hot path's
-            snap = {
-                "kind": "tick", "tick": self.ticks,
-                "t": time.monotonic(),
-                "tick_ms": plan_ms + device_ms + stream_ms,
-                "plan_ms": plan_ms, "device_ms": device_ms,
-                "stream_ms": stream_ms,
-                "occupancy": occupancy, "queue_depth": queue_depth,
-                "queue_oldest_wait_s": oldest,
-                # per-tier backlog: a postmortem can show the batch
-                # queue absorbing an overload while interactive stays
-                # shallow (the QoS degradation order, as it happened)
-                "qos_depth": self.scheduler.depth_by_tier(),
-                "budget_limit": self.scheduler.tick_token_budget,
-                "decode_tokens": n_dec,
-                "prefill_tokens": prefill_tokens, "chunk": chunk,
-                "emitted": emitted,
-                "slots": self._slot_snaps(),
-                "recompiles": rec_total,
-                # the weight set this tick served: a swap between two
-                # snapshots is visible as the version stepping (the
-                # report renderer's w=vN column)
-                "weight_version": self.weight_version,
-            }
-            if multi_k is not None:
-                # multi-step window: this one dispatch carried up to
-                # multi_k decode steps per row (report's k= column)
-                snap["multi_k"] = multi_k
-            if device_wait_ms is not None:
-                # overlap decomposition: device_ms = dispatch_ms (host
-                # side of the jitted call) + device_wait_ms (time
-                # BLOCKED on readback — what pipelining exists to
-                # shrink); pipeline_depth is the ticks still in flight
-                # after this reconcile, overrun the dropped late-finish
-                # tokens
-                snap["device_wait_ms"] = device_wait_ms
-                snap["dispatch_ms"] = dispatch_ms
-            if self.pipeline:
-                snap["pipeline_depth"] = len(self._pending)
-                snap["overrun_tokens"] = overrun
-            if draft_tokens is not None:
-                # speculative ticks: proposals entering this tick's
-                # verify windows and how many survived rejection
-                snap["draft_tokens"] = draft_tokens
-                snap["accepted_tokens"] = accepted_tokens
-            if mem is not None:
-                snap["mem"] = mem
-            if self.paged:
-                # cheap counts every tick; the live/cached refcount
-                # decomposition only on sample ticks (numpy scan)
-                snap["blocks"] = (self.pool.stats() if sample_tick
-                                  else {"in_use": self.pool.in_use_count(),
-                                        "free": self.pool.free_count()})
-                snap["prefix_hit_tokens"] = self.prefix_hit_tokens
-                if self.host is not None:
-                    # tiered KV cache: per-tick swap activity + the
-                    # host pool's current footprint
-                    snap["demoted"] = self._tick_demoted
-                    snap["restored"] = self._tick_restored
-                    snap["host_blocks"] = self.host.count()
-                if self._tick_exported or self._tick_imported:
-                    # KV-block migration: blocks exported/imported by
-                    # control calls serviced since the previous tick
-                    snap["kv_exported"] = self._tick_exported
-                    snap["kv_imported"] = self._tick_imported
+        plan_ms = rec.plan_ms
+        with self._phase("record", tick=rec.tick):
+            self._tick_ns += int((plan_ms + device_ms + stream_ms) * 1e6)
+            # runtime introspection runs with or without a recorder (the
+            # gauges are its output); only the snapshot build + ring append
+            # below counts as flight-recorder overhead
+            rec_total = recompiles.total()
+            oldest = self.scheduler.oldest_age_s()
+            sample_tick = self.ticks % self.MEM_SAMPLE_EVERY == 1
+            if sample_tick:
+                # gauge refreshes ride the slow cadence: SLO polls are
+                # ~1 s apart and ticks are ~ms, so a 32-tick-stale gauge
+                # is fresh to every scraper — and the hot path stays lean
+                mem = self._sample_memory()
+                self._m_recompiles.set(rec_total)
+                self._m_oldest_wait.set(round(oldest, 3))
+            else:
+                mem = None
+            # device-compute attribution: split this tick's device time
+            # evenly over the rows that were active — summed per request
+            # into the critical-path "device" phase (a finished row freed
+            # earlier in this step misses its final share; attribution,
+            # not accounting)
+            if device_ms > 0.0:
+                live = [st for st in self._slots if st is not None]
+                if live:
+                    share = device_ms / len(live)
+                    for st in live:
+                        st.req.device_ms_accum += share
+            snap = None
+            t0 = time.perf_counter_ns()
+            if self.flight is not None:
+                # one flat dict, no rounding: this runs every tick and the
+                # smoke bound is 5% of a ~1 ms CPU tick — formatting is the
+                # renderer's job, not the hot path's
+                snap = {
+                    "kind": "tick", "tick": self.ticks,
+                    "t": time.monotonic(),
+                    "tick_ms": plan_ms + device_ms + stream_ms,
+                    "plan_ms": plan_ms, "device_ms": device_ms,
+                    "stream_ms": stream_ms,
+                    "occupancy": occupancy, "queue_depth": queue_depth,
+                    "queue_oldest_wait_s": oldest,
+                    # per-tier backlog: a postmortem can show the batch
+                    # queue absorbing an overload while interactive stays
+                    # shallow (the QoS degradation order, as it happened)
+                    "qos_depth": self.scheduler.depth_by_tier(),
+                    "budget_limit": self.scheduler.tick_token_budget,
+                    "decode_tokens": rec.n_dec,
+                    "prefill_tokens": rec.fed_tokens, "chunk": rec.chunk,
+                    "emitted": emitted,
+                    "slots": self._slot_snaps(),
+                    "recompiles": rec_total,
+                    # the weight set this tick served: a swap between two
+                    # snapshots is visible as the version stepping (the
+                    # report renderer's w=vN column)
+                    "weight_version": self.weight_version,
+                }
+                if rec.multi_k is not None:
+                    # multi-step window: this one dispatch carried up to
+                    # multi_k decode steps per row (report's k= column)
+                    snap["multi_k"] = rec.multi_k
+                if rec.work is not None:
+                    # mixed ticks: (query, key) pairs and K/V positions the
+                    # dealt tokens required, of the S x C query positions
+                    # the dispatch computed
+                    snap.update(rec.work)
+                if self.pipeline:
+                    snap["pipeline_depth"] = len(self._pending)
+                    snap["overrun_tokens"] = overrun
+                if draft_tokens is not None:
+                    # speculative ticks: proposals entering this tick's
+                    # verify windows and how many survived rejection
+                    snap["draft_tokens"] = draft_tokens
+                    snap["accepted_tokens"] = accepted_tokens
+                if mem is not None:
+                    snap["mem"] = mem
+                if self.paged:
+                    # cheap counts every tick; the live/cached refcount
+                    # decomposition only on sample ticks (numpy scan)
+                    snap["blocks"] = (self.pool.stats() if sample_tick
+                                      else {"in_use": self.pool.in_use_count(),
+                                            "free": self.pool.free_count()})
+                    snap["prefix_hit_tokens"] = self.prefix_hit_tokens
+                    if self.host is not None:
+                        # tiered KV cache: per-tick swap activity + the
+                        # host pool's current footprint
+                        snap["demoted"] = self._tick_demoted
+                        snap["restored"] = self._tick_restored
+                        snap["host_blocks"] = self.host.count()
+                    if self._tick_exported or self._tick_imported:
+                        # KV-block migration: blocks exported/imported by
+                        # control calls serviced since the previous tick
+                        snap["kv_exported"] = self._tick_exported
+                        snap["kv_imported"] = self._tick_imported
+            self._flight_ns += time.perf_counter_ns() - t0
+            self._tick_demoted = 0
+            self._tick_restored = 0
+            self._tick_exported = 0
+            self._tick_imported = 0
+        # the period ends here: what the engine thread did since the
+        # previous tick's record, by phase, and the period's own length
+        # (the phases sum to it but for the statements between brackets)
+        period = self._phase.take()
+        if rec.work is not None:
+            self.attended_tokens_total += rec.work["attended_tokens"]
+            self.query_positions_total += rec.work["query_positions"]
+            self.useful_query_tokens_total += rec.n_dec + rec.fed_tokens
+        if snap is not None:
+            # overlap decomposition: device_ms = dispatch_ms (upload_ms
+            # + the jitted call returning) + device_wait_ms (time
+            # BLOCKED on readback — what pipelining exists to shrink).
+            # plan, upload and dispatch are this TICK's (in the
+            # pipelined loop they ran one period earlier); ctrl, admit,
+            # record, idle and loop are this PERIOD's
+            snap.update(
+                device_wait_ms=device_wait_ms,
+                dispatch_ms=rec.dispatch_ms, upload_ms=rec.upload_ms,
+                ctrl_ms=period.get("ctrl", 0.0),
+                admit_ms=period.get("admit", 0.0),
+                record_ms=period.get("record", 0.0),
+                idle_ms=period.get("idle", 0.0),
+                loop_ms=period["loop"],
+            )
+            if self.pipeline and self.spec:
+                # the previous tick's tokens reached their consumers
+                # inside this period, behind this tick's dispatch
+                snap["deferred_stream_ms"] = (
+                    period.get("stream", 0.0) - stream_ms)
             self.flight.record(snap)
-        self._flight_ns += time.perf_counter_ns() - t0
-        self._tick_demoted = 0
-        self._tick_restored = 0
-        self._tick_exported = 0
-        self._tick_imported = 0
 
     def stats(self) -> dict:
         """Counters + latency percentiles (TTFT and per-token, ms) for
@@ -3904,6 +4046,12 @@ class ServingEngine:
                 "p99": self._m_device_wait.percentile(99),
             },
             "overrun_tokens": self.overrun_tokens,
+            # mixed ticks: (query, key) pairs the dealt tokens required,
+            # query positions the dispatches computed ([S, C] whatever
+            # was dealt), and the decode + fed tokens among them
+            "attended_tokens_total": self.attended_tokens_total,
+            "query_positions_total": self.query_positions_total,
+            "useful_query_tokens_total": self.useful_query_tokens_total,
             # engine-side critical-path phases (the stream tail and
             # router overhead land in the same histogram family from
             # the TCP pump / router; one merged chain's exact breakdown

@@ -346,6 +346,28 @@ def report_flight(path: str, last: Optional[int] = None,
         f"p90 {_percentile(tick_ms, 90):.2f}  "
         f"p99 {_percentile(tick_ms, 99):.2f}  max {max(tick_ms):.2f}\n"
     )
+    timed = [r for r in ticks if "loop_ms" in r]
+    if timed:
+        # the engine thread's whole period by phase (dispatch_ms holds
+        # the upload; what the phases leave of loop_ms is the
+        # statements between brackets)
+        loop = sum(float(r["loop_ms"]) for r in timed) or 1e-9
+        share = {
+            "ctrl": "ctrl_ms", "admit": "admit_ms", "plan": "plan_ms",
+            "upload": "upload_ms", "dispatch": "dispatch_ms",
+            "wait": "device_wait_ms", "stream": "stream_ms",
+            "record": "record_ms", "idle": "idle_ms"}
+        by_phase = {k: sum(float(r.get(f, 0.0)) for r in timed)
+                for k, f in share.items()}
+        by_phase["dispatch"] -= by_phase["upload"]
+        out.write(
+            f"loop_ms: p50 "
+            f"{_percentile([float(r['loop_ms']) for r in timed], 50):.2f}"
+            f"  share: "
+            + " ".join(f"{k} {100 * v / loop:.1f}%"
+                       for k, v in by_phase.items())
+            + "\n"
+        )
     waits = [float(r["device_wait_ms"]) for r in ticks
              if "device_wait_ms" in r]
     if waits:

@@ -1945,7 +1945,9 @@ class LMTrainer(Trainer):
             # re-upload across epochs
             staged = batches.nbytes <= self.stage_limit_bytes
             if staged:
-                feed = [put_feed(batches)]
+                with jax.profiler.TraceAnnotation("lm_trainer.stage",
+                                                  staged=1):
+                    feed = [put_feed(batches)]
             else:
                 feed = [batches[i:i + W]
                         for i in range(0, len(batches), W)]
@@ -1968,30 +1970,39 @@ class LMTrainer(Trainer):
         params = jax.device_put(params, p_sh, may_alias=False)
         opt_state = jax.device_put(opt_state, o_sh, may_alias=False)
         history: History = []
+        # the loop's phases as spans on the profiler's clock (what
+        # Trainer(profile_dir=) shows beside the device's operations);
+        # nothing at all while no profile is being taken
+        span = jax.profiler.TraceAnnotation
         for epoch in range(start_epoch, self.num_epoch):
             # keep losses on-device until the epoch ends so dispatches
             # pipeline (no per-step host sync)
             epoch_losses = []
-            for fb in (epoch_groups(epoch) if sharded else feed):
+            for w, fb in enumerate(epoch_groups(epoch) if sharded
+                                   else feed):
                 if not staged:
-                    fb = put_feed(fb)
-                params, opt_state, losses = step(params, opt_state, fb)
+                    with span("lm_trainer.stage", epoch=epoch, window=w):
+                        fb = put_feed(fb)
+                with span("lm_trainer.dispatch", epoch=epoch, window=w):
+                    params, opt_state, losses = step(params, opt_state, fb)
                 epoch_losses.append(losses)
-            for losses in epoch_losses:
-                for loss in np.atleast_1d(np.asarray(losses)):
-                    row = {"loss": float(loss)}
-                    history.append(row)
-                    if self.metrics_writer is not None:
-                        self.metrics_writer.log(
-                            step=len(history), samples=B * T, **row,
-                        )
+            with span("lm_trainer.drain", epoch=epoch):
+                for losses in epoch_losses:
+                    for loss in np.atleast_1d(np.asarray(losses)):
+                        row = {"loss": float(loss)}
+                        history.append(row)
+                        if self.metrics_writer is not None:
+                            self.metrics_writer.log(
+                                step=len(history), samples=B * T, **row,
+                            )
             if self.checkpointer is not None:
-                self.checkpointer.maybe_save(
-                    epoch + 1, jax.tree.map(np.asarray, params),
-                    jax.tree.map(np.asarray, opt_state),
-                    extra={"epoch": epoch + 1},
-                    force=(epoch + 1 == self.num_epoch),
-                )
+                with span("lm_trainer.checkpoint", epoch=epoch):
+                    self.checkpointer.maybe_save(
+                        epoch + 1, jax.tree.map(np.asarray, params),
+                        jax.tree.map(np.asarray, opt_state),
+                        extra={"epoch": epoch + 1},
+                        force=(epoch + 1 == self.num_epoch),
+                    )
         self.params = jax.tree.map(np.asarray, params)
         self.history = history
         self.executor_histories = [history]
@@ -2183,34 +2194,48 @@ class LMTrainer(Trainer):
                     yield tb.reshape(M, micro_B, T)
         else:
             staged = batches.nbytes <= self.stage_limit_bytes
-            feed = [put_feed(b) for b in batches] if staged else list(batches)
+            if staged:
+                with jax.profiler.TraceAnnotation("lm_trainer.stage",
+                                                  staged=1):
+                    feed = [put_feed(b) for b in batches]
+            else:
+                feed = list(batches)
         history: History = []
+        span = jax.profiler.TraceAnnotation  # as in _train
         for epoch in range(start_epoch, self.num_epoch):
             epoch_losses = []
-            for fb in (epoch_steps(epoch) if sharded else feed):
+            for w, fb in enumerate(epoch_steps(epoch) if sharded
+                                   else feed):
                 if not staged:
-                    fb = put_feed(fb)
-                pp_params, opt_state, loss = step(pp_params, opt_state, fb)
+                    with span("lm_trainer.stage", epoch=epoch, window=w):
+                        fb = put_feed(fb)
+                with span("lm_trainer.dispatch", epoch=epoch, window=w):
+                    pp_params, opt_state, loss = step(
+                        pp_params, opt_state, fb)
                 epoch_losses.append(loss)
-            for loss in epoch_losses:
-                row = {"loss": float(np.asarray(loss))}
-                history.append(row)
-                if self.metrics_writer is not None:
-                    self.metrics_writer.log(
-                        step=len(history), samples=B * T, **row,
-                    )
+            with span("lm_trainer.drain", epoch=epoch):
+                for loss in epoch_losses:
+                    row = {"loss": float(np.asarray(loss))}
+                    history.append(row)
+                    if self.metrics_writer is not None:
+                        self.metrics_writer.log(
+                            step=len(history), samples=B * T, **row,
+                        )
             if self.checkpointer is not None:
                 final = epoch + 1 == self.num_epoch
                 # gate the (params-sized, cross-mesh) gather on the save
                 # cadence — maybe_save would skip the step anyway
                 if final or (epoch + 1) % self.checkpointer.every_steps == 0:
-                    self.checkpointer.maybe_save(
-                        epoch + 1,
-                        from_pipeline_params(_gather_host(pp_params), L),
-                        opt_state_to_plain(_gather_host(opt_state), L),
-                        extra={"epoch": epoch + 1},
-                        force=final,
-                    )
+                    with span("lm_trainer.checkpoint", epoch=epoch):
+                        self.checkpointer.maybe_save(
+                            epoch + 1,
+                            from_pipeline_params(
+                                _gather_host(pp_params), L),
+                            opt_state_to_plain(
+                                _gather_host(opt_state), L),
+                            extra={"epoch": epoch + 1},
+                            force=final,
+                        )
         self.params = from_pipeline_params(_gather_host(pp_params), L)
         self.history = history
         self.executor_histories = [history]

@@ -713,17 +713,17 @@ def test_donation_pass_catches_seeded_engine_violation(tmp_path):
     assert all(v for v in factories.values())
 
     seeded = text.replace(
-        """            tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-            self._cache, self._last_logits, toks, self._rngs = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs
-            )""",
-        """            tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-            new_cache, self._last_logits, toks, self._rngs = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs
-            )
-            stale = self._cache""",
+        """                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
+                self._cache, self._last_logits, toks, self._rngs = tick(
+                    self._params_only, self._cache, self._last_logits,
+                    self._rngs
+                )""",
+        """                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
+                new_cache, self._last_logits, toks, self._rngs = tick(
+                    self._params_only, self._cache, self._last_logits,
+                    self._rngs
+                )
+                stale = self._cache""",
         1,
     )
     assert seeded != text, "engine call-site shape changed; update seed"
@@ -744,26 +744,19 @@ def test_donation_pass_catches_seeded_inflight_handoff(tmp_path):
                             "engine.py")
     text = open(eng_path).read()
     seeded = text.replace(
-        """        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        dev = self._upload(packed)
-        if self.paged:
-            tick = _paged_mixed_tick_fn(self._dm_paged, cfgs, C,
-                                        self._ctx)
-        else:
-            tick = _mixed_tick_fn(self._dm_slot, cfgs, C, self._ctx)""",
-        """        t0 = time.perf_counter()
-        plan_ms = (t0 - t_plan0) * 1e3
-        dev = self._upload(packed)
-        leak = _InflightTick(toks=self._cache, rows=rows, plan_ms=0.0,
+        """            dev = self._upload(packed)
+        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
+                         fed_tokens=fed_tokens, chunk=C,
+                         **work) as dispatch:""",
+        """            dev = self._upload(packed)
+        leak = _InflightTick(toks=self._cache, rows=rows, tick=tick_no,
+                             plan_ms=0.0, upload_ms=0.0,
                              dispatch_ms=0.0, n_dec=n_dec,
                              fed_tokens=fed_tokens, chunk=C)
         self._pending.append(leak)
-        if self.paged:
-            tick = _paged_mixed_tick_fn(self._dm_paged, cfgs, C,
-                                        self._ctx)
-        else:
-            tick = _mixed_tick_fn(self._dm_slot, cfgs, C, self._ctx)""",
+        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
+                         fed_tokens=fed_tokens, chunk=C,
+                         **work) as dispatch:""",
         1,
     )
     assert seeded != text, "engine dispatch shape changed; update seed"
@@ -828,19 +821,19 @@ def test_rng_pass_catches_seeded_engine_violation(tmp_path):
                             "engine.py")
     text = open(eng_path).read()
     seeded = text.replace(
-        """            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            new_rngs.append(rng)""",
-        """            rng, sub = jax.random.split(rngs[s])
-            toks.append(
-                sample_tokens(last_logits[s][None], sub, temp,
-                              top_k, top_p)[0]
-            )
-            extra = jax.random.uniform(sub, ())
-            new_rngs.append(rng)""",
+        """                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                new_rngs.append(rng)""",
+        """                rng, sub = jax.random.split(rngs[s])
+                toks.append(
+                    sample_tokens(last_logits[s][None], sub, temp,
+                                  top_k, top_p)[0]
+                )
+                extra = jax.random.uniform(sub, ())
+                new_rngs.append(rng)""",
         1,
     )
     assert seeded != text, "engine tick shape changed; update seed"

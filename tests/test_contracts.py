@@ -321,15 +321,13 @@ def test_hostsync_hoisted_readback_into_plan_body(tmp_path):
     pass pins _plan_dispatch_mixed."""
     e = _mutate(
         tmp_path, ENGINE,
-        "        t_plan0 = time.perf_counter()\n"
-        "        if self.host is not None:\n"
-        "            self._issue_restores()\n"
-        "        S = self.slots",
-        "        t_plan0 = time.perf_counter()\n"
-        "        if self.host is not None:\n"
-        "            self._issue_restores()\n"
-        "        _peek = np.asarray(self._last_logits)\n"
-        "        S = self.slots")
+        "            if self.host is not None:\n"
+        "                self._issue_restores()\n"
+        "            S = self.slots\n",
+        "            if self.host is not None:\n"
+        "                self._issue_restores()\n"
+        "            _peek = np.asarray(self._last_logits)\n"
+        "            S = self.slots\n")
     findings = analyze([e], passes=[HostSyncHazardPass()])
     hits = [f for f in findings
             if f.key == "_plan_dispatch_mixed:_plan_dispatch_mixed"
@@ -342,8 +340,10 @@ def test_hostsync_tainted_int_cast_in_plan_body(tmp_path):
     one-element sync; int() of host state (lengths, numpy lookups like
     the n-gram drafter's) stays legal — the real engine is clean."""
     anchor = ("        return _InflightTick(\n"
-              "            toks=toks, rows=rows, plan_ms=plan_ms,\n"
-              "            dispatch_ms=(time.perf_counter() - t0) * 1e3,\n"
+              "            toks=toks, rows=rows, tick=tick_no, "
+              "plan_ms=plan.ms,\n"
+              "            upload_ms=upload.ms, "
+              "dispatch_ms=upload.ms + dispatch.ms,\n"
               "            n_dec=n_dec, fed_tokens=0, chunk=None,\n"
               "        )")
     e = _mutate(tmp_path, ENGINE, anchor,
@@ -374,15 +374,14 @@ def test_hostsync_hazard_in_reached_helper(tmp_path):
 def test_hostsync_suppression_comment(tmp_path):
     e = _mutate(
         tmp_path, ENGINE,
-        "        t_plan0 = time.perf_counter()\n"
-        "        if self.host is not None:\n"
-        "            self._issue_restores()\n"
-        "        S = self.slots",
-        "        t_plan0 = time.perf_counter()\n"
-        "        if self.host is not None:\n"
-        "            self._issue_restores()\n"
-        "        _peek = np.asarray(self._rngs)  # analysis: host-sync-ok\n"
-        "        S = self.slots")
+        "            if self.host is not None:\n"
+        "                self._issue_restores()\n"
+        "            S = self.slots\n",
+        "            if self.host is not None:\n"
+        "                self._issue_restores()\n"
+        "            _peek = np.asarray(self._rngs)"
+        "  # analysis: host-sync-ok\n"
+        "            S = self.slots\n")
     assert analyze([e], passes=[HostSyncHazardPass()]) == []
 
 

@@ -492,6 +492,8 @@ def test_report_flight_renders_manual_dump(tmp_path, capsys):
     assert "reason=manual" in out
     assert "phase share" in out and "device" in out
     assert "tick_ms: p50" in out and "slowest ticks:" in out
+    # the engine thread's whole period by phase
+    assert "loop_ms: p50" in out and "upload" in out and "record" in out
     assert "memory at last sample" in out
     # --last truncates the timeline but not the summary
     telemetry_report.main(["--flight", str(path), "--last", "2"])
