@@ -536,7 +536,8 @@ def tick_kernels(engine) -> dict:
 
 def attend_label(kernels: dict) -> str:
     names = sorted(k for k in kernels
-                   if k in ("paged_attention", "splash_prefill"))
+                   if k in ("paged_attention", "splash_prefill",
+                            "slot_decode_attend"))
     return "+".join(names) if names else "dense"
 
 

@@ -248,16 +248,17 @@ class CausalSelfAttention(nn.Module):
     # VMEM; the gathered path materializes the whole [B, L, Hk, hd]
     # (dequantized!) view per call and stays the bit-parity reference.
     paged_kernel: str = "auto"
-    # chunked-prefill attend implementation (the mixed tick's T > 1
-    # shape): 'auto' (the splash-style Pallas kernel of
-    # ops.splash_prefill where the shape tiles on this backend — KV
-    # blocks beyond each row's diagonal skipped outright — else the
-    # dense masked reference), 'splash' (force; interpret mode
-    # off-TPU, the parity tests' lever), or 'gather' (force the dense
-    # reference). Serves BOTH decode cache layouts: the slot leaves
-    # directly, and the paged path's gathered view when the paged
-    # Pallas kernel did not take the call. Decode steps (T == 1) always
-    # take the dense path — that shape is its home turf.
+    # attend implementation over the decode cache, for every query
+    # width (a chunk of a mixed tick, one decode token, a speculative
+    # window): 'auto' (the Pallas kernel of ops.splash_prefill where
+    # the shape tiles on this backend — it copies in each row's K/V
+    # tiles up to the row's cursor and no further — else the dense
+    # masked reference, which reads all L positions of every row),
+    # 'splash' (force; interpret mode off-TPU, the parity tests'
+    # lever), or 'gather' (force the dense reference). Serves BOTH
+    # decode cache layouts: the slot leaves directly, and the paged
+    # path's gathered view when the paged Pallas kernel did not take
+    # the call.
     prefill_kernel: str = "auto"
 
     _DENSE_MAX_T = 512  # short sequences: one fused dense block is fastest
@@ -279,18 +280,16 @@ class CausalSelfAttention(nn.Module):
 
     def _use_prefill_kernel(self, T, G, hd, L, Hk=1) -> bool:
         """Resolve ``prefill_kernel`` for this call shape: 'auto'
-        defers to the splash kernel's preferred() gate (TPU + tileable
-        + a true chunk), 'splash' forces it (interpret mode off-TPU),
-        'gather' keeps the dense reference. Single-token decode steps
-        never take the kernel — skipping KV blocks buys nothing at
-        T == 1."""
-        if T < 2 or self.prefill_kernel == "gather":
-            return False
+        defers to the kernel's preferred() gate (TPU + tileable),
+        'splash' forces it (interpret mode off-TPU), 'gather' keeps the
+        dense reference — each for every ``T``. A decode step
+        (``T == 1``) has no masked half to skip, but it is bound by
+        bytes, not FLOPs: the dense attend streams all ``L`` positions
+        of every row's K and V, the kernel only those up to the row's
+        cursor."""
         from distkeras_tpu.ops import splash_prefill as _sp
 
-        if self.prefill_kernel == "splash":
-            return True
-        return _sp.preferred(T, G, hd, L, Hk)
+        return _sp.resolves_to_kernel(self.prefill_kernel, T, G, hd, L, Hk)
 
     def _paged_attend(self, q, k, v, block_tables, seq_lens,
                       valid_lens=None):
@@ -387,15 +386,17 @@ class CausalSelfAttention(nn.Module):
         else:
             keys, vals = view(ck.value), view(cv.value)
         if self._use_prefill_kernel(T, G, hd, L, Hk):
-            # splash chunked prefill over the gathered view: identical
-            # absolute-position masks, KV tiles beyond each row's
-            # diagonal skipped (ops/splash_prefill.py); the dense
-            # attend below stays the bit-parity reference
+            # the cursor-bounded kernel over the gathered view:
+            # identical absolute-position masks, KV tiles beyond each
+            # row's diagonal not read (ops/splash_prefill.py; the
+            # gather above still built them); the dense attend below
+            # stays the bit-parity reference
             from distkeras_tpu.ops.splash_prefill import (
                 splash_prefill_attention,
             )
 
-            return splash_prefill_attention(q, keys, vals, seq_lens)
+            return splash_prefill_attention(q, keys, vals, seq_lens,
+                                            valid_lens)
         scale = 1.0 / np.sqrt(hd)
         qg = q.reshape(B, T, Hk, G, hd)
         s = jnp.einsum(
@@ -513,17 +514,20 @@ class CausalSelfAttention(nn.Module):
             keys, vals = ck.value, cv.value
         idx.value = cur + (T if valid_lens is None else valid_lens)
         if self._use_prefill_kernel(T, G, hd, L, Hk):
-            # splash chunked prefill over the slot cache leaves: same
-            # per-row absolute-position masks as the dense attend below
-            # (which stays the bit-parity reference), KV tiles beyond
-            # each row's diagonal skipped (ops/splash_prefill.py)
+            # the cursor-bounded kernel over the slot cache leaves,
+            # chunk or decode step alike: same per-row
+            # absolute-position masks as the dense attend below (which
+            # stays the bit-parity reference), KV tiles beyond each
+            # row's last valid token never copied in
+            # (ops/splash_prefill.py)
             from distkeras_tpu.ops.splash_prefill import (
                 splash_prefill_attention,
             )
 
             starts = (cur if self.slot_cursor
                       else jnp.broadcast_to(cur, (B,)))
-            return splash_prefill_attention(q, keys, vals, starts)
+            return splash_prefill_attention(q, keys, vals, starts,
+                                            valid_lens)
         scale = 1.0 / np.sqrt(hd)
         qg = q.reshape(B, T, Hk, G, hd)
         s = jnp.einsum(
@@ -748,7 +752,7 @@ class Block(nn.Module):
     page_block_size: int = 16
     num_pages: int = 0
     paged_kernel: str = "auto"  # paged attend: auto | pallas | gather
-    prefill_kernel: str = "auto"  # chunk attend: auto | splash | gather
+    prefill_kernel: str = "auto"  # cache attend: auto | splash | gather
 
     @nn.compact
     def __call__(self, x, block_tables=None, seq_lens=None,
@@ -867,11 +871,12 @@ class TransformerLM(nn.Module):
     # table, int8 dequant fused in VMEM), 'pallas' (force; interpret
     # mode off-TPU), 'gather' (the XLA gather+einsum reference)
     paged_kernel: str = "auto"
-    # chunked-prefill attend implementation (mixed-tick T > 1 shapes,
-    # both decode cache layouts): 'auto' (the splash-style Pallas
-    # kernel of ops/splash_prefill.py where the shape tiles on this
-    # backend — beyond-diagonal KV tiles skipped), 'splash' (force;
-    # interpret mode off-TPU), 'gather' (the dense masked reference)
+    # attend implementation over the decode cache, every query width
+    # (chunk, decode step, verify window; both cache layouts): 'auto'
+    # (the Pallas kernel of ops/splash_prefill.py where the shape tiles
+    # on this backend — each row's K/V read up to its cursor only),
+    # 'splash' (force; interpret mode off-TPU), 'gather' (the dense
+    # masked reference)
     prefill_kernel: str = "auto"
     # features_only=True returns the backbone's ln_f output [B, T, D]
     # instead of logits, for the fused chunked cross-entropy

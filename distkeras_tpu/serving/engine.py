@@ -89,6 +89,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distkeras_tpu import telemetry
 from distkeras_tpu.models.transformer import filter_logits, sample_tokens
+from distkeras_tpu.ops import splash_prefill
 from distkeras_tpu.telemetry.events import EventJournal
 from distkeras_tpu.telemetry.flight import FlightRecorder
 from distkeras_tpu.telemetry.runtime import MemoryWatermarks, recompiles
@@ -1038,8 +1039,10 @@ class _InflightTick:
     n_dec: int
     fed_tokens: int
     chunk: Optional[int]
-    # mixed ticks: what the dispatch computes against what was dealt
-    # (attended_tokens, key_positions, query_positions; see _tick_work)
+    # mixed ticks: what the dispatch computes and copies in against
+    # what was dealt (attended_tokens, key_positions,
+    # key_positions_fetched, cache_positions, query_positions; see the
+    # plan in _plan_dispatch_mixed)
     work: Optional[dict] = None
     # multi-step decode: the window width this record dispatched (None
     # = ordinary one-token tick); ``acc`` doubles as its device [S]
@@ -1198,14 +1201,17 @@ class ServingEngine:
         where the shape tiles on this backend, else the gathered
         reference), 'pallas' (force; interpret mode off-TPU), 'gather'
         (force the reference). Paged mode only.
-      prefill_kernel: chunked-prefill attend implementation for the
-        mixed tick's T > 1 shapes (both cache layouts) — 'auto' (the
-        splash-style Pallas kernel of
-        :mod:`distkeras_tpu.ops.splash_prefill` where the shape tiles
-        on this backend: KV tiles beyond each row's diagonal skipped
-        outright, the compute-bound prefill-replica shape), 'splash'
-        (force; interpret mode off-TPU), 'gather' (force the dense
-        masked reference, which stays the bit-parity baseline).
+      prefill_kernel: the attend over the decode cache, for every tick
+        shape (a mixed tick's chunk, a decode tick's one token, a
+        verify window; both cache layouts) — 'auto' (the Pallas kernel
+        of :mod:`distkeras_tpu.ops.splash_prefill` where the shape
+        tiles on this backend: each row's K/V is copied in up to the
+        row's cursor and no further, where the dense attend reads all
+        ``max_len`` positions of every row), 'splash' (force;
+        interpret mode off-TPU), 'gather' (force the dense masked
+        reference, which stays the bit-parity baseline).
+        ``stats()["key_positions_fetched_total"]`` over
+        ``cache_positions_total`` says how far it engages.
       role: advertised replica specialization for disaggregated
         serving — 'mixed' (default), 'prefill' (a compute-optimized
         replica the router sends long prompts to, exporting their KV
@@ -1452,6 +1458,8 @@ class ServingEngine:
         # what the mixed ticks computed against what they were dealt
         self.attended_tokens_total = 0
         self.query_positions_total = 0
+        self.key_positions_fetched_total = 0
+        self.cache_positions_total = 0
         self.useful_query_tokens_total = 0
         self._flight_ns = 0  # time spent building/recording snapshots
         self._tick_ns = 0    # total tick wall time (plan+device+stream)
@@ -2899,6 +2907,27 @@ class ServingEngine:
         self._tick_imported += k
         return {"imported": k, "tokens": k * bs, "mode": mode}
 
+    def _kv_fetched(self, starts, valid, C: int) -> int:
+        """K/V positions the attend of one ``[S, C]`` mixed tick copies
+        in, all ``S`` rows of it: each row's walk from its cursor
+        (``starts``) to the last of its ``valid`` tokens, in whole KV
+        tiles, where the slot cache's attend resolves to the
+        cursor-bounded kernel (``prefill_kernel``, by backend and
+        shape); every position of every row where it is the dense
+        attend, reads a dequantised int8 cache, or reads the paged
+        layout's gathered view. Host arithmetic on cursors the plan
+        already holds."""
+        m = self.model
+        L = m.max_len
+        H = m.num_heads // self.tp
+        Hk = (m.num_kv_heads or m.num_heads) // self.tp
+        if (not self.paged and m.cache_dtype == "model"
+                and splash_prefill.resolves_to_kernel(
+                    self.prefill_kernel, C, H // Hk,
+                    m.d_model // m.num_heads, L, Hk)):
+            return splash_prefill.fetched_positions(starts, valid, L)
+        return self.slots * L
+
     def _mixed_tick(self):
         """One fused mixed prefill/decode tick, sync mode: plan and
         dispatch, then reconcile immediately (the strictly alternating
@@ -2965,12 +2994,15 @@ class ServingEngine:
             # positions the dispatch computes whatever was dealt: (query,
             # key) pairs attended and K/V positions read, live rows only
             attended = key_positions = 0
+            # the cursor every live row's attend starts from: what bounds
+            # the K/V the dispatch copies in
+            starts = np.fromiter((st.cursor if st else 0
+                                  for st in self._slots), np.int64, S)
             for s, st in enumerate(self._slots):
                 if st is None:
-                    # idle rows tick along like decoders (sampling greedily
-                    # into the void at their parked cursor, as the unchunked
-                    # tick always has)
-                    valid[s] = 1
+                    # idle rows sample greedily into the void and feed
+                    # nothing (valid 0): the row writes no K/V, its parked
+                    # cursor holds, and its attend walks no cache
                     sample_mask[s] = 1
                 elif st.decoding:
                     valid[s] = 1
@@ -3017,7 +3049,10 @@ class ServingEngine:
             else:
                 packed = _pack_i32(fed, valid, sample_mask)
         work = {"attended_tokens": attended,
-                "key_positions": key_positions, "query_positions": S * C}
+                "key_positions": key_positions,
+                "key_positions_fetched": self._kv_fetched(starts, valid, C),
+                "cache_positions": S * self.model.max_len,
+                "query_positions": S * C}
         with self._phase("upload", tick=tick_no) as upload:
             dev = self._upload(packed)
         with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
@@ -3904,7 +3939,8 @@ class ServingEngine:
                 if rec.work is not None:
                     # mixed ticks: (query, key) pairs and K/V positions the
                     # dealt tokens required, of the S x C query positions
-                    # the dispatch computed
+                    # the dispatch computed and the K/V positions its
+                    # attend copied in
                     snap.update(rec.work)
                 if self.pipeline:
                     snap["pipeline_depth"] = len(self._pending)
@@ -3946,6 +3982,9 @@ class ServingEngine:
         if rec.work is not None:
             self.attended_tokens_total += rec.work["attended_tokens"]
             self.query_positions_total += rec.work["query_positions"]
+            self.key_positions_fetched_total += rec.work[
+                "key_positions_fetched"]
+            self.cache_positions_total += rec.work["cache_positions"]
             self.useful_query_tokens_total += rec.n_dec + rec.fed_tokens
         if snap is not None:
             # overlap decomposition: device_ms = dispatch_ms (upload_ms
@@ -4051,6 +4090,11 @@ class ServingEngine:
             # was dealt), and the decode + fed tokens among them
             "attended_tokens_total": self.attended_tokens_total,
             "query_positions_total": self.query_positions_total,
+            # K/V positions the mixed ticks' attends copied in (every
+            # row's walk to its cursor, in whole tiles), of the S x L
+            # a dense attend reads every tick
+            "key_positions_fetched_total": self.key_positions_fetched_total,
+            "cache_positions_total": self.cache_positions_total,
             "useful_query_tokens_total": self.useful_query_tokens_total,
             # engine-side critical-path phases (the stream tail and
             # router overhead land in the same histogram family from

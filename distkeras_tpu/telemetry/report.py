@@ -368,6 +368,19 @@ def report_flight(path: str, last: Optional[int] = None,
                        for k, v in by_phase.items())
             + "\n"
         )
+    walked = [r for r in ticks if r.get("cache_positions")]
+    if walked:
+        # mixed ticks: K/V positions the attend copied in (every row's
+        # walk to its cursor, in whole tiles) beside what the live rows
+        # needed, each over the S x L a dense attend reads every tick
+        got = [r["key_positions_fetched"] / r["cache_positions"]
+               for r in walked]
+        need = [r["key_positions"] / r["cache_positions"] for r in walked]
+        out.write(
+            f"kv_fetched/cache: p50 {_percentile(got, 50):.3f}  "
+            f"max {max(got):.3f}  (needed p50 {_percentile(need, 50):.3f};"
+            f" 1.000 = every row's whole cache)\n"
+        )
     waits = [float(r["device_wait_ms"]) for r in ticks
              if "device_wait_ms" in r]
     if waits:

@@ -132,6 +132,22 @@ def test_splash_prefill(for_chip):
     assert "tpu_custom_call" in text and "%splash_prefill" in text
 
 
+@pytest.mark.parametrize("T,name", [
+    (64, "%splash_prefill"),     # a mixed tick's chunk attend
+    (1, "%slot_decode_attend"),  # a decode tick's attend: the same walk
+])
+def test_slot_attend_at_the_benchmarks_shapes(for_chip, T, name):
+    """chipbench's serving configuration (Cerebras-GPT-1.3B: 16 slots,
+    16 heads of 128, MHA, a 2048-token slot cache): both of its ticks'
+    attends are the cursor-bounded kernel, each under its own name."""
+    assert splash_prefill.supports(T, 1, 128, 2048, 16)
+    text = for_chip(splash_prefill.splash_prefill_attention,
+                    *splash_shapes(16, T, 16, 16, 128, 2048))
+    assert "tpu_custom_call" in text and name in text
+    other = {"%splash_prefill", "%slot_decode_attend"} - {name}
+    assert not any(o in text for o in other)
+
+
 # (kernel, T, H, Hk, hd): what supports() says must be what the compiler
 # says. The refusals are VMEM: the tiles hold all Hk heads of a chunk.
 GATE_CASES = [
@@ -139,10 +155,15 @@ GATE_CASES = [
     ("paged", 24, 8, 8, 128),     # MHA, hd128
     ("paged", 1024, 8, 2, 256),   # refused: 16 MiB of tiles
     ("splash", 2, 8, 2, 256),     # the smallest chunk
-    ("splash", 384, 8, 2, 256),   # near the budget
-    ("splash", 96, 32, 32, 128),  # wide MHA: the K/V tiles dominate
+    ("splash", 320, 8, 2, 256),   # near the budget
+    ("splash", 56, 32, 32, 128),  # wide MHA near the budget
+    ("splash", 96, 32, 32, 128),  # refused: 32 heads of stacked scores
     ("splash", 1024, 8, 8, 128),  # refused
     ("splash", 512, 16, 16, 128),  # refused
+    ("splash", 1, 16, 16, 128),   # a decode step, MHA: one query row
+    ("splash", 1, 8, 2, 256),     # a decode step, GQA: T*G = 4
+    ("splash", 3, 8, 8, 128),     # a ragged window, padded to 8 rows
+    ("splash", 1, 64, 64, 128),   # refused even at T == 1: 64-head tiles
 ]
 
 

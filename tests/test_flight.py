@@ -494,6 +494,9 @@ def test_report_flight_renders_manual_dump(tmp_path, capsys):
     assert "tick_ms: p50" in out and "slowest ticks:" in out
     # the engine thread's whole period by phase
     assert "loop_ms: p50" in out and "upload" in out and "record" in out
+    # what the attend copied in of the cache (the dense attend off the
+    # chip: all of it)
+    assert "kv_fetched/cache: p50 1.000  max 1.000  (needed p50 0." in out
     assert "memory at last sample" in out
     # --last truncates the timeline but not the summary
     telemetry_report.main(["--flight", str(path), "--last", "2"])

@@ -1,8 +1,9 @@
 """The engine loop's phase bracket and the trainer's spans: every tick
 path times its phases through one bracket whose fields sum to the
 period (``loop_ms``), the old flight fields keep their formulas, the
-spans land in a ``jax.profiler`` trace with their arguments, and the
-mixed tick's work counters agree with a count by hand."""
+spans land in a ``jax.profiler`` trace with their arguments, the
+mixed tick's work counters agree with a count by hand, and its count of
+K/V positions fetched agrees with the device's own cursors."""
 
 import glob
 import os
@@ -239,3 +240,92 @@ def test_work_counters_against_a_hand_count(lm, case):
     assert st["useful_query_tokens_total"] == sum(
         t["decode_tokens"] + t["prefill_tokens"] for t in ticks)
     assert st["useful_query_tokens_total"] <= st["query_positions_total"]
+
+
+# -- (4) K/V positions the attend copies in, against the device's cursors ----
+
+# a cache of three 32-position KV tiles, so rows cross tile edges
+FETCH_KW = {**KW, "max_len": 96}
+FETCH_CASES = {
+    # the kernel forced (interpret mode): every row's walk, in tiles
+    "splash": (dict(prefill_kernel="splash"), True),
+    # the dense attend reads every position of every row, every tick
+    "gather": (dict(prefill_kernel="gather"), False),
+    # 'auto' off the chip resolves to the dense attend
+    "auto_cpu": (dict(prefill_kernel="auto"), False),
+    # the paged layout's gathered view is built whole whatever reads it
+    "splash_paged": (dict(prefill_kernel="splash", paged=True,
+                          block_size=8), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FETCH_CASES))
+def test_key_positions_fetched_follows_the_cursors(case):
+    from distkeras_tpu.ops import splash_prefill as sp
+
+    kw, bounded = FETCH_CASES[case]
+    model = get_model("transformer_lm", **FETCH_KW)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+    eng = _engine((model, params), prefill_chunk=4, **kw)
+    S, L = 3, FETCH_KW["max_len"]
+    kb = sp.choose_kv_block(L)
+    assert L // kb == 3
+    # what the device holds as each row's cursor when a tick is planned
+    # (admissions done), and the valid-token counts the tick uploads:
+    # the truth the host's count is held to (an idle row feeds nothing
+    # and walks one tile wherever its last tenant left its cursor)
+    device_starts, valid_lens = [], []
+    plan, upload = eng._plan_dispatch_mixed, eng._upload
+
+    def spy_plan():
+        if not eng.paged:
+            device_starts.append(np.asarray(next(
+                leaf for leaf in jax.tree.leaves(eng._cache)
+                if leaf.ndim == 1)))
+        return plan()
+
+    def spy_upload(packed):
+        # slot layout: fed [S, C], then valid [S], then the sample mask
+        valid_lens.append(np.asarray(packed)[-2 * S:-S])
+        return upload(packed)
+
+    eng._plan_dispatch_mixed, eng._upload = spy_plan, spy_upload
+    # five requests over three slots: rows finish at different depths,
+    # idle for a few ticks, and are taken again
+    lengths, new = [30, 6, 41, 9, 5], [6, 3, 30, 2, 40]
+    for i, p in enumerate(_prompts(lengths)):
+        eng.submit(p, max_new_tokens=new[i], seed=i)
+    eng.drain()
+    ticks = _ticks(eng)
+    assert len(ticks) > 30
+    for i, t in enumerate(ticks):
+        assert t["cache_positions"] == S * L
+        assert (t["key_positions"] <= t["key_positions_fetched"]
+                <= t["cache_positions"])
+        if bounded:
+            # a row walks to the tile of its last valid token, one tile
+            # where it has none
+            want = sum(min((int(c) + int(n) - 1) // kb, L // kb - 1) + 1
+                       if n else 1
+                       for c, n in zip(device_starts[i], valid_lens[i])) * kb
+            assert t["key_positions_fetched"] == want, (i, device_starts[i])
+        else:
+            assert t["key_positions_fetched"] == S * L
+    fetched = [t["key_positions_fetched"] for t in ticks]
+    if not eng.paged:
+        # idle rows were planned with no valid token, and their device
+        # cursors held while they idled
+        # (a slot empty after the tick before and after this one)
+        idle = {(i, s) for i in range(1, len(ticks)) for s in range(S)
+                if ticks[i - 1]["slots"][s] is None
+                and ticks[i]["slots"][s] is None}
+        assert idle and all(valid_lens[i][s] == 0 for i, s in idle)
+        assert all(device_starts[i][s] == device_starts[i + 1][s]
+                   for i, s in idle if (i + 1, s) in idle)
+    if bounded:
+        # rows at different depths: the count moves with them
+        assert min(fetched) == S * kb and max(fetched) > S * kb
+        assert max(fetched) < S * L
+    st = eng.stats()
+    assert st["key_positions_fetched_total"] == sum(fetched)
+    assert st["cache_positions_total"] == S * L * len(ticks)

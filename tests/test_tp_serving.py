@@ -52,13 +52,13 @@ def _workload():
     return prompts, cfgs
 
 
-def _run(model, params, mesh, mode, prefill):
+def _run(model, params, mesh, mode, prefill, **kw):
     eng = ServingEngine(
         model, params, slots=2,
         paged=(mode == "paged"), block_size=8,
         prefill_chunk=4 if prefill == "chunked" else None,
         registry=telemetry.MetricRegistry(), tracer=telemetry.Tracer(),
-        mesh=mesh,
+        mesh=mesh, **kw,
     )
     prompts, cfgs = _workload()
     reqs = [eng.submit(p, **c) for p, c in zip(prompts, cfgs)]
@@ -96,6 +96,25 @@ def test_tp_streams_bit_identical(mode, heads, cache_dtype, prefill):
     got, eng = _run(model, params, mesh, mode, prefill)
     assert got == base
     assert eng.stats()["tp"] == TP
+
+
+@pytest.mark.parametrize("heads", ["mha", "gqa"])
+def test_tp_streams_match_with_the_cache_kernel_forced(heads):
+    """The cursor-bounded attention kernel under the mesh (interpret
+    mode; each shard walks its own KV heads, chunk ticks and decode
+    ticks alike) against the single-chip dense attend: the same
+    streams, and the same count of K/V positions fetched on the host."""
+    model, params = _model_and_params(heads, "model")
+    base, dense = _run(model, params, None, "slot", "chunked",
+                       prefill_kernel="gather")
+    got, eng = _run(model, params, make_mesh({"model": TP}), "slot",
+                    "chunked", prefill_kernel="splash")
+    assert got == base
+    st, st_dense = eng.stats(), dense.stats()
+    assert st["cache_positions_total"] == st_dense["cache_positions_total"]
+    assert (0 < st["key_positions_fetched_total"]
+            < st_dense["key_positions_fetched_total"]
+            == st_dense["cache_positions_total"])
 
 
 def test_tp_zero_steady_state_recompiles():
