@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import math
+import sys
 
 
 class Verdict:
@@ -22,9 +23,15 @@ class Verdict:
     def correct(self) -> bool:
         return bool(self.rows) and all(r["ok"] for r in self.rows)
 
+    def compared(self) -> dict:
+        """``{name: {"value", "limit"}}`` of every number compared."""
+        return {r["compared"]: {"value": r["value"], "limit": r["limit"]}
+                for r in self.rows}
+
     def print(self):
+        """Every row, with what else it recorded, on standard error."""
         for row in self.rows:
-            print(json.dumps({"check": row}), flush=True)
+            print(json.dumps({"check": row}), file=sys.stderr, flush=True)
 
 
 def served_gaps(ref_logits, served):

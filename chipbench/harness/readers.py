@@ -9,10 +9,10 @@ the reduced profiler trace (``trace_reduce.reduce_events``) or ``None``.
 
 from __future__ import annotations
 
-import importlib
 import statistics
 
 from chipbench.harness import device, traffic, trace_reduce
+from chipbench.harness.spec import named
 
 
 def trace_idle(spec, run, cell, trace):
@@ -32,8 +32,7 @@ def trace_kernel(spec, run, cell, trace):
     seconds = trace_reduce.kernel_seconds(trace, spec["pattern"])
     if seconds <= 0:
         return None
-    module, fn = spec["counts"].split(":")
-    least = getattr(importlib.import_module(module), fn)(
+    least = named(spec["counts"])(
         cell, run, trace, device.peaks(run["device"]["kind"]))
     return None if least is None else 100.0 * least / seconds
 
@@ -75,8 +74,7 @@ def client(spec, run, cell, trace):
 
 
 def derived(spec, run, cell, trace):
-    module, fn = spec["function"].split(":")
-    return getattr(importlib.import_module(module), fn)(
+    return named(spec["function"])(
         cell, run, device.peaks(run["device"]["kind"]))
 
 

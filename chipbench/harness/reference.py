@@ -14,6 +14,10 @@ same mathematics with every linear layer's operands rounded to int8 (one
 scale per token and per output channel, straight-through gradients) and
 keys and values rounded per token and head, the step below the bfloat16
 the configurations state. It has to come out as not correct.
+
+This is the reference of every configuration that names no other
+(``spec.reference``, whose docstring is the protocol): each function a
+runner calls takes the configuration first.
 """
 
 from __future__ import annotations
@@ -57,8 +61,8 @@ def _shapes(m: dict) -> dict:
 _FAN_IN_AXES = {"qkv": 1, "out": 2, "mlp_up": 1, "mlp_down": 1, "head": 1}
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _make_params(model_items, key):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _make_params(model_items, dtype, key):
     m = dict(model_items)
     paths, shapes = [], []
 
@@ -88,17 +92,19 @@ def _make_params(model_items, key):
         node = out
         for name in path[:-1]:
             node = node.setdefault(name, {})
-        node[leaf] = value
+        node[leaf] = value.astype(dtype)
     return {"params": out}
 
 
-def make_params(model: dict, seed: int):
-    """``{"params": ...}`` in float32 on the default device, in one jitted
-    call, from the seed. ``model`` holds ``vocab_size``, ``d_model``,
+def make_params(config: dict, seed: int):
+    """``{"params": ...}`` on the default device, in one jitted call, from
+    the seed, in the dtype ``config["precision"]["parameters"]`` states.
+    Of ``config["model"]`` it reads ``vocab_size``, ``d_model``,
     ``num_heads`` and ``num_layers``."""
-    items = tuple(sorted((k, model[k]) for k in
+    items = tuple(sorted((k, config["model"][k]) for k in
                          ("vocab_size", "d_model", "num_heads", "num_layers")))
-    return _make_params(items, key_of(seed))
+    return _make_params(items, config["precision"]["parameters"],
+                        key_of(seed))
 
 
 @functools.lru_cache(maxsize=8)
@@ -236,7 +242,8 @@ def _layer_inputs(params, toks, precision):
     return xs
 
 
-def forward_logits(variables, tokens, at, precision="f32", pad_to=None):
+def forward_logits(config, variables, tokens, at, precision="f32",
+                   pad_to=None):
     """Logits ``[len(at), V]`` of one sequence at the positions ``at``,
     through every layer. ``pad_to`` pads the sequence (causal: padding
     after the end changes nothing before it), and the positions are
@@ -254,7 +261,7 @@ def forward_logits(variables, tokens, at, precision="f32", pad_to=None):
     return logits_fwd(_top(params), take_rows(x, rows), precision)[:len(at)]
 
 
-def row_losses(variables, batch, precision="f32"):
+def row_losses(config, variables, batch, precision="f32"):
     """Each row's mean next-token loss, forward only."""
     params = variables["params"]
     out = []
@@ -310,12 +317,13 @@ def _adam_leaf(p, m, v, g, lr, t):
     return p - lr * m_hat / (jnp.sqrt(v_hat) + eps), m, v
 
 
-def train_losses(variables, batches, schedule: dict, precision="f32"):
+def train_losses(config, variables, batches, precision="f32"):
     """The losses of the first ``len(batches)`` adam steps (b1 0.9, b2
-    0.999, eps 1e-8, the schedule's learning rate), each taken before its
-    update as a trainer reports it, and the norm of every leaf's first
-    gradient. ``variables`` is consumed."""
+    0.999, eps 1e-8, the learning rate of ``config["trainer"]``'s
+    schedule), each taken before its update as a trainer reports it, and
+    the norm of every leaf's first gradient. ``variables`` is consumed."""
     params = variables["params"]
+    schedule = config["trainer"]["schedule"]
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
     losses, first_grad_norms = [], None

@@ -18,10 +18,10 @@ import time
 
 import numpy as np
 
-from chipbench.harness import device, reference, traffic
+from chipbench.harness import device, spec, traffic
 from chipbench.harness.check import Verdict, served_gaps
 
-WARM_PROMPT, WARM_NEW = 70, 4  # two chunks then decode: both tick programs
+WARM_NEW = 4  # the warm-up request decodes, after its two chunks of prefill
 REQUEST_TIMEOUT_S = 120.0
 
 
@@ -70,7 +70,7 @@ def start_system(cfg: dict, params, out_dir: str):
     from distkeras_tpu.models import get_model
     from distkeras_tpu.serving import LMServer, ServingClient, ServingEngine
 
-    model = get_model("transformer_lm", **cfg["model"],
+    model = get_model(spec.model_name(cfg), **cfg["model"],
                       dtype=jnp.dtype(cfg["compute_dtype"]))
     engine = ServingEngine(
         model, params, registry=telemetry.MetricRegistry(),
@@ -82,6 +82,13 @@ def start_system(cfg: dict, params, out_dir: str):
     return engine, server, client
 
 
+def warm_prompt_len(engine) -> int:
+    """A prompt that takes two prefill chunks, one whole and one of six
+    tokens (70 at the engine's default chunk of 64): with the decode
+    after it, both tick programs compile in set-up."""
+    return (engine.prefill_chunk or 64) + 6
+
+
 def make_request(cfg, sizes, seed, index, due=None) -> Request:
     p_len, o_len = sizes[index % len(sizes)]
     return Request(index, traffic.prompt_tokens(
@@ -90,16 +97,16 @@ def make_request(cfg, sizes, seed, index, due=None) -> Request:
 
 def run(cell: dict, seed: int, seconds: float, trace: bool, ctx) -> dict:
     cfg, mix = cell["config_spec"], cell["traffic_spec"]
-    params = reference.make_params(cfg["model"], seed)
+    params = spec.reference(cfg).make_params(cfg, seed)
     engine, server, client = start_system(cfg, params, ctx["out_dir"])
     trace_dir = os.path.join(ctx["out_dir"], f"trace.{cell['name']}")
     try:
         # warm the two tick programs on traffic of the mix's own shape
         # (other tokens), then declare the steady state
-        warm = [Request(-1 - i, traffic.prompt_tokens(
-            cfg["model"]["vocab_size"], WARM_PROMPT, seed, -1 - i), WARM_NEW)
-            for i in range(2)]
-        for w in warm:
+        for i in range(2):
+            w = Request(-1 - i, traffic.prompt_tokens(
+                cfg["model"]["vocab_size"], warm_prompt_len(engine), seed,
+                -1 - i), WARM_NEW)
             w.drive(client)
             if not w.ok:
                 raise RuntimeError(f"warm-up request failed: {w.reason} "
@@ -256,23 +263,30 @@ def sample_order(finished, seed: int):
                          traffic.rng(seed, 5).permutation(len(rest))]
 
 
-def request_readings(variables, r, precision: str = "f32"):
+def padded_length(n: int) -> int:
+    """The length the reference's sequence is padded to: the least power
+    of two that holds it, 256 at the least, so that few lengths compile."""
+    return max(256, 1 << (n - 1).bit_length())
+
+
+def request_readings(cfg, variables, r, precision: str = "f32"):
     """``(gaps, margins)`` over the served tokens of one request under
     the float32 reference: the gap of each served token, or (for a lower
     ``precision``) of the token that precision itself puts first at the
     same position, and the reference's own margin there."""
+    forward_logits = spec.reference(cfg).forward_logits
     seq = np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)])
     at = np.arange(len(r.prompt) - 1, len(seq) - 1)
-    pad = next(b for b in (256, 512, 1024, 2048, len(seq)) if b >= len(seq))
-    ref = reference.forward_logits(variables, seq, at, "f32", pad)
+    pad = padded_length(len(seq))
+    ref = forward_logits(cfg, variables, seq, at, "f32", pad)
     served = r.tokens
     if precision != "f32":
-        low = reference.forward_logits(variables, seq, at, precision, pad)
+        low = forward_logits(cfg, variables, seq, at, precision, pad)
         served = np.asarray(low.argmax(axis=-1))
     return served_gaps(ref, served)
 
 
-def sample_readings(variables, finished, seed: int, limits: dict,
+def sample_readings(cfg, variables, finished, seed: int, limits: dict,
                     precision: str = "f32"):
     """Gaps and margins of a sample of the finished requests, drawn by
     the seed with the longest in it: ``sample_requests`` of them, then
@@ -287,7 +301,7 @@ def sample_readings(variables, finished, seed: int, limits: dict,
         enough = near >= limits["near_ties_wanted"]
         if i >= limits["sample_requests"] and enough:
             break
-        gap, margin = request_readings(variables, r, precision)
+        gap, margin = request_readings(cfg, variables, r, precision)
         gaps.append(gap)
         margins.append(margin)
         near += int((margin < limits["near_tie_margin"]).sum())
@@ -305,7 +319,7 @@ def check(cell, cfg, seed, variables, finished,
     fail a sound run on one token)."""
     limits = cell["limits"]
     verdict = Verdict()
-    gaps, margins = sample_readings(variables, finished, seed, limits,
+    gaps, margins = sample_readings(cfg, variables, finished, seed, limits,
                                     precision)
     verdict.hold("requests_missing_from_sample", float(not gaps), 0.0)
     if gaps:

@@ -4,10 +4,36 @@ Everything that belongs to one configuration, one traffic mix, one cell
 or one metric sits in a file of its own under ``chipbench/``; a later PR
 adds files and edits none. ``BENCHMARK.json`` at the root of the repo is
 :func:`benchmark_json` of these files (a test holds the two together).
+
+What a later PR brings, and the key that finds it (a test adds one of
+each in a temporary root, with no file edited):
+
+- a configuration: ``configs/<name>.json``. ``"model_name"`` names the
+  architecture in ``distkeras_tpu.models``' registry (absent:
+  ``transformer_lm``), built with ``**"model"``; ``"reference"`` names
+  the module of its plain reference (absent:
+  ``chipbench.harness.reference``; see :func:`reference` for what such
+  a module holds).
+- a traffic mix: ``traffic/<name>.json``, parameters of a ``"kind"`` the
+  one generator reads (``chipbench.run.runner_for`` goes by it). The
+  warm-up request follows from the engine (its prefill chunk and six
+  tokens more: two chunks, then decode), not from the mix.
+- a cell: ``cells/<name>.json`` over a configuration and a mix.
+- a per-layer metric: ``layer_metrics/<name>.json`` with its reader. For
+  a cell that exists it names the cell under ``"cells"`` and joins that
+  cell's ``per_layer`` after the cell's own list; a new cell may also
+  list it itself; a ``"cells"`` entry that names no cell is refused.
+  ``"since"`` is the number of the PR that brought the file:
+  ``BENCHMARK.json`` lists per-layer metrics by (``since``, name), so
+  every accepted entry stays where it is and a new one comes after. A
+  later PR's metric file carries its own number; only the eight files
+  PR 24 was accepted with say none (``FIRST``), and any other file
+  that says none is refused.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -18,6 +44,11 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 COMMAND = ["python3", "-m", "chipbench.run"]
 RUN_SECONDS_FILE = "run_seconds.json"
+FIRST_PR = 24  # "since" of the eight metric files that do not say
+FIRST = frozenset((
+    "causal_attention_roofline.train", "device_idle_pct.knee",
+    "device_idle_pct.sat", "device_idle_pct.train", "mfu_pct.train",
+    "occupancy_pct.sat", "tick_device_ms.knee", "tick_host_pct.sat"))
 
 
 def _check_name(name: str, what: str):
@@ -56,9 +87,64 @@ def metric(kind: str, name: str, root: str = ROOT) -> dict:
     return m
 
 
+def named(path: str):
+    """The object ``"<module>:<attribute>"`` names."""
+    module, attribute = path.split(":")
+    return getattr(importlib.import_module(module), attribute)
+
+
+def model_name(config: dict) -> str:
+    """The architecture's name in ``distkeras_tpu.models``' registry."""
+    return config.get("model_name", "transformer_lm")
+
+
+def reference(config: dict):
+    """The module that holds a configuration's plain reference. It
+    imports nothing of the program, and every function takes the whole
+    configuration first: a reference may need what no weight's shape
+    says (rope's base, the experts held, top-k, group limits).
+
+    Serving (``serve_runner``):
+
+    - ``PRECISIONS``: ``"f32"`` and the names of the lower precisions
+      the control can be computed in; ``config["precision"]["control"]``
+      is one of them.
+    - ``make_params(config, seed) -> variables``: the weights, on the
+      device in one jitted call from the seed, in the dtypes
+      ``config["precision"]["parameters"]`` states, laid out as the
+      program's model reads them. The program is handed these.
+    - ``forward_logits(config, variables, tokens, at, precision,
+      pad_to) -> [len(at), V]``: float32 logits of the one sequence
+      ``tokens`` at the positions ``at``, computed in ``precision``,
+      the sequence padded to ``pad_to`` so that few lengths compile.
+
+    Training (``train_runner``; a serving-only configuration's
+    reference need not have these):
+
+    - ``row_losses(config, variables, batch, precision) -> [B]``: each
+      row's mean next-token loss, forward only.
+    - ``train_losses(config, variables, batches, precision) -> (losses,
+      first_grad_norms)``: the losses of the first ``len(batches)``
+      optimizer steps under ``config["trainer"]``, each before its
+      update, and the norm of every leaf's first gradient;
+      ``variables`` is consumed.
+    """
+    return importlib.import_module(
+        config.get("reference", "chipbench.harness.reference"))
+
+
+def _age(m: dict):
+    """Accepted entries stay where they are, a later PR's come after."""
+    if "since" not in m and m["name"] not in FIRST:
+        raise ValueError(f"layer_metrics/{m['name']}: no \"since\" (the "
+                         f"number of the PR that brings the file)")
+    return m.get("since", FIRST_PR), m["name"]
+
+
 def cell(name: str, root: str = ROOT) -> dict:
     """One cell with its configuration, traffic mix and metrics resolved,
-    and every cross-reference checked."""
+    and every cross-reference checked. After the cell's own ``per_layer``
+    come the metric files that name the cell under ``"cells"``."""
     c = load("cells", name, root)
     _line(c["why"], f"cells/{name} why")
     if c["chips"] not in (1, 4):
@@ -67,6 +153,11 @@ def cell(name: str, root: str = ROOT) -> dict:
     c["traffic_spec"] = load("traffic", c["traffic"], root)
     c["end_to_end_specs"] = [metric("end_to_end", m, root)
                              for m in c["end_to_end"]]
+    waiting = [m for m in (load("layer_metrics", n, root)
+                           for n in names("layer_metrics", root))
+               if name in m.get("cells", ())
+               and m["name"] not in c["per_layer"]]
+    c["per_layer"] += [m["name"] for m in sorted(waiting, key=_age)]
     c["per_layer_specs"] = [metric("layer_metrics", m, root)
                             for m in c["per_layer"]]
     # readings kept beside the result with no claim to move a metric
@@ -79,6 +170,7 @@ def cell(name: str, root: str = ROOT) -> dict:
     if not c["per_layer"]:
         raise ValueError(f"cells/{name}: no per-layer metric")
     for m in c["per_layer_specs"]:
+        _age(m)
         _line(m["layer"], f"layer_metrics/{m['name']} layer")
         if m["moves"] not in c["end_to_end"]:
             raise ValueError(
@@ -95,7 +187,13 @@ def run_seconds(root: str = ROOT) -> int:
 def benchmark_json(root: str = ROOT) -> dict:
     """What ``BENCHMARK.json`` has to hold, from the files alone."""
     rel = os.path.basename(root)
-    cells = [cell(n, root) for n in names("cells", root)]
+    known = names("cells", root)
+    for n in names("layer_metrics", root):
+        for c in load("layer_metrics", n, root).get("cells", ()):
+            if c not in known:
+                raise ValueError(f"layer_metrics/{n}: \"cells\" names "
+                                 f"{c!r}, which is no cell")
+    cells = [cell(n, root) for n in known]
     cells.sort(key=lambda c: (c.get("order", 1 << 30), c["name"]))
     configs, seen = [], set()
     for c in cells:
@@ -128,13 +226,12 @@ def benchmark_json(root: str = ROOT) -> dict:
         if len(e2e_cells[n]) < len(cells):
             row["workloads"] = e2e_cells[n]
         end_to_end.append(row)
-    per_layer = []
-    for n in sorted(layer_cells):
-        m = metric("layer_metrics", n, root)
-        per_layer.append({
-            "name": n, "unit": m["unit"], "better": m["better"],
-            "source": m["source"], "layer": m["layer"],
-            "moves": m["moves"], "workloads": layer_cells[n]})
+    layer_specs = sorted((metric("layer_metrics", n, root)
+                          for n in layer_cells), key=_age)
+    per_layer = [{
+        "name": m["name"], "unit": m["unit"], "better": m["better"],
+        "source": m["source"], "layer": m["layer"], "moves": m["moves"],
+        "workloads": layer_cells[m["name"]]} for m in layer_specs]
     return {
         "command": COMMAND, "paths": [rel],
         "run_seconds": run_seconds(root), "configs": configs,

@@ -15,7 +15,7 @@ import time
 
 import numpy as np
 
-from chipbench.harness import device, reference, traffic
+from chipbench.harness import device, spec, traffic
 from chipbench.harness.check import Verdict
 
 CHECK_STEPS = 3  # the reference follows the first three optimizer steps
@@ -32,7 +32,7 @@ def build_trainer(cfg: dict, params, corpus, epochs: int, seed: int,
 
     tr = cfg["trainer"]
     sched = tr["schedule"]
-    model = get_model("transformer_lm", **cfg["model"],
+    model = get_model(spec.model_name(cfg), **cfg["model"],
                       dtype=jnp.dtype(cfg["compute_dtype"]),
                       remat=tr["remat"])
     optimizer = optax.adam(optax.linear_schedule(
@@ -68,7 +68,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, ctx) -> dict:
     # was sized at, and the rate is then taken over the stamps themselves
     epochs = 1 + max(2, math.ceil(
         seconds * job["nominal_tokens_per_s"] / tokens_per_epoch))
-    params = reference.make_params(cfg["model"], seed)
+    params = spec.reference(cfg).make_params(cfg, seed)
     corpus = traffic.unigram_corpus(job, cfg["model"]["vocab_size"],
                                     S * B, seed)
     metrics_path = os.path.join(ctx["out_dir"], f"{cell['name']}.metrics.jsonl")
@@ -125,6 +125,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, ctx) -> dict:
         "setup_s": (t_call - ctx["proc_start"]) + stamps[0],
         "window_s": window_s,
         "train_tok_s": (epochs - 1) * tokens_per_epoch / window_s,
+        # beside the rate, so that a whole run that reads slow says
+        # whether every epoch was slow or one of them stalled
+        "epoch_s_median": float(np.median(np.diff(stamps))),
+        "epoch_s_max": float(np.max(np.diff(stamps))),
         "attempted": len(window_losses),
         "failed": int(sum(not math.isfinite(x) for x in window_losses)),
         "compiles_in_window": in_window, "device": device_peak,
@@ -156,10 +160,11 @@ def check(cell: dict, cfg: dict, seed: int, corpus, losses,
     B = cfg["trainer"]["batch_size"]
     batches = np.asarray(corpus).reshape(-1, B, corpus.shape[1])[:CHECK_STEPS]
 
+    reference = spec.reference(cfg)
+
     def follow(p):
         return reference.train_losses(
-            reference.make_params(cfg["model"], seed), batches,
-            cfg["trainer"]["schedule"], p)[0]
+            cfg, reference.make_params(cfg, seed), batches, p)[0]
 
     ref_losses = follow("f32")
     first = losses if precision == "f32" else follow(precision)

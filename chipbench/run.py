@@ -57,7 +57,6 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, devices,
                       "readings": {k: v for k, v in run.items()
                                    if isinstance(v, (int, float))}}),
           flush=True)
-    run["verdict"].print()
     reduced = None
     if trace:
         reduced = trace_reduce.reduce_events(
@@ -83,6 +82,10 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, devices,
         result["device"]["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    # each number compared beside its limit: last in the line, and the
+    # last lines of standard error
+    result["compared"] = run["verdict"].compared()
+    run["verdict"].print()
     return result
 
 

@@ -4,6 +4,7 @@ Run with ``python -m pytest chipbench/tests -q -p no:cacheprovider``.
 """
 
 import copy
+import functools
 import json
 import os
 import shutil
@@ -77,27 +78,31 @@ def test_configs_keep_every_published_width(role):
         assert cfg["n_layer"] == 24
 
 
-def test_files_alone_add_a_config_a_mix_a_cell_and_a_metric(tmp_path):
+def _copied_root(tmp_path) -> str:
     root = str(tmp_path / "chipbench")
-    for kind in ("configs", "traffic", "cells", "layer_metrics",
-                 "end_to_end", "recorded"):
-        shutil.copytree(os.path.join(spec.ROOT, kind),
-                        os.path.join(root, kind))
-    shutil.copy(os.path.join(spec.ROOT, spec.RUN_SECONDS_FILE), root)
+    shutil.copytree(spec.ROOT, root, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests", "tools", "harness", "kernels", "*.py"))
+    return root
+
+
+def _add(root, kind, name, base, drop=(), **changes):
+    new = {k: v for k, v in spec.load(kind, base, root).items()
+           if k != "name" and k not in drop}
+    new.update(changes)
+    with open(os.path.join(root, kind, name + ".json"), "w") as f:
+        json.dump(new, f)
+
+
+def test_files_alone_add_a_config_a_mix_a_cell_and_a_metric(tmp_path):
+    root = _copied_root(tmp_path)
     before = spec.benchmark_json(root)
 
-    def add(kind, name, base, **changes):
-        new = {k: v for k, v in spec.load(kind, base, root).items()
-               if k != "name"}
-        new.update(changes)
-        with open(os.path.join(root, kind, name + ".json"), "w") as f:
-            json.dump(new, f)
-
+    add = functools.partial(_add, root)
     add("configs", "cerebras-gpt-1.3b-serve8", "cerebras-gpt-1.3b-serve",
         engine={"slots": 8, "max_len": 2048})
     add("traffic", "chat-sat64", "chat-sat", clients=64)
     add("layer_metrics", "tick_device_ms.sat64", "tick_device_ms.knee",
-        moves="serve_tok_s")
+        moves="serve_tok_s", since=99)
     add("cells", "serve8-chat-sat64", "serve-chat-sat", order=9,
         config="cerebras-gpt-1.3b-serve8", traffic="chat-sat64",
         per_layer=["occupancy_pct.sat", "tick_device_ms.sat64"])
@@ -114,7 +119,128 @@ def test_files_alone_add_a_config_a_mix_a_cell_and_a_metric(tmp_path):
     # and the harness runs the new cell with no line of code added
     cell = spec.cell("serve8-chat-sat64", root)
     assert cell["traffic_spec"]["clients"] == 64
-    assert entry.runner_for(cell["traffic_spec"]["kind"])
+    assert entry.runner_for(cell["traffic_spec"]["kind"]) is serve_runner.run
+
+
+@pytest.fixture
+def rope_lm(monkeypatch):
+    """A second name in the program's registry: the same stack with
+    rotary positions, as a later PR's architecture would register its
+    own class."""
+    from distkeras_tpu.models import registry
+
+    transformer_lm = registry._REGISTRY["transformer_lm"]
+    monkeypatch.setitem(
+        registry._REGISTRY, "rope_lm",
+        lambda **kwargs: transformer_lm(pos_emb="rope", **kwargs))
+
+
+def test_files_alone_add_a_model_a_reference_and_a_waiting_metric(
+        tmp_path, out_dir, capsys, rope_lm):
+    """What a later PR brings, rehearsed: a configuration whose model is
+    another name of the registry and whose block the accepted reference
+    does not compute, with a reference of its own; a metric for a cell
+    that exists. Every seam is crossed by a name in a new file, and no
+    file is edited."""
+    root = _copied_root(tmp_path)
+    before = spec.benchmark_json(root)
+    _add(root, "configs", "rope-serve", "cerebras-gpt-1.3b-serve",
+         model_name="rope_lm", rope_theta=10000.0,
+         reference="chipbench.tests.rope_reference")
+    _add(root, "cells", "rope-chat-sat", "serve-chat-sat", order=9,
+         config="rope-serve")
+    _add(root, "layer_metrics", "loop_host_ms.late", "loop_host_ms.sat",
+         cells=["serve-chat-sat", "rope-chat-sat"], since=99)
+    for key in ("reference", "model_name"):
+        _add(root, "configs", f"rope-serve-no-{key}", "rope-serve",
+             drop=(key,))
+        _add(root, "cells", f"rope-chat-sat-no-{key}", "rope-chat-sat",
+             config=f"rope-serve-no-{key}")
+
+    # the metric joins the accepted cell after its own and the waiting
+    # ones, and comes last in BENCHMARK.json; every entry before it stays
+    assert spec.cell("serve-chat-sat", root)["per_layer"] == \
+        spec.cell("serve-chat-sat")["per_layer"] + ["loop_host_ms.late"]
+    after = spec.benchmark_json(root)
+    assert after["per_layer"][-1]["name"] == "loop_host_ms.late"
+    assert after["per_layer"][-1]["workloads"] == ["serve-chat-sat",
+                                                   "rope-chat-sat"]
+    # an accepted metric the new cells list themselves gains them; no
+    # entry moves
+    assert [m["name"] for m in after["per_layer"][:-1]] == \
+        [m["name"] for m in before["per_layer"]]
+
+    cell = tiny_cell("rope-chat-sat", root)
+    assert spec.model_name(cell["config_spec"]) == "rope_lm"
+    assert "pos_emb" not in cell["config_spec"]["model"]
+    assert spec.reference(cell["config_spec"]).__name__ == \
+        "chipbench.tests.rope_reference"
+    capsys.readouterr()
+    result = entry.execute(cell, SEED, 1.5, False, jax.devices()[:1], out_dir)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 0
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["served_logit_gap.max"]["value"] <= \
+        result["compared"]["served_logit_gap.max"]["limit"] == 1e-3
+    assert capsys.readouterr().err.rstrip().splitlines()[-1].startswith(
+        '{"check"')
+    # the same files with either name taken out: the accepted reference
+    # against the rotary model, the rotary reference against the default
+    # model. Each dispatch chose, not chance.
+    for key in ("reference", "model_name"):
+        wrong = tiny_cell(f"rope-chat-sat-no-{key}", root)
+        assert (spec.reference(wrong["config_spec"]) is reference) == \
+            (key == "reference")
+        assert (spec.model_name(wrong["config_spec"]) == "transformer_lm") \
+            == (key == "model_name")
+        assert entry.execute(wrong, SEED, 1.5, False, jax.devices()[:1],
+                             out_dir)["correct"] is False
+
+
+def test_a_metric_file_that_names_no_cell_or_no_pr_is_refused(tmp_path):
+    root = _copied_root(tmp_path)
+    _add(root, "layer_metrics", "loop_host_ms.typo", "loop_host_ms.sat",
+         cells=["serve-chat-sta"])
+    with pytest.raises(ValueError, match="which is no cell"):
+        spec.benchmark_json(root)
+    # without "since" it would sort among the accepted eight and move them
+    _add(root, "layer_metrics", "loop_host_ms.typo", "loop_host_ms.sat",
+         drop=("since",))
+    with pytest.raises(ValueError, match='no "since"'):
+        spec.cell("serve-chat-sat", root)
+    with pytest.raises(ValueError, match='no "since"'):
+        spec.benchmark_json(root)
+    _add(root, "layer_metrics", "loop_host_ms.typo", "loop_host_ms.sat",
+         since=30)
+    assert spec.benchmark_json(root)["per_layer"][-1]["name"] == \
+        "loop_host_ms.typo"
+    # the eight PR 24 was accepted with are the ones that say none
+    assert spec.FIRST == {n for n in spec.names("layer_metrics")
+                          if "since" not in spec.load("layer_metrics", n)}
+    assert [m["name"] for m in spec.benchmark_json()["per_layer"][:8]] == \
+        sorted(spec.FIRST)
+
+
+@pytest.mark.parametrize("length,padded", [
+    (1, 256), (256, 256), (257, 512), (1024, 1024), (1025, 2048),
+    (2048, 2048), (2049, 4096), (5000, 8192)])
+def test_the_references_sequence_pads_to_a_power_of_two(length, padded):
+    assert serve_runner.padded_length(length) == padded
+    if length <= 2048:  # what the accepted cells compile, as before
+        assert padded == next(b for b in (256, 512, 1024, 2048)
+                              if b >= length)
+
+
+def test_the_warm_up_prompt_follows_the_engines_chunk():
+    class Engine:
+        prefill_chunk = 64
+
+    assert serve_runner.warm_prompt_len(Engine) == 70  # as the parent's
+    Engine.prefill_chunk = 16
+    assert serve_runner.warm_prompt_len(Engine) == 22
+    with pytest.raises(ValueError, match="unknown traffic kind"):
+        entry.runner_for("no_such_kind")
+    assert entry.runner_for("train_job") is train_runner.run
 
 
 def test_bad_names_and_references_are_refused(tmp_path):
@@ -122,9 +248,7 @@ def test_bad_names_and_references_are_refused(tmp_path):
         spec.load("cells", "no such cell")
     cell = spec.cell(CELLS[0])
     bad = dict(cell["per_layer_specs"][0], moves="not_reported")
-    root = str(tmp_path / "chipbench")
-    shutil.copytree(spec.ROOT, root, ignore=shutil.ignore_patterns(
-        "__pycache__", "tests", "tools", "harness", "kernels"))
+    root = _copied_root(tmp_path)
     name = cell["per_layer"][0]
     with open(os.path.join(root, "layer_metrics", name + ".json"), "w") as f:
         json.dump({k: v for k, v in bad.items() if k != "name"}, f)
@@ -193,12 +317,16 @@ def test_percentile(values, p, want):
 # -- the reference ------------------------------------------------------------
 
 
+TINY_CONFIG = tiny_cell("serve-chat-sat")["config_spec"]
+
+
 @pytest.fixture(scope="module")
 def tiny_lm():
     from distkeras_tpu.models import get_model
 
-    model = get_model("transformer_lm", **TINY_MODEL, dtype=jnp.float32)
-    return model, reference.make_params(TINY_MODEL, SEED)
+    model = get_model(spec.model_name(TINY_CONFIG), **TINY_MODEL,
+                      dtype=jnp.float32)
+    return model, reference.make_params(TINY_CONFIG, SEED)
 
 
 def test_weights_have_the_programs_layout_and_follow_the_seed(tiny_lm):
@@ -208,8 +336,8 @@ def test_weights_have_the_programs_layout_and_follow_the_seed(tiny_lm):
     assert jax.tree.structure(want) == jax.tree.structure(params)
     assert all(a.shape == b.shape and a.dtype == b.dtype for a, b in
                zip(jax.tree.leaves(want), jax.tree.leaves(params)))
-    again = reference.make_params(TINY_MODEL, SEED)
-    other = reference.make_params(TINY_MODEL, SEED + 1)
+    again = reference.make_params(TINY_CONFIG, SEED)
+    other = reference.make_params(TINY_CONFIG, SEED + 1)
     for a, b, c in zip(*map(jax.tree.leaves, (params, again, other))):
         assert np.array_equal(a, b) and not np.array_equal(a, c)
 
@@ -221,7 +349,8 @@ def test_reference_agrees_with_the_model_in_float32(tiny_lm):
     toks = traffic.rng(SEED, 9).integers(0, 211, size=(3, 40)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         want = model.apply(params, jnp.asarray(toks))
-    got = reference.forward_logits(params, toks[0], np.arange(40), pad_to=64)
+    got = reference.forward_logits(TINY_CONFIG, params, toks[0],
+                                   np.arange(40), pad_to=64)
     assert float(jnp.abs(want[0] - got).max()) < 1e-4
 
     def objective(p):
@@ -236,7 +365,8 @@ def test_reference_agrees_with_the_model_in_float32(tiny_lm):
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
         assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
             jnp.linalg.norm(a)) + 1e-9
-    assert np.mean(reference.row_losses(params, toks)) == pytest.approx(
+    assert np.mean(reference.row_losses(TINY_CONFIG, params,
+                                        toks)) == pytest.approx(
         ref_loss, rel=1e-5)
 
 
@@ -244,8 +374,9 @@ def test_int8_control_moves_the_logits(tiny_lm):
     _, params = tiny_lm
     toks = traffic.rng(SEED, 9).integers(0, 211, size=40).astype(np.int32)
     at = np.arange(40)
-    exact = reference.forward_logits(params, toks, at)
-    low = reference.forward_logits(params, toks, at, precision="int8")
+    exact = reference.forward_logits(TINY_CONFIG, params, toks, at)
+    low = reference.forward_logits(TINY_CONFIG, params, toks, at,
+                                   precision="int8")
     assert 1e-3 < float(jnp.abs(exact - low).max()) < 1.0
 
 
@@ -260,12 +391,13 @@ def test_the_sample_grows_until_it_holds_enough_near_ties(monkeypatch):
     gap = np.array([0.2] + [0.0] * 9, np.float32)
     margin = np.array([0.01, 0.02] + [0.5] * 8, np.float32)
     monkeypatch.setattr(serve_runner, "request_readings",
-                        lambda variables, r, precision="f32": (gap, margin))
+                        lambda cfg, variables, r, precision="f32": (
+                            gap, margin))
     limits = {"sample_requests": 3, "sample_requests_max": 8,
               "near_tie_margin": 0.05, "near_ties_wanted": 10,
               "far_off_gap": 0.1, "served_logit_gap_max": 1.0,
               "served_far_off_per_near_tie": 0.6}
-    gaps, _ = serve_runner.sample_readings(None, reqs, SEED, limits)
+    gaps, _ = serve_runner.sample_readings(None, None, reqs, SEED, limits)
     assert len(gaps) == 5
     assert serve_runner.sample_order(reqs, SEED)[0] is reqs[-1]
     cell = {"limits": limits}

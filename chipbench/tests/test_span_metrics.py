@@ -197,36 +197,51 @@ WAITING = {
                     "optimizer_device_pct.train"]}
 
 
-@pytest.mark.parametrize("name", sorted(WAITING))
-def test_waiting_metrics_join_their_cell_in_span_run_alone(name):
-    """The metrics this PR brings name their cell in their own file;
-    ``tools.span_run`` puts them after the cell's own, held to the same
-    checks, and each names a reader and a function that exist. The
-    cell's file, ``spec.cell`` and ``BENCHMARK.json`` know nothing of
-    them (``test_chipbench`` holds those two together)."""
-    import importlib
+@pytest.mark.parametrize("name,metric", [
+    (cell, m) for cell in sorted(WAITING) for m in WAITING[cell]])
+def test_a_waiting_metric_joins_its_cell_through_spec(name, metric):
+    """A metric that PR 25 brought names its cell in its own file:
+    ``spec.cell`` puts it after the cell's own list, held to the same
+    checks; it names a reader and a function that exist; and
+    ``BENCHMARK.json`` lists it with that workload after the eight
+    entries PR 24 was accepted with."""
+    import json
+    import os
 
     from chipbench.harness import readers, spec
-    from chipbench.tools import span_run
 
-    own = spec.cell(name)["per_layer"]
-    assert span_run.waiting(name) == WAITING[name]
-    assert not set(WAITING[name]) & set(own)
-    cell = span_run.cell_with_waiting(name)
+    with open(os.path.join(spec.ROOT, "cells", name + ".json")) as f:
+        own = json.load(f)["per_layer"]
+    cell = spec.cell(name)
     assert cell["per_layer"] == own + WAITING[name]
     assert [m["name"] for m in cell["per_layer_specs"]] == cell["per_layer"]
-    for m in cell["per_layer_specs"][len(own):]:
-        assert m["moves"] in cell["end_to_end"] and m["cells"] == [name]
-        assert m["reader"] in readers.READERS
-        module, fn = m.get("function", m.get("counts")).split(":")
-        assert callable(getattr(importlib.import_module(module), fn))
-    listed = {m["name"] for m in spec.benchmark_json()["per_layer"]}
-    assert not listed & set(WAITING[name])
+    m = cell["per_layer_specs"][cell["per_layer"].index(metric)]
+    assert m["moves"] in cell["end_to_end"]
+    assert m["cells"] == [name] and m["since"] == 25
+    assert m["reader"] in readers.READERS
+    assert callable(spec.named(m.get("function", m.get("counts"))))
+    with open(os.path.join(os.path.dirname(spec.ROOT),
+                           "BENCHMARK.json")) as f:
+        listed = json.load(f)["per_layer"]
+    accepted = [e["name"] for e in listed[:8]]
+    assert metric not in accepted
+    assert all(spec.load("layer_metrics", n).get("since", 24) == 24
+               for n in accepted)
+    entry = [e for e in listed[8:] if e["name"] == metric]
+    assert len(entry) == 1 and entry[0]["workloads"] == [name]
+    assert entry[0]["moves"] == m["moves"] and entry[0]["layer"] == m["layer"]
 
 
-def test_span_run_without_a_chip_gives_no_result(capsys):
+@pytest.mark.parametrize("through", ["run", "span_run"])
+def test_a_traced_run_without_a_chip_gives_no_result(through, capsys):
+    """``tools.span_run`` is a forwarder to ``chipbench.run --trace 1``
+    (documents outside the benchmark still name it)."""
+    from chipbench import run
     from chipbench.tools import span_run
 
-    assert span_run.main(["--workload", "serve-chat-sat", "--seed", "1",
-                          "--seconds", "1"]) == 3
+    argv = ["--workload", "serve-chat-sat", "--seed", "1", "--seconds", "1"]
+    if through == "run":
+        assert run.main(argv + ["--trace", "1"]) == 3
+    else:
+        assert span_run.main(argv) == 3
     assert "correct" not in capsys.readouterr().out

@@ -9,10 +9,11 @@ TINY_MODEL = {"vocab_size": 211, "d_model": 64, "num_heads": 4,
               "num_layers": 2, "max_len": 128}
 
 
-def tiny_cell(name: str) -> dict:
-    cell = copy.deepcopy(spec.cell(name))
+def tiny_cell(name: str, root: str = spec.ROOT) -> dict:
+    cell = copy.deepcopy(spec.cell(name, root))
     cfg, mix = cell["config_spec"], cell["traffic_spec"]
-    cfg["model"] = dict(TINY_MODEL)
+    # the sizes are replaced; what else the file states (positions) stays
+    cfg["model"] = {**cfg["model"], **TINY_MODEL}
     cfg["compute_dtype"] = "float32"
     if cfg["role"] == "train":
         cfg["trainer"]["batch_size"] = 2

@@ -30,9 +30,14 @@ def one(cell_name: str, seed: int, seconds: float, variant: str = "program",
     import numpy as np
 
     from chipbench import run as entry
-    from chipbench.harness import device, reference
+    from chipbench.harness import device
 
     cell = spec.cell(cell_name)
+    reference = spec.reference(cell["config_spec"])
+    low = cell["config_spec"]["precision"]["control"]
+    if low not in reference.PRECISIONS:
+        raise ValueError(f"configs/{cell['config']}: control precision "
+                         f"{low!r} not among {reference.PRECISIONS}")
     if variant == "int8cache":  # the program's own lower-precision path
         cell["config_spec"]["model"]["cache_dtype"] = "int8"
     device.enable_compile_cache()
@@ -45,15 +50,15 @@ def one(cell_name: str, seed: int, seconds: float, variant: str = "program",
     if kind == "train_job":
         from chipbench.harness import train_runner
 
-        control = train_runner.check(*run["check_args"], precision="int8")
+        control = train_runner.check(*run["check_args"], precision=low)
         _, cfg, _, corpus, losses = run["check_args"]
         B = cfg["trainer"]["batch_size"]
         batches = np.asarray(corpus).reshape(-1, B, corpus.shape[1])[
             :train_runner.CHECK_STEPS]
         want = [r["reference"] for r in run["verdict"].rows
                 if "reference" in r]
-        start = reference.make_params(cfg["model"], seed)
-        rows = [reference.row_losses(start, b) for b in batches]
+        start = reference.make_params(cfg, seed)
+        rows = [reference.row_losses(cfg, start, b) for b in batches]
         # the faults the loss limits are there to catch, read off the
         # reference: a quarter of the batch left out; a step that returns
         # its state unchanged (every loss stays at the start's weights);
@@ -62,29 +67,30 @@ def one(cell_name: str, seed: int, seconds: float, variant: str = "program",
             abs(np.mean(r[:-1]) - np.mean(r)) / np.mean(r) for r in rows]
         out["fault_state_unchanged"] = [
             abs(np.mean(r) - w) / w for r, w in zip(rows, want)]
-        sched = dict(cfg["trainer"]["schedule"])
-        sched.update(init=0.9 * sched["init"], peak=0.9 * sched["peak"])
-        scaled, _ = reference.train_losses(start, batches, sched)
+        sched = cfg["trainer"]["schedule"]
+        slow = dict(cfg, trainer=dict(cfg["trainer"], schedule=dict(
+            sched, init=0.9 * sched["init"], peak=0.9 * sched["peak"])))
+        scaled, _ = reference.train_losses(slow, start, batches)
         out["fault_update_scaled_by_0.9"] = [
             abs(s - w) / w for s, w in zip(scaled, want)]
     else:
         from chipbench.harness import serve_runner
 
-        control = serve_runner.check(*run["check_args"], precision="int8")
+        control = serve_runner.check(*run["check_args"], precision=low)
         if sample:  # every compared token's readings, for setting limits
-            _, _, _, params, finished = run["check_args"]
+            _, cfg, _, params, finished = run["check_args"]
             limits = dict(cell["limits"], sample_requests=sample,
                           sample_requests_max=sample)
             gap, margin = serve_runner.sample_readings(
-                params, finished, seed, limits)
-            low = (serve_runner.sample_readings(
-                params, finished, seed, limits, "int8")[0]
+                cfg, params, finished, seed, limits)
+            control_gap = (serve_runner.sample_readings(
+                cfg, params, finished, seed, limits, low)[0]
                 if variant == "program" else [np.zeros(0)])
             os.makedirs(OUT, exist_ok=True)
             np.savez(os.path.join(
                 OUT, f"{cell_name}.tokens.{variant}.{seed}.npz"),
                 gap=np.concatenate(gap), margin=np.concatenate(margin),
-                control_gap=np.concatenate(low),
+                control_gap=np.concatenate(control_gap),
                 lens=[len(g) for g in gap], finished=len(finished))
     out["control"] = control.rows
     out["control_correct"] = control.correct

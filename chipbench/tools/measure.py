@@ -73,9 +73,8 @@ def runs(args):
                                  peak=r["device"]["memory_peak_bytes"],
                                  **{m: v["value"] for m, v in
                                     r["metrics"].items()})
-                    brief["checks"] = [(n["check"]["compared"],
-                                        n["check"]["value"])
-                                       for n in row["notes"] if "check" in n]
+                    brief["checks"] = [(k, v["value"]) for k, v in
+                                       r.get("compared", {}).items()]
                     brief["readings"] = [n["readings"] for n in row["notes"]
                                          if "readings" in n]
                 else:
