@@ -1,0 +1,458 @@
+"""DeepSeek-V3.2-Exp's language model for the serving path: RMSNorm,
+leading dense SwiGLU layers then expert layers, multi-head latent
+attention (MLA) with the lightning indexer's top-k selection, and
+group-limited sigmoid routing over all experts of which this chip holds
+a share.
+
+The layer equations are ISSUE 28's and ``chipbench/references/
+deepseek_v32.py`` follows them in plain float32; this module is the
+program. What it does differently from the plain form, with the same
+mathematics:
+
+- **Absorbed attention.** The cache holds per position one latent ``[c_kv
+  | rope(k_r)]`` (``kv_lora_rank + qk_rope_head_dim`` wide) and one
+  index key; ``W_ukv``'s key half is folded into the query and its
+  value half into the output, so every tick kind attends the latent
+  itself: one shared 576-wide key under all heads
+  (:mod:`distkeras_tpu.ops.mla`).
+- **Selection by threshold.** The positions a query may attend are
+  those whose index score is at least the query's ``index_topk``-th
+  largest, found exactly by a search on the score's bits.
+- **One chip's share of the experts.** The router scores all
+  ``n_routed_experts``; the layer holds ``experts_held`` of them
+  (``expert_rank`` says which) and computes their part by a dropless
+  grouped matmul (:func:`distkeras_tpu.ops.moe.dropless_held_experts`).
+  The gate's normalisation runs over all chosen experts; the shared
+  expert is whole; what the absent experts would add is left out.
+
+Departures from the published model (the reference has the same): the
+indexer's FP8 quantisation and the Hadamard rotation before it are left
+out (an orthogonal rotation changes no score in exact arithmetic); rope
+pairs channel ``i`` with ``i + half`` in MLA and indexer alike (the
+published checkpoint interleaves MLA's pairs: a fixed permutation of
+``wq_b``'s and ``wkv_a``'s rope columns at load); no multi-token
+prediction module.
+
+Cache leaves a layer (collection ``cache``, decode mode): ``latent [S,
+L, 576]``, ``index_key [S, L, 128]`` and the cursor ``cache_index [S]``;
+rope is applied at each row's own cursor. The residual stream and the
+norms are float32; matmul operands are ``dtype`` with float32
+accumulation; router scores and logits are float32.
+
+The serving engine reads two things off the class besides the module
+fields: ``tick_counters`` (names sown into the ``counters`` collection,
+returned with a tick's tokens) and :meth:`serving_refusals`.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distkeras_tpu.models.registry import register_model
+from distkeras_tpu.ops import mla
+from distkeras_tpu.ops.moe import dropless_held_experts, group_limited_route
+
+
+def _normal(fan_in_axes: int = 1):
+    """Fan-in scaled normal over the first ``fan_in_axes`` axes."""
+    def init(key, shape, dtype):
+        fan_in = int(np.prod(shape[:fan_in_axes]))
+        return (jax.random.normal(key, shape, jnp.float32)
+                / np.sqrt(fan_in)).astype(dtype)
+    return init
+
+
+def _dot(x, kernel, dtype):
+    """``x [..., in] @ kernel [in, ...]``: operands in ``dtype``, float32
+    accumulation and result."""
+    return jax.lax.dot_general(
+        x.astype(dtype), kernel.astype(dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, scale, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * scale.astype(jnp.float32)
+
+
+class SwiGLU(nn.Module):
+    """``W_down(silu(W_gate u) * W_up u)``; float32 out."""
+    width: int
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        d = u.shape[-1]
+        w_gate = self.param("w_gate", _normal(), (d, self.width),
+                            self.param_dtype)
+        w_up = self.param("w_up", _normal(), (d, self.width),
+                          self.param_dtype)
+        w_down = self.param("w_down", _normal(), (self.width, d),
+                            self.param_dtype)
+        h = jax.nn.silu(_dot(u, w_gate, self.dtype)) * _dot(
+            u, w_up, self.dtype)
+        return _dot(h, w_down, self.dtype)
+
+
+class LatentAttention(nn.Module):
+    """MLA with the lightning indexer; see the module docstring."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    inv_freq: tuple  # YaRN's, one per rope pair
+    softmax_scale: float
+    rms_eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+    cache_len: int = 0
+    kv_tile: int = 512
+
+    @nn.compact
+    def __call__(self, u, valid_lens=None):
+        B, T, d = u.shape
+        H, R, rope = self.num_heads, self.kv_lora_rank, self.qk_rope_head_dim
+        nope, J, Di = (self.qk_nope_head_dim, self.index_n_heads,
+                       self.index_head_dim)
+        pd, dt = self.param_dtype, self.dtype
+        wq_a = self.param("wq_a", _normal(), (d, self.q_lora_rank), pd)
+        q_norm = self.param("q_norm", nn.initializers.ones,
+                            (self.q_lora_rank,), pd)
+        wq_b = self.param("wq_b", _normal(),
+                          (self.q_lora_rank, H, nope + rope), pd)
+        wkv_a = self.param("wkv_a", _normal(), (d, R + rope), pd)
+        kv_norm = self.param("kv_norm", nn.initializers.ones, (R,), pd)
+        wkv_b = self.param("wkv_b", _normal(),
+                           (R, H, nope + self.v_head_dim), pd)
+        wo = self.param("wo", _normal(2), (H, self.v_head_dim, d), pd)
+        iq_b = self.param("index_wq_b", _normal(),
+                          (self.q_lora_rank, J, Di), pd)
+        ik_w = self.param("index_wk", _normal(), (d, Di), pd)
+        ik_scale = self.param("index_k_norm_scale", nn.initializers.ones,
+                              (Di,), pd)
+        ik_bias = self.param("index_k_norm_bias", nn.initializers.zeros,
+                             (Di,), pd)
+        iw = self.param("index_weights_proj", _normal(), (d, J), pd)
+
+        if self.decode:
+            L = self.cache_len
+            latent = self.variable("cache", "latent", jnp.zeros,
+                                   (B, L, R + rope), dt)
+            index_key = self.variable("cache", "index_key", jnp.zeros,
+                                      (B, L, Di), dt)
+            cursor = self.variable("cache", "cache_index",
+                                   lambda: jnp.zeros((B,), jnp.int32))
+            starts = cursor.value
+        else:
+            starts = jnp.zeros((B,), jnp.int32)
+        pos = starts[:, None] + jnp.arange(T)[None]  # [B, T]
+        inv_freq = np.asarray(self.inv_freq, np.float32)
+
+        with jax.named_scope("mla_project"):
+            cq = rms_norm(_dot(u, wq_a, dt), q_norm, self.rms_eps)
+            q = _dot(cq, wq_b, dt).astype(dt)  # [B, T, H, nope + rope]
+            q_rope = mla.rope_half(q[..., nope:], pos, inv_freq)
+            # the key half of W_ukv folded into the query
+            q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :nope],
+                               wkv_b[..., :nope].astype(dt),
+                               preferred_element_type=jnp.float32)
+            q_full = jnp.concatenate([q_lat.astype(dt), q_rope], axis=-1)
+            kv = _dot(u, wkv_a, dt)
+            entry = jnp.concatenate(
+                [rms_norm(kv[..., :R], kv_norm, self.rms_eps),
+                 mla.rope_half(kv[..., R:], pos, inv_freq)],
+                axis=-1).astype(dt)  # [B, T, R + rope]
+            # the indexer: rope on the first `rope` channels of both
+            qi = _dot(cq, iq_b, dt).astype(dt)
+            qi = jnp.concatenate(
+                [mla.rope_half(qi[..., :rope], pos, inv_freq),
+                 qi[..., rope:]], axis=-1)
+            ki = _dot(u, ik_w, dt)
+            mean = ki.mean(axis=-1, keepdims=True)
+            ki = (ki - mean) * jax.lax.rsqrt(
+                jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+                + self.rms_eps) * ik_scale.astype(jnp.float32) \
+                + ik_bias.astype(jnp.float32)
+            ki = jnp.concatenate(
+                [mla.rope_half(ki[..., :rope], pos, inv_freq),
+                 ki[..., rope:]], axis=-1).astype(dt)
+            w = _dot(u, iw, dt) * (J ** -0.5 * Di ** -0.5)
+
+        if self.decode:
+            with jax.named_scope("cache_update"):
+                # each row's valid tokens land at its cursor; a chunk's
+                # padding is pushed past the cache and dropped
+                fed = (jnp.full((B,), T, jnp.int32) if valid_lens is None
+                       else valid_lens)
+                at = jnp.where(jnp.arange(T)[None, :] < fed[:, None], pos, L)
+                rows = jnp.arange(B)[:, None]
+                latent.value = latent.value.at[rows, at].set(entry,
+                                                             mode="drop")
+                index_key.value = index_key.value.at[rows, at].set(
+                    ki, mode="drop")
+                cursor.value = starts + fed
+            held, keys = latent.value, index_key.value
+            tile = min(self.kv_tile, L)
+        else:
+            # no cache: the sequence itself, padded to whole tiles
+            tile = min(self.kv_tile, T)
+            pad = (-T) % tile
+            held = jnp.pad(entry, ((0, 0), (0, pad), (0, 0)))
+            keys = jnp.pad(ki, ((0, 0), (0, pad), (0, 0)))
+        out = mla.sparse_latent_attention(
+            q_full, qi, w, held, keys, starts, valid_lens,
+            topk=self.index_topk, tile=tile, scale=self.softmax_scale,
+            rank=R)
+        with jax.named_scope("mla_project"):
+            # the value half of W_ukv folded into the output
+            o = jnp.einsum("bthr,rhv->bthv", out.astype(dt),
+                           wkv_b[..., nope:].astype(dt),
+                           preferred_element_type=jnp.float32)
+            return jax.lax.dot_general(
+                o.astype(dt), wo.astype(dt),
+                (((2, 3), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+
+class RoutedExperts(nn.Module):
+    """The expert layer: sigmoid router over all experts with
+    group-limited top-k, this chip's share of the routed experts, and
+    the shared expert."""
+    n_routed_experts: int
+    experts_held: int
+    expert_rank: int
+    num_experts_per_tok: int
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float
+    width: int
+    n_shared_experts: int = 1
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    expert_tile: int = 128
+
+    @nn.compact
+    def __call__(self, u, live):
+        B, T, d = u.shape
+        E, held = self.n_routed_experts, self.experts_held
+        pd = self.param_dtype
+        router = self.param("router", _normal(), (d, E), pd)
+        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                          (E,), jnp.float32)
+        w_gate = self.param("w_gate", _normal(), (held, d, self.width), pd)
+        w_up = self.param("w_up", _normal(), (held, d, self.width), pd)
+        w_down = self.param("w_down", _normal(), (held, self.width, d), pd)
+        x = u.reshape(B * T, d)
+        with jax.named_scope("moe_route"):
+            # float32 scores at full precision: a routing decision is
+            # discrete, and rounding here sends a token elsewhere
+            scores = jax.nn.sigmoid(jnp.dot(
+                x, router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            experts, gates = group_limited_route(
+                scores, bias.astype(jnp.float32), self.n_group,
+                self.topk_group, self.num_experts_per_tok,
+                self.routed_scaling_factor)
+        with jax.named_scope("moe_experts"):
+            y, counts = dropless_held_experts(
+                x.astype(self.dtype), experts, gates, live.reshape(B * T),
+                w_gate.astype(self.dtype), w_up.astype(self.dtype),
+                w_down.astype(self.dtype), self.expert_rank * held,
+                self.expert_tile)
+        for name, value in counts.items():
+            self.sow("counters", name, value, reduce_fn=jnp.add,
+                     init_fn=lambda: jnp.zeros((), jnp.int32))
+        with jax.named_scope("moe_shared"):
+            shared = SwiGLU(self.width * self.n_shared_experts, self.dtype,
+                            pd, name="shared")(u)
+        return shared + y.reshape(B, T, d)
+
+
+class DecoderLayer(nn.Module):
+    attn: tuple  # LatentAttention's fields as sorted items (hashable)
+    ffn: tuple   # SwiGLU's, or RoutedExperts' where the layer is not dense
+    dense: bool
+    rms_eps: float
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, live, valid_lens=None):
+        d = x.shape[-1]
+        n1 = self.param("attn_norm", nn.initializers.ones, (d,),
+                        self.param_dtype)
+        n2 = self.param("ffn_norm", nn.initializers.ones, (d,),
+                        self.param_dtype)
+        x = x + LatentAttention(**dict(self.attn), name="attn")(
+            rms_norm(x, n1, self.rms_eps), valid_lens)
+        u = rms_norm(x, n2, self.rms_eps)
+        if self.dense:
+            return x + SwiGLU(**dict(self.ffn), name="mlp")(u)
+        return x + RoutedExperts(**dict(self.ffn), name="moe")(u, live)
+
+
+@register_model("deepseek_v32_lm")
+class DeepseekV32LM(nn.Module):
+    """Decoder-only LM of the DeepSeek-V3.2-Exp architecture. Defaults
+    are the published widths; ``num_layers``, ``first_k_dense``,
+    ``experts_held`` and ``vocab_size`` are what a configuration cuts."""
+
+    vocab_size: int = 129280
+    d_model: int = 7168
+    num_layers: int = 61
+    first_k_dense: int = 3
+    num_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    # this chip's share of each expert layer: experts
+    # expert_rank * experts_held .. + experts_held - 1 (None: all)
+    experts_held: Optional[int] = None
+    expert_rank: int = 0
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rms_eps: float = 1e-6
+    max_len: int = 4096
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+    # per-row cache cursors: the only decode mode this model has
+    slot_cursor: bool = False
+    cache_dtype: str = "model"
+    # accepted because the engine hands it to every model it clones; the
+    # cursor-bounded walk is this model's only attend
+    prefill_kernel: str = "auto"
+    kv_tile: int = 512       # positions a step of the cache walk reads
+    expert_tile: int = 128   # rows a step of the grouped matmul runs
+
+    # sown into the "counters" collection by every expert layer; the
+    # serving tick returns their sums with the tick's tokens
+    tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+
+    def serving_refusals(self, **options):
+        """Raise for each :class:`ServingEngine` option this model does
+        not have yet (the engine calls this with what it was given),
+        rather than run wrong."""
+        lacks = {
+            "paged": "a paged (block-pooled) latent cache: serving/"
+                     "kvpool.py allocates [blocks, block, Hk, hd] K and V",
+            "draft": "speculative decoding: no draft path reads the latent "
+                     "cache, and the multi-token-prediction module that "
+                     "would draft is not built",
+            "mesh": "tensor parallelism: the latent is shared by all "
+                    "heads, so heads are not split; replicas take batches",
+            "multi_step": "multi-step decode windows: the expert layers' "
+                          "counters are returned once a tick",
+            "monolithic_prefill": "whole-prompt prefill (prefill_chunk="
+                                  "None): the walk holds a chunk's scores, "
+                                  "not a prompt's",
+        }
+        for name, why in lacks.items():
+            if options.get(name):
+                raise ValueError(
+                    f"deepseek_v32_lm cannot be served with {name}: it "
+                    f"lacks {why}")
+
+    def kv_positions_fetched(self, starts, valid, chunk: int) -> int:
+        """Cache positions one tick's walks read (latent and index key
+        walk the same positions), for the engine's count."""
+        return mla.fetched_positions(starts, valid,
+                                     min(self.kv_tile, self.max_len))
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, block_tables=None,
+                 seq_lens=None, valid_lens=None):
+        if block_tables is not None or seq_lens is not None:
+            raise ValueError("deepseek_v32_lm has no paged cache")
+        if self.cache_dtype != "model":
+            raise ValueError(
+                f"deepseek_v32_lm keeps its latent cache in the model's "
+                f"dtype; cache_dtype={self.cache_dtype!r} (an int8 or fp8 "
+                f"latent) is not built")
+        if self.decode and not self.slot_cursor:
+            raise ValueError("deepseek_v32_lm decodes with per-row cursors "
+                             "only (slot_cursor=True)")
+        if self.decode and self.max_len % min(self.kv_tile, self.max_len):
+            raise ValueError(f"max_len={self.max_len} must be a multiple "
+                             f"of kv_tile={self.kv_tile} (or shorter)")
+        B, T = tokens.shape
+        held = (self.n_routed_experts if self.experts_held is None
+                else self.experts_held)
+        attn = dict(
+            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, index_n_heads=self.index_n_heads,
+            index_head_dim=self.index_head_dim,
+            index_topk=self.index_topk,
+            inv_freq=tuple(float(f) for f in mla.yarn_inv_freq(
+                self.qk_rope_head_dim, self.rope_theta, self.rope_factor,
+                self.rope_original_len, self.rope_beta_fast,
+                self.rope_beta_slow)),
+            softmax_scale=mla.yarn_softmax_scale(
+                self.qk_nope_head_dim + self.qk_rope_head_dim,
+                self.rope_factor),
+            rms_eps=self.rms_eps, dtype=self.dtype,
+            param_dtype=self.param_dtype, decode=self.decode,
+            cache_len=self.max_len if self.decode else 0,
+            kv_tile=self.kv_tile)
+        moe = dict(
+            n_routed_experts=self.n_routed_experts, experts_held=held,
+            expert_rank=self.expert_rank,
+            num_experts_per_tok=self.num_experts_per_tok,
+            n_group=self.n_group, topk_group=self.topk_group,
+            routed_scaling_factor=self.routed_scaling_factor,
+            width=self.moe_intermediate_size,
+            n_shared_experts=self.n_shared_experts, dtype=self.dtype,
+            param_dtype=self.param_dtype, expert_tile=self.expert_tile)
+        mlp = dict(width=self.intermediate_size, dtype=self.dtype,
+                   param_dtype=self.param_dtype)
+        live = (jnp.ones((B, T), bool) if valid_lens is None
+                else jnp.arange(T)[None, :] < valid_lens[:, None])
+        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                     param_dtype=self.param_dtype,
+                     name="embed")(tokens).astype(jnp.float32)
+        for i in range(self.num_layers):
+            dense = i < self.first_k_dense
+            x = DecoderLayer(tuple(sorted(attn.items())),
+                             tuple(sorted((mlp if dense else moe).items())),
+                             dense,
+                             self.rms_eps, self.param_dtype,
+                             name=f"layers_{i}")(x, live, valid_lens)
+        norm = self.param("norm", nn.initializers.ones,
+                          (self.d_model,), self.param_dtype)
+        head = self.param("head", _normal(),
+                          (self.d_model, self.vocab_size),
+                          self.param_dtype)
+        # untied head, float32 logits
+        return _dot(rms_norm(x, norm, self.rms_eps), head, self.dtype)

@@ -1,0 +1,231 @@
+"""Attention over a latent (MLA) slot cache with a learned sparse
+selection: the lightning indexer's score over a row's held positions,
+the top-k threshold that bounds what the attend may see, and the
+absorbed attend itself (one shared 576-wide "KV head" under all query
+heads), each walking a row's cache in tiles up to the row's cursor and
+no further.
+
+A row of the slot cache holds, per position, one latent ``[c_kv |
+rope(k_r)]`` (``kv_lora_rank + rope`` wide) and one index key. For a
+chunk of ``C`` queries of row ``r`` at absolute positions ``start ..
+start + C - 1``:
+
+1. ``index_score``: ``I[t, s] = sum_j w[t, j] * relu(q_i[t, j] .
+   k_i[s])`` for the positions ``s`` the row holds (scope
+   ``index_score``), kept as order-preserving unsigned keys;
+2. ``index_select``: the ``topk``-th largest key of every query among
+   its causal positions, by a 32-step search on the keys' bits (exact:
+   no sort, no approximation; scope ``index_select``). A query with no
+   more than ``topk`` positions gets threshold 0 and sees them all;
+3. ``mla_attend``: an online-softmax walk of the latent tiles with the
+   mask ``key >= threshold and s <= t`` (scope ``mla_attend``). Values
+   are the first ``kv_lora_rank`` channels of the same tile, so a tile
+   is read once.
+
+Everything is plain ``jax.numpy`` under ``lax`` loops with trip counts
+read from the cursors: XLA compiles one program for every context
+length, and a row that holds a third of ``max_len`` costs a third. No
+Pallas kernel here: the trace reports these scopes' seconds, not a
+roofline. Rows that feed at most one token (decoding and idle rows of a
+mixed tick) take the same walk with one query instead of ``C``.
+
+Two things in the walk are there for the TPU compiler and were measured
+on a v5e (PR 28, a ``[32, 64]`` tick of the five-layer cut): loops that
+close over the pooled ``[S, L, D]`` cache and a score matmul that
+contracts a key tile's minor axis make it give the whole pool a
+transposed layout and copy it, every layer and tick (a third of the
+device's time; 352 ms a tick where every row holds a chunk). The row's
+slice handed out by the scan and the tile transposed behind an
+``optimization_barrier`` leave the pool where it is: 123-165 ms for the
+same ticks.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+NEG = -1e30
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """``[dim // 2]`` inverse frequencies of YaRN-scaled rope: channels
+    that turn more than ``beta_fast`` times over the original context
+    keep their frequency, those that turn less than ``beta_slow`` times
+    are slowed by ``factor``, with a linear ramp between."""
+    half = dim // 2
+    freqs = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / dim)
+
+    def correction_dim(turns):
+        return dim * math.log(original_len / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    smooth = 1.0 - ramp
+    return (freqs / factor * (1 - smooth) + freqs * smooth).astype(
+        np.float32)
+
+
+def yarn_softmax_scale(qk_head_dim: int, factor: float) -> float:
+    """``qk_head_dim ** -0.5 * m ** 2`` with ``m = 0.1 ln(factor) + 1``."""
+    m = 0.1 * math.log(factor) + 1.0
+    return qk_head_dim ** -0.5 * m * m
+
+
+def rope_half(x, pos, inv_freq):
+    """Rotate the last axis of ``x [..., T, H, 2 * half]`` (or ``[..., T,
+    2 * half]``) by each token's own position ``pos [..., T]``, channel
+    ``i`` paired with ``i + half``; float32 inside."""
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    if x.ndim == pos.ndim + 2:  # a head axis between T and the channels
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    half = x.shape[-1] // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def sortable_keys(x):
+    """float32 -> uint32 with the same order (``a < b`` iff ``key(a) <
+    key(b)``), so that a threshold can be searched bit by bit."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    b = jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+    return jax.lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(
+        0x80000000)
+
+
+def kth_largest_key(keys, k: int):
+    """``[C]``: for each row of ``keys [C, L]`` (uint32) the largest
+    ``T`` with ``count(keys >= T) >= k``: the ``k``-th largest key, or 0
+    where the row holds fewer than ``k`` non-zero keys."""
+
+    def bit(b, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - b.astype(jnp.uint32)))
+        count = jnp.sum(keys >= cand[:, None], axis=1, dtype=jnp.int32)
+        return jnp.where(count >= k, cand, t)
+
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.zeros(keys.shape[:1], jnp.uint32))
+
+
+def _row_walk(q, qi, w, latent, index_keys, start, n, *, topk: int,
+              tile: int, scale: float, rank: int):
+    """One row's chunk: ``q [C, H, D]`` absorbed queries, ``qi [C, J,
+    Di]`` index queries, ``w [C, J]`` float32 head weights, against the
+    row's own ``latent [L, D]`` and ``index_keys [L, Di]`` up to position
+    ``n`` (exclusive); query ``c`` sits at ``start + c``. Returns ``[C,
+    H, rank]`` float32 (zeros where the row holds nothing)."""
+    C, H, D = q.shape
+    L, Di = index_keys.shape
+    qpos = start + jnp.arange(C)
+    tiles = (n + tile - 1) // tile
+
+    def causal(i):
+        return (i * tile + jnp.arange(tile))[None, :] <= qpos[:, None]
+
+    with jax.named_scope("index_score"):
+        def score(i, keys):
+            kt = jax.lax.dynamic_slice(index_keys, (i * tile, 0), (tile, Di))
+            s = jnp.einsum("cjd,td->cjt", qi, kt,
+                           preferred_element_type=jnp.float32)
+            score = jnp.einsum("cjt,cj->ct", jax.nn.relu(s), w)
+            key = jnp.where(causal(i), sortable_keys(score), jnp.uint32(0))
+            return jax.lax.dynamic_update_slice(keys, key, (0, i * tile))
+
+        keys = jax.lax.fori_loop(0, tiles, score,
+                                 jnp.zeros((C, L), jnp.uint32))
+    with jax.named_scope("index_select"):
+        threshold = kth_largest_key(keys, topk)
+    with jax.named_scope("mla_attend"):
+        def attend(i, carry):
+            m, l, acc = carry
+            kt = jax.lax.dynamic_slice(latent, (i * tile, 0), (tile, D))
+            ok = (jax.lax.dynamic_slice(keys, (0, i * tile), (C, tile))
+                  >= threshold[:, None]) & causal(i)
+            # the tile is transposed here, behind a barrier: folded into
+            # the matmul, the transpose becomes a layout that the
+            # compiler gives the whole cache and copies every tick
+            s = jnp.einsum("chd,dt->cht", q,
+                           jax.lax.optimization_barrier(kt.T),
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok[:, None, :], s, NEG)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(ok[:, None, :], jnp.exp(s - m_new[..., None]), 0.0)
+            fade = jnp.exp(m - m_new)
+            acc = acc * fade[..., None] + jnp.einsum(
+                "cht,tv->chv", p.astype(kt.dtype), kt[:, :rank],
+                preferred_element_type=jnp.float32)
+            return m_new, l * fade + p.sum(axis=-1), acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, tiles, attend,
+            (jnp.full((C, H), NEG, jnp.float32),
+             jnp.zeros((C, H), jnp.float32),
+             jnp.zeros((C, H, rank), jnp.float32)))
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def sparse_latent_attention(q, qi, w, latent, index_keys, starts,
+                            valid_lens, *, topk: int, tile: int,
+                            scale: float, rank: int):
+    """``[S, C, H, rank]``: every row's chunk of queries attended over
+    the positions the indexer selects among those the row holds.
+
+    ``q [S, C, H, D]`` (absorbed: ``D = rank + rope``), ``qi [S, C, J,
+    Di]``, ``w [S, C, J]``; ``latent [S, L, D]`` and ``index_keys [S, L,
+    Di]`` already hold this chunk's own entries; ``starts [S]`` are the
+    cursors before the chunk and ``valid_lens [S]`` how many of its
+    ``C`` tokens each row feeds (``None``: all). ``L`` is a multiple of
+    ``tile``. A row that feeds at most one token walks with its first
+    query alone; the other outputs of such a row are zeros, which
+    nothing reads."""
+    S, C, H, D = q.shape
+    if latent.shape[1] % tile:
+        raise ValueError(f"cache length {latent.shape[1]} is no multiple of "
+                         f"the walk's tile {tile}")
+    valid = (jnp.full((S,), C, jnp.int32) if valid_lens is None
+             else valid_lens)
+    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale,
+                             rank=rank)
+
+    def one(args):
+        # the row's own slices of the cache (the scan hands them out): the
+        # walk's loops then hold a row, not the pool
+        qr, qir, wr, start, fed, lat, keys = args
+        # a row that feeds nothing (idle, or starved of budget) walks no
+        # tile, wherever its cursor was left
+        n = jnp.where(fed > 0, start + fed, 0)
+
+        def chunk(_):
+            return walk(qr, qir, wr, lat, keys, start, n)
+
+        def single(_):
+            first = walk(qr[:1], qir[:1], wr[:1], lat, keys, start, n)
+            return jnp.zeros((C, H, rank), jnp.float32).at[:1].set(first)
+
+        if C == 1:
+            return chunk(None)
+        return jax.lax.cond(fed <= 1, single, chunk, None)
+
+    return jax.lax.map(one, (q, qi, w, starts, valid, latent, index_keys))
+
+
+def fetched_positions(starts, valid, tile: int) -> int:
+    """Cache positions the walks of one tick read, all rows of it: each
+    row's tiles up to its last valid token, none for a row that feeds
+    nothing (host arithmetic, for the engine's
+    ``key_positions_fetched``)."""
+    valid = np.asarray(valid, np.int64)
+    n = np.where(valid > 0, np.asarray(starts, np.int64) + valid, 0)
+    return int((-(-n // tile) * tile).sum())

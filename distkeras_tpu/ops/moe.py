@@ -11,6 +11,15 @@ Everything is dense one-hot matmul dispatch (MXU-friendly, static shapes,
 no gather/scatter), so the whole layer jits into one XLA program. Dropped
 tokens (capacity overflow) contribute zero and ride the residual connection,
 the standard Switch behavior.
+
+:func:`group_limited_route` and :func:`dropless_held_experts` are the
+serving path's routed layer (``models/deepseek_v32.py``): the router
+scores every expert of the model, the layer is told which of them this
+chip holds and computes their part of the result by a grouped matmul
+over as many rows as were routed here, with no capacity and no dropped
+token. What the experts held elsewhere would add arrives, in a
+deployment, from the chips that hold them; nothing here stands in for
+it.
 """
 
 from __future__ import annotations
@@ -97,6 +106,90 @@ def switch_moe(
         y = y.reshape(E, C, D)
     out = jnp.einsum("ecd,sec->sd", y, combine_t)
     return out.astype(x.dtype), aux.astype(jnp.float32)
+
+
+def group_limited_route(scores, bias, n_group: int, topk_group: int,
+                        top_k: int, scale: float):
+    """Group-limited top-k over ALL experts (``noaux_tc``): ``scores [N,
+    E]`` float32 affinities (sigmoid), ``bias [E]`` the selection-only
+    correction. Each of ``n_group`` groups scores the sum of its two
+    best ``scores + bias``; the ``topk_group`` best groups stay; the
+    ``top_k`` best experts among them are chosen. Gates are the chosen
+    ``scores`` (without ``bias``) over their sum, times ``scale``.
+    Returns ``(experts [N, top_k] int32, gates [N, top_k] float32)``."""
+    N, E = scores.shape
+    biased = scores + bias
+    groups = biased.reshape(N, n_group, E // n_group)
+    group_score = jax.lax.top_k(groups, 2)[0].sum(axis=-1)
+    kept = jax.lax.top_k(group_score, topk_group)[1]
+    keep = jnp.zeros((N, n_group), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    masked = jnp.where(jnp.repeat(keep, E // n_group, axis=1), biased,
+                       -jnp.inf)
+    experts = jax.lax.top_k(masked, top_k)[1]
+    gates = jnp.take_along_axis(scores, experts, axis=1)
+    gates = gates / gates.sum(axis=-1, keepdims=True) * scale
+    return experts.astype(jnp.int32), gates
+
+
+def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
+                          first: int, tile: int = 128):
+    """The part of a routed layer's result that the experts held here
+    give: no capacity, no token dropped, no one-hot dispatch.
+
+    ``x [N, D]`` tokens; ``experts``/``gates [N, k]`` the router's choice
+    over all experts (global ids); ``live [N]`` marks the tokens anyone
+    reads (a mixed tick's padding is not routed); this chip holds the
+    ``E_l`` experts ``first .. first + E_l - 1`` as ``w_gate``/``w_up
+    [E_l, D, F]`` and ``w_down [E_l, F, D]`` (SwiGLU). The (token,
+    expert) pairs sent to held experts are sorted by expert and each
+    expert's rows run through its weights ``tile`` rows at a time, as
+    many tiles as it was sent rows (a grouped matmul whose trip counts
+    come from the routing), gathered from ``x`` and scatter-added into
+    the result. Returns ``(y [N, D] float32, counts)`` with ``counts`` =
+    ``routed_here`` (pairs of live tokens sent to held experts),
+    ``routed_total`` (all pairs of live tokens) and
+    ``expert_rows_computed`` (rows the tiles ran over, padding
+    included), int32 scalars."""
+    N, D = x.shape
+    k = experts.shape[1]
+    E_l = w_gate.shape[0]
+    local = experts - first
+    held = (local >= 0) & (local < E_l) & live[:, None]
+    key = jnp.where(held, local, E_l).reshape(N * k)
+    order = jnp.argsort(key, stable=True)
+    # a tile that starts near the end reads past the pairs: padding
+    # keeps the slice from being clamped back onto other rows
+    tok = jnp.pad((order // k).astype(jnp.int32), (0, tile),
+                  constant_values=N)
+    gate = jnp.pad(gates.reshape(N * k)[order], (0, tile))
+    sizes = jnp.bincount(key, length=E_l + 1)[:E_l].astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    tiles = (sizes + tile - 1) // tile
+    y = jnp.zeros((N, D), jnp.float32)
+    for e in range(E_l):
+        def rows(i, y, e=e):
+            at = starts[e] + i * tile
+            mine = i * tile + jnp.arange(tile) < sizes[e]
+            ids = jnp.where(mine, jax.lax.dynamic_slice(tok, (at,), (tile,)),
+                            N)
+            g = jnp.where(mine, jax.lax.dynamic_slice(gate, (at,), (tile,)),
+                          0.0)
+            xt = jnp.take(x, ids, axis=0, mode="fill", fill_value=0)
+            h = jax.nn.silu(jnp.dot(
+                xt, w_gate[e], preferred_element_type=jnp.float32)
+            ) * jnp.dot(xt, w_up[e], preferred_element_type=jnp.float32)
+            out = jnp.dot(h.astype(x.dtype), w_down[e],
+                          preferred_element_type=jnp.float32)
+            return y.at[ids].add(out * g[:, None], mode="drop")
+
+        y = jax.lax.fori_loop(0, tiles[e], rows, y)
+    counts = {
+        "routed_here": held.sum(dtype=jnp.int32),
+        "routed_total": live.sum(dtype=jnp.int32) * k,
+        "expert_rows_computed": tiles.sum(dtype=jnp.int32) * tile,
+    }
+    return y, counts
 
 
 class SwitchMoE(nn.Module):

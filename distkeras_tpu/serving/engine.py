@@ -82,6 +82,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, List, NamedTuple, Optional, Tuple
 
+import flax
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -310,6 +311,21 @@ def _paged_prefill_fn(dm_paged, ctx: Optional[_ShardCtx] = None):
     return prefill
 
 
+# a tick's work that only some models have (see ServingEngine.stats)
+_MODEL_WORK = ("index_positions_scored", "keys_selected", "routed_here",
+               "routed_total", "expert_rows_computed")
+
+
+def _counter_sums(sown, names):
+    """``[len(names)]`` int32: each named counter summed over the
+    modules that sowed it (inside the jitted bodies)."""
+    flat = flax.traverse_util.flatten_dict(flax.core.unfreeze(sown))
+    return jnp.stack([
+        sum((v for path, v in flat.items() if path[-1] == name),
+            jnp.zeros((), jnp.int32)).astype(jnp.int32)
+        for name in names])
+
+
 @functools.lru_cache(maxsize=256)
 def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
     """Compiled CHUNKED mixed prefill/decode tick (the Sarathi-style
@@ -328,7 +344,11 @@ def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
     RNG discipline) are untouched, so sharded streams stay
     bit-identical to the single-chip path. Host control arguments
     (fed tokens, valid lens, sample mask) arrive as ONE packed int32
-    buffer — a single transfer per tick."""
+    buffer — a single transfer per tick. A model that declares
+    ``tick_counters`` (names it sows into the ``counters`` collection)
+    gets their sums over the layers appended to the tokens, ``[S +
+    len(counters)]``; for any other model the program is unchanged."""
+    counters = tuple(getattr(dm_slot, "tick_counters", ()))
 
     @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
                        out_kinds="crrr", donate=(1, 2, 3))
@@ -353,13 +373,19 @@ def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
         )
         logits, vs = dm_slot.apply(
             {**params_only, "cache": cache}, inputs,
-            valid_lens=valid, mutable=["cache"],
+            valid_lens=valid,
+            mutable=["cache", "counters"] if counters else ["cache"],
         )
         # row s's next-step logits live at its last valid token; a
         # starved prefill row (valid 0) wraps to garbage it never reads
         last = jnp.take_along_axis(
             logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1
         )[:, 0]
+        if counters:
+            # what the model counted on the device this tick rides
+            # behind the S tokens: one readback carries both
+            sampled = jnp.concatenate(
+                [sampled, _counter_sums(vs.get("counters", {}), counters)])
         return vs["cache"], last, sampled, jnp.stack(new_rngs)
 
     return tick
@@ -1111,14 +1137,20 @@ class ServingEngine:
     Args:
       model: a TRAINING-mode :class:`TransformerLM` (``decode=False``) —
         decode twins are cloned internally, so trained checkpoints work
-        as-is (same param tree).
+        as-is (same param tree) — or another registered LM with a slot
+        cache of its own ("Models with another cache" below).
       params: trained variables (``{"params": ...}``).
       slots: number of concurrent sequences ``S`` — the pooled KV cache
         is ``[S, max_len, ...]`` per layer, allocated once.
       max_len: serving context length (prompt + generated); defaults to
         ``model.max_len``. Smaller values shrink the pooled cache.
       scheduler: admission policy; defaults to a
-        :class:`FIFOScheduler` with its default backpressure knobs.
+        :class:`FIFOScheduler` with its default backpressure knobs
+        (``tick_token_budget`` 256: a ``[S, C]`` mixed tick computes
+        ``S x C`` positions whatever is dealt, so an engine of 32 slots
+        and long prompts wants ``S x C``). A ``dict`` is taken as
+        :class:`FIFOScheduler`'s arguments, for callers that build the
+        engine from a file.
       metrics: a :class:`MetricsWriter`; an in-memory one is created if
         omitted (so :meth:`stats` always works).
       registry: the :class:`~distkeras_tpu.telemetry.MetricRegistry` the
@@ -1291,6 +1323,30 @@ class ServingEngine:
         because k is fixed, steady state never recompiles. Default 1:
         fast path off.
 
+    **Models with another cache.** ``model`` may be any registered LM
+    whose decode twin (``clone(decode=True, slot_cursor=True)``) keeps
+    per-row ``[S]`` int32 cursors in its ``cache`` collection and takes
+    ``valid_lens``. ``deepseek_v32_lm`` (a latent ``[S, L, 576]`` leaf
+    and an index-key leaf a layer, attention over a learned selection,
+    routed experts of which this chip holds a share) is served through
+    the same loop, scheduler and tick programs; the weights are held in
+    the dtype they are handed (bf16 there). The engine asks such a
+    model three things: ``tick_counters`` (sums returned with a tick's
+    tokens: ``routed_here``, ``routed_total``,
+    ``expert_rows_computed``), ``kv_positions_fetched`` /
+    ``index_topk`` (the host's counts ``key_positions_fetched``,
+    ``index_positions_scored``, ``keys_selected``) and
+    ``serving_refusals``. For that model the constructor **refuses**,
+    with the model's reason: ``paged=True`` (``kvpool`` allocates
+    ``[blocks, block, Hk, hd]`` K and V; no latent block exists),
+    ``draft=`` of either kind (no draft path reads a latent cache and
+    the multi-token-prediction module is not built), ``mesh=`` (all
+    heads share one latent: heads are not split), ``multi_step_k > 1``
+    (the counters return once a tick), ``prefill_chunk=None`` (the walk
+    holds a chunk's scores, not a prompt's) and a model cloned with
+    ``cache_dtype="int8"`` (no quantised latent): each would otherwise
+    run wrong or not at all.
+
     Drive it with :meth:`step` (one admit→tick→complete→refill cycle,
     e.g. from a test) or :meth:`serve_forever` (the TCP front-end's
     loop thread). ``submit`` is thread-safe; stepping is single-threaded
@@ -1332,6 +1388,14 @@ class ServingEngine:
             )
         self.role = role
         self.prefill_kernel = prefill_kernel
+        # a model whose cache is not [S, L, Hk, hd] K and V says what of
+        # the engine it cannot be served with yet, before anything is
+        # allocated (the refusal carries the model's reason)
+        refusals = getattr(model, "serving_refusals", None)
+        if refusals is not None:
+            refusals(paged=paged, draft=draft is not None,
+                     mesh=mesh is not None, multi_step=multi_step_k > 1,
+                     monolithic_prefill=prefill_chunk is None)
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1 (or None for monolithic "
@@ -1461,6 +1525,11 @@ class ServingEngine:
         self.key_positions_fetched_total = 0
         self.cache_positions_total = 0
         self.useful_query_tokens_total = 0
+        # what only a model with a learned selection over its cache or
+        # with routed experts counts (index_positions_scored,
+        # keys_selected; routed_here, routed_total,
+        # expert_rows_computed from the device): name -> total
+        self.model_work_totals: dict = {}
         self._flight_ns = 0  # time spent building/recording snapshots
         self._tick_ns = 0    # total tick wall time (plan+device+stream)
         self.model = (model if max_len is None
@@ -1472,6 +1541,11 @@ class ServingEngine:
         # control-plane journal: drain/undrain, role flips, weight
         # swaps — served by the `events` op and merged fleet-wide
         self.journal = EventJournal(actor="engine")
+        if isinstance(scheduler, dict):
+            # a configuration file's way to say it: FIFOScheduler's own
+            # arguments (tick_token_budget, max_queue_depth, ...)
+            scheduler = FIFOScheduler(tracer=self.tracer,
+                                      registry=self.registry, **scheduler)
         self.scheduler = scheduler or FIFOScheduler(
             tracer=self.tracer, registry=self.registry
         )
@@ -2149,6 +2223,19 @@ class ServingEngine:
         while self.step():
             if time.monotonic() > deadline:
                 raise TimeoutError("engine did not drain in time")
+
+    def abandon_streams(self, reason: str = "error"):
+        """End the stream of every request still queued or in a slot.
+        For a loop that has stopped for good (:meth:`LMServer.stop`): it
+        will emit no further token, and a consumer left waiting on such
+        a stream (the server's pump thread) would wait for ever, keeping
+        the server, this engine and its device-side cache alive."""
+        for req in self.scheduler.abandon():
+            req.stream._finish(reason)
+        for slot, st in enumerate(self._slots):
+            if st is not None:
+                self._slots[slot] = None
+                st.req.stream._finish(reason)
 
     def begin_drain(self):
         """Close admissions for a graceful shutdown: subsequent
@@ -2918,6 +3005,9 @@ class ServingEngine:
         layout's gathered view. Host arithmetic on cursors the plan
         already holds."""
         m = self.model
+        fetched = getattr(m, "kv_positions_fetched", None)
+        if fetched is not None:  # the model's own walk over its cache
+            return fetched(starts, valid, C)
         L = m.max_len
         H = m.num_heads // self.tp
         Hk = (m.num_kv_heads or m.num_heads) // self.tp
@@ -3053,6 +3143,16 @@ class ServingEngine:
                 "key_positions_fetched": self._kv_fetched(starts, valid, C),
                 "cache_positions": S * self.model.max_len,
                 "query_positions": S * C}
+        topk = getattr(self.model, "index_topk", None)
+        if topk is not None:
+            # a learned selection over the cache: positions the indexer
+            # scores for the live queries (query t scores its t + 1), and
+            # the positions their attend is then allowed, min(t + 1,
+            # topk) a query
+            seen = (starts[:, None] + 1 + np.arange(C))[
+                np.arange(C) < valid[:, None]]
+            work["index_positions_scored"] = int(seen.sum())
+            work["keys_selected"] = int(np.minimum(seen, topk).sum())
         with self._phase("upload", tick=tick_no) as upload:
             dev = self._upload(packed)
         with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
@@ -3086,6 +3186,12 @@ class ServingEngine:
             toks_host = np.asarray(rec.toks)
             counts_host = (np.asarray(rec.acc) if rec.multi_k is not None
                            else None)
+            counters = getattr(self.model, "tick_counters", ())
+            if counters and rec.work is not None:
+                # what the model counted on the device rides behind the
+                # S tokens (see _mixed_tick_fn)
+                rec.work.update(zip(
+                    counters, toks_host[self.slots:].tolist()))
             # the device buffers are freed here, on the read's side of
             # the boundary (a millisecond of this thread's time on a
             # v5e, PR 25), not wherever the record happens to die
@@ -3986,6 +4092,11 @@ class ServingEngine:
                 "key_positions_fetched"]
             self.cache_positions_total += rec.work["cache_positions"]
             self.useful_query_tokens_total += rec.n_dec + rec.fed_tokens
+            for name in _MODEL_WORK:
+                if name in rec.work:
+                    self.model_work_totals[name] = (
+                        self.model_work_totals.get(name, 0)
+                        + rec.work[name])
         if snap is not None:
             # overlap decomposition: device_ms = dispatch_ms (upload_ms
             # + the jitted call returning) + device_wait_ms (time
@@ -4096,6 +4207,13 @@ class ServingEngine:
             "key_positions_fetched_total": self.key_positions_fetched_total,
             "cache_positions_total": self.cache_positions_total,
             "useful_query_tokens_total": self.useful_query_tokens_total,
+            # only for a model that selects over its cache or routes to
+            # experts: index_positions_scored_total, keys_selected_total
+            # (beside attended_tokens_total: the pairs a dense attend
+            # would have been allowed), routed_here_total over
+            # routed_total_total, expert_rows_computed_total
+            **{f"{name}_total": total
+               for name, total in self.model_work_totals.items()},
             # engine-side critical-path phases (the stream tail and
             # router overhead land in the same histogram family from
             # the TCP pump / router; one merged chain's exact breakdown

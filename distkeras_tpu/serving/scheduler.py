@@ -21,6 +21,13 @@ passing it maps onto an equivalent token budget (N default-sized chunks
 per tick) with a :class:`DeprecationWarning`, and still bounds
 admissions per pop for engines running the legacy monolithic prefill.
 
+``size_classes=k`` replaces first come, first served within a tier by
+a deal over the sizes of what is waiting (prompt length, then
+``max_new_tokens``; the oldest request of a class first), so that what
+runs is the same mix of long and short work whatever order the requests
+came in; see :class:`FIFOScheduler`. The default is first come, first
+served.
+
 Requests carry a **QoS tier** (``Request.tier``, one of :data:`QOS_TIERS`:
 ``"interactive"`` then ``"batch"``). The scheduler keeps one FIFO queue
 per tier and serves them in strict priority order — batch requests are
@@ -189,6 +196,19 @@ class FIFOScheduler:
         a burst of RESTORING admissions from starving the live decode
         streams, the same role ``tick_token_budget`` plays for prompt
         chunks. Defaults to 4 blocks/tick.
+      size_classes: ``k > 1`` deals admissions evenly over the sizes of
+        what is waiting, instead of in order of arrival: the waiting
+        requests of the tier at hand are ranked by prompt length into
+        ``k`` classes and each class by its output allowance
+        (``max_new_tokens``) into ``k``; admissions go round the ``k *
+        k`` classes in turn, the oldest request of the class first. What
+        runs at any time is then the same mix of long and short work
+        whatever order the requests came in, so a replica kept full
+        emits tokens at a steadier rate, and no size waits for ever
+        (every class comes round every ``k * k`` admissions). Applies
+        where admission has no resource gate (the slot engine); a gated
+        pop (the paged engine's free-block check) stays first come,
+        first served. Defaults to 1: first come, first served.
     """
 
     def __init__(self, max_queue_depth: int = 256,
@@ -196,7 +216,7 @@ class FIFOScheduler:
                  tracer: Optional["telemetry.Tracer"] = None,
                  registry: Optional["telemetry.MetricRegistry"] = None,
                  max_prefills_per_tick: Optional[int] = None,
-                 restore_budget: int = 4):
+                 restore_budget: int = 4, size_classes: int = 1):
         if max_queue_depth < 1:
             raise ValueError(
                 f"max_queue_depth must be >= 1; got {max_queue_depth}"
@@ -228,6 +248,12 @@ class FIFOScheduler:
             raise ValueError(
                 f"restore_budget must be >= 1; got {restore_budget}"
             )
+        if size_classes < 1:
+            raise ValueError(
+                f"size_classes must be >= 1; got {size_classes}"
+            )
+        self.size_classes = size_classes
+        self._size_turn = 0  # admissions dealt by size class so far
         self.max_queue_depth = max_queue_depth
         self.tick_token_budget = tick_token_budget
         self.restore_budget = restore_budget
@@ -328,6 +354,31 @@ class FIFOScheduler:
                 return tier, self._qs[tier][0]
         return None
 
+    def _next_by_size_locked(self, q) -> Request:
+        """The request of ``q`` whose turn it is under ``size_classes``
+        (see the class docstring): the oldest of the class of prompt
+        lengths, and within it of output allowances, that this
+        admission's turn names. ``q`` is in order of arrival, so a
+        smaller index is an older request."""
+        k, n = self.size_classes, len(q)
+        waiting = list(q)
+        by_prompt = sorted(range(n),
+                           key=lambda i: (waiting[i].prompt.size, i))
+        kp, ko = self._size_turn % k, (self._size_turn // k) % k
+        cls = by_prompt[kp * n // k:(kp + 1) * n // k] or by_prompt
+        by_out = sorted(cls, key=lambda i: (waiting[i].max_new_tokens, i))
+        m = len(by_out)
+        return waiting[min(by_out[ko * m // k:(ko + 1) * m // k] or by_out)]
+
+    @staticmethod
+    def _take_locked(q, req: Request) -> Request:
+        """Remove ``req`` from ``q`` by identity (a request compares its
+        prompt array, which ``deque.remove`` cannot)."""
+        if q[0] is req:
+            return q.popleft()
+        del q[next(i for i, r in enumerate(q) if r is req)]
+        return req
+
     def _refresh_head_locked(self):
         """Recompute the oldest-head timestamp across tiers (each tier
         is FIFO, so its head is its oldest — the fleet-wide oldest wait
@@ -336,6 +387,17 @@ class FIFOScheduler:
         interactive traffic jumps ahead of it)."""
         heads = [q[0].submit_t for q in self._qs.values() if q]
         self._head_submit_t = min(heads) if heads else None
+
+    def abandon(self) -> List[Request]:
+        """Empty the queue and return what it held (a loop that has
+        stopped for good ends these requests' streams)."""
+        with self._lock:
+            held = [r for q in self._qs.values() for r in q]
+            for q in self._qs.values():
+                q.clear()
+            self._head_submit_t = None
+            self._blocked = None
+        return held
 
     def depth(self) -> int:
         with self._lock:
@@ -440,16 +502,19 @@ class FIFOScheduler:
                         break
                     tier, req = head
                     q = self._qs[tier]
+                    if self.size_classes > 1 and admissible is None:
+                        req = self._next_by_size_locked(q)
                     if (req.deadline_s is not None
                             and now - req.submit_t > req.deadline_s):
-                        expired.append(q.popleft())
+                        expired.append(self._take_locked(q, req))
                         continue
                     if admissible is not None and not admissible(req):
                         streak = (blocked[1] + 1 if blocked is not None
                                   and blocked[0] is req else 1)
                         self._blocked = (req, streak, self._cap_epoch)
                         break
-                    admitted.append(q.popleft())
+                    admitted.append(self._take_locked(q, req))
+                    self._size_turn += 1
                     if blocked is not None and blocked[0] is req:
                         self._blocked = blocked = None
             depth = self._depth_locked()

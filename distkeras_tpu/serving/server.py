@@ -257,6 +257,7 @@ class LMServer:
             t = threading.Thread(target=target, daemon=True)
             t.start()
             self._threads.append(t)
+        self._loop_thread = t
         if self.slo is not None:
             self.slo.start()
         if self._watchdog is not None:
@@ -283,6 +284,12 @@ class LMServer:
             shutdown_close(c)
         for t in self._threads:
             t.join(timeout)
+        # the loop has stopped and emits no more: end the streams of
+        # the requests it still held, or their pump threads wait on
+        # them for ever and keep this server, the engine and its cache
+        # (gigabytes of device memory) alive after stop()
+        if not self._loop_thread.is_alive():
+            self.engine.abandon_streams()
 
     # -- loops --------------------------------------------------------------
 
@@ -342,9 +349,12 @@ class LMServer:
             })
         except (ConnectionError, OSError):
             # client went away mid-stream: drain silently (the engine
-            # finishes the request; its tokens are simply dropped)
-            for _ in req.stream:
-                pass
+            # finishes the request; its tokens are simply dropped). A
+            # stream whose end was already read (the "done" frame is
+            # what failed to send) holds nothing more to wait for
+            if req.stream.finish_reason is None:
+                for _ in req.stream:
+                    pass
             self.engine.tracer.record(
                 req.trace_id, "stream", t0,
                 (time.monotonic() - t0) * 1e3, tokens=n, aborted=1,

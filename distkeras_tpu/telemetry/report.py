@@ -381,6 +381,32 @@ def report_flight(path: str, last: Optional[int] = None,
             f"max {max(got):.3f}  (needed p50 {_percentile(need, 50):.3f};"
             f" 1.000 = every row's whole cache)\n"
         )
+    chosen = [r for r in ticks if r.get("index_positions_scored")]
+    if chosen:
+        # a learned selection over the cache: positions the indexer
+        # scored for the live queries, and the share of them the attend
+        # was then allowed
+        scored = sum(r["index_positions_scored"] for r in chosen)
+        selected = sum(r["keys_selected"] for r in chosen)
+        out.write(
+            f"index_positions_scored: {scored}  keys_selected: {selected} "
+            f"({100 * selected / scored:.1f}% of what a dense attend "
+            f"sees)\n"
+        )
+    routed = [r for r in ticks if r.get("routed_total")]
+    if routed:
+        # routed experts, one chip's share: (token, expert) pairs sent to
+        # experts held here of all pairs, and the rows the grouped
+        # matmul ran over for them (its tiles' padding included)
+        here = sum(r["routed_here"] for r in routed)
+        rows = sum(r["expert_rows_computed"] for r in routed)
+        out.write(
+            f"routed_here/routed_total: {here}/"
+            f"{sum(r['routed_total'] for r in routed)}  "
+            f"expert_rows_computed: {rows}"
+            + (f" ({100 * here / rows:.1f}% useful)" if rows else "")
+            + "\n"
+        )
     waits = [float(r["device_wait_ms"]) for r in ticks
              if "device_wait_ms" in r]
     if waits:

@@ -1,0 +1,428 @@
+"""``deepseek_v32_lm`` (MLA latent slot cache, lightning-indexer top-k
+selection, dropless group-limited routing over one chip's share of the
+experts) against its plain reference, ``chipbench/references/
+deepseek_v32.py``, on seeded random weights at a small size with every
+ratio kept: 4 heads, 16 experts in 4 groups of which 4 are held, 4 per
+token, ``index_topk`` 16 so that the selection bites within 64
+positions.
+
+Everything here is float32 on the CPU, so the two sides differ only by
+the order of float32 sums (absorbed against expanded attention, tiles
+against whole rows, grouped rows against every expert over every token):
+``TOL`` = 2e-4 on logits of magnitude ~4 is a hundred times the 6e-6
+measured, and a hundredth of what either control moves them by.
+"""
+
+import gc
+import threading
+import time
+import weakref
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.references import deepseek_v32 as ref
+from distkeras_tpu.models import get_model
+from distkeras_tpu.models.deepseek_v32 import RoutedExperts
+from distkeras_tpu.ops import mla
+from distkeras_tpu.ops.moe import dropless_held_experts, group_limited_route
+from distkeras_tpu.serving import LMServer, ServingClient, ServingEngine
+from distkeras_tpu.telemetry import report as telemetry_report
+
+TOL = 2e-4
+SMALL = dict(
+    vocab_size=96, d_model=64, num_layers=3, first_k_dense=1, num_heads=4,
+    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, index_n_heads=4, index_head_dim=16,
+    index_topk=16, intermediate_size=128, moe_intermediate_size=32,
+    n_routed_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
+    experts_held=4, expert_rank=0, rope_original_len=32, max_len=64,
+    kv_tile=16, expert_tile=8)
+
+
+def _config(**over):
+    return {"model": dict(SMALL, **over),
+            "precision": {"parameters": "float32"}}
+
+
+@pytest.fixture(scope="module")
+def small():
+    cfg = _config()
+    params = ref.make_params(cfg, 7)
+    model = get_model("deepseek_v32_lm", **cfg["model"], dtype=jnp.float32)
+    return cfg, params, model
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, SMALL["vocab_size"], size=n).astype(np.int32)
+
+
+# -- the model against the reference ------------------------------------------
+
+
+@pytest.mark.parametrize("length", [9, 48, 64])
+def test_full_forward_agrees_with_the_reference(small, length):
+    """Shorter than ``index_topk`` (every position attended), and long
+    enough that three quarters of the positions are left out."""
+    cfg, params, model = small
+    toks = _tokens(length)
+    got = np.asarray(model.apply(params, toks[None])[0])
+    want = ref.forward_logits(cfg, params, toks, np.arange(length))
+    assert np.abs(got - want).max() < TOL
+    assert np.abs(want).max() > 1.0  # logits of a size worth comparing
+
+
+@pytest.mark.parametrize("precision,moved", [("dense", 0.5), ("int8", 0.1)])
+def test_a_control_falls_outside_the_tolerance(small, precision, moved):
+    """The selection switched off, and every operand rounded to int8,
+    each move the logits by thousands of tolerances."""
+    cfg, params, _ = small
+    toks = _tokens(48)
+    at = np.arange(48)
+    want = ref.forward_logits(cfg, params, toks, at)
+    low = ref.forward_logits(cfg, params, toks, at, precision)
+    assert np.abs(low - want).max() > moved > 100 * TOL
+
+
+def test_padding_the_reference_changes_nothing(small):
+    cfg, params, _ = small
+    toks = _tokens(21)
+    at = np.arange(5, 21)
+    plain = ref.forward_logits(cfg, params, toks, at)
+    padded = ref.forward_logits(cfg, params, toks, at, "f32", 64)
+    assert np.abs(plain - padded).max() < 1e-5
+
+
+def test_the_weights_follow_the_seed_and_the_models_layout(small):
+    cfg, params, model = small
+    init = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                          jnp.zeros((1, 4), jnp.int32))
+    assert jax.tree.map(lambda a: a.shape, params["params"]) == jax.tree.map(
+        lambda a: a.shape, init["params"])
+    again, other = ref.make_params(cfg, 7), ref.make_params(cfg, 2 ** 31 + 5)
+    moe = params["params"]["layers_1"]["moe"]
+    assert np.array_equal(moe["router"], again["params"]["layers_1"]["moe"][
+        "router"])
+    assert not np.array_equal(moe["router"], other["params"]["layers_1"][
+        "moe"]["router"])
+    # small and not zero, so that a dropped correction bias shows
+    assert 0 < np.abs(moe["e_score_correction_bias"]).max() < 0.2
+
+
+# -- through the engine's slot cache -------------------------------------------
+
+
+def _serve(model, params, prompts, news, **engine):
+    """Drive the engine tick by tick; returns the requests and, for every
+    (request, position) the engine held next-token logits for, those
+    logits."""
+    eng = ServingEngine(model, params, **engine)
+    reqs = [eng.submit(p, n) for p, n in zip(prompts, news)]
+    seen = {}
+    while eng.step():
+        logits = np.asarray(eng._last_logits)
+        for s, st in enumerate(eng._slots):
+            if st is not None and st.decoding:
+                seen[(st.req.rid, st.cursor - 1)] = logits[s]
+    return eng, reqs, seen
+
+
+def test_chunked_prefill_then_decode_agrees_with_the_reference(small):
+    """Five requests through three slots in chunks of 8: rows at
+    different cursors, slots refilled after another tenant, chunks that
+    straddle ``index_topk`` = 16 and decode far past it. Every logits
+    row the engine sampled from is the reference's full forward at that
+    position."""
+    cfg, params, model = small
+    prompts = [_tokens(n, i) for i, n in enumerate((30, 11, 21, 5, 40))]
+    news = [12, 20, 9, 30, 10]
+    eng, reqs, seen = _serve(model, params, prompts, news, slots=3,
+                             max_len=64, prefill_chunk=8)
+    compared = 0
+    for r, p, n in zip(reqs, prompts, news):
+        toks = r.stream.tokens(timeout=10)
+        assert len(toks) == n and r.stream.finish_reason == "length"
+        seq = np.concatenate([p, np.asarray(toks, np.int32)])
+        want = ref.forward_logits(cfg, params, seq, np.arange(len(seq)),
+                                  "f32", 64)
+        at = sorted(pos for rid, pos in seen if rid == r.rid)
+        assert at[0] == len(p) - 1 and len(at) >= n
+        for pos in at:
+            assert np.abs(seen[(r.rid, pos)] - want[pos]).max() < TOL
+            compared += 1
+        # greedy: each served token is the reference's own
+        assert toks == want[len(p) - 1:len(seq) - 1].argmax(-1).tolist()
+    assert compared >= sum(news)
+    assert eng.requests_completed == 5
+
+
+def test_the_engine_counts_what_the_model_selects_and_routes(small,
+                                                             tmp_path,
+                                                             capsys):
+    cfg, params, model = small
+    prompts = [_tokens(n, i) for i, n in enumerate((30, 11, 21))]
+    eng, reqs, _ = _serve(model, params, prompts, [6, 6, 6], slots=2,
+                          max_len=64, prefill_chunk=8)
+    st = eng.stats()
+    live = st["useful_query_tokens_total"]
+    # every prompt token and every sampled token is fed once (the last
+    # sampled token too: the tick that samples it feeds it)
+    assert live == sum(len(p) for p in prompts) + 3 * 6
+    # two expert layers, four experts a token, a quarter of them here
+    assert st["routed_total_total"] == live * 4 * 2
+    assert 0 < st["routed_here_total"] < st["routed_total_total"]
+    rows = st["expert_rows_computed_total"]
+    assert rows >= st["routed_here_total"] and rows % SMALL["expert_tile"] == 0
+    # a query at position t scores t + 1 positions and may attend
+    # min(t + 1, 16) of them
+    assert st["index_positions_scored_total"] == st["attended_tokens_total"]
+    want = sum(min(t + 1, 16) for p in prompts
+               for t in range(len(p) + 6))
+    assert st["keys_selected_total"] == want
+    assert st["key_positions_fetched_total"] < st["cache_positions_total"]
+    ticks = [t for t in eng.flight.snapshots() if t.get("kind") == "tick"]
+    assert all("routed_here" in t and "keys_selected" in t for t in ticks)
+    path = tmp_path / "flight.jsonl"
+    eng.flight.dump(str(path), reason="manual")
+    telemetry_report.main(["--flight", str(path)])
+    out = capsys.readouterr().out
+    assert "index_positions_scored:" in out and "keys_selected:" in out
+    assert "routed_here/routed_total:" in out
+    assert "expert_rows_computed:" in out
+
+
+@pytest.mark.parametrize("option,what", [
+    (dict(paged=True), "paged"),
+    (dict(draft="ngram"), "draft"),
+    (dict(multi_step_k=2), "multi_step"),
+    (dict(prefill_chunk=None), "monolithic_prefill"),
+    (dict(mesh="any"), "mesh"),
+])
+def test_the_engine_refuses_what_the_model_lacks(small, option, what):
+    _, params, model = small
+    with pytest.raises(ValueError, match=f"cannot be served with {what}"):
+        ServingEngine(model, params, slots=2, max_len=64, **option)
+
+
+def test_the_engine_refuses_a_draft_model_and_an_int8_cache(small):
+    _, params, model = small
+    with pytest.raises(ValueError, match="cannot be served with draft"):
+        ServingEngine(model, params, slots=2, max_len=64, draft=model,
+                      draft_params=params)
+    with pytest.raises(ValueError, match="int8 or fp8"):
+        ServingEngine(model.clone(cache_dtype="int8"), params, slots=2,
+                      max_len=64)
+    with pytest.raises(ValueError, match="multiple of kv_tile"):
+        ServingEngine(model, params, slots=2, max_len=40)
+
+
+def test_a_scheduler_given_as_a_mapping_deals_every_row_a_chunk(small):
+    """``scheduler={"tick_token_budget": S x C}`` (how a configuration
+    file says it): three prompts of 24 are fed in three ticks of three
+    chunks of 8, where the default budget would do the same here and a
+    budget of 10 takes eight."""
+    _, params, model = small
+    prompts = [_tokens(24, i) for i in range(3)]
+
+    def ticks_to_decode(scheduler):
+        eng = ServingEngine(model, params, slots=3, max_len=64,
+                            prefill_chunk=8, scheduler=scheduler)
+        for p in prompts:
+            eng.submit(p, 30)  # long enough that no row leaves meanwhile
+        n = 0
+        while not all(st is not None and st.decoding for st in eng._slots):
+            eng.step()
+            n += 1
+            assert n < 20
+        return n, eng.scheduler.tick_token_budget
+
+    assert ticks_to_decode({"tick_token_budget": 24}) == (3, 24)
+    assert ticks_to_decode({"tick_token_budget": 10}) == (8, 10)
+    assert ticks_to_decode(None) == (3, 256)
+
+
+def test_a_stopped_server_frees_its_engine(small):
+    """Requests cut in flight by ``stop()`` get their streams ended, so
+    no pump thread keeps the server, the engine and its cache alive."""
+    _, params, model = small
+    eng = ServingEngine(model, params, slots=2, max_len=64, prefill_chunk=8)
+    server = LMServer(eng).start()
+    client = ServingClient("127.0.0.1", server.port, timeout=None)
+    rids = [client.generate(_tokens(20, i), 40) for i in range(6)]
+    assert len(rids) == 6
+    time.sleep(0.2)
+    alive = weakref.ref(eng)
+    client.close()
+    server.stop()
+    del eng, server, client
+    for _ in range(20):
+        gc.collect()
+        if alive() is None:
+            break
+        time.sleep(0.1)
+    assert alive() is None
+    for _ in range(50):  # the pumps see their streams end and return
+        pumps = [t for t in threading.enumerate() if "_pump" in t.name]
+        if not pumps:
+            break
+        time.sleep(0.1)
+    assert not pumps
+
+
+# -- the expert layer ------------------------------------------------------------
+
+
+def _layer_inputs(seed=3, n=40):
+    m = ref.sizes(_config(experts_held=16))
+    p = ref.make_params(_config(experts_held=16, num_layers=2), seed)[
+        "params"]["layers_1"]["moe"]
+    u = jax.random.normal(jax.random.PRNGKey(seed), (1, n, SMALL["d_model"]))
+    return m, p, u
+
+
+def _module(held, rank):
+    return RoutedExperts(
+        n_routed_experts=16, experts_held=held, expert_rank=rank,
+        num_experts_per_tok=4, n_group=4, topk_group=2,
+        routed_scaling_factor=2.5, width=32, dtype=jnp.float32,
+        expert_tile=8)
+
+
+def _share(p, held, rank):
+    lo = rank * held
+    return {**p, **{k: p[k][lo:lo + held]
+                    for k in ("w_gate", "w_up", "w_down")}}
+
+
+def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
+    """Four chips of four experts each: their routed parts, with the
+    shared expert (which every chip computes alike) counted once, are
+    the reference's layer over all sixteen."""
+    m, p, u = _layer_inputs()
+    live = jnp.ones(u.shape[:2], bool)
+    with jax.default_matmul_precision("highest"):
+        uncut = ref._expert_layer(m, p, u[0], "f32")
+        shared = ref._swiglu(p["shared"], u[0], "f32")
+    parts = [_module(4, r).apply({"params": _share(p, 4, r)}, u, live)[0]
+             for r in range(4)]
+    total = shared + sum(part - shared for part in parts)
+    assert np.abs(np.asarray(total - uncut)).max() < TOL
+    # and a part alone is not the layer: the cut is not a no-op
+    assert np.abs(np.asarray(parts[0] - uncut)).max() > 100 * TOL
+    whole = _module(16, 0).apply({"params": p}, u, live)[0]
+    assert np.abs(np.asarray(whole - uncut)).max() < TOL
+
+
+def test_no_token_is_dropped_when_every_token_chooses_held_experts():
+    """A correction bias that sends every token to experts 0-3, all held
+    here: 40 rows an expert, five tiles of 8, where a capacity of 1.25 x
+    the even share would keep 12."""
+    m, p, u = _layer_inputs()
+    p = dict(p, e_score_correction_bias=jnp.zeros((16,)).at[:4].set(10.0))
+    live = jnp.ones(u.shape[:2], bool)
+    out, sown = _module(4, 0).apply({"params": _share(p, 4, 0)}, u, live,
+                                    mutable=["counters"])
+    counts = {k: int(v) for k, v in sown["counters"].items()}
+    assert counts["routed_here"] == counts["routed_total"] == 40 * 4
+    assert counts["expert_rows_computed"] == 4 * 40
+    with jax.default_matmul_precision("highest"):
+        want = ref._expert_layer(dict(m, experts_held=4), _share(p, 4, 0),
+                                 u[0], "f32")
+    assert np.abs(np.asarray(out[0] - want)).max() < TOL
+
+
+def test_padding_tokens_are_not_routed():
+    m, p, u = _layer_inputs()
+    live = jnp.arange(40)[None, :] < 7
+    out, sown = _module(16, 0).apply({"params": p}, u, live,
+                                     mutable=["counters"])
+    assert int(sown["counters"]["routed_total"]) == 7 * 4
+    assert int(sown["counters"]["routed_here"]) == 7 * 4
+    with jax.default_matmul_precision("highest"):
+        want = ref._expert_layer(m, p, u[0], "f32")
+    assert np.abs(np.asarray(out[0, :7] - want[:7])).max() < TOL
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_group_limited_routing_agrees_with_the_reference(seed):
+    m = ref.sizes(_config())
+    rng = np.random.default_rng(seed)
+    scores = jax.nn.sigmoid(jnp.asarray(rng.normal(size=(50, 16)),
+                                        jnp.float32))
+    bias = jnp.asarray(0.05 * rng.normal(size=(16,)), jnp.float32)
+    experts, gates = group_limited_route(scores, bias, 4, 2, 4, 2.5)
+    want_e, want_g = ref.route(m, scores, bias)
+    assert np.array_equal(np.sort(experts, -1), np.sort(want_e, -1))
+    assert np.allclose(np.sort(gates, -1), np.sort(want_g, -1), atol=1e-6)
+    assert np.allclose(gates.sum(-1), 2.5, atol=1e-5)
+    # at most two of the four groups hold a chosen expert
+    assert (np.asarray([len(set(row // 4)) for row in np.asarray(experts)])
+            <= 2).all()
+
+
+def test_the_grouped_matmul_gathers_scatters_and_counts():
+    rng = np.random.default_rng(0)
+    N, D, F, k = 19, 8, 6, 2
+    x = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+    experts = jnp.asarray(rng.integers(0, 6, size=(N, k)), jnp.int32)
+    gates = jnp.asarray(rng.uniform(size=(N, k)), jnp.float32)
+    w = [jnp.asarray(rng.normal(size=s), jnp.float32)
+         for s in ((3, D, F), (3, D, F), (3, F, D))]
+    live = jnp.asarray(rng.uniform(size=N) < 0.8)
+    y, counts = dropless_held_experts(x, experts, gates, live, *w, first=2,
+                                      tile=4)
+    want = np.zeros((N, D), np.float32)
+    here = 0
+    for t in range(N):
+        for j in range(k):
+            e = int(experts[t, j]) - 2
+            if live[t] and 0 <= e < 3:
+                h = jax.nn.silu(x[t] @ w[0][e]) * (x[t] @ w[1][e])
+                want[t] += gates[t, j] * np.asarray(h @ w[2][e])
+                here += 1
+    assert np.abs(np.asarray(y) - want).max() < 1e-4
+    assert int(counts["routed_here"]) == here
+    assert int(counts["routed_total"]) == int(live.sum()) * k
+    assert int(counts["expert_rows_computed"]) % 4 == 0
+
+
+# -- the selection and the rope -----------------------------------------------
+
+
+@pytest.mark.parametrize("k", [1, 5, 16, 40])
+def test_the_threshold_search_finds_the_kth_largest(k):
+    rng = np.random.default_rng(k)
+    scores = rng.normal(size=(6, 32)).astype(np.float32)
+    scores[0, :4] = 0.0          # ties and zeros
+    scores[1] = -np.abs(scores[1])  # all negative
+    keys = mla.sortable_keys(jnp.asarray(scores))
+    got = np.asarray(mla.kth_largest_key(keys, k))
+    if k > 32:  # fewer than k keys: threshold 0, everything is in
+        assert (got == 0).all()
+        return
+    want = np.sort(np.asarray(keys), axis=1)[:, -k]
+    assert np.array_equal(got, want)
+    picked = np.asarray(keys) >= got[:, None]
+    kth = np.sort(scores, axis=1)[:, -k]
+    assert np.array_equal(picked, scores >= kth[:, None])
+
+
+def test_yarn_frequencies_agree_with_the_reference():
+    m = ref.sizes({"model": {}})
+    got = mla.yarn_inv_freq(64, 10000.0, 40.0, 4096, 32.0, 1.0)
+    want = ref.yarn_inv_freq(m)
+    assert np.allclose(got, want, rtol=1e-6)
+    # fast channels keep their frequency, slow ones are slowed 40 times
+    assert np.isclose(got[0], 1.0) and np.isclose(
+        got[-1], 10000.0 ** (-62 / 64) / 40)
+    assert np.isclose(mla.yarn_softmax_scale(192, 40.0),
+                      192 ** -0.5 * (0.1 * np.log(40) + 1) ** 2)
+
+
+def test_the_walk_reads_whole_tiles_up_to_the_cursor_and_no_further():
+    # rows at 0 (idle), 5 + 8 fed, 40 + 1 fed, 33 starved (feeds nothing)
+    assert mla.fetched_positions([0, 5, 40, 33], [0, 8, 1, 0], 16) == (
+        0 + 16 + 48 + 0)
