@@ -998,6 +998,56 @@ class TransformerLM(nn.Module):
             return x
         return VocabHead(self.vocab_size, self.dtype, name="head")(x)
 
+    @staticmethod
+    def casts_first(names) -> bool:
+        """Whether the leaf at ``names`` (its dict keys, outermost first)
+        is one its module casts to the compute dtype before anything
+        else — the rule :func:`compute_params` applies."""
+        return (len(names) >= 2
+                and names[-1] in _CAST_FIRST.get(names[-2], ()))
+
+
+# The leaves a module above casts to its ``dtype`` as the first thing it
+# does with them, by the name TransformerLM gives the module (the idiom
+# of parallel/spmd.py . lm_param_specs). What is not listed stays as
+# handed: LayerNorm applies scale and bias in f32 and casts the result,
+# VocabHead adds its bias to f32 logits, SwitchMoE routes in f32.
+_CAST_FIRST = {
+    **{name: ("kernel", "bias")  # TPDenseGeneral
+       for name in ("qkv", "q_proj", "kv_proj", "out", "mlp_up",
+                    "mlp_down")},
+    "head": ("kernel",),  # VocabHead
+    "embed": ("embedding",),  # nn.Embed promotes the table, then gathers
+    "moe": ("w1", "b1", "w2", "b2"),  # SwitchMoE's expert banks
+}
+
+
+def compute_params(model, params):
+    """``params`` with every leaf that ``model`` would cast to its
+    compute dtype on first use holding the result of that cast, so a
+    program that takes the tree as an argument neither reads the wider
+    leaf nor converts it on every call. ``model.apply`` gives the same
+    bits on either tree: the cast is the one the module makes.
+
+    The rule is the model's (``model.casts_first``); a model that
+    brings none, a ``float32`` model, and a leaf already in the compute
+    dtype come back as handed, leaf objects included. Casts run on the
+    device a leaf lies on, leaf by leaf."""
+    from jax.tree_util import DictKey, tree_map_with_path
+
+    rule = getattr(model, "casts_first", None)
+    dtype = jnp.dtype(getattr(model, "dtype", jnp.float32))
+    if rule is None or dtype == jnp.float32:
+        return params
+
+    def cast(path, leaf):
+        names = [k.key for k in path if isinstance(k, DictKey)]
+        if leaf.dtype == dtype or not rule(names):
+            return leaf
+        return jnp.asarray(leaf).astype(dtype)
+
+    return tree_map_with_path(cast, params)
+
 
 def generate(model, params, prompt, max_new_tokens: int,
              temperature: float = 0.0, seed: int = 0,

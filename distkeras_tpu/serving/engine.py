@@ -89,7 +89,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distkeras_tpu import telemetry
-from distkeras_tpu.models.transformer import filter_logits, sample_tokens
+from distkeras_tpu.models.transformer import (
+    compute_params,
+    filter_logits,
+    sample_tokens,
+)
 from distkeras_tpu.ops import splash_prefill
 from distkeras_tpu.telemetry.events import EventJournal
 from distkeras_tpu.telemetry.flight import FlightRecorder
@@ -1139,7 +1143,19 @@ class ServingEngine:
         decode twins are cloned internally, so trained checkpoints work
         as-is (same param tree) — or another registered LM with a slot
         cache of its own ("Models with another cache" below).
-      params: trained variables (``{"params": ...}``).
+      params: trained variables (``{"params": ...}``). The engine
+        holds, for every leaf the model casts to its compute dtype as
+        the first thing it does with it (``models/transformer.py ·
+        compute_params``: the kernels, not the norms), the result of
+        that cast, made once on the device: the tick programs take the
+        weights as arguments, and a wide leaf would be read and
+        converted every tick. Served tokens are bit for bit those of
+        the tree as handed. The engine keeps no reference to a handed
+        leaf it replaced: a caller that wants the memory back drops its
+        own tree. ``stats()["weight_bytes_held"]`` beside
+        ``weight_bytes_handed`` says what it came to. A ``float32``
+        model, a model that brings no rule and a tree already in the
+        compute dtype are held as handed, leaf objects included.
       slots: number of concurrent sequences ``S`` — the pooled KV cache
         is ``[S, max_len, ...]`` per layer, allocated once.
       max_len: serving context length (prompt + generated); defaults to
@@ -1557,6 +1573,13 @@ class ServingEngine:
         self._wire_metrics()
         self.metrics = metrics or MetricsWriter()
         self._params_only = {"params": params["params"]}
+        # what update_weights holds a push to: structure, shapes and
+        # dtypes as handed, whatever the engine holds after its cast
+        self._weights_like = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype),
+            self._params_only["params"])
+        self.weight_bytes_handed = sum(
+            x.nbytes for x in jax.tree.leaves(self._params_only))
         if paged:
             if self.model.max_len % block_size != 0:
                 raise ValueError(
@@ -1733,6 +1756,15 @@ class ServingEngine:
         self._ctx: Optional[_ShardCtx] = None
         if mesh is not None:
             self._init_mesh_ctx()
+        # hold the model's own first-use casts, made where each leaf now
+        # lies (`params` in the class docstring); the handed leaves they
+        # replace are the caller's alone from here on
+        self._params_only = compute_params(self.model, self._params_only)
+        if self._dm_draft is not None:
+            self._draft_params_only = compute_params(
+                self.draft_model, self._draft_params_only)
+        self.weight_bytes_held = sum(
+            x.nbytes for x in jax.tree.leaves(self._params_only))
         self._slots: List[Optional[_SlotState]] = [None] * slots
         # graceful drain: begin_drain() closes admissions (new submits
         # raise DrainingError) while queued + in-flight requests finish
@@ -2313,11 +2345,16 @@ class ServingEngine:
 
         ``variables`` is the model's variables dict (``{"params":
         ...}``; a bare params tree is wrapped). Structure, shapes, and
-        dtypes must match the current weights exactly — the first
-        mismatched leaf raises a typed
-        :class:`~distkeras_tpu.serving.WeightPushError` *before*
-        anything is touched. A draft model's weights are not updated
-        (push the flagship only; restart to change the drafter).
+        dtypes must match the weights **as handed at construction**
+        exactly (a trainer's ``float32`` tree stays the thing to push,
+        whatever the engine holds) — the first mismatched leaf raises a
+        typed :class:`~distkeras_tpu.serving.WeightPushError` *before*
+        anything is touched. The staged tree then gets the cast the
+        constructor made (``compute_params``, on the device), so the
+        rebound tree has the held tree's shapes and dtypes; until the
+        rebind the device holds the old tree, the staged push and its
+        casts. A draft model's weights are not updated (push the
+        flagship only; restart to change the drafter).
 
         ``version`` stamps the new weights (a checkpoint step, a PS
         commit count); the engine keeps its version monotonic — a
@@ -2326,12 +2363,13 @@ class ServingEngine:
         t0 = time.perf_counter()
         if not (isinstance(variables, dict) and "params" in variables):
             variables = {"params": variables}
-        validate_like(self._params_only["params"], variables["params"])
+        validate_like(self._weights_like, variables["params"])
         new = {"params": variables["params"]}
         if self.mesh is not None:
             new = jax.device_put(new, self._param_shardings)
         else:
             new = jax.device_put(new, self._device)
+        new = compute_params(self.model, new)
         # the rebind IS the swap: in-flight dispatches hold their own
         # reference to the old tree (params are never donated), so
         # they complete on the old version while new dispatches read
@@ -4037,6 +4075,8 @@ class ServingEngine:
                     # snapshots is visible as the version stepping (the
                     # report renderer's w=vN column)
                     "weight_version": self.weight_version,
+                    "weight_bytes_held": self.weight_bytes_held,
+                    "weight_bytes_handed": self.weight_bytes_handed,
                 }
                 if rec.multi_k is not None:
                     # multi-step window: this one dispatch carried up to
@@ -4147,6 +4187,11 @@ class ServingEngine:
             # router's rolling updates poll this for convergence
             "weight_version": self.weight_version,
             "weight_swaps": self.weight_swaps,
+            # bytes of the flagship's tree the tick programs read every
+            # tick, beside the bytes of the tree as handed: less where
+            # the engine holds the model's compute-dtype casts
+            "weight_bytes_held": self.weight_bytes_held,
+            "weight_bytes_handed": self.weight_bytes_handed,
             "mean_occupancy": (
                 round(self._occ_sum / self.ticks, 3) if self.ticks else 0.0
             ),

@@ -11,8 +11,8 @@ The pieces, bottom-up:
   flax codec every other frame uses), chunked by the client so a
   multi-GB tree rides many bounded frames instead of one giant one.
 - :func:`validate_like` — the admission gate for a pushed tree:
-  structure, shape, and dtype must match the serving engine's current
-  weights exactly; the first mismatched leaf (in the current tree's
+  structure, shape, and dtype must match the weights the serving engine
+  was built on exactly; the first mismatched leaf (in the current tree's
   flatten order) is named in a typed :class:`WeightPushError`, so a
   bad checkpoint is refused at the boundary instead of surfacing as a
   shape error inside a jitted tick.
@@ -91,11 +91,19 @@ def _leaf_paths(tree) -> List[Tuple[str, Any]]:
     ]
 
 
+def _dtype(leaf) -> np.dtype:
+    """A leaf's dtype without fetching it: arrays on the host or on a
+    device and ``ShapeDtypeStruct``s say it themselves."""
+    dt = getattr(leaf, "dtype", None)
+    return np.dtype(dt) if dt is not None else np.asarray(leaf).dtype
+
+
 def validate_like(current: Any, new: Any):
     """Raise :class:`WeightPushError` naming the first leaf (in the
     current tree's flatten order) whose presence, shape, or dtype
-    differs between ``current`` (the engine's live weights) and
-    ``new`` (the pushed tree); return silently when the trees match.
+    differs between ``current`` (the weights the engine was built on:
+    arrays, or their ``ShapeDtypeStruct``s) and ``new`` (the pushed
+    tree); return silently when the trees match.
     Values are never compared — a weight update is *supposed* to
     change them."""
     cur = _leaf_paths(current)
@@ -107,7 +115,7 @@ def validate_like(current: Any, new: Any):
             raise WeightPushError(
                 f"pushed weights are missing leaf {path}: expected "
                 f"shape {tuple(np.shape(leaf))} "
-                f"dtype {np.asarray(leaf).dtype}",
+                f"dtype {_dtype(leaf)}",
                 leaf=path,
             )
         want_shape = tuple(np.shape(leaf))
@@ -118,8 +126,7 @@ def validate_like(current: Any, new: Any):
                 f"{got_shape} != expected {want_shape}",
                 leaf=path,
             )
-        want_dt = np.asarray(leaf).dtype
-        got_dt = np.asarray(got).dtype
+        want_dt, got_dt = _dtype(leaf), _dtype(got)
         if want_dt != got_dt:
             raise WeightPushError(
                 f"pushed weights mismatch at leaf {path}: dtype "

@@ -444,6 +444,16 @@ def report_flight(path: str, last: Optional[int] = None,
             f"host tier: {demoted} blocks demoted, {restored} "
             f"restored, {host_now} resident at last tick\n"
         )
+    held = next((r for r in reversed(ticks) if "weight_bytes_held" in r),
+                None)
+    if held is not None:
+        # what every tick program reads beside its cache: less than was
+        # handed where the engine holds the model's compute-dtype casts
+        out.write(
+            f"weight_bytes_held: {held['weight_bytes_held'] / 1e9:.3f} GB"
+            f" a tick ({held['weight_bytes_handed'] / 1e9:.3f} GB "
+            f"handed)\n"
+        )
     versions = [r["weight_version"] for r in ticks
                 if "weight_version" in r]
     if versions and show_wv:
