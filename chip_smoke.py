@@ -510,25 +510,19 @@ def tick_kernels(engine) -> dict:
     def packed(n):  # the host control buffer, as _upload hands it over
         return jnp.zeros((n,), jnp.int32)
 
-    if engine.paged:
-        MB = engine._max_blocks
-        ticks = {
-            "serve.paged_mixed_tick": (
-                eng._paged_mixed_tick_fn(engine._dm_paged, cfgs, C,
-                                         engine._ctx),
-                (packed(S * (MB + C + 3)),)),
-            "serve.paged_tick": (
-                eng._paged_tick_fn(engine._dm_paged, cfgs, engine._ctx),
-                (packed(S * (MB + 1)),)),
-        }
-    else:
-        ticks = {
-            "serve.mixed_tick": (
-                eng._mixed_tick_fn(engine._dm_slot, cfgs, C, engine._ctx),
-                (packed(S * (C + 2)),)),
-            "serve.tick": (
-                eng._tick_fn(engine._dm_slot, cfgs, engine._ctx), ()),
-        }
+    layout = engine._layout
+    # the layout's head of a control buffer: tables and seq lens per row
+    # where the cache is paged, nothing where it is slots (whose plain
+    # decode tick takes no buffer at all)
+    head = S * (layout.max_blocks + 1) if layout.paged else 0
+    ticks = {
+        layout.tag("mixed_tick"): (
+            eng._mixed_tick_fn(layout, cfgs, C, engine._ctx),
+            (packed(head + S * (C + 2)),)),
+        layout.tag("tick"): (
+            eng._tick_fn(layout, cfgs, engine._ctx),
+            (packed(head),) if head else ()),
+    }
     return {name: kernel_calls(
         fn.lower(*state, *extra).compile().as_text())
         for name, (fn, extra) in ticks.items()}

@@ -205,6 +205,86 @@ def _compile(body, ctx: Optional[_ShardCtx], in_kinds: str,
     )
 
 
+class _CacheLayout(NamedTuple):
+    """How the KV cache is laid out, as far as a tick can tell: the one
+    hashable value the tick builders key on and the only place that
+    knows how the layouts differ inside a tick. ``dm`` is the decode
+    module a tick applies. ``max_blocks`` None is the SLOT layout: one
+    slab per row whose ``[S]`` cursors live in the cache pytree and
+    advance on the device, so a tick's control buffer holds the kind's
+    own fields and nothing else. An int is the PAGED layout's
+    block-table width: tables and cursors (``seq_lens``) are the
+    host's, and ride at the head of every tick's control buffer."""
+
+    dm: Any
+    max_blocks: Optional[int] = None
+
+    @property
+    def paged(self) -> bool:
+        return self.max_blocks is not None
+
+    def tag(self, kind: str) -> str:
+        """The name this layout's program of ``kind`` compiles under."""
+        return ("serve.paged_" if self.paged else "serve.") + kind
+
+    def unpack(self, packed, S: int, shapes=()):
+        """Device side (traced): take this layout's head off a tick's
+        control buffer. Returns the keywords ``dm.apply`` takes to find
+        each row's K/V, and the kind's own fields by ``shapes``."""
+        if not self.paged:
+            return {}, _unpack_i32(packed, shapes)
+        tables, lens, *fields = _unpack_i32(
+            packed, ((S, self.max_blocks), (S,), *shapes))
+        return {"block_tables": tables, "seq_lens": lens}, fields
+
+    def pack(self, eng, fields=(), advance=None):
+        """Host side: one tick's control buffer for ``eng`` — this
+        layout's head, then the kind's own ``fields`` — with the
+        host-owned cursors moved on by ``advance`` [S], what the
+        dispatch writes, once they are packed. None where there is
+        nothing to send: the slot layout's plain decode tick."""
+        if self.paged:
+            fields = (eng._block_tables, eng._seq_lens, *fields)
+        packed = _pack_i32(*fields) if fields else None
+        if advance is not None:
+            self.advance(eng, advance)
+        return packed
+
+    def advance(self, eng, by):
+        """Move ``eng``'s host-owned cursors on by ``by`` [S]; idle rows
+        stay parked at 0 on the trash block. The slot layout's cursors
+        advance on the device, inside the cache."""
+        if self.paged:
+            # REBIND, never mutate: jnp.asarray can alias the numpy
+            # buffer zero-copy while the async tick still reads it —
+            # in-place writes would race the device
+            eng._seq_lens = eng._seq_lens + np.asarray(by, np.int32)
+
+
+def _sample_rows(cfgs, logits, rngs, advance=None):
+    """One token per slot from the pooled ``[S, vocab]`` ``logits``
+    (traced): row ``s`` by its own ``cfgs[s] = (temperature, top_k,
+    top_p)``, from its own RNG chain, on a ``[1, vocab]`` slice — the
+    exact call shape of a solo B=1 ``generate``, so streams are
+    token-identical. A chain moves on only where ``advance`` [S] says
+    the row really sampled (a prefilling, restoring or stopped row must
+    not burn the chain that makes its stream identical to solo
+    ``generate()``); ``None`` moves every row's. Returns the ``[S]``
+    tokens and the rows' chains, a list the caller stacks where it
+    returns them."""
+    toks, chains = [], []
+    with jax.named_scope("sample"):
+        for s, (temp, top_k, top_p) in enumerate(cfgs):
+            rng, sub = jax.random.split(rngs[s])
+            toks.append(
+                sample_tokens(logits[s][None], sub, temp,
+                              top_k, top_p)[0]
+            )
+            chains.append(rng if advance is None
+                          else jnp.where(advance[s], rng, rngs[s]))
+    return jnp.stack(toks), chains
+
+
 @functools.lru_cache(maxsize=64)
 def _prefill_fn(dm_one, ctx: Optional[_ShardCtx] = None):
     """Compiled per-slot prefill for a B=1 decode module: run the prompt
@@ -256,35 +336,30 @@ def _prefill_fn(dm_one, ctx: Optional[_ShardCtx] = None):
 
 
 @functools.lru_cache(maxsize=256)
-def _tick_fn(dm_slot, cfgs, ctx: Optional[_ShardCtx] = None):
+def _tick_fn(layout, cfgs, ctx: Optional[_ShardCtx] = None):
     """Compiled decode tick for one per-slot sampling-config tuple
     ``cfgs = ((temperature, top_k, top_p), ...)``: sample one token per
-    slot (each from its own RNG chain, on a ``[1, vocab]`` logits slice —
-    the exact call shape of a solo B=1 ``generate``, so streams are
-    token-identical), then advance all slots one decode step. With a
-    mesh ``ctx`` the same body runs under shard_map: sampling happens on
-    the replicated post-psum logits (every shard draws the identical
-    token), the decode step on each shard's head slice."""
+    slot (:func:`_sample_rows`; every chain advances), then advance all
+    slots one decode step. With a mesh ``ctx`` the same body runs under
+    shard_map: sampling happens on the replicated post-psum logits
+    (every shard draws the identical token), the decode step on each
+    shard's head slice. The slot layout's tick takes NO control buffer
+    — its cursors are in the cache — and its dispatch uploads nothing;
+    the paged layout's takes its head (tables and seq lens) as one
+    packed int32 transfer."""
 
-    @functools.partial(_compile, ctx=ctx, in_kinds="pcrr",
+    @functools.partial(_compile, ctx=ctx,
+                       in_kinds="pcrrr" if layout.paged else "pcrr",
                        out_kinds="crrr", donate=(1, 2, 3))
-    def tick(params_only, cache, last_logits, rngs):
-        recompiles.note("serve.tick")
-        with jax.named_scope("sample"):
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(rng)
-        tok = jnp.stack(toks)  # [S]
-        logits, vs = dm_slot.apply(
+    def tick(params_only, cache, last_logits, rngs, packed=None):
+        recompiles.note(layout.tag("tick"))
+        where, _ = layout.unpack(packed, rngs.shape[0])
+        tok, chains = _sample_rows(cfgs, last_logits, rngs)  # [S]
+        logits, vs = layout.dm.apply(
             {**params_only, "cache": cache}, tok[:, None],
-            mutable=["cache"],
+            mutable=["cache"], **where,
         )
-        return vs["cache"], logits[:, -1], tok, jnp.stack(new_rngs)
+        return vs["cache"], logits[:, -1], tok, jnp.stack(chains)
 
     return tick
 
@@ -331,54 +406,50 @@ def _counter_sums(sown, names):
 
 
 @functools.lru_cache(maxsize=256)
-def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
+def _mixed_tick_fn(layout, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
     """Compiled CHUNKED mixed prefill/decode tick (the Sarathi-style
     fused step): one ``[S, chunk]`` dispatch advances every slot —
     decoding rows consume 1 valid token (their own freshly-sampled
     one), prefilling rows consume up to ``chunk`` prompt tokens, idle
     rows run padding. Per-slot sampling is identical to :func:`_tick_fn`
-    (same RNG chains, same ``[1, vocab]`` call shape), but a slot's RNG
-    only advances when it actually sampled (``sample_mask``) — prefill
-    ticks must not burn the chain that makes streams token-identical to
-    solo ``generate()``. Logits are taken at each row's LAST VALID
-    token, so the tick that feeds a prompt's final chunk leaves exactly
-    the logits a monolithic prefill would have. A mesh ``ctx`` runs the
+    (:func:`_sample_rows`), but a slot's RNG only advances when it
+    actually sampled (``sample_mask``) — prefill ticks must not burn
+    the chain that makes streams token-identical to solo
+    ``generate()``. Logits are taken at each row's LAST VALID token, so
+    the tick that feeds a prompt's final chunk leaves exactly the
+    logits a monolithic prefill would have. A mesh ``ctx`` runs the
     identical body per head-shard under shard_map — the ``[S, C]``
     chunk semantics (absolute per-row positions, valid-length writes,
     RNG discipline) are untouched, so sharded streams stay
-    bit-identical to the single-chip path. Host control arguments
-    (fed tokens, valid lens, sample mask) arrive as ONE packed int32
-    buffer — a single transfer per tick. A model that declares
-    ``tick_counters`` (names it sows into the ``counters`` collection)
-    gets their sums over the layers appended to the tokens, ``[S +
-    len(counters)]``; for any other model the program is unchanged."""
-    counters = tuple(getattr(dm_slot, "tick_counters", ()))
+    bit-identical to the single-chip path. Host control arguments (the
+    layout's head, then fed tokens, valid lens, sample mask) arrive as
+    ONE packed int32 buffer — a single transfer per tick; under the
+    paged layout K/V reads and writes go through each row's block
+    table, and chunk padding lands in the reserved trash block. A model
+    that declares ``tick_counters`` (names it sows into the
+    ``counters`` collection) gets their sums over the layers appended
+    to the tokens, ``[S + len(counters)]``; for any other model the
+    program is unchanged."""
+    counters = tuple(getattr(layout.dm, "tick_counters", ()))
 
     @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
                        out_kinds="crrr", donate=(1, 2, 3))
     def tick(params_only, cache, last_logits, rngs, packed):
-        recompiles.note("serve.mixed_tick")
+        recompiles.note(layout.tag("mixed_tick"))
         S = rngs.shape[0]
-        fed, valid, smask = _unpack_i32(
-            packed, ((S, chunk), (S,), (S,)))
+        where, (fed, valid, smask) = layout.unpack(
+            packed, S, ((S, chunk), (S,), (S,)))
         sample_mask = smask != 0
-        with jax.named_scope("sample"):
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
-        sampled = jnp.stack(toks)  # [S]
+        sampled, chains = _sample_rows(cfgs, last_logits, rngs,
+                                       sample_mask)  # [S]
         inputs = fed.at[:, 0].set(
             jnp.where(sample_mask, sampled, fed[:, 0])
         )
-        logits, vs = dm_slot.apply(
+        logits, vs = layout.dm.apply(
             {**params_only, "cache": cache}, inputs,
             valid_lens=valid,
             mutable=["cache", "counters"] if counters else ["cache"],
+            **where,
         )
         # row s's next-step logits live at its last valid token; a
         # starved prefill row (valid 0) wraps to garbage it never reads
@@ -390,53 +461,7 @@ def _mixed_tick_fn(dm_slot, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
             # behind the S tokens: one readback carries both
             sampled = jnp.concatenate(
                 [sampled, _counter_sums(vs.get("counters", {}), counters)])
-        return vs["cache"], last, sampled, jnp.stack(new_rngs)
-
-    return tick
-
-
-@functools.lru_cache(maxsize=256)
-def _paged_mixed_tick_fn(dm_paged, cfgs, chunk,
-                         ctx: Optional[_ShardCtx] = None):
-    """Paged twin of :func:`_mixed_tick_fn`: same fused
-    sample/feed/advance semantics, with K/V reads and writes routed
-    through each row's block table (chunk padding lands in the reserved
-    trash block). The host control arguments — block tables, seq lens,
-    fed tokens, valid lens, sample mask — ride ONE packed int32
-    transfer (the max_blocks width is recovered from the packed length,
-    so one cached builder serves every pool geometry)."""
-
-    @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
-                       out_kinds="crrr", donate=(1, 2, 3))
-    def tick(params_only, cache, last_logits, rngs, packed):
-        recompiles.note("serve.paged_mixed_tick")
-        S = rngs.shape[0]
-        MB = packed.shape[0] // S - chunk - 3
-        tables, lens, fed, valid, smask = _unpack_i32(
-            packed, ((S, MB), (S,), (S, chunk), (S,), (S,)))
-        sample_mask = smask != 0
-        with jax.named_scope("sample"):
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
-        sampled = jnp.stack(toks)
-        inputs = fed.at[:, 0].set(
-            jnp.where(sample_mask, sampled, fed[:, 0])
-        )
-        logits, vs = dm_paged.apply(
-            {**params_only, "cache": cache}, inputs,
-            block_tables=tables, seq_lens=lens, valid_lens=valid,
-            mutable=["cache"],
-        )
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1
-        )[:, 0]
-        return vs["cache"], last, sampled, jnp.stack(new_rngs)
+        return vs["cache"], last, sampled, jnp.stack(chains)
 
     return tick
 
@@ -462,101 +487,51 @@ def _paged_mixed_tick_fn(dm_paged, cfgs, chunk,
 
 
 @functools.lru_cache(maxsize=256)
-def _multi_tick_fn(dm_slot, cfgs, k, ctx: Optional[_ShardCtx] = None):
-    """Compiled k-step decode window, slot mode: ``lax.scan`` over the
-    :func:`_tick_fn` body. The packed control buffer carries per-row
-    EOS ids (-1 = none) and emission limits ``lim = min(k, remaining)``
-    (0 = idle row); a row is ALIVE while it has neither hit its EOS nor
-    emitted ``lim`` tokens. Alive rows advance exactly as k consecutive
-    k=1 ticks would — the EOS token itself is fed in its own step, as
-    the sync loop feeds it in its own tick — and stopped rows run
-    valid-0 padding. Returns ``[S, k]`` tokens (column-major per step;
-    garbage past each row's count, never read) and the per-row counts
-    the reconcile trims by."""
+def _multi_tick_fn(layout, cfgs, k, ctx: Optional[_ShardCtx] = None):
+    """Compiled k-step decode window: ``lax.scan`` over the
+    :func:`_tick_fn` body. The packed control buffer carries, behind
+    the layout's head, per-row EOS ids (-1 = none) and emission limits
+    ``lim = min(k, remaining)`` (0 = idle row); a row is ALIVE while it
+    has neither hit its EOS nor emitted ``lim`` tokens. Alive rows
+    advance exactly as k consecutive k=1 ticks would — the EOS token
+    itself is fed in its own step, as the sync loop feeds it in its own
+    tick — and stopped rows run valid-0 padding (no KV write, no cursor
+    advance; under the paged layout the write is steered to the
+    reserved trash block). Returns ``[S, k]`` tokens (column-major per
+    step; garbage past each row's count, never read) and the per-row
+    counts the reconcile trims by."""
 
     @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
                        out_kinds="crrrr", donate=(1, 2, 3))
     def tick(params_only, cache, last_logits, rngs, packed):
-        recompiles.note("serve.multi_tick")
+        recompiles.note(layout.tag("multi_tick"))
         S = rngs.shape[0]
-        eos, lim = _unpack_i32(packed, ((S,), (S,)))
+        where, (eos, lim) = layout.unpack(packed, S, ((S,), (S,)))
 
         def step(carry, _):
             cache, last, rngs, stopped, emitted = carry
             alive = ~stopped & (emitted < lim)
-            with jax.named_scope("sample"):
-                toks, new_rngs = [], []
-                for s, (temp, top_k, top_p) in enumerate(cfgs):
-                    rng, sub = jax.random.split(rngs[s])
-                    toks.append(
-                        sample_tokens(last[s][None], sub, temp,
-                                      top_k, top_p)[0]
-                    )
-                    new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
-            tok = jnp.stack(toks)  # [S]
+            tok, chains = _sample_rows(cfgs, last, rngs, alive)  # [S]
             valid = alive.astype(jnp.int32)
-            logits, vs = dm_slot.apply(
-                {**params_only, "cache": cache}, tok[:, None],
-                valid_lens=valid, mutable=["cache"],
+            inputs = tok[:, None]
+            at = where
+            if layout.paged:
+                # the host's cursors came up once, at the WINDOW'S
+                # START: each step writes alive rows at ``lens +
+                # emitted``, the device-side mirror of the advance the
+                # host makes per dispatch at k=1. It preallocated the
+                # worst case at admission (``_blocks_for`` covers
+                # prompt + max_new), so a window never allocates;
+                # writes past a trimmed row's chain land in the trash
+                # block (its table is zero beyond the chain)
+                at = {**where, "seq_lens": where["seq_lens"] + emitted}
+            logits, vs = layout.dm.apply(
+                {**params_only, "cache": cache}, inputs,
+                valid_lens=valid, mutable=["cache"], **at,
             )
             last = jnp.where(alive[:, None], logits[:, -1], last)
             stopped = stopped | (alive & (eos >= 0) & (tok == eos))
-            return ((vs["cache"], last, jnp.stack(new_rngs), stopped,
-                     emitted + valid), tok)
-
-        init = (cache, last_logits, rngs,
-                jnp.zeros((S,), bool), jnp.zeros((S,), jnp.int32))
-        (cache, last, rngs, _, counts), toks = jax.lax.scan(
-            step, init, None, length=k)
-        return cache, last, toks.T, counts, rngs
-
-    return tick
-
-
-@functools.lru_cache(maxsize=256)
-def _paged_multi_tick_fn(dm_paged, cfgs, k,
-                         ctx: Optional[_ShardCtx] = None):
-    """Paged twin of :func:`_multi_tick_fn`: the packed transfer adds
-    block tables and WINDOW-START seq lens; each step writes alive rows
-    at absolute position ``lens + emitted`` (the device-side mirror of
-    the host cursor advance the k=1 paged tick does per dispatch).
-    Stopped rows steer their write to the reserved trash block via
-    valid 0 and do not advance. The host preallocated the worst case at
-    admission (``_blocks_for`` covers prompt + max_new), so a window
-    never allocates; writes past a trimmed row's chain land in the
-    trash block (its table is zero beyond the chain)."""
-
-    @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
-                       out_kinds="crrrr", donate=(1, 2, 3))
-    def tick(params_only, cache, last_logits, rngs, packed):
-        recompiles.note("serve.paged_multi_tick")
-        S = rngs.shape[0]
-        MB = packed.shape[0] // S - 3
-        tables, lens, eos, lim = _unpack_i32(
-            packed, ((S, MB), (S,), (S,), (S,)))
-
-        def step(carry, _):
-            cache, last, rngs, stopped, emitted = carry
-            alive = ~stopped & (emitted < lim)
-            with jax.named_scope("sample"):
-                toks, new_rngs = [], []
-                for s, (temp, top_k, top_p) in enumerate(cfgs):
-                    rng, sub = jax.random.split(rngs[s])
-                    toks.append(
-                        sample_tokens(last[s][None], sub, temp,
-                                      top_k, top_p)[0]
-                    )
-                    new_rngs.append(jnp.where(alive[s], rng, rngs[s]))
-            tok = jnp.stack(toks)  # [S]
-            valid = alive.astype(jnp.int32)
-            logits, vs = dm_paged.apply(
-                {**params_only, "cache": cache}, tok[:, None],
-                block_tables=tables, seq_lens=lens + emitted,
-                valid_lens=valid, mutable=["cache"],
-            )
-            last = jnp.where(alive[:, None], logits[:, -1], last)
-            stopped = stopped | (alive & (eos >= 0) & (tok == eos))
-            return ((vs["cache"], last, jnp.stack(new_rngs), stopped,
+            return ((vs["cache"], last, jnp.stack(chains), stopped,
                      emitted + valid), tok)
 
         init = (cache, last_logits, rngs,
@@ -614,7 +589,7 @@ def _rewind_cursors(cache, rewind):
 @jax.named_scope("sample")
 def _spec_accept(cfgs, k, onehot_q, full, rngs, valid, n_forced,
                  sample_mask, draft_toks, q_probs):
-    """Rejection-sampling core shared by both verify ticks (traced).
+    """Rejection-sampling core of the verify tick (traced).
 
     ``full`` [S, W+1, V]: position j is the target's filtered-sampling
     source for window token j (j=0 is the pre-window ``last_logits``).
@@ -709,82 +684,56 @@ def _merge_drafts(fed, valid, n_forced, draft_toks, k):
 
 
 @functools.lru_cache(maxsize=256)
-def _spec_verify_fn(dm_slot, cfgs, W, k, onehot_q,
+def _spec_verify_fn(layout, cfgs, W, k, onehot_q,
                     ctx: Optional[_ShardCtx] = None):
-    """Compiled speculative verify tick, slot layout: ONE ``[S, W]``
-    dispatch writes every row's window K/V at its absolute positions
-    (the chunked mixed tick's valid_lens machinery verbatim), scores
-    all window positions, runs per-row rejection sampling
-    (:func:`_spec_accept`), and rewinds the [S] cache cursors past the
-    rejected suffixes in the same dispatch — acceptance-length
-    variation changes only traced values, never shapes, so steady
-    state stays at zero recompiles. Under a mesh ``ctx`` the body runs
-    per head-shard with sampling on replicated logits, like every
-    other tick. Host int controls (fed, valid, n_forced, sample mask)
-    ride one packed transfer; ``draft_toks`` stays a separate arg
+    """Compiled speculative verify tick: ONE ``[S, W]`` dispatch writes
+    every row's window K/V at its absolute positions (the chunked mixed
+    tick's valid_lens machinery verbatim; through each row's block
+    table under the paged layout), scores all window positions, runs
+    per-row rejection sampling (:func:`_spec_accept`), and rolls the
+    rejected suffixes back — acceptance-length variation changes only
+    traced values, never shapes, so steady state stays at zero
+    recompiles. Under a mesh ``ctx`` the body runs per head-shard with
+    sampling on replicated logits, like every other tick. Host int
+    controls (the layout's head, then fed, valid, n_forced, sample
+    mask) ride one packed transfer; ``draft_toks`` stays a separate arg
     because a model drafter's proposals are already device-resident."""
 
     @functools.partial(_compile, ctx=ctx, in_kinds="pcrrrrr",
                        out_kinds="crrrr", donate=(1, 2, 3))
     def tick(params_only, cache, last_logits, rngs, packed, draft_toks,
              q_probs):
-        recompiles.note("serve.spec_tick")
+        recompiles.note(layout.tag("spec_tick"))
         S = rngs.shape[0]
-        fed, valid, n_forced, smask = _unpack_i32(
-            packed, ((S, W), (S,), (S,), (S,)))
+        where, (fed, valid, n_forced, smask) = layout.unpack(
+            packed, S, ((S, W), (S,), (S,), (S,)))
         sample_mask = smask != 0
         merged = _merge_drafts(fed, valid, n_forced, draft_toks, k)
-        logits, vs = dm_slot.apply(
+        logits, vs = layout.dm.apply(
             {**params_only, "cache": cache}, merged,
-            valid_lens=valid, mutable=["cache"],
+            valid_lens=valid, mutable=["cache"], **where,
         )
         full = jnp.concatenate(
             [last_logits[:, None], logits.astype(jnp.float32)], axis=1)
         out_toks, acc, new_last, new_rngs = _spec_accept(
             cfgs, k, onehot_q, full, rngs, valid, n_forced,
             sample_mask, draft_toks, q_probs)
-        new_cache = _rewind_cursors(vs["cache"],
-                                    valid - (n_forced + acc))
+        new_cache = vs["cache"]
+        if not layout.paged:
+            # the slot cursors are in the cache: rewind them past the
+            # rejected suffixes in the same dispatch. The paged cursors
+            # are the host's, so there is no rollback here at all: the
+            # engine advances each row by ``n_forced + acc`` instead of
+            # ``valid`` when it reads ``acc`` back, and rejected-draft
+            # bytes sit in row-private blocks beyond the cursor
+            # (windows never reach shared prefix blocks: those end
+            # before the row's write region by the COW-at-admission
+            # invariant, and never past the chain: window width <=
+            # remaining <= the preallocated worst case — so rollback
+            # touches no block refcounts)
+            new_cache = _rewind_cursors(new_cache,
+                                        valid - (n_forced + acc))
         return new_cache, new_last, out_toks, acc, new_rngs
-
-    return tick
-
-
-@functools.lru_cache(maxsize=256)
-def _paged_spec_verify_fn(dm_paged, cfgs, W, k, onehot_q,
-                          ctx: Optional[_ShardCtx] = None):
-    """Paged twin of :func:`_spec_verify_fn`: window K/V routed through
-    each row's block table. No in-dispatch rollback — the paged
-    cursors (``seq_lens``) are host-owned, so the engine simply
-    advances each row by ``n_forced + acc`` instead of ``valid``;
-    rejected-draft bytes sit in row-private blocks beyond the cursor
-    (windows never reach shared prefix blocks: those end before the
-    row's write region by the COW-at-admission invariant, and never
-    past the chain: window width <= remaining <= the preallocated
-    worst case — so rollback touches no block refcounts at all)."""
-
-    @functools.partial(_compile, ctx=ctx, in_kinds="pcrrrrr",
-                       out_kinds="crrrr", donate=(1, 2, 3))
-    def tick(params_only, cache, last_logits, rngs, packed, draft_toks,
-             q_probs):
-        recompiles.note("serve.paged_spec_tick")
-        S = rngs.shape[0]
-        MB = packed.shape[0] // S - W - 4
-        tables, lens, fed, valid, n_forced, smask = _unpack_i32(
-            packed, ((S, MB), (S,), (S, W), (S,), (S,), (S,)))
-        sample_mask = smask != 0
-        merged = _merge_drafts(fed, valid, n_forced, draft_toks, k)
-        logits, vs = dm_paged.apply(
-            {**params_only, "cache": cache}, merged,
-            block_tables=tables, seq_lens=lens, valid_lens=valid,
-            mutable=["cache"],
-        )
-        full = jnp.concatenate(
-            [last_logits[:, None], logits.astype(jnp.float32)], axis=1)
-        out_toks, acc, new_last, new_rngs = _spec_accept(
-            cfgs, k, onehot_q, full, rngs, valid, n_forced,
-            sample_mask, draft_toks, q_probs)
-        return vs["cache"], new_last, out_toks, acc, new_rngs
 
     return tick
 
@@ -908,39 +857,6 @@ def _reset_slot_cursors(cache, slot):
     return jax.tree.map(
         lambda c: c.at[slot].set(0) if c.ndim == 1 else c, cache
     )
-
-
-@functools.lru_cache(maxsize=256)
-def _paged_tick_fn(dm_paged, cfgs, ctx: Optional[_ShardCtx] = None):
-    """Paged twin of :func:`_tick_fn`: identical per-slot sampling (same
-    RNG chains, same [1, vocab] call shape), then one decode step whose
-    K/V reads/writes go through each row's block table. Tables and seq
-    lens arrive as one packed int32 transfer."""
-
-    @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
-                       out_kinds="crrr", donate=(1, 2, 3))
-    def tick(params_only, cache, last_logits, rngs, packed):
-        recompiles.note("serve.paged_tick")
-        S = rngs.shape[0]
-        MB = packed.shape[0] // S - 1
-        tables, lens = _unpack_i32(packed, ((S, MB), (S,)))
-        with jax.named_scope("sample"):
-            toks, new_rngs = [], []
-            for s, (temp, top_k, top_p) in enumerate(cfgs):
-                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(rng)
-        tok = jnp.stack(toks)  # [S]
-        logits, vs = dm_paged.apply(
-            {**params_only, "cache": cache}, tok[:, None],
-            block_tables=tables, seq_lens=lens, mutable=["cache"],
-        )
-        return vs["cache"], logits[:, -1], tok, jnp.stack(new_rngs)
-
-    return tick
 
 
 @functools.lru_cache(maxsize=32)
@@ -1630,6 +1546,7 @@ class ServingEngine:
                 **({"tp_size": self.tp, "tp_axis": tp_axis}
                    if mesh is not None else {}),
             )
+            self._layout = _CacheLayout(self._dm_paged, self._max_blocks)
             # cache template is always the GLOBAL (tp=1) layout; under a
             # mesh, device_put + the cache specs slice the KV-head axis
             # (a tp module's init can't trace outside shard_map)
@@ -1681,6 +1598,7 @@ class ServingEngine:
                 decode=True, slot_cursor=True,
                 prefill_kernel=prefill_kernel, parent=None, **tp_kw
             )
+            self._layout = _CacheLayout(self._dm_slot)
             self._dm_one = self.model.clone(decode=True,
                                             prefill_kernel=prefill_kernel,
                                             parent=None, **tp_kw)
@@ -2730,8 +2648,8 @@ class ServingEngine:
             tables[slot, :] = 0
             tables[slot, :len(chain)] = chain
             self._block_tables = tables
-            # copy-and-rebind (aliasing hazard, see _decode_tick): the
-            # row starts at the cached span; chunks advance it
+            # copy-and-rebind (aliasing hazard, see _CacheLayout.advance):
+            # the row starts at the cached span; chunks advance it
             lens = self._seq_lens.copy()
             lens[slot] = cached
             self._seq_lens = lens
@@ -2864,7 +2782,7 @@ class ServingEngine:
             st.restoring = [(h, off) for h, off in st.restoring
                             if off < new_cached] or None
             if lens is None:
-                # copy-and-rebind (aliasing hazard, see _decode_tick)
+                # copy-and-rebind (see _CacheLayout.advance)
                 lens = self._seq_lens.copy()
             lens[s] = new_cached
         if lens is not None:
@@ -3163,19 +3081,10 @@ class ServingEngine:
                 # take == 0: starved this tick — valid stays 0, the row
                 # writes nothing and its cursor holds
                 rows[s] = ("pre", st, take, flipped)
-            if self.paged:
-                # REBIND, never mutate (aliasing hazard, see _decode_tick):
-                # live rows advance by what the dispatch consumes; idle rows
-                # stay parked at 0 on the trash block
-                adv = np.zeros((S,), np.int32)
-                for s, row in enumerate(rows):
-                    if row is not None:
-                        adv[s] = 1 if row[0] == "dec" else valid[s]
-                packed = _pack_i32(self._block_tables, self._seq_lens, fed,
-                                   valid, sample_mask)
-                self._seq_lens = self._seq_lens + adv
-            else:
-                packed = _pack_i32(fed, valid, sample_mask)
+            # every row advances by what the dispatch consumes of it: 1
+            # where it decodes, its chunk where it prefills, 0 otherwise
+            packed = self._layout.pack(self, (fed, valid, sample_mask),
+                                       advance=valid)
         work = {"attended_tokens": attended,
                 "key_positions": key_positions,
                 "key_positions_fetched": self._kv_fetched(starts, valid, C),
@@ -3191,24 +3100,69 @@ class ServingEngine:
                 np.arange(C) < valid[:, None]]
             work["index_positions_scored"] = int(seen.sum())
             work["keys_selected"] = int(np.minimum(seen, topk).sum())
+        return self._dispatch(tick_no, plan, cfgs, packed, rows,
+                              n_dec=n_dec, fed_tokens=fed_tokens, chunk=C,
+                              work=work)
+
+    def _dispatch(self, tick_no: int, plan: _Phase, cfgs, packed, rows, *,
+                  n_dec: int, fed_tokens: int = 0,
+                  chunk: Optional[int] = None,
+                  multi_k: Optional[int] = None,
+                  work: Optional[dict] = None, drafts=None,
+                  spec_rows=None, **spec_rec) -> _InflightTick:
+        """The one place a planned tick reaches the device: upload its
+        control buffer (``packed``; None is the slot layout's plain
+        decode tick, which takes none and uploads nothing), look the
+        program up by what was planned — a verify window where
+        ``spec_rows`` is given (``chunk`` wide, with ``drafts`` the
+        host's ``[S, k]`` proposals unless a draft model makes them on
+        the device), a ``multi_k``-step window, a mixed tick ``chunk``
+        wide, else the plain decode tick — call it, and return the
+        in-flight record of its outputs. The donated cache, logits and
+        RNG chains are rebound by the very statement that donates them,
+        here and nowhere else (the donation-safety pass holds this
+        function to it)."""
+        span = {"n_dec": n_dec, "fed_tokens": fed_tokens}
+        if chunk is not None:
+            span["chunk"] = chunk
+        if multi_k is not None:
+            span["multi_k"] = multi_k
+        if work is not None:
+            span.update(work)
         with self._phase("upload", tick=tick_no) as upload:
-            dev = self._upload(packed)
-        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
-                         fed_tokens=fed_tokens, chunk=C,
-                         **work) as dispatch:
-            if self.paged:
-                tick = _paged_mixed_tick_fn(self._dm_paged, cfgs, C,
-                                            self._ctx)
+            operands = [] if packed is None else [self._upload(packed)]
+            if spec_rows is not None and self.draft_kind != "model":
+                operands.append(jnp.asarray(drafts))
+        with self._phase("dispatch", tick=tick_no, **span) as dispatch:
+            if spec_rows is not None:
+                if self.draft_kind == "model":
+                    q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
+                    operands += [draft_dev, q_probs]
+                else:
+                    operands.append(jnp.zeros((1,), jnp.float32))
+                tick = _spec_verify_fn(self._layout, cfgs, chunk,
+                                       self.spec_k,
+                                       self.draft_kind == "ngram",
+                                       self._ctx)
+            elif multi_k is not None:
+                tick = _multi_tick_fn(self._layout, cfgs, multi_k,
+                                      self._ctx)
+            elif chunk is not None:
+                tick = _mixed_tick_fn(self._layout, cfgs, chunk, self._ctx)
             else:
-                tick = _mixed_tick_fn(self._dm_slot, cfgs, C, self._ctx)
-            self._cache, self._last_logits, toks, self._rngs = tick(
+                tick = _tick_fn(self._layout, cfgs, self._ctx)
+            # ``acc``: a verify window's accepted-prefix lengths, a
+            # multi-step window's per-row counts
+            (self._cache, self._last_logits, toks, *acc,
+             self._rngs) = tick(
                 self._params_only, self._cache, self._last_logits,
-                self._rngs, dev,
+                self._rngs, *operands,
             )
         return _InflightTick(
             toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
             upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
-            n_dec=n_dec, fed_tokens=fed_tokens, chunk=C, work=work,
+            n_dec=n_dec, fed_tokens=fed_tokens, chunk=chunk, work=work,
+            multi_k=multi_k, acc=acc[0] if acc else None, **spec_rec,
         )
 
     def _reconcile(self, rec: _InflightTick):
@@ -3561,40 +3515,16 @@ class ServingEngine:
                         st.decoding = True
                         flipped = True
                 rows[s] = ("pre", st, take, flipped)
-            if self.paged:
-                packed = _pack_i32(self._block_tables, self._seq_lens,
-                                   fed, valid, n_forced, sample_mask)
-            else:
-                packed = _pack_i32(fed, valid, n_forced, sample_mask)
-        with self._phase("upload", tick=tick_no) as upload:
-            dev = self._upload(packed)
-            if self.draft_kind != "model":
-                draft_dev = jnp.asarray(draft_np)
-        with self._phase("dispatch", tick=tick_no, n_dec=len(dec),
-                         fed_tokens=fed_tokens, chunk=W) as dispatch:
-            if self.draft_kind == "model":
-                q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
-            else:
-                q_probs = jnp.zeros((1,), jnp.float32)
-            onehot = self.draft_kind == "ngram"
-            if self.paged:
-                tick = _paged_spec_verify_fn(self._dm_paged, cfgs, W, k,
-                                             onehot, self._ctx)
-            else:
-                tick = _spec_verify_fn(self._dm_slot, cfgs, W, k, onehot,
-                                       self._ctx)
-            (self._cache, self._last_logits, toks, acc,
-             self._rngs) = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs, dev, draft_dev, q_probs,
-            )
-        return _InflightTick(
-            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
-            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
-            n_dec=len(dec), fed_tokens=fed_tokens, chunk=W,
-            acc=acc, n_forced=n_forced, granted=granted,
-            spec_set=spec_set,
-        )
+            # host-owned cursors hold here: how far a row advances is
+            # known only when its accepted length is read back
+            # (_reconcile_spec)
+            packed = self._layout.pack(
+                self, (fed, valid, n_forced, sample_mask))
+        return self._dispatch(tick_no, plan, cfgs, packed, rows,
+                              n_dec=len(dec), fed_tokens=fed_tokens,
+                              chunk=W, drafts=draft_np,
+                              spec_rows=spec_rows, n_forced=n_forced,
+                              granted=granted, spec_set=spec_set)
 
     def _reconcile_spec(self, rec: _InflightTick,
                         defer: Optional[list]):
@@ -3614,12 +3544,10 @@ class ServingEngine:
             rec.toks = rec.acc = None  # freed here, as in _reconcile
         wait_ms = wait.ms
         with self._phase("stream", tick=rec.tick) as stream:
-            if self.paged:
-                # REBIND, never mutate (aliasing hazard, see _decode_tick):
-                # each row keeps only its forced tokens plus the accepted
-                # prefix — the rejected-suffix rollback IS this arithmetic
-                self._seq_lens = self._seq_lens + (
-                    rec.n_forced + acc_host).astype(np.int32)
+            # each row keeps only its forced tokens plus the accepted
+            # prefix — where the cursors are the host's, the
+            # rejected-suffix rollback IS this arithmetic
+            self._layout.advance(self, rec.n_forced + acc_host)
             self.ticks += 1
             occupancy = sum(st is not None for st in self._slots)
             self._occ_sum += occupancy
@@ -3726,41 +3654,12 @@ class ServingEngine:
                 for st in self._slots
             ]
             n_dec = sum(1 for r in rows if r is not None)
-            if self.paged:
-                # the tick writes each live row's K/V at its cursor;
-                # advance the host-owned cursors (idle rows stay parked
-                # at 0 on the trash block). REBIND, never mutate:
-                # jnp.asarray can alias the numpy buffer zero-copy while
-                # the async tick still reads it — in-place writes would
-                # race the device
-                packed = _pack_i32(self._block_tables, self._seq_lens)
-                alive = np.fromiter(
-                    (st is not None for st in self._slots), bool,
-                    self.slots
-                )
-                self._seq_lens = self._seq_lens + alive.astype(np.int32)
-        with self._phase("upload", tick=tick_no) as upload:
-            # the slot tick takes no control buffer at all
-            dev = self._upload(packed) if self.paged else None
-        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
-                         fed_tokens=0) as dispatch:
-            if self.paged:
-                tick = _paged_tick_fn(self._dm_paged, cfgs, self._ctx)
-                self._cache, self._last_logits, toks, self._rngs = tick(
-                    self._params_only, self._cache, self._last_logits,
-                    self._rngs, dev,
-                )
-            else:
-                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-                self._cache, self._last_logits, toks, self._rngs = tick(
-                    self._params_only, self._cache, self._last_logits,
-                    self._rngs
-                )
-        return _InflightTick(
-            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
-            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
-            n_dec=n_dec, fed_tokens=0, chunk=None,
-        )
+            # the tick writes each live row's K/V at its cursor
+            packed = self._layout.pack(self, advance=np.fromiter(
+                (st is not None for st in self._slots), np.int32,
+                self.slots))
+        return self._dispatch(tick_no, plan, cfgs, packed, rows,
+                              n_dec=n_dec)
 
     # -- device-resident multi-step decode -----------------------------------
 
@@ -3841,32 +3740,9 @@ class ServingEngine:
                 # a row that stops short of lim completes at this
                 # window's reconcile: nothing reads its cursor again
                 st.cursor += int(lim[s])
-            if self.paged:
-                packed = _pack_i32(self._block_tables, self._seq_lens,
-                                   eos, lim)
-                # REBIND, never mutate (aliasing hazard, see
-                # _decode_tick)
-                self._seq_lens = self._seq_lens + lim
-                tick = _paged_multi_tick_fn(self._dm_paged, cfgs, k,
-                                            self._ctx)
-            else:
-                packed = _pack_i32(eos, lim)
-                tick = _multi_tick_fn(self._dm_slot, cfgs, k, self._ctx)
-        with self._phase("upload", tick=tick_no) as upload:
-            dev = self._upload(packed)
-        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
-                         fed_tokens=0, multi_k=k) as dispatch:
-            (self._cache, self._last_logits, toks, counts,
-             self._rngs) = tick(
-                self._params_only, self._cache, self._last_logits,
-                self._rngs, dev,
-            )
-        return _InflightTick(
-            toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
-            upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
-            n_dec=n_dec, fed_tokens=0, chunk=None,
-            multi_k=k, acc=counts,
-        )
+            packed = self._layout.pack(self, (eos, lim), advance=lim)
+        return self._dispatch(tick_no, plan, cfgs, packed, rows,
+                              n_dec=n_dec, multi_k=k)
 
     def _complete(self, slot: int, reason: str,
                   defer: Optional[list] = None):

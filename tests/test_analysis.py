@@ -708,22 +708,22 @@ def test_donation_pass_catches_seeded_engine_violation(tmp_path):
     direct, factories = _module_donators(src.tree)
     # every compiled serving body donates; the discovery must see them
     assert set(direct) == {"_reset_slot_cursors", "_copy_block"}
-    assert {"_tick_fn", "_mixed_tick_fn", "_paged_tick_fn",
+    assert {"_tick_fn", "_mixed_tick_fn", "_multi_tick_fn",
             "_spec_verify_fn", "_draft_feed_fn"} <= set(factories)
     assert all(v for v in factories.values())
 
     seeded = text.replace(
-        """                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-                self._cache, self._last_logits, toks, self._rngs = tick(
-                    self._params_only, self._cache, self._last_logits,
-                    self._rngs
-                )""",
-        """                tick = _tick_fn(self._dm_slot, cfgs, self._ctx)
-                new_cache, self._last_logits, toks, self._rngs = tick(
-                    self._params_only, self._cache, self._last_logits,
-                    self._rngs
-                )
-                stale = self._cache""",
+        """            (self._cache, self._last_logits, toks, *acc,
+             self._rngs) = tick(
+                self._params_only, self._cache, self._last_logits,
+                self._rngs, *operands,
+            )""",
+        """            (new_cache, self._last_logits, toks, *acc,
+             self._rngs) = tick(
+                self._params_only, self._cache, self._last_logits,
+                self._rngs, *operands,
+            )
+            stale = self._cache""",
         1,
     )
     assert seeded != text, "engine call-site shape changed; update seed"
@@ -731,7 +731,7 @@ def test_donation_pass_catches_seeded_engine_violation(tmp_path):
     p.write_text(seeded)
     findings = analyze([str(p)])
     assert any(f.rule == "donation-safety"
-               and f.key == "_plan_dispatch_decode.self._cache"
+               and f.key == "_dispatch.self._cache"
                for f in findings), [f.render() for f in findings]
 
 
@@ -743,20 +743,16 @@ def test_donation_pass_catches_seeded_inflight_handoff(tmp_path):
     eng_path = os.path.join(REPO_ROOT, "distkeras_tpu", "serving",
                             "engine.py")
     text = open(eng_path).read()
+    site = ('        with self._phase("dispatch", tick=tick_no, **span) '
+            'as dispatch:')
     seeded = text.replace(
-        """            dev = self._upload(packed)
-        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
-                         fed_tokens=fed_tokens, chunk=C,
-                         **work) as dispatch:""",
-        """            dev = self._upload(packed)
-        leak = _InflightTick(toks=self._cache, rows=rows, tick=tick_no,
-                             plan_ms=0.0, upload_ms=0.0,
+        site,
+        """        leak = _InflightTick(toks=self._cache, rows=rows,
+                             tick=tick_no, plan_ms=0.0, upload_ms=0.0,
                              dispatch_ms=0.0, n_dec=n_dec,
-                             fed_tokens=fed_tokens, chunk=C)
+                             fed_tokens=fed_tokens, chunk=chunk)
         self._pending.append(leak)
-        with self._phase("dispatch", tick=tick_no, n_dec=n_dec,
-                         fed_tokens=fed_tokens, chunk=C,
-                         **work) as dispatch:""",
+""" + site,
         1,
     )
     assert seeded != text, "engine dispatch shape changed; update seed"
@@ -764,7 +760,7 @@ def test_donation_pass_catches_seeded_inflight_handoff(tmp_path):
     p.write_text(seeded)
     findings = analyze([str(p)])
     assert any(f.rule == "donation-safety"
-               and f.key == "_plan_dispatch_mixed.self._cache:handoff"
+               and f.key == "_dispatch.self._cache:handoff"
                for f in findings), [f.render() for f in findings]
 
 
@@ -815,25 +811,25 @@ def test_donation_handoff_fixture_good_and_bad(tmp_path):
 
 
 def test_rng_pass_catches_seeded_engine_violation(tmp_path):
-    """Seed a key reuse into the real mixed tick (the per-slot sub key
-    drawn twice) and assert the pass pins it."""
+    """Seed a key reuse into the ticks' real sampler (the per-slot sub
+    key drawn twice) and assert the pass pins it."""
     eng_path = os.path.join(REPO_ROOT, "distkeras_tpu", "serving",
                             "engine.py")
     text = open(eng_path).read()
     seeded = text.replace(
-        """                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                new_rngs.append(rng)""",
-        """                rng, sub = jax.random.split(rngs[s])
-                toks.append(
-                    sample_tokens(last_logits[s][None], sub, temp,
-                                  top_k, top_p)[0]
-                )
-                extra = jax.random.uniform(sub, ())
-                new_rngs.append(rng)""",
+        """            rng, sub = jax.random.split(rngs[s])
+            toks.append(
+                sample_tokens(logits[s][None], sub, temp,
+                              top_k, top_p)[0]
+            )
+""",
+        """            rng, sub = jax.random.split(rngs[s])
+            toks.append(
+                sample_tokens(logits[s][None], sub, temp,
+                              top_k, top_p)[0]
+            )
+            extra = jax.random.uniform(sub, ())
+""",
         1,
     )
     assert seeded != text, "engine tick shape changed; update seed"

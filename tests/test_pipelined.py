@@ -468,8 +468,8 @@ def test_engine_completion_invalidates_short_circuit():
 def test_serve_bench_pipeline_smoke():
     """The --pipeline bench's tiny self-asserting variant: parity
     across the matrix, zero steady-state recompiles, bounded flight
-    overhead, and the overlap speedup wherever the runtime can express
-    it (recorded either way)."""
+    overhead; the overlap speedup is recorded with the regime that
+    produced it, never asserted on the CPU."""
     sys.path.insert(0, os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "benchmarks"))
@@ -479,3 +479,4 @@ def test_serve_bench_pipeline_smoke():
     assert r["parity"] is True
     assert r["pipe_steady_recompiles"] == {}
     assert r["sync_steady_recompiles"] == {}
+    assert r["speedup"] > 0 and "overlap_capable" in r

@@ -257,7 +257,12 @@ def test_eos_inside_accepted_prefix_same_tick_refill():
     p0 = rng.integers(0, 64, size=6).astype(np.int32)
     p1 = rng.integers(0, 64, size=5).astype(np.int32)
     probe = _solo(model, params, p0, max_new_tokens=10)
-    eos = probe[4]  # deep enough that a verify window spans it
+    # an eos ends the stream where it FIRST stands, so it is taken from
+    # the observed stream: the deepest token new to it there, behind
+    # enough tokens that verify windows run first and one spans it
+    depth = max(i for i, t in enumerate(probe) if t not in probe[:i])
+    assert depth >= 2, probe
+    eos = probe[depth]
     eng = _engine(model, params, slots=1, draft="ngram", spec_k=4)
     r0 = eng.submit(p0, max_new_tokens=10, eos_id=eos)
     r1 = eng.submit(p1, max_new_tokens=4)
@@ -270,7 +275,7 @@ def test_eos_inside_accepted_prefix_same_tick_refill():
             assert eng.slot_requests[0] == r1.rid
             break
     eng.drain()
-    assert r0.stream.tokens(timeout=10) == probe[:5]
+    assert r0.stream.tokens(timeout=10) == probe[:depth + 1]
     assert r0.stream.finish_reason == "eos"
     assert r1.stream.tokens(timeout=10) == _solo(model, params, p1,
                                                  max_new_tokens=4)
@@ -408,6 +413,26 @@ def test_spec_zero_steady_state_recompiles():
     assert second == first and third == first
 
 
+def _draftable_prompt(model, params, k, max_new):
+    """A seeded prompt on whose greedy continuation the n-gram drafter
+    is right at least once, found by probing: what a random tiny model
+    emits decides whether its stream ever repeats itself. Until its
+    first hit every verify window emits one token, so window ``i``
+    proposes from the prompt plus the first ``i`` tokens."""
+    from distkeras_tpu.serving.engine import _ngram_propose
+
+    for seed in range(16):
+        prompt = np.random.default_rng(seed).integers(
+            0, 64, size=6).astype(np.int32)
+        probe = _solo(model, params, prompt, max_new_tokens=max_new)
+        for i in range(1, max_new - 1):
+            toks, found = _ngram_propose(
+                np.concatenate([prompt, probe[:i]]).astype(np.int32), k)
+            if found and toks[0] == probe[i]:
+                return prompt
+    pytest.fail("no seeded prompt whose stream the n-gram drafter hits")
+
+
 def test_spec_telemetry_exposed():
     from distkeras_tpu.telemetry.exposition import render_prometheus
 
@@ -415,8 +440,7 @@ def test_spec_telemetry_exposed():
     registry = telemetry.MetricRegistry()
     eng = _engine(model, params, slots=2, registry=registry,
                   draft="ngram", spec_k=3)
-    prompt = np.random.default_rng(7).integers(
-        0, 64, size=6).astype(np.int32)
+    prompt = _draftable_prompt(model, params, k=3, max_new=12)
     r = eng.submit(prompt, max_new_tokens=12)
     eng.drain()
     r.stream.tokens(timeout=10)
