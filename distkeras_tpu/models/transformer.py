@@ -27,7 +27,7 @@ flow (jit-stable static shapes).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -181,6 +181,38 @@ def _quantize_int8(x):
         jnp.round(x.astype(jnp.float32) / s[..., None]), -127, 127
     ).astype(jnp.int8)
     return qx, s
+
+
+class _LivePacking(NamedTuple):
+    """Where a mixed tick's live tokens lie once packed (traced; see
+    :meth:`TransformerLM.__call__`, ``live_tokens``)."""
+
+    idx: jnp.ndarray  # [N]: the flat [S * C] position packed row n holds
+    inv: jnp.ndarray  # [S, C]: the packed row of a position; N where none
+
+
+def _live_packing(valid_lens, C: int, N: int) -> _LivePacking:
+    """Pack the positions ``t < valid_lens[s]`` of an ``[S, C]`` tick
+    to ``N`` rows, live positions first and in row-major order (a stable
+    sort), so row ``s``'s tokens are contiguous and end at
+    ``cumsum(valid_lens)[s] - 1``. Rows beyond the live count hold dead
+    positions: computed, never unpacked, never read. The caller
+    guarantees ``sum(valid_lens) <= N``."""
+    live = (jnp.arange(C)[None, :] < valid_lens[:, None]).reshape(-1)
+    idx = jnp.argsort(~live, stable=True)[:N]
+    inv = jnp.where(live, jnp.cumsum(live.astype(jnp.int32)) - 1, N)
+    return _LivePacking(idx, inv.reshape(-1, C))
+
+
+def _unpack_live(t, packing: _LivePacking):
+    """``[1, N, ...]`` packed rows -> the ``[S, C, ...]`` layout, zeros
+    where no token was dealt."""
+    return jnp.take(t[0], packing.inv, axis=0, mode="fill", fill_value=0)
+
+
+def _pack_live(t, packing: _LivePacking):
+    """``[S, C, ...]`` -> the ``[1, N, ...]`` packed rows."""
+    return t.reshape((-1,) + t.shape[2:])[packing.idx][None]
 
 
 class CausalSelfAttention(nn.Module):
@@ -547,7 +579,13 @@ class CausalSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, block_tables=None, seq_lens=None,
-                 valid_lens=None):
+                 valid_lens=None, packing=None):
+        """``packing`` (a mixed tick's live tokens packed, see
+        :meth:`TransformerLM.__call__`): ``x`` is ``[1, N, D]``, the
+        projections run on those ``N`` rows, and only the attend sees
+        the ``[S, C]`` layout — q, k and v go back to it with zeros
+        where nothing was dealt, and its output is gathered to ``N``
+        again."""
         B, T, D = x.shape
         H = self.num_heads
         hd = D // H
@@ -619,6 +657,8 @@ class CausalSelfAttention(nn.Module):
                 tp_size=self.tp_size, tp_axis=self.tp_axis,
                 dtype=self.dtype, name="qkv",
             )(x)  # [B, T, 3, H_local, hd]
+            if packing is not None:
+                qkv = _unpack_live(qkv, packing)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
             # GQA: separate projections (a fused qkv would force equal
@@ -634,6 +674,8 @@ class CausalSelfAttention(nn.Module):
                 tp_size=self.tp_size, tp_axis=self.tp_axis,
                 dtype=self.dtype, name="kv_proj",
             )(x)  # [B, T, 2, Hk_local, hd]
+            if packing is not None:
+                q, kv = _unpack_live(q, packing), _unpack_live(kv, packing)
             k, v = kv[:, :, 0], kv[:, :, 1]
         if self.rope and not self.decode:
             # global positions: ring shards offset by their shard index;
@@ -656,6 +698,8 @@ class CausalSelfAttention(nn.Module):
                                          valid_lens)
             else:
                 out = self._cached_attend(q, k, v, valid_lens)
+            if packing is not None:
+                out = _pack_live(out, packing)
             return TPDenseGeneral(
                 features=(D,), in_axes=2, mode="row",
                 tp_size=self.tp_size, tp_axis=self.tp_axis,
@@ -756,7 +800,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, block_tables=None, seq_lens=None,
-                 valid_lens=None):
+                 valid_lens=None, packing=None):
         D = x.shape[-1]
         h = nn.LayerNorm(dtype=self.dtype)(x)
         x = x + CausalSelfAttention(
@@ -771,7 +815,7 @@ class Block(nn.Module):
             num_pages=self.num_pages,
             paged_kernel=self.paged_kernel,
             prefill_kernel=self.prefill_kernel,
-        )(h, block_tables, seq_lens, valid_lens)
+        )(h, block_tables, seq_lens, valid_lens, packing)
         h = nn.LayerNorm(dtype=self.dtype)(x)
         if self.moe_experts > 0:
             from distkeras_tpu.ops.moe import SwitchMoE
@@ -887,9 +931,29 @@ class TransformerLM(nn.Module):
     # attributes are config, not state, so the param tree is shared.
     features_only: bool = False
 
+    @property
+    def packs_live_tokens(self) -> bool:
+        """Whether a decode apply takes ``live_tokens`` (the serving
+        engine asks before it passes one). Not with routed experts:
+        ``SwitchMoE`` sizes its capacity from the tokens it is given,
+        so leaving the padding out would change which tokens it
+        drops."""
+        return self.moe_experts == 0
+
     @nn.compact
     def __call__(self, tokens, train: bool = False,
-                 block_tables=None, seq_lens=None, valid_lens=None):
+                 block_tables=None, seq_lens=None, valid_lens=None,
+                 live_tokens: Optional[int] = None):
+        """``live_tokens`` (a static count ``N``, with ``valid_lens``
+        on a per-row-cursor decode module) is the PACKED form of a
+        chunked mixed tick: of the ``[S, C]`` ``tokens`` only the
+        ``sum(valid_lens) <= N`` live ones are embedded, and every
+        per-token layer (LayerNorms, projections, MLP) runs on ``[1,
+        N, D]``; attention alone puts q, k and v back where they lie in
+        ``[S, C]``, so cache writes, cursors and the attend are those
+        of the unpacked call. ``ln_f`` and the head run on each row's
+        LAST VALID token only: the result is ``[S, 1, vocab]`` (a row
+        with ``valid_lens`` 0 holds another row's, never read)."""
         if self.remat not in ("none", "block"):
             raise ValueError(
                 f"Unknown remat policy '{self.remat}'. Known: none, block"
@@ -908,6 +972,18 @@ class TransformerLM(nn.Module):
                 "paged=True (block-pooled KV cache) requires decode=True"
             )
         rope = self.pos_emb == "rope"
+        packing = None
+        if live_tokens is not None:
+            if valid_lens is None or not self.packs_live_tokens:
+                raise ValueError(
+                    "live_tokens (the packed mixed tick) needs valid_lens "
+                    "and dense MLPs (moe_experts=0)"
+                )
+            chunk = tokens.shape[1]
+            packing = _live_packing(valid_lens, chunk, live_tokens)
+            # the row and column each packed token came from
+            live_row, live_col = packing.idx // chunk, packing.idx % chunk
+            tokens = tokens.reshape(-1)[packing.idx][None]  # [1, N]
         # explicit submodule names: the pipeline-parallel path addresses
         # param subtrees by name (parallel/pipeline.py), so these are API
         x = nn.Embed(
@@ -930,7 +1006,10 @@ class TransformerLM(nn.Module):
                     # paged cursors are host-owned and arrive per call:
                     # positions start at each row's seq_lens entry (no
                     # pos_index cache variable to keep in sync)
-                    local_pos = local_pos[None, :] + seq_lens[:, None]
+                    if packing is not None:
+                        local_pos = (seq_lens[live_row] + live_col)[None]
+                    else:
+                        local_pos = local_pos[None, :] + seq_lens[:, None]
                 else:
                     # decode steps see only the new tokens; their
                     # positions start at the running cursor (kept with
@@ -943,7 +1022,10 @@ class TransformerLM(nn.Module):
                             jnp.int32,
                         ),
                     )
-                    if self.slot_cursor:
+                    if packing is not None:
+                        local_pos = (pos_idx.value[live_row]
+                                     + live_col)[None]
+                    elif self.slot_cursor:
                         local_pos = (local_pos[None, :]
                                      + pos_idx.value[:, None])
                     else:
@@ -992,7 +1074,11 @@ class TransformerLM(nn.Module):
                 paged_kernel=self.paged_kernel,
                 prefill_kernel=self.prefill_kernel,
                 name=f"Block_{i}",
-            )(x, block_tables, seq_lens, valid_lens)
+            )(x, block_tables, seq_lens, valid_lens, packing)
+        if packing is not None:
+            # [S, 1, D]: each row's last valid token, where its packed
+            # run ends
+            x = x[0][jnp.maximum(jnp.cumsum(valid_lens) - 1, 0)][:, None]
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         if self.features_only:
             return x
@@ -1022,6 +1108,17 @@ _CAST_FIRST = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _cast_program(dtype, shardings):
+    """ONE program that casts a list of leaves to ``dtype``, each
+    result placed as ``shardings`` says (None: where the compiler puts
+    it, its operand's device). One program where a cast a leaf was one
+    small compile a distinct shape in every process, none of them long
+    enough for the persistent cache to keep."""
+    return jax.jit(lambda leaves: [x.astype(dtype) for x in leaves],
+                   out_shardings=list(shardings))
+
+
 def compute_params(model, params):
     """``params`` with every leaf that ``model`` would cast to its
     compute dtype on first use holding the result of that cast, so a
@@ -1031,22 +1128,38 @@ def compute_params(model, params):
 
     The rule is the model's (``model.casts_first``); a model that
     brings none, a ``float32`` model, and a leaf already in the compute
-    dtype come back as handed, leaf objects included. Casts run on the
-    device a leaf lies on, leaf by leaf."""
-    from jax.tree_util import DictKey, tree_map_with_path
+    dtype come back as handed, leaf objects included. The casts are one
+    jitted program over the leaves that need one (:func:`_cast_program`;
+    the handed leaves are not donated: they are the caller's), and a
+    leaf spread over several devices keeps the sharding it was handed
+    with."""
+    from jax.tree_util import (DictKey, tree_flatten_with_path,
+                               tree_unflatten)
 
     rule = getattr(model, "casts_first", None)
     dtype = jnp.dtype(getattr(model, "dtype", jnp.float32))
     if rule is None or dtype == jnp.float32:
         return params
+    flat, treedef = tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in flat]
+    cast = [i for i, (path, leaf) in enumerate(flat)
+            if leaf.dtype != dtype and rule(
+                [k.key for k in path if isinstance(k, DictKey)])]
+    if not cast:
+        return params
 
-    def cast(path, leaf):
-        names = [k.key for k in path if isinstance(k, DictKey)]
-        if leaf.dtype == dtype or not rule(names):
-            return leaf
-        return jnp.asarray(leaf).astype(dtype)
+    def placed(leaf):
+        # only a concrete array that spans devices has a placement the
+        # compiler could lose; anything else follows its operand
+        sharding = (None if isinstance(leaf, jax.core.Tracer)
+                    else getattr(leaf, "sharding", None))
+        return (sharding if sharding is not None
+                and len(sharding.device_set) > 1 else None)
 
-    return tree_map_with_path(cast, params)
+    program = _cast_program(dtype, tuple(placed(leaves[i]) for i in cast))
+    for i, held in zip(cast, program([leaves[i] for i in cast])):
+        leaves[i] = held
+    return tree_unflatten(treedef, leaves)
 
 
 def generate(model, params, prompt, max_new_tokens: int,

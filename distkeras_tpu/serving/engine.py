@@ -405,8 +405,21 @@ def _counter_sums(sown, names):
         for name in names])
 
 
+def _packed_count(budget: int, slots: int, chunk: int) -> int:
+    """``N``, the ONE compiled count the per-token layers of a
+    ``[slots, chunk]`` mixed tick run over once its live tokens are
+    packed. Derived, not set: the default scheduler deals a tick at most
+    ``max(budget, slots)`` tokens (every decoding row reserves one, the
+    remainder goes to chunks), rounded up to the sublane's 8 rows, and
+    never more than the tick holds. One count and no ladder: under the
+    chip's ridge fewer rows buy nothing, and each further program is
+    seconds of set-up and a compile waiting inside steady state."""
+    return min(slots * chunk, -(-max(int(budget), slots) // 8) * 8)
+
+
 @functools.lru_cache(maxsize=256)
-def _mixed_tick_fn(layout, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
+def _mixed_tick_fn(layout, cfgs, chunk, ctx: Optional[_ShardCtx] = None,
+                   live: Optional[int] = None):
     """Compiled CHUNKED mixed prefill/decode tick (the Sarathi-style
     fused step): one ``[S, chunk]`` dispatch advances every slot —
     decoding rows consume 1 valid token (their own freshly-sampled
@@ -429,7 +442,17 @@ def _mixed_tick_fn(layout, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
     that declares ``tick_counters`` (names it sows into the
     ``counters`` collection) gets their sums over the layers appended
     to the tokens, ``[S + len(counters)]``; for any other model the
-    program is unchanged."""
+    program is unchanged.
+
+    ``live`` (``N``, see :meth:`ServingEngine._live_count`) is the
+    PACKED form, for a decode module that declares
+    ``packs_live_tokens``: the per-token layers run over the tick's
+    live tokens packed to ``N`` rows, found on the device from the
+    valid lens alone; only the attend sees ``[S, chunk]``, and the head
+    runs on each row's last valid token, so the logits come back ``[S,
+    1, vocab]``. Same control buffer, cache bytes, cursors and
+    tokens. ``None`` is the full-width program, text for text what it
+    was before there was a packed one."""
     counters = tuple(getattr(layout.dm, "tick_counters", ()))
 
     @functools.partial(_compile, ctx=ctx, in_kinds="pcrrr",
@@ -445,17 +468,23 @@ def _mixed_tick_fn(layout, cfgs, chunk, ctx: Optional[_ShardCtx] = None):
         inputs = fed.at[:, 0].set(
             jnp.where(sample_mask, sampled, fed[:, 0])
         )
+        if live is not None:
+            where = {**where, "live_tokens": live}
         logits, vs = layout.dm.apply(
             {**params_only, "cache": cache}, inputs,
             valid_lens=valid,
             mutable=["cache", "counters"] if counters else ["cache"],
             **where,
         )
-        # row s's next-step logits live at its last valid token; a
-        # starved prefill row (valid 0) wraps to garbage it never reads
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1
-        )[:, 0]
+        if live is not None:
+            last = logits[:, 0]  # the model took them there itself
+        else:
+            # row s's next-step logits live at its last valid token; a
+            # starved prefill row (valid 0) wraps to garbage it never
+            # reads
+            last = jnp.take_along_axis(
+                logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1
+            )[:, 0]
         if counters:
             # what the model counted on the device this tick rides
             # behind the S tokens: one readback carries both
@@ -987,8 +1016,8 @@ class _InflightTick:
     chunk: Optional[int]
     # mixed ticks: what the dispatch computes and copies in against
     # what was dealt (attended_tokens, key_positions,
-    # key_positions_fetched, cache_positions, query_positions; see the
-    # plan in _plan_dispatch_mixed)
+    # key_positions_fetched, cache_positions, query_positions,
+    # attend_query_positions; see the plan in _plan_dispatch_mixed)
     work: Optional[dict] = None
     # multi-step decode: the window width this record dispatched (None
     # = ordinary one-token tick); ``acc`` doubles as its device [S]
@@ -1078,9 +1107,13 @@ class ServingEngine:
         ``model.max_len``. Smaller values shrink the pooled cache.
       scheduler: admission policy; defaults to a
         :class:`FIFOScheduler` with its default backpressure knobs
-        (``tick_token_budget`` 256: a ``[S, C]`` mixed tick computes
-        ``S x C`` positions whatever is dealt, so an engine of 32 slots
-        and long prompts wants ``S x C``). A ``dict`` is taken as
+        (``tick_token_budget`` 256). The budget also bounds what a
+        ``[S, C]`` mixed tick multiplies: where it is under ``S x C``
+        and the model's MLPs are dense, the tick's per-token layers run
+        over its live tokens packed to the one count
+        :func:`_packed_count` derives from it, and only the attend spans
+        ``S x C``; an engine of 32 slots and long prompts may still
+        want ``S x C`` dealt. A ``dict`` is taken as
         :class:`FIFOScheduler`'s arguments, for callers that build the
         engine from a file.
       metrics: a :class:`MetricsWriter`; an in-memory one is created if
@@ -1454,6 +1487,8 @@ class ServingEngine:
         # what the mixed ticks computed against what they were dealt
         self.attended_tokens_total = 0
         self.query_positions_total = 0
+        self.attend_query_positions_total = 0
+        self.packed_ticks_total = 0
         self.key_positions_fetched_total = 0
         self.cache_positions_total = 0
         self.useful_query_tokens_total = 0
@@ -2974,6 +3009,21 @@ class ServingEngine:
             return splash_prefill.fetched_positions(starts, valid, L)
         return self.slots * L
 
+    def _live_count(self, C: int, dealt: int) -> Optional[int]:
+        """The count a ``[S, C]`` mixed tick that was ``dealt`` so many
+        tokens is packed to (:func:`_packed_count`), or None for the
+        full-width program: where the decode module does not declare
+        ``packs_live_tokens``, where packing would leave nothing out (a
+        ``[S, 1]`` tick; a budget that covers every row's chunk), and
+        where this plan overran the count (a scheduler of the user's
+        that deals more than its ``tick_token_budget``)."""
+        budget = getattr(self.scheduler, "tick_token_budget", None)
+        if budget is None or not getattr(
+                self._layout.dm, "packs_live_tokens", False):
+            return None
+        N = _packed_count(budget, self.slots, C)
+        return N if N < self.slots * C and dealt <= N else None
+
     def _mixed_tick(self):
         """One fused mixed prefill/decode tick, sync mode: plan and
         dispatch, then reconcile immediately (the strictly alternating
@@ -3004,7 +3054,9 @@ class ServingEngine:
         last chunk is being fed to DECODING (all host-known) — then
         dispatch ONE ``[S, C]`` valid-length dispatch without touching
         the device results. When no prefill token was dealt the shape
-        shrinks to the plain ``[S, 1]`` decode tick. Returns the
+        shrinks to the plain ``[S, 1]`` decode tick; when one was, the
+        per-token layers run over the dealt tokens packed to one
+        compiled count where :meth:`_live_count` gives one. Returns the
         in-flight record :meth:`_reconcile` later materializes.
         RESTORING rows (host-tier uploads still in flight) are planned
         as idle — valid 0, no budget charge, RNG untouched; their
@@ -3036,9 +3088,10 @@ class ServingEngine:
             valid = np.zeros((S,), np.int32)
             sample_mask = np.zeros((S,), np.int32)
             rows: List[Optional[tuple]] = [None] * S
-            # work the model requires of this tick, against the S x C query
-            # positions the dispatch computes whatever was dealt: (query,
-            # key) pairs attended and K/V positions read, live rows only
+            # work the model requires of this tick, against the query
+            # positions the dispatch computes whatever was dealt (S x C,
+            # or the packed count): (query, key) pairs attended and K/V
+            # positions read, live rows only
             attended = key_positions = 0
             # the cursor every live row's attend starts from: what bounds
             # the K/V the dispatch copies in
@@ -3085,11 +3138,15 @@ class ServingEngine:
             # where it decodes, its chunk where it prefills, 0 otherwise
             packed = self._layout.pack(self, (fed, valid, sample_mask),
                                        advance=valid)
+        live = self._live_count(C, n_dec + fed_tokens)
         work = {"attended_tokens": attended,
                 "key_positions": key_positions,
                 "key_positions_fetched": self._kv_fetched(starts, valid, C),
                 "cache_positions": S * self.model.max_len,
-                "query_positions": S * C}
+                # the positions the per-token layers run over, and the
+                # attend's beside them: they differ on a packed tick
+                "query_positions": live or S * C,
+                "attend_query_positions": S * C}
         topk = getattr(self.model, "index_topk", None)
         if topk is not None:
             # a learned selection over the cache: positions the indexer
@@ -3102,11 +3159,12 @@ class ServingEngine:
             work["keys_selected"] = int(np.minimum(seen, topk).sum())
         return self._dispatch(tick_no, plan, cfgs, packed, rows,
                               n_dec=n_dec, fed_tokens=fed_tokens, chunk=C,
-                              work=work)
+                              live=live, work=work)
 
     def _dispatch(self, tick_no: int, plan: _Phase, cfgs, packed, rows, *,
                   n_dec: int, fed_tokens: int = 0,
                   chunk: Optional[int] = None,
+                  live: Optional[int] = None,
                   multi_k: Optional[int] = None,
                   work: Optional[dict] = None, drafts=None,
                   spec_rows=None, **spec_rec) -> _InflightTick:
@@ -3117,7 +3175,8 @@ class ServingEngine:
         ``spec_rows`` is given (``chunk`` wide, with ``drafts`` the
         host's ``[S, k]`` proposals unless a draft model makes them on
         the device), a ``multi_k``-step window, a mixed tick ``chunk``
-        wide, else the plain decode tick — call it, and return the
+        wide (packed to ``live`` tokens where the plan says so), else
+        the plain decode tick — call it, and return the
         in-flight record of its outputs. The donated cache, logits and
         RNG chains are rebound by the very statement that donates them,
         here and nowhere else (the donation-safety pass holds this
@@ -3148,7 +3207,8 @@ class ServingEngine:
                 tick = _multi_tick_fn(self._layout, cfgs, multi_k,
                                       self._ctx)
             elif chunk is not None:
-                tick = _mixed_tick_fn(self._layout, cfgs, chunk, self._ctx)
+                tick = _mixed_tick_fn(self._layout, cfgs, chunk, self._ctx,
+                                      live)
             else:
                 tick = _tick_fn(self._layout, cfgs, self._ctx)
             # ``acc``: a verify window's accepted-prefix lengths, a
@@ -3960,9 +4020,9 @@ class ServingEngine:
                     snap["multi_k"] = rec.multi_k
                 if rec.work is not None:
                     # mixed ticks: (query, key) pairs and K/V positions the
-                    # dealt tokens required, of the S x C query positions
-                    # the dispatch computed and the K/V positions its
-                    # attend copied in
+                    # dealt tokens required, of the query positions the
+                    # dispatch computed (its per-token layers' and its
+                    # attend's) and the K/V positions its attend copied in
                     snap.update(rec.work)
                 if self.pipeline:
                     snap["pipeline_depth"] = len(self._pending)
@@ -4004,6 +4064,11 @@ class ServingEngine:
         if rec.work is not None:
             self.attended_tokens_total += rec.work["attended_tokens"]
             self.query_positions_total += rec.work["query_positions"]
+            self.attend_query_positions_total += rec.work[
+                "attend_query_positions"]
+            self.packed_ticks_total += (
+                rec.work["query_positions"]
+                < rec.work["attend_query_positions"])
             self.key_positions_fetched_total += rec.work[
                 "key_positions_fetched"]
             self.cache_positions_total += rec.work["cache_positions"]
@@ -4118,10 +4183,14 @@ class ServingEngine:
             },
             "overrun_tokens": self.overrun_tokens,
             # mixed ticks: (query, key) pairs the dealt tokens required,
-            # query positions the dispatches computed ([S, C] whatever
+            # query positions the dispatches' per-token layers computed
+            # (N on a packed tick, else the attend's [S, C] whatever
             # was dealt), and the decode + fed tokens among them
             "attended_tokens_total": self.attended_tokens_total,
             "query_positions_total": self.query_positions_total,
+            "attend_query_positions_total":
+                self.attend_query_positions_total,
+            "packed_ticks_total": self.packed_ticks_total,
             # K/V positions the mixed ticks' attends copied in (every
             # row's walk to its cursor, in whole tiles), of the S x L
             # a dense attend reads every tick

@@ -182,7 +182,9 @@ class FIFOScheduler:
       tick_token_budget: useful tokens one engine tick may process —
         decoding slots reserve one each, prefilling slots split the
         remainder as prompt chunks (:meth:`plan_prefill`). Defaults to
-        256.
+        256. It is also what a mixed tick's per-token layers are sized
+        by: the engine packs a tick's live tokens to the one count
+        this bounds (``serving/engine.py . _packed_count``).
       max_prefills_per_tick: DEPRECATED (pre-chunking interleave cap).
         Still accepted: maps onto ``tick_token_budget = N *
         DEFAULT_PREFILL_CHUNK`` (one legacy whole-prompt prefill ≈ one

@@ -381,6 +381,18 @@ def report_flight(path: str, last: Optional[int] = None,
             f"max {max(got):.3f}  (needed p50 {_percentile(need, 50):.3f};"
             f" 1.000 = every row's whole cache)\n"
         )
+    packed = [r for r in ticks if r.get("attend_query_positions", 0)
+              > r.get("query_positions", 0)]
+    if packed:
+        # mixed ticks whose per-token layers ran over the dealt tokens
+        # packed to the one compiled count, of the positions the attend
+        # still spans
+        fed = [r for r in ticks if (r.get("chunk") or 1) > 1]
+        out.write(
+            f"packed ticks: {len(packed)}/{len(fed)} chunk ticks ran their "
+            f"per-token layers over {packed[-1]['query_positions']} of "
+            f"{packed[-1]['attend_query_positions']} query positions\n"
+        )
     chosen = [r for r in ticks if r.get("index_positions_scored")]
     if chosen:
         # a learned selection over the cache: positions the indexer

@@ -196,3 +196,55 @@ def test_shapes_the_paged_gate_keeps_off_the_kernel(T, G, hd, bs, itemsize):
     some of these since the tiles took the whole head axis), never
     measured, so they stay on the gathered attend."""
     assert not paged_attention.supports(T, G, hd, bs, itemsize, 2)
+
+
+def test_the_packed_tick_at_the_benchmarks_shapes(for_chip, monkeypatch,
+                                                  one_chip):
+    """chipbench's GPT serving configuration (16 slots, chunk 64, the
+    scheduler's budget of 256), lowered from abstract arguments: of the
+    mixed tick's 1 024 positions the per-token matmuls take the 256 the
+    budget can deal, the head takes each row's last token, and the
+    attend is still the ``[16, 64]`` kernel. Lowered, not compiled: the
+    text names every matmul's rows (the compile is 16 s and says no
+    more about them)."""
+    import re
+
+    from distkeras_tpu.models import get_model
+    from distkeras_tpu.models.transformer import compute_params
+    from distkeras_tpu.serving import engine
+
+    S, C, L, V = 16, 64, 2048, 50257
+    N = engine._packed_count(256, S, C)
+    assert N == 256
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = get_model("transformer_lm", vocab_size=V, d_model=2048,
+                      num_heads=16, num_layers=24, max_len=L, dtype=BF16)
+    dm = model.clone(decode=True, slot_cursor=True, parent=None)
+    shapes = jax.eval_shape(dm.init, jax.random.PRNGKey(0),
+                            jnp.zeros((S, 1), I32))
+    held = jax.eval_shape(lambda p: compute_params(model, p),
+                          {"params": shapes["params"]})
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    args = abstract((held, shapes["cache"],
+                     jax.ShapeDtypeStruct((S, V), F32),
+                     jax.ShapeDtypeStruct((S, 2), jnp.uint32),
+                     jax.ShapeDtypeStruct((S * C + 2 * S,), I32)))
+    cfgs = ((0.0, None, None),) * S
+
+    def matmul_rows(live):
+        text = engine._mixed_tick_fn(engine._CacheLayout(dm), cfgs, C, None,
+                                     live).lower(*args).as_text()
+        # one kernel, jitted once, called by each of the 24 layers
+        assert "splash_prefill" in text
+        assert text.count("call @_attend") == 24
+        return set(re.findall(
+            r"dot_general.*?-> tensor<([0-9x]+)x[a-z0-9]+>", text))
+
+    assert matmul_rows(N) == {"1x256x6144", "1x256x2048", "1x256x8192",
+                              "16x1x50257"}
+    assert matmul_rows(None) == {"16x64x6144", "16x64x2048", "16x64x8192",
+                                 "16x64x50257"}

@@ -176,6 +176,71 @@ def test_nothing_to_cast_returns_the_handed_leaves(case):
     assert _same_objects(compute_params(model, tree), tree)
 
 
+# -- (b2) one program makes the casts ------------------------------------------
+
+
+def _leaf_by_leaf(model, tree):
+    """The cast as it was made before there was one program: every leaf
+    the rule names, eagerly and alone."""
+    def cast(path, leaf):
+        names = [k.key for k in path
+                 if isinstance(k, jax.tree_util.DictKey)]
+        return (leaf.astype(model.dtype)
+                if leaf.dtype != model.dtype and model.casts_first(names)
+                else leaf)
+
+    return jax.tree_util.tree_map_with_path(cast, tree)
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_the_one_program_cast_is_the_leaf_by_leaf_cast(variant):
+    """Leaf for leaf the bits and dtypes of the eager casts, from ONE
+    compiled program (nine shapes of leaf were nine small compiles a
+    process), with the handed tree left alive: it is the caller's."""
+    from distkeras_tpu.models import transformer
+
+    model = _model(variant)
+    handed = _init(model)
+    before = {path: np.asarray(x) for path, x in _paths(handed).items()}
+    transformer._cast_program.cache_clear()
+    held = _paths(compute_params(model, handed))
+    want = _paths(_leaf_by_leaf(model, handed))
+    assert held.keys() == want.keys()
+    for path, leaf in want.items():
+        assert held[path].dtype == leaf.dtype, path
+        assert np.array_equal(np.asarray(held[path]).view(np.uint8),
+                              np.asarray(leaf).view(np.uint8)), path
+        if leaf.dtype == jnp.float32:
+            assert held[path] is _paths(handed)[path]
+    assert transformer._cast_program.cache_info().currsize == 1
+    program = transformer._cast_program(
+        jnp.dtype(model.dtype),
+        (None,) * sum(x.dtype == jnp.bfloat16 for x in held.values()))
+    assert program._cache_size() == 1
+    # not donated: every handed leaf still reads what it read
+    for path, x in _paths(handed).items():
+        assert not x.is_deleted()
+        assert np.array_equal(np.asarray(x), before[path])
+
+
+def test_update_weights_applies_the_same_program(served):
+    from distkeras_tpu.models import transformer
+
+    model, pa, pb = served
+    transformer._cast_program.cache_clear()
+    eng = _engine(model, pa)
+    assert transformer._cast_program.cache_info().currsize == 1
+    eng.update_weights(pb)
+    # the same jitted function, found again by dtype and placements
+    info = transformer._cast_program.cache_info()
+    assert (info.currsize, info.hits) == (1, 1)
+    want = _paths(_leaf_by_leaf(model, pb))
+    for path, leaf in _paths(eng._params_only).items():
+        assert np.array_equal(np.asarray(leaf).view(np.uint8),
+                              np.asarray(want[path]).view(np.uint8))
+    assert all(not x.is_deleted() for x in jax.tree.leaves(pb))
+
+
 # -- (c) the engine serves the handed tree's tokens ---------------------------
 
 

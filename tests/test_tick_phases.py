@@ -165,6 +165,8 @@ def _profile_engine(lm, trace_dir, tmp_path):
         assert s["n_dec"] == t["decode_tokens"]
         assert s["attended_tokens"] == t["attended_tokens"] > 0
         assert s["query_positions"] == t["query_positions"]
+        assert (s["attend_query_positions"]
+                == t["attend_query_positions"] >= t["query_positions"])
 
 
 def _profile_trainer(lm, trace_dir, tmp_path):
@@ -237,9 +239,34 @@ def test_work_counters_against_a_hand_count(lm, case):
     st = eng.stats()
     assert st["attended_tokens_total"] == sum(w[0] for w in want)
     assert st["query_positions_total"] == sum(w[2] for w in want)
+    # the default budget covers these ticks whole: nothing is packed
+    assert st["attend_query_positions_total"] == sum(w[2] for w in want)
+    assert st["packed_ticks_total"] == 0
     assert st["useful_query_tokens_total"] == sum(
         t["decode_tokens"] + t["prefill_tokens"] for t in ticks)
     assert st["useful_query_tokens_total"] <= st["query_positions_total"]
+
+
+def test_packed_tick_counters_against_a_hand_count(lm):
+    """``one_prompt`` again under a budget of 6: ``N`` = 8 of the ``[3,
+    4]`` tick's 12 positions, so the two ticks that feed a chunk report
+    the 8 their per-token layers ran over beside the attend's 12; the
+    ``[3, 1]`` decode ticks have nothing to leave out. The model's work
+    (pairs attended, keys read) is what it was."""
+    eng = _engine(lm, prefill_chunk=4, scheduler={"tick_token_budget": 6})
+    (p,) = _prompts([6])
+    eng.submit(p, max_new_tokens=3, seed=0)
+    eng.drain()
+    ticks = _ticks(eng)
+    got = [(t["attended_tokens"], t["key_positions"], t["query_positions"],
+            t["attend_query_positions"]) for t in ticks]
+    assert got == [(10, 4, 8, 12), (11, 6, 8, 12), (7, 7, 3, 3),
+                   (8, 8, 3, 3), (9, 9, 3, 3)]
+    st = eng.stats()
+    assert st["packed_ticks_total"] == 2
+    assert st["query_positions_total"] == 8 + 8 + 3 * 3
+    assert st["attend_query_positions_total"] == 12 + 12 + 3 * 3
+    assert st["useful_query_tokens_total"] == 6 + 3
 
 
 # -- (4) K/V positions the attend copies in, against the device's cursors ----
