@@ -53,53 +53,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu.models.blocks import (  # noqa: F401 (re-exported)
+    RoutedExperts, SwiGLU, _dot, _normal, rms_norm)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import mla
-from distkeras_tpu.ops.moe import dropless_held_experts, group_limited_route
-
-
-def _normal(fan_in_axes: int = 1):
-    """Fan-in scaled normal over the first ``fan_in_axes`` axes."""
-    def init(key, shape, dtype):
-        fan_in = int(np.prod(shape[:fan_in_axes]))
-        return (jax.random.normal(key, shape, jnp.float32)
-                / np.sqrt(fan_in)).astype(dtype)
-    return init
-
-
-def _dot(x, kernel, dtype):
-    """``x [..., in] @ kernel [in, ...]``: operands in ``dtype``, float32
-    accumulation and result."""
-    return jax.lax.dot_general(
-        x.astype(dtype), kernel.astype(dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def rms_norm(x, scale, eps: float):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                             + eps) * scale.astype(jnp.float32)
-
-
-class SwiGLU(nn.Module):
-    """``W_down(silu(W_gate u) * W_up u)``; float32 out."""
-    width: int
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, u):
-        d = u.shape[-1]
-        w_gate = self.param("w_gate", _normal(), (d, self.width),
-                            self.param_dtype)
-        w_up = self.param("w_up", _normal(), (d, self.width),
-                          self.param_dtype)
-        w_down = self.param("w_down", _normal(), (self.width, d),
-                            self.param_dtype)
-        h = jax.nn.silu(_dot(u, w_gate, self.dtype)) * _dot(
-            u, w_up, self.dtype)
-        return _dot(h, w_down, self.dtype)
 
 
 class LatentAttention(nn.Module):
@@ -226,60 +183,6 @@ class LatentAttention(nn.Module):
                 o.astype(dt), wo.astype(dt),
                 (((2, 3), (0, 1)), ((), ())),
                 preferred_element_type=jnp.float32)
-
-
-class RoutedExperts(nn.Module):
-    """The expert layer: sigmoid router over all experts with
-    group-limited top-k, this chip's share of the routed experts, and
-    the shared expert."""
-    n_routed_experts: int
-    experts_held: int
-    expert_rank: int
-    num_experts_per_tok: int
-    n_group: int
-    topk_group: int
-    routed_scaling_factor: float
-    width: int
-    n_shared_experts: int = 1
-    dtype: jnp.dtype = jnp.bfloat16
-    param_dtype: jnp.dtype = jnp.float32
-    expert_tile: int = 128
-
-    @nn.compact
-    def __call__(self, u, live):
-        B, T, d = u.shape
-        E, held = self.n_routed_experts, self.experts_held
-        pd = self.param_dtype
-        router = self.param("router", _normal(), (d, E), pd)
-        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
-                          (E,), jnp.float32)
-        w_gate = self.param("w_gate", _normal(), (held, d, self.width), pd)
-        w_up = self.param("w_up", _normal(), (held, d, self.width), pd)
-        w_down = self.param("w_down", _normal(), (held, self.width, d), pd)
-        x = u.reshape(B * T, d)
-        with jax.named_scope("moe_route"):
-            # float32 scores at full precision: a routing decision is
-            # discrete, and rounding here sends a token elsewhere
-            scores = jax.nn.sigmoid(jnp.dot(
-                x, router.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            experts, gates = group_limited_route(
-                scores, bias.astype(jnp.float32), self.n_group,
-                self.topk_group, self.num_experts_per_tok,
-                self.routed_scaling_factor)
-        with jax.named_scope("moe_experts"):
-            y, counts = dropless_held_experts(
-                x.astype(self.dtype), experts, gates, live.reshape(B * T),
-                w_gate.astype(self.dtype), w_up.astype(self.dtype),
-                w_down.astype(self.dtype), self.expert_rank * held,
-                self.expert_tile)
-        for name, value in counts.items():
-            self.sow("counters", name, value, reduce_fn=jnp.add,
-                     init_fn=lambda: jnp.zeros((), jnp.int32))
-        with jax.named_scope("moe_shared"):
-            shared = SwiGLU(self.width * self.n_shared_experts, self.dtype,
-                            pd, name="shared")(u)
-        return shared + y.reshape(B, T, d)
 
 
 class DecoderLayer(nn.Module):

@@ -27,13 +27,14 @@ flow (jit-stable static shapes).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu.models.blocks import live_packing, pack_live, unpack_live
 from distkeras_tpu.models.registry import register_model
 
 
@@ -181,38 +182,6 @@ def _quantize_int8(x):
         jnp.round(x.astype(jnp.float32) / s[..., None]), -127, 127
     ).astype(jnp.int8)
     return qx, s
-
-
-class _LivePacking(NamedTuple):
-    """Where a mixed tick's live tokens lie once packed (traced; see
-    :meth:`TransformerLM.__call__`, ``live_tokens``)."""
-
-    idx: jnp.ndarray  # [N]: the flat [S * C] position packed row n holds
-    inv: jnp.ndarray  # [S, C]: the packed row of a position; N where none
-
-
-def _live_packing(valid_lens, C: int, N: int) -> _LivePacking:
-    """Pack the positions ``t < valid_lens[s]`` of an ``[S, C]`` tick
-    to ``N`` rows, live positions first and in row-major order (a stable
-    sort), so row ``s``'s tokens are contiguous and end at
-    ``cumsum(valid_lens)[s] - 1``. Rows beyond the live count hold dead
-    positions: computed, never unpacked, never read. The caller
-    guarantees ``sum(valid_lens) <= N``."""
-    live = (jnp.arange(C)[None, :] < valid_lens[:, None]).reshape(-1)
-    idx = jnp.argsort(~live, stable=True)[:N]
-    inv = jnp.where(live, jnp.cumsum(live.astype(jnp.int32)) - 1, N)
-    return _LivePacking(idx, inv.reshape(-1, C))
-
-
-def _unpack_live(t, packing: _LivePacking):
-    """``[1, N, ...]`` packed rows -> the ``[S, C, ...]`` layout, zeros
-    where no token was dealt."""
-    return jnp.take(t[0], packing.inv, axis=0, mode="fill", fill_value=0)
-
-
-def _pack_live(t, packing: _LivePacking):
-    """``[S, C, ...]`` -> the ``[1, N, ...]`` packed rows."""
-    return t.reshape((-1,) + t.shape[2:])[packing.idx][None]
 
 
 class CausalSelfAttention(nn.Module):
@@ -658,7 +627,7 @@ class CausalSelfAttention(nn.Module):
                 dtype=self.dtype, name="qkv",
             )(x)  # [B, T, 3, H_local, hd]
             if packing is not None:
-                qkv = _unpack_live(qkv, packing)
+                qkv = unpack_live(qkv, packing)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
             # GQA: separate projections (a fused qkv would force equal
@@ -675,7 +644,7 @@ class CausalSelfAttention(nn.Module):
                 dtype=self.dtype, name="kv_proj",
             )(x)  # [B, T, 2, Hk_local, hd]
             if packing is not None:
-                q, kv = _unpack_live(q, packing), _unpack_live(kv, packing)
+                q, kv = unpack_live(q, packing), unpack_live(kv, packing)
             k, v = kv[:, :, 0], kv[:, :, 1]
         if self.rope and not self.decode:
             # global positions: ring shards offset by their shard index;
@@ -699,7 +668,7 @@ class CausalSelfAttention(nn.Module):
             else:
                 out = self._cached_attend(q, k, v, valid_lens)
             if packing is not None:
-                out = _pack_live(out, packing)
+                out = pack_live(out, packing)
             return TPDenseGeneral(
                 features=(D,), in_axes=2, mode="row",
                 tp_size=self.tp_size, tp_axis=self.tp_axis,
@@ -980,7 +949,7 @@ class TransformerLM(nn.Module):
                     "and dense MLPs (moe_experts=0)"
                 )
             chunk = tokens.shape[1]
-            packing = _live_packing(valid_lens, chunk, live_tokens)
+            packing = live_packing(valid_lens, chunk, live_tokens)
             # the row and column each packed token came from
             live_row, live_col = packing.idx // chunk, packing.idx % chunk
             tokens = tokens.reshape(-1)[packing.idx][None]  # [1, N]

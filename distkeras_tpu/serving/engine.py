@@ -392,7 +392,8 @@ def _paged_prefill_fn(dm_paged, ctx: Optional[_ShardCtx] = None):
 
 # a tick's work that only some models have (see ServingEngine.stats)
 _MODEL_WORK = ("index_positions_scored", "keys_selected", "routed_here",
-               "routed_total", "expert_rows_computed")
+               "routed_total", "expert_rows_computed", "full_key_positions",
+               "window_key_positions")
 
 
 def _counter_sums(sown, names):
@@ -1312,6 +1313,19 @@ class ServingEngine:
     ``cache_dtype="int8"`` (no quantised latent): each would otherwise
     run wrong or not at all.
 
+    ``mimo_v2_lm`` has layers of two kinds in one stack: full layers
+    with a ``[S, L, Hk * 192]`` / ``[S, L, Hk * 128]`` K and V, window
+    layers with a ring ``[S, R, Hk, 192]`` / ``[S, R, Hk, 128]`` of ``R``
+    = 256 positions, one ``[S]`` cursor a layer as everywhere. The
+    engine pools, resets and donates both as it does any leaf; it asks
+    the model two things more, ``kv_positions_by_kind`` (the counts
+    ``full_key_positions``, ``window_key_positions`` of a dispatch) and
+    ``cache_bytes_by_kind`` (``stats()["cache_bytes_full"]``,
+    ``["cache_bytes_window"]``), and the model declares
+    ``packs_live_tokens``. It refuses ``paged``, ``draft``, ``mesh``,
+    ``multi_step_k > 1``, ``prefill_chunk=None``, a chunk the ring
+    cannot hold behind the window, and ``cache_dtype != "model"``.
+
     Drive it with :meth:`step` (one admit→tick→complete→refill cycle,
     e.g. from a test) or :meth:`serve_forever` (the TCP front-end's
     loop thread). ``submit`` is thread-safe; stepping is single-threaded
@@ -1360,7 +1374,8 @@ class ServingEngine:
         if refusals is not None:
             refusals(paged=paged, draft=draft is not None,
                      mesh=mesh is not None, multi_step=multi_step_k > 1,
-                     monolithic_prefill=prefill_chunk is None)
+                     monolithic_prefill=prefill_chunk is None,
+                     prefill_chunk=prefill_chunk)
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1 (or None for monolithic "
@@ -1718,6 +1733,13 @@ class ServingEngine:
                 self.draft_model, self._draft_params_only)
         self.weight_bytes_held = sum(
             x.nbytes for x in jax.tree.leaves(self._params_only))
+        # a model whose layers differ in kind says what each kind's
+        # cache leaves hold: cache_bytes_<kind> in stats() and the
+        # flight records
+        by_kind = getattr(self.model, "cache_bytes_by_kind", None)
+        self._cache_bytes = ({} if by_kind is None else {
+            f"cache_bytes_{kind}": n
+            for kind, n in by_kind(self._cache).items()})
         self._slots: List[Optional[_SlotState]] = [None] * slots
         # graceful drain: begin_drain() closes admissions (new submits
         # raise DrainingError) while queued + in-flight requests finish
@@ -3147,6 +3169,11 @@ class ServingEngine:
                 # attend's beside them: they differ on a packed tick
                 "query_positions": live or S * C,
                 "attend_query_positions": S * C}
+        by_kind = getattr(self.model, "kv_positions_by_kind", None)
+        if by_kind is not None:
+            # layers of more than one kind: what each kind's attends
+            # copy in, summed over the layers of the kind
+            work.update(by_kind(starts, valid, C))
         topk = getattr(self.model, "index_topk", None)
         if topk is not None:
             # a learned selection over the cache: positions the indexer
@@ -4013,6 +4040,7 @@ class ServingEngine:
                     "weight_version": self.weight_version,
                     "weight_bytes_held": self.weight_bytes_held,
                     "weight_bytes_handed": self.weight_bytes_handed,
+                    **self._cache_bytes,
                 }
                 if rec.multi_k is not None:
                     # multi-step window: this one dispatch carried up to
@@ -4201,9 +4229,14 @@ class ServingEngine:
             # experts: index_positions_scored_total, keys_selected_total
             # (beside attended_tokens_total: the pairs a dense attend
             # would have been allowed), routed_here_total over
-            # routed_total_total, expert_rows_computed_total
+            # routed_total_total, expert_rows_computed_total; for a model
+            # whose layers differ in kind: full_key_positions_total,
+            # window_key_positions_total (K/V positions the attends of
+            # each kind copied in, summed over the kind's layers) and
+            # cache_bytes_full, cache_bytes_window
             **{f"{name}_total": total
                for name, total in self.model_work_totals.items()},
+            **self._cache_bytes,
             # engine-side critical-path phases (the stream tail and
             # router overhead land in the same histogram family from
             # the TCP pump / router; one merged chain's exact breakdown

@@ -419,6 +419,20 @@ def report_flight(path: str, last: Optional[int] = None,
             + (f" ({100 * here / rows:.1f}% useful)" if rows else "")
             + "\n"
         )
+    kinds = [r for r in ticks if "full_key_positions" in r]
+    if kinds:
+        # layers of two kinds: K/V positions the attends of each kind
+        # copied in (summed over the kind's layers), and what each
+        # kind's cache leaves hold
+        out.write(
+            f"full_key_positions: "
+            f"{sum(r['full_key_positions'] for r in kinds)}  "
+            f"window_key_positions: "
+            f"{sum(r['window_key_positions'] for r in kinds)}  "
+            f"cache_bytes_full: {kinds[-1]['cache_bytes_full'] / 1e9:.3f} "
+            f"GB  cache_bytes_window: "
+            f"{kinds[-1]['cache_bytes_window'] / 1e9:.3f} GB\n"
+        )
     waits = [float(r["device_wait_ms"]) for r in ticks
              if "device_wait_ms" in r]
     if waits:

@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distkeras_tpu.ops import (paged_attention, pallas_attention,
-                               pallas_pair, splash_prefill)
+from distkeras_tpu.ops import (hybrid_attend, paged_attention,
+                               pallas_attention, pallas_pair, splash_prefill)
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +51,7 @@ def for_chip(monkeypatch, one_chip):
     from jax.experimental.compilation_cache import compilation_cache
 
     for mod in (pallas_attention, pallas_pair, paged_attention,
-                splash_prefill):
+                splash_prefill, hybrid_attend):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -146,6 +146,29 @@ def test_slot_attend_at_the_benchmarks_shapes(for_chip, T, name):
     assert "tpu_custom_call" in text and name in text
     other = {"%splash_prefill", "%slot_decode_attend"} - {name}
     assert not any(o in text for o in other)
+
+
+@pytest.mark.parametrize("T,name", [
+    (64, "%full_attend"),        # a mixed tick's chunk attend
+    (1, "%full_decode_attend"),  # a decode tick's attend
+])
+def test_full_attend_at_the_published_widths(for_chip, T, name):
+    """chipbench's ``mimo-v2.5-serve`` (32 slots of 24576 positions, 64
+    query heads of 192 over 4 KV heads, values of 128, the heads of a
+    position side by side in the leaf's 768 and 512 lanes): the chip's
+    compiler takes the 192-lane slices, the 1024-row chunk tile and the
+    scoped VMEM the call asks for."""
+    assert hybrid_attend.supports(T, 16, 192, 128, 24576, 4)
+    text = for_chip(
+        hybrid_attend.full_attention,
+        ((32, T, 64, 192), BF16), ((32, 24576, 4 * 192), BF16),
+        ((32, 24576, 4 * 128), BF16), ((32,), I32), ((32,), I32))
+    assert "tpu_custom_call" in text and name in text
+    other = {"%full_attend", "%full_decode_attend"} - {name}
+    assert not any(o in text for o in other)
+    # the pool stays where it lies: no copy of a cache leaf about the call
+    assert not any(" copy(" in line and "[32,24576," in line.split(" copy(")[0]
+                   for line in text.splitlines())
 
 
 # (kernel, T, H, Hk, hd): what supports() says must be what the compiler
