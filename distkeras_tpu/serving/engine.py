@@ -32,17 +32,23 @@ row. This engine removes both taxes while keeping every shape static
   tuple — twice with chunking (the ``[S, C]`` mixed shape and the
   ``[S, 1]`` all-decode shape), so an all-decode steady state pays
   exactly the unchunked tick.
-- **Same-tick refill.** A slot whose request sampled its eos (or hit its
-  token budget) is freed when the tick's tokens are processed and
-  refilled from the scheduler queue in the same :meth:`step` call — the
-  next tick already decodes the new request.
-- **Pipelined loop** (``pipeline=True``): the step becomes a depth-2
-  software pipeline — tick N+1 is planned optimistically and dispatched
-  BEFORE tick N's tokens are read back, so host planning and token
-  streaming overlap device compute; late finishes drop their one
-  overrun token at reconciliation and streams stay bit-identical to
-  the sync loop (kept as the default reference). Every tick's host
-  control arguments ride one packed int32 transfer in both modes.
+- **A tick ahead** (the default, ``pipeline=True``): the step is a
+  depth-2 software pipeline — tick N+1 is planned from host state and
+  dispatched BEFORE tick N's tokens are read back, so the host's
+  planning, streaming and admission pass under the device's tick and
+  not between two of them. A row whose token budget runs out in the
+  unread tick is not fed again (a length finish is host-known); a row
+  that samples its eos there is: its one overrun token is dropped at
+  reconciliation and streams stay bit-identical to the alternating
+  loop. A freed slot refills on the next step's admit, one tick later
+  than the alternating loop refills it. Every tick's host control
+  arguments ride one packed int32 transfer in both loops.
+- **The alternating loop** (``pipeline=False``, the bit-parity
+  reference): plan, dispatch, read, stream, and only then the next
+  plan. A slot whose request sampled its eos (or hit its token budget)
+  is freed when the tick's tokens are processed and refilled from the
+  scheduler queue in the same :meth:`step` call — the next tick already
+  decodes the new request (same-tick refill).
 - **Paged mode** (``paged=True``): the per-slot slabs become one pool of
   fixed-size KV blocks (:mod:`distkeras_tpu.serving.kvpool`) addressed
   through per-row block tables, with radix-tree prompt-prefix sharing
@@ -884,9 +890,33 @@ def _reset_slot_cursors(cache, slot):
     row's own cursor), so stale bytes beyond the cursor are
     unreachable."""
     recompiles.note("serve.reset_cursors")
+    return _parked(cache, slot)
+
+
+def _parked(cache, slot):
     return jax.tree.map(
         lambda c: c.at[slot].set(0) if c.ndim == 1 else c, cache
     )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _seed_slot(rngs, slot, seed):
+    """Start slot ``slot``'s RNG chain at ``PRNGKey(seed)``: the key is
+    made and written in one program, so an admission costs the engine
+    thread one enqueue behind the tick in flight and not three."""
+    recompiles.note("serve.seed_slot")
+    return rngs.at[slot].set(jax.random.PRNGKey(seed))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _enter_slot(cache, rngs, slot, seed):
+    """A slot-cache admission's whole device side as one program:
+    :func:`_reset_slot_cursors` and :func:`_seed_slot` behind each
+    other. ``slot`` and ``seed`` come as host scalars and ride the
+    call, so nothing is uploaded ahead of it."""
+    recompiles.note("serve.enter_slot")
+    return (_parked(cache, slot),
+            rngs.at[slot].set(jax.random.PRNGKey(seed)))
 
 
 @functools.lru_cache(maxsize=32)
@@ -973,6 +1003,11 @@ class _SlotState:
     # run: advanced when a tick is planned (chunked engines; the mixed
     # tick's work counters read it)
     cursor: int = 0
+    # tokens the dispatched-but-unread ticks sample for this row: the
+    # loop that runs a tick ahead plans against :attr:`unplanned`, so a
+    # row whose budget runs out in an unread tick is not fed again (a
+    # length finish is host-known; only an EOS finish overruns)
+    inflight: int = 0
     admit_seq: int = 0  # admission order: prefill budget is dealt FIFO
     admit_t: float = 0.0  # monotonic admission time (prefill span)
     # speculative decoding (engine.spec): the row's emitted-but-unfed
@@ -985,6 +1020,12 @@ class _SlotState:
     history: Optional[np.ndarray] = None
     draft_queue: Optional[np.ndarray] = None
     draft_rewind: int = 0
+
+    @property
+    def unplanned(self) -> int:
+        """Tokens of the row's budget that no dispatched tick samples
+        yet: what the next plan may still deal it."""
+        return self.remaining - self.inflight
 
 
 @dataclass
@@ -1005,7 +1046,8 @@ class _InflightTick:
     this handoff)."""
 
     toks: Any                       # device [S] ([S, k+1] spec, [S, k] multi)
-    # per slot: None (idle at plan) | ("dec", st) | ("pre", st, take,
+    # per slot: None (idle at plan) | ("dec", st, n) — n tokens sampled
+    # for the row, 1 outside a multi-step window | ("pre", st, take,
     # flipped) — flipped marks the prompt's last chunk landing
     rows: List[Optional[tuple]]
     tick: int                       # the number this tick reconciles as
@@ -1245,23 +1287,35 @@ class ServingEngine:
         (default 3).
       pipeline: overlap host planning and token streaming with device
         compute (the DOWNPOUR thesis applied to the tick loop: never
-        stall either side on the other). ``True`` turns the loop into a
-        depth-2 software pipeline — tick N+1 is planned optimistically
-        (as if no row finished in tick N) and dispatched BEFORE tick
-        N's tokens are read back, so the device starts the next step
-        while the host streams the previous one. When tick N's tokens
-        land and a row HAD finished (late EOS / length), that row's
-        tick-N+1 token is an overrun: dropped before streaming, the
-        slot cancelled and refilled on tick N+2 (RNG chains die with
-        the request, so greedy AND sampled streams stay bit-identical
-        to the sync loop). Slots and blocks are only freed at
+        stall either side on the other). ``True`` (the default since
+        PR 35; every cell of the benchmark runs it) makes the loop a
+        depth-2 software pipeline — tick N+1 is planned from host
+        state and dispatched BEFORE tick N's tokens are read back, so
+        the device starts the next step while the host streams the
+        previous one. The plan knows what the unread tick leaves of a
+        row's token budget (``remaining`` less the tokens in flight):
+        a row whose budget runs out there is held, not fed, so a
+        length finish costs nothing. An eos is not host-known: when
+        tick N's tokens land and a row HAD sampled its eos, that row's
+        tick-N+1 token is an overrun: dropped before streaming
+        (``stats()["overrun_tokens"]``; ``["overrun_pct"]`` is its
+        share of every token the ticks sampled), the slot freed and
+        refilled on tick N+2 (RNG chains die with the request, so
+        greedy AND sampled streams stay bit-identical to the
+        alternating loop). Slots and blocks are only freed at
         reconciliation, so plan-ahead can never double-admit against
-        an unreconciled finish. Speculative engines run a depth-1
-        pipeline instead (the next plan needs the accepted tokens):
-        readback and bookkeeping stay synchronous, but emission and
-        telemetry are deferred past the next dispatch. ``False`` (the
-        default) keeps the strictly alternating loop as the bit-parity
-        reference, same policy as ``paged_kernel='gather'``.
+        an unreconciled finish. The plain decode tick of
+        ``prefill_chunk=None`` has no control buffer to hold a row
+        with: there a length finish overruns too. Speculative engines
+        run a depth-1 pipeline instead (the next plan needs the
+        accepted tokens): readback and bookkeeping stay synchronous,
+        but emission and telemetry are deferred past the next
+        dispatch. ``False`` keeps the strictly alternating loop as the
+        bit-parity reference the tests compare against, same policy as
+        ``paged_kernel='gather'``. In a profile of a healthy replica
+        ``engine.dispatch`` of tick N+1 returns before ``engine.wait``
+        of tick N begins, and no gap of the device lies under the
+        host's spans while slots are occupied.
       device: pin this engine's device-side state (weights, cache,
         logits, RNG chains) to one specific :class:`jax.Device` — the
         multi-replica pattern, where N single-chip engines in one
@@ -1350,7 +1404,7 @@ class ServingEngine:
                  prefill_kernel: str = "auto",
                  draft=None, draft_params=None, spec_k: int = 4,
                  ngram_max: int = 3, device=None,
-                 pipeline: bool = False, role: str = "mixed",
+                 pipeline: bool = True, role: str = "mixed",
                  multi_step_k: int = 1):
         if slots < 1:
             raise ValueError(f"slots must be >= 1; got {slots}")
@@ -2104,11 +2158,12 @@ class ServingEngine:
         return [st.req.rid if st else None for st in self._slots]
 
     def step(self) -> bool:
-        """One scheduler iteration: admit into free slots, run one tick
-        over the pool (mixed prefill/decode when chunked), emit tokens,
-        free finished slots, and refill them from the queue (same call —
-        the freed slot never idles a tick). Returns False when there is
-        nothing to do.
+        """One scheduler iteration: admit into free slots, plan and
+        dispatch the next tick over the pool (mixed prefill/decode when
+        chunked), then read the tick before it, emit its tokens and
+        free finished slots (the next call's admit refills them). With
+        ``pipeline=False``: admit, run one tick, emit, free, and refill
+        in the same call. Returns False when there is nothing to do.
 
         An exception escaping the cycle dumps the flight recorder to a
         postmortem JSONL (``report --flight`` renders it) before
@@ -2170,7 +2225,8 @@ class ServingEngine:
         """One pipelined scheduler iteration. Non-speculative engines
         run depth-2: admit, plan tick N+1 OPTIMISTICALLY (every planned
         row is assumed to continue — finishes in the still-unread tick
-        N are unknown), dispatch it, and only then reconcile tick N —
+        N are unknown, but for a row whose token budget N uses up:
+        that one is held), dispatch it, and only then reconcile tick N —
         materialize its tokens (the device is already running N+1),
         stream them, drop overruns for rows that turn out to have
         finished earlier, and free/complete slots (refilled by the next
@@ -2198,7 +2254,12 @@ class ServingEngine:
                     or bool(self._pending))
         self._admit_phase()
         occupied = any(st is not None for st in self._slots)
-        if occupied:
+        # a row whose budget the unread tick uses up has nothing left to
+        # be fed: where every occupied row is such a one there is no
+        # tick to run ahead with
+        ahead = any(st is not None and st.unplanned > 0
+                    for st in self._slots)
+        if ahead:
             k = self._multi_gate()
             if k > 1:
                 rec = self._plan_dispatch_multi(k)
@@ -2207,10 +2268,10 @@ class ServingEngine:
             else:
                 rec = self._plan_dispatch_decode()
             self._pending.append(rec)
-        # keep exactly one tick unreconciled while occupied (the
-        # pipeline depth); flush everything once the pool idles so the
+        # keep exactly one tick unreconciled while one was dispatched
+        # (the pipeline depth); flush everything once nothing was, so the
         # last streams always complete
-        keep = 1 if occupied else 0
+        keep = 1 if ahead else 0
         while len(self._pending) > keep:
             self._reconcile(self._pending.popleft())
         return (occupied or self.scheduler.depth() > 0
@@ -2710,11 +2771,15 @@ class ServingEngine:
             lens = self._seq_lens.copy()
             lens[slot] = cached
             self._seq_lens = lens
+            self._rngs = _seed_slot(self._rngs, np.int32(slot),
+                                    np.int64(req.seed))
         else:
             chain = None
-            self._cache = _reset_slot_cursors(self._cache,
-                                              jnp.int32(slot))
-        self._rngs = self._rngs.at[slot].set(jax.random.PRNGKey(req.seed))
+            # one enqueue behind the tick in flight (the cache is its
+            # output): the loop a tick ahead admits while the device runs
+            self._cache, self._rngs = _enter_slot(
+                self._cache, self._rngs, np.int32(slot),
+                np.int64(req.seed))
         st = _SlotState(
             req=req, remaining=req.max_new_tokens, blocks=chain,
             cached_tokens=cached, cursor=cached,
@@ -3094,7 +3159,10 @@ class ServingEngine:
                 if st else _IDLE_CFG
                 for st in self._slots
             )
-            n_dec = sum(1 for st in self._slots if st and st.decoding)
+            # a decoding row whose budget the unread tick uses up is held
+            # (its length finish is known before its last token is read)
+            n_dec = sum(1 for st in self._slots
+                        if st and st.decoding and st.unplanned > 0)
             pre = sorted(
                 ((s, st) for s, st in enumerate(self._slots)
                  if st and not st.decoding and st.restoring is None),
@@ -3125,17 +3193,18 @@ class ServingEngine:
                     # nothing (valid 0): the row writes no K/V, its parked
                     # cursor holds, and its attend walks no cache
                     sample_mask[s] = 1
-                elif st.decoding:
+                elif st.decoding and st.unplanned > 0:
                     valid[s] = 1
                     sample_mask[s] = 1
-                    rows[s] = ("dec", st)
+                    rows[s] = ("dec", st, 1)
                     st.cursor += 1
+                    st.inflight += 1
                     attended += st.cursor
                     key_positions += st.cursor
                 # else: PREFILLING rows are dealt below; RESTORING rows
-                # stay at valid 0 / sample 0 — the row writes nothing, its
-                # cursor holds at the cached span, and its RNG chain is
-                # untouched until its first real chunk
+                # and rows held for the read of their last token stay at
+                # valid 0 / sample 0 — the row writes nothing, its cursor
+                # holds, and its RNG chain is untouched
             for (s, st), take in zip(pre, takes):
                 flipped = False
                 if take > 0:
@@ -3322,6 +3391,7 @@ class ServingEngine:
                         )
                         self._m_prefill_ms.observe(prefill_ms)
                     continue
+                st.inflight -= row[2]
                 if counts_host is None:
                     e, _ = self._stream_row(s, st, [int(toks_host[s])], now)
                 else:
@@ -3737,10 +3807,15 @@ class ServingEngine:
                 for st in self._slots
             )
             rows: List[Optional[tuple]] = [
-                ("dec", st) if st is not None else None
+                ("dec", st, 1) if st is not None else None
                 for st in self._slots
             ]
             n_dec = sum(1 for r in rows if r is not None)
+            # this tick has no control buffer to hold a row back with:
+            # a row whose budget the unread tick uses up overruns here
+            for st in self._slots:
+                if st is not None:
+                    st.inflight += 1
             # the tick writes each live row's K/V at its cursor
             packed = self._layout.pack(self, advance=np.fromiter(
                 (st is not None for st in self._slots), np.int32,
@@ -3811,11 +3886,8 @@ class ServingEngine:
                 if st else _IDLE_CFG
                 for st in self._slots
             )
-            rows: List[Optional[tuple]] = [
-                ("dec", st) if st is not None else None
-                for st in self._slots
-            ]
-            n_dec = sum(1 for r in rows if r is not None)
+            rows: List[Optional[tuple]] = [None] * S
+            n_dec = sum(1 for st in self._slots if st is not None)
             eos = np.full((S,), -1, np.int32)
             lim = np.zeros((S,), np.int32)
             for s, st in enumerate(self._slots):
@@ -3823,10 +3895,14 @@ class ServingEngine:
                     continue
                 if st.req.eos_id is not None:
                     eos[s] = st.req.eos_id
-                lim[s] = min(k, st.remaining)
+                # what the unread window leaves of the row's budget (0:
+                # the row goes quiet on the device, as after its EOS)
+                lim[s] = min(k, st.unplanned)
+                rows[s] = ("dec", st, int(lim[s]))
                 # a row that stops short of lim completes at this
                 # window's reconcile: nothing reads its cursor again
                 st.cursor += int(lim[s])
+                st.inflight += int(lim[s])
             packed = self._layout.pack(self, (eos, lim), advance=lim)
         return self._dispatch(tick_no, plan, cfgs, packed, rows,
                               n_dec=n_dec, multi_k=k)
@@ -4210,6 +4286,10 @@ class ServingEngine:
                 "p99": self._m_device_wait.percentile(99),
             },
             "overrun_tokens": self.overrun_tokens,
+            # ... as a share of every token the ticks sampled for a
+            # request: what running a tick ahead costs the device
+            "overrun_pct": 100.0 * self.overrun_tokens / max(
+                self.tokens_generated + self.overrun_tokens, 1),
             # mixed ticks: (query, key) pairs the dealt tokens required,
             # query positions the dispatches' per-token layers computed
             # (N on a packed tick, else the attend's [S, C] whatever

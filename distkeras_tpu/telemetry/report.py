@@ -393,6 +393,16 @@ def report_flight(path: str, last: Optional[int] = None,
             f"per-token layers over {packed[-1]['query_positions']} of "
             f"{packed[-1]['attend_query_positions']} query positions\n"
         )
+    if any("pipeline_depth" in r for r in ticks):
+        # the loop that runs a tick ahead: tokens it sampled for rows
+        # that had finished in the unread tick, dropped at
+        # reconciliation, of every token the ticks sampled
+        dropped = sum(int(r.get("overrun_tokens", 0)) for r in ticks)
+        sampled = dropped + sum(int(r.get("emitted", 0)) for r in ticks)
+        out.write(
+            f"overrun_pct: {100 * dropped / max(sampled, 1):.2f} "
+            f"({dropped} of {sampled} sampled tokens dropped)\n"
+        )
     chosen = [r for r in ticks if r.get("index_positions_scored")]
     if chosen:
         # a learned selection over the cache: positions the indexer

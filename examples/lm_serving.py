@@ -117,11 +117,6 @@ def main():
     ap.add_argument("--spec-k", type=int, default=4,
                     help="draft tokens proposed per row per tick "
                          "(default 4)")
-    ap.add_argument("--pipeline", action="store_true",
-                    help="pipelined engine loop: dispatch tick N+1 "
-                         "before reading tick N's tokens (host "
-                         "planning + streaming overlap device "
-                         "compute; streams stay bit-identical)")
     ap.add_argument("--multi-step-k", type=int, default=1,
                     help="device-resident multi-step decode: run k "
                          "decode steps per dispatch in all-decode "
@@ -169,10 +164,6 @@ def main():
         engine_kw["multi_step_k"] = args.multi_step_k
         print(f"multi-step decode: up to {args.multi_step_k} tokens "
               f"per dispatch in all-decode steady state")
-    if args.pipeline:
-        engine_kw["pipeline"] = True
-        print("pipelined engine loop: depth-2 (plan/stream tick N "
-              "overlaps device compute of tick N+1)")
     if args.prefill_chunk is not None:
         engine_kw["prefill_chunk"] = (None if args.prefill_chunk == 0
                                       else args.prefill_chunk)
@@ -305,11 +296,13 @@ def main():
                 f"(mean occupancy {stats['mean_occupancy']}, "
                 f"ttft p50 {stats['ttft_ms']['p50']:.1f}ms)"
             )
-        if args.pipeline:
+        if stats.get("pipeline"):
             dw = stats.get("device_wait_ms", {}).get("p50")
             print(
                 f"pipeline: {stats.get('overrun_tokens', 0)} overrun "
-                f"tokens dropped at reconciliation, device-wait p50 "
+                f"tokens dropped at reconciliation "
+                f"({stats.get('overrun_pct', 0.0):.2f}% of the tokens "
+                f"sampled), device-wait p50 "
                 + (f"{dw:.2f}ms" if dw is not None else "n/a")
             )
         if args.multi_step_k > 1:

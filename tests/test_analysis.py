@@ -707,7 +707,8 @@ def test_donation_pass_catches_seeded_engine_violation(tmp_path):
     src = SourceFile(eng_path, "engine.py", text)
     direct, factories = _module_donators(src.tree)
     # every compiled serving body donates; the discovery must see them
-    assert set(direct) == {"_reset_slot_cursors", "_copy_block"}
+    assert set(direct) == {"_reset_slot_cursors", "_seed_slot",
+                           "_enter_slot", "_copy_block"}
     assert {"_tick_fn", "_mixed_tick_fn", "_multi_tick_fn",
             "_spec_verify_fn", "_draft_feed_fn"} <= set(factories)
     assert all(v for v in factories.values())

@@ -218,22 +218,27 @@ def test_decoding_rows_saturate_budget_prefill_starves_boundedly():
                                                  max_new_tokens=3)
 
 
-def test_eos_during_prefill_tick_refills_same_step():
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_eos_during_prefill_tick_refills_same_step(pipeline):
     """A decoding row samples its eos on a tick where its neighbour is
     mid-prefill: the freed slot refills from the queue in the same
-    step() call, the replacement's chunks share the budget with the
-    still-prefilling neighbour, and every stream stays parity-exact."""
+    step() call (the strictly alternating loop; a tick ahead, on the
+    next step's admit), the replacement's chunks share the budget with
+    the still-prefilling neighbour, and every stream stays
+    parity-exact."""
     model, params = _model_and_params()
     rng = np.random.default_rng(6)
     pa = rng.integers(0, 64, size=4).astype(np.int32)
-    pb = rng.integers(0, 64, size=12).astype(np.int32)  # 6 chunk ticks
+    # 6 chunk ticks (8 where the plan is two ticks ahead of the read)
+    pb = rng.integers(0, 64, size=16 if pipeline else 12).astype(np.int32)
     pc = rng.integers(0, 64, size=5).astype(np.int32)
     probe = _solo(model, params, pa, max_new_tokens=10)
     eos = probe[2]
     want_a = _solo(model, params, pa, max_new_tokens=10, eos_id=eos)
     # a finishes within its first 3 tokens (tick 5 at the latest)...
     assert 1 <= len(want_a) <= 3
-    eng = _engine(model, params, slots=2, prefill_chunk=2)
+    eng = _engine(model, params, slots=2, prefill_chunk=2,
+                  pipeline=pipeline)
     ra = eng.submit(pa, max_new_tokens=10, eos_id=eos)
     # ...while b's 12-token prompt needs 6 chunk ticks: a's eos lands
     # while b is still mid-prefill (a: 2 chunk ticks + <=3 decode)
@@ -243,6 +248,11 @@ def test_eos_during_prefill_tick_refills_same_step():
     while eng.step():
         if refill_tick is None and ra.done_t is not None:
             refill_tick = eng.ticks
+            if pipeline:
+                # the eos was read behind the next tick's dispatch: the
+                # slot is free now and the next step's admit refills it
+                assert rc.rid not in eng.slot_requests
+                eng.step()
             assert rc.rid in eng.slot_requests  # same-step refill
             sb = next(s for s, st in enumerate(eng._slots)
                       if st is not None and st.req.rid == rb.rid)

@@ -212,7 +212,7 @@ def test_crash_in_step_dumps_postmortem_and_renders(tmp_path, capsys):
     def boom():
         raise RuntimeError("injected device fault")
 
-    eng._mixed_tick = boom
+    eng._plan_dispatch_mixed = boom  # both loops plan a tick through it
     with pytest.raises(RuntimeError, match="injected device fault"):
         eng.step()
     dumps = glob.glob(str(tmp_path / "distkeras-postmortem-*-crash-*"))

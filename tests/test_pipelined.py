@@ -9,6 +9,8 @@ pipeline_depth, overrun_tokens). Plus the FIFOScheduler head-of-line
 short-circuit satellites and the serve_bench --pipeline --smoke drift
 guard."""
 
+import functools
+import importlib
 import os
 import sys
 import time
@@ -20,10 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from distkeras_tpu import telemetry
+from distkeras_tpu.telemetry import report as telemetry_report
 from distkeras_tpu.models import get_model
 from distkeras_tpu.models.transformer import generate
 from distkeras_tpu.serving import FIFOScheduler, ServingEngine
-from distkeras_tpu.serving.engine import _pack_i32, _unpack_i32
+from distkeras_tpu.serving.engine import (_pack_i32, _seed_slot,
+                                          _unpack_i32)
+from distkeras_tpu.telemetry.runtime import recompiles
 
 KW = dict(vocab_size=64, d_model=32, num_heads=4, num_layers=2,
           max_len=64, dtype=jnp.float32, attention="dense",
@@ -56,12 +61,22 @@ def _engine(model, params, paged, **kw):
     return ServingEngine(model, params, paged=paged, **kw)
 
 
-def _serve(model, params, paged, prompts, lens, temps, **kw):
-    eng = _engine(model, params, paged, slots=3, **kw)
-    reqs = [eng.submit(p, max_new_tokens=m, temperature=t, seed=i)
-            for i, (p, m, t) in enumerate(zip(prompts, lens, temps))]
+def _run(model, params, prompts, news, temps, eos=None, paged=False, **kw):
+    """Serve the requests (the first one ends at ``eos`` where given);
+    returns the streams, how each ended, and the engine."""
+    eng = _engine(model, params, paged, **kw)
+    reqs = [eng.submit(p, max_new_tokens=n, temperature=t, seed=i,
+                       eos_id=eos if i == 0 else None)
+            for i, (p, n, t) in enumerate(zip(prompts, news, temps))]
     eng.drain()
-    return [r.stream.tokens(timeout=60) for r in reqs], eng
+    return ([r.stream.tokens(timeout=60) for r in reqs],
+            [r.stream.finish_reason for r in reqs], eng)
+
+
+def _serve(model, params, paged, prompts, lens, temps, **kw):
+    streams, _, eng = _run(model, params, prompts, lens, temps, paged=paged,
+                           slots=3, **kw)
+    return streams, eng
 
 
 def _solo(model, params, prompts, lens, temps):
@@ -80,8 +95,9 @@ def _solo(model, params, prompts, lens, temps):
 @pytest.mark.parametrize("prefill", ["chunked", "monolithic"])
 def test_pipeline_parity_matrix(mode, prefill):
     """pipeline=True streams (greedy AND sampled RNG chains, mixed
-    per-slot configs, late length-finish overruns on every request)
-    must be token-identical to the sync loop AND to solo generate()."""
+    per-slot configs, every request finishing by length behind an
+    unread tick) must be token-identical to the sync loop AND to solo
+    generate()."""
     model, params = _model_and_params()
     prompts, lens, temps = _workload()
     kw = dict(prefill_chunk=4 if prefill == "chunked" else None)
@@ -93,9 +109,18 @@ def test_pipeline_parity_matrix(mode, prefill):
     assert pipe == sync
     st = eng.stats()
     assert st["pipeline"] is True
-    # every request length-finishes while its next tick is already in
-    # flight — each drops exactly one overrun token
-    assert st["overrun_tokens"] >= len(prompts)
+    if prefill == "chunked":
+        # a length finish is host-known: the plan holds the row whose
+        # budget the unread tick uses up, so nothing overruns
+        assert st["overrun_tokens"] == 0 == st["overrun_pct"]
+    else:
+        # the plain decode tick has no control buffer to hold a row
+        # back with: every request drops one overrun token
+        # (but the last, which runs alone: nothing to run ahead with)
+        assert st["overrun_tokens"] >= len(prompts) - 1
+        assert st["overrun_pct"] == pytest.approx(
+            100 * st["overrun_tokens"]
+            / (st["tokens_generated"] + st["overrun_tokens"]))
 
 
 @pytest.mark.parametrize("mode", ["slot", "paged"])
@@ -139,6 +164,249 @@ def test_pipeline_parity_tp4(mode):
                        temps, prefill_chunk=4, pipeline=True, mesh=mesh)
     assert pipe == sync
     assert eng.stats()["tp"] == 4
+
+
+# -- the loop under the newer programs ---------------------------------------
+
+# tests/test_deepseek_v32.py's and tests/test_mimo_v2.py's sizes: every
+# ratio of the published models kept, float32, weights from the plain
+# references' seeds
+DEEPSEEK = dict(
+    vocab_size=96, d_model=64, num_layers=3, first_k_dense=1, num_heads=4,
+    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, index_n_heads=4, index_head_dim=16,
+    index_topk=16, intermediate_size=128, moe_intermediate_size=32,
+    n_routed_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
+    experts_held=4, expert_rank=0, rope_original_len=32, max_len=64,
+    kv_tile=16, expert_tile=8)
+RING = 16
+MIMO = dict(
+    vocab_size=97, d_model=64, num_layers=4, num_heads=8, head_dim=24,
+    v_head_dim=16, num_kv_heads=2, swa_num_kv_heads=4, sliding_window=8,
+    window_ring=RING, hybrid_layer_pattern=[0, 1, 1, 0],
+    moe_layer_freq=[0, 1, 1, 1], intermediate_size=128,
+    moe_intermediate_size=32, n_routed_experts=16, num_experts_per_tok=4,
+    experts_held=8, expert_rank=0, max_len=64, expert_tile=8)
+
+# name -> (model, its size, engine arguments, routed (token, expert)
+# pairs a live token makes over the model's expert layers)
+NEWER = {
+    # PR 32's packed mixed tick: a budget of 12 packs a [5, 8] tick's
+    # live tokens to 16 rows
+    "packed": ("transformer_lm", KW, dict(
+        slots=5, prefill_chunk=8, scheduler={"tick_token_budget": 12}),
+        None),
+    "deepseek": ("deepseek_v32_lm", DEEPSEEK, dict(
+        slots=3, max_len=64, prefill_chunk=8), 4 * 2),
+    # rings beside full-length leaves, packed to 8 rows of a [3, 4] tick
+    "mimo": ("mimo_v2_lm", MIMO, dict(
+        slots=3, max_len=64, prefill_chunk=4,
+        scheduler={"tick_token_budget": 6}), 4 * 3),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _newer(name):
+    if name == "transformer_lm":
+        return _model_and_params()
+    ref = importlib.import_module(
+        "chipbench.references." + name.removesuffix("_lm"))
+    size = {"deepseek_v32_lm": DEEPSEEK, "mimo_v2_lm": MIMO}[name]
+    cfg = {"model": size, "precision": {"parameters": "float32"}}
+    return (get_model(name, **size, dtype=jnp.float32),
+            ref.make_params(cfg, 7))
+
+
+def _fresh_token(stream, start=2):
+    """``(index, token)`` of the first token from ``start`` on that the
+    stream had not emitted before: an eos that ends it right there."""
+    return next((i, t) for i, t in enumerate(stream)
+                if i >= start and t not in stream[:i])
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "sampled"])
+@pytest.mark.parametrize("case", sorted(NEWER))
+def test_pipeline_parity_newer_programs(case, sampling):
+    """The loop that runs a tick ahead under the programs that came
+    after it: the packed mixed tick, the latent model whose counters
+    ride behind a tick's tokens, the model with rings beside full-length
+    leaves. Token for token the alternating loop's streams, one request
+    ending at its eos behind an unread tick; and each tick's record
+    holds the counts of its own dispatch, read a period late."""
+    name, size, kw, pairs = NEWER[case]
+    model, params = _newer(name)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, size["vocab_size"], size=n).astype(np.int32)
+               for n in (19, 7, 26, 11, 3)]
+    news = [12, 9, 6, 14, 8]
+    temps = ([0.0] * 5 if sampling == "greedy"
+             else [0.9, 0.0, 0.7, 1.0, 0.8])
+    free, _, _ = _run(model, params, prompts, news, temps, pipeline=False,
+                      **kw)
+    at, eos = _fresh_token(free[0])
+    sync, sync_ends, es = _run(model, params, prompts, news, temps, eos,
+                               pipeline=False, **kw)
+    pipe, pipe_ends, ep = _run(model, params, prompts, news, temps, eos,
+                               pipeline=True, **kw)
+    assert sync[0] == free[0][:at + 1] and sync[1:] == free[1:]
+    assert pipe == sync
+    assert pipe_ends == sync_ends == ["eos"] + ["length"] * 4
+    ss, sp = es.stats(), ep.stats()
+    # the eos was read behind the next tick's dispatch: that tick's token
+    # for the row is the one overrun; the four length finishes are held
+    assert (ss["overrun_tokens"], sp["overrun_tokens"]) == (0, 1)
+    assert sp["useful_query_tokens_total"] == (
+        ss["useful_query_tokens_total"] + 1)
+    if case != "deepseek":
+        assert sp["packed_ticks_total"] > 0 and ss["packed_ticks_total"] > 0
+    if pairs is not None:
+        for st, eng in ((ss, es), (sp, ep)):
+            assert st["routed_total_total"] == (
+                pairs * st["useful_query_tokens_total"])
+            assert st["expert_rows_computed_total"] >= st[
+                "routed_here_total"] > 0
+            # every record holds what ITS dispatch routed, whichever
+            # period read it
+            ticks = [t for t in eng.flight.snapshots()
+                     if t["kind"] == "tick"]
+            assert ticks and all(
+                t["routed_total"] == pairs * (
+                    t["decode_tokens"] + t["prefill_tokens"])
+                for t in ticks)
+        # a token is routed as it is whichever tick holds it: the totals
+        # differ by the overrun token's pairs alone
+        assert 0 <= (sp["routed_here_total"]
+                     - ss["routed_here_total"]) <= pairs
+
+
+def _cursors(eng):
+    return [np.asarray(leaf) for leaf in jax.tree.leaves(eng._cache)
+            if leaf.ndim == 1 and leaf.dtype == jnp.int32]
+
+
+@pytest.mark.parametrize("edge", ["max_len", "ring_wrap"])
+def test_a_tick_ahead_at_the_cache_edges(edge):
+    """``mimo_v2_lm``, one slot, two tenants. ``max_len``: the first
+    fills its full-length leaves to the last position and finishes by
+    length: the plan holds the row, and no cursor passes ``max_len``.
+    ``ring_wrap``: the first ends at an eos whose overrun token is fed
+    at a multiple of the ring's size, so it lands on the ring's first
+    entry, across the wrap. Both times the next tenant of the slot
+    streams what the alternating loop streams."""
+    model, params = _newer("mimo_v2_lm")
+    kw = dict(slots=1, max_len=64, prefill_chunk=4)
+    rng = np.random.default_rng(9)
+    first = rng.integers(0, MIMO["vocab_size"], size=40).astype(np.int32)
+    second = rng.integers(0, MIMO["vocab_size"], size=5).astype(np.int32)
+    temps = [0.8, 0.0]
+    eos = None
+    if edge == "max_len":
+        prompts, news = [first, second], [24, 9]
+    else:
+        free, _, _ = _run(model, params, [first[:5]], [40], temps[:1],
+                          pipeline=False, **kw)
+        # token i is fed at position 5 + i, the overrun token behind it
+        # at 5 + i + 1: a multiple of the ring's 16
+        at, eos = next((i, t) for i, t in enumerate(free[0])
+                       if (5 + i + 1) % RING == 0
+                       and t not in free[0][:i])
+        prompts, news = [first[:5], second], [40, 9]
+    sync, ends, _ = _run(model, params, prompts, news, temps, eos,
+                         pipeline=False, **kw)
+    eng = _engine(model, params, False, pipeline=True, **kw)
+    reqs = [eng.submit(p, max_new_tokens=n, temperature=t, seed=i,
+                       eos_id=eos if i == 0 else None)
+            for i, (p, n, t) in enumerate(zip(prompts, news, temps))]
+    tenant, high = None, 0
+    while eng.step():
+        tenant = tenant or eng._slots[0]
+        high = max([high] + [int(c.max()) for c in _cursors(eng)])
+    assert [r.stream.tokens(timeout=60) for r in reqs] == sync
+    assert [r.stream.finish_reason for r in reqs] == ends
+    assert tenant.req is reqs[0]
+    if edge == "max_len":
+        assert ends == ["length", "length"] and len(sync[0]) == 24
+        assert tenant.cursor == 64 == high
+        assert eng.stats()["overrun_tokens"] == 0
+    else:
+        assert ends == ["eos", "length"] and len(sync[0]) == at + 1
+        # the last token the row was fed is the overrun one
+        assert tenant.cursor % RING == 1 and tenant.cursor == 5 + at + 2
+        assert eng.stats()["overrun_tokens"] == 1
+
+
+def test_the_default_engine_runs_a_tick_ahead(tmp_path, capsys):
+    """Built with no ``pipeline=``: the loop runs a tick ahead, compiles
+    nothing after its first mixed and decode ticks, and reports the
+    overrun share by its definition, in ``stats()`` and in ``report
+    --flight``."""
+    model, params = _model_and_params()
+    prompts, _, _ = _workload(n=3)
+    free = _solo(model, params, prompts[:1], [12], [0.0])[0]
+    at, eos = _fresh_token(free)
+    eng = ServingEngine(model, params, slots=2, prefill_chunk=4,
+                        registry=telemetry.MetricRegistry(),
+                        tracer=telemetry.Tracer())
+    warm = eng.submit(prompts[2], max_new_tokens=3)
+    eng.drain()  # a mixed tick and a decode tick have run
+    assert len(warm.stream.tokens(timeout=60)) == 3
+    eng.mark_steady()
+    reqs = [eng.submit(prompts[0], max_new_tokens=12, eos_id=eos),
+            eng.submit(prompts[1], max_new_tokens=7)]
+    eng.drain()
+    assert reqs[0].stream.tokens(timeout=60) == free[:at + 1]
+    st = eng.stats()
+    assert st["pipeline"] is True
+    assert eng.recompiles_since_mark() == {} == st["recompiles_since_mark"]
+    kinds = {(t["chunk"], t["prefill_tokens"] > 0)
+             for t in eng.flight.snapshots() if t["kind"] == "tick"}
+    assert kinds == {(4, True), (1, False)}
+    assert max(t["pipeline_depth"] for t in eng.flight.snapshots()
+               if t["kind"] == "tick") == 1
+    assert st["overrun_tokens"] == 1
+    assert st["tokens_generated"] == 3 + (at + 1) + 7
+    assert st["overrun_pct"] == pytest.approx(
+        100 * 1 / (st["tokens_generated"] + 1))
+    path = tmp_path / "flight.jsonl"
+    eng.flight.dump(str(path), reason="manual")
+    telemetry_report.main(["--flight", str(path)])
+    sampled = st["tokens_generated"] + 1
+    assert (f"overrun_pct: {100 / sampled:.2f} (1 of {sampled} sampled "
+            f"tokens dropped)") in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 2 ** 40 + 3, -1])
+@pytest.mark.parametrize("mode", ["slot", "paged"])
+def test_an_admission_is_one_device_call(mode, seed):
+    """The loop a tick ahead admits while the device runs: what an
+    admission does on the device (park the slot's cursors, seed its RNG
+    chain) is one enqueue behind the tick in flight, with slot and seed
+    riding the call as host scalars; the key it writes is
+    ``PRNGKey(seed)`` bit for bit, whatever the seed's size."""
+    model, params = _model_and_params()
+    eng = _engine(model, params, mode == "paged", slots=3, prefill_chunk=4)
+    warm = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+    eng.drain()
+    assert len(warm.stream.tokens(timeout=60)) == 2
+    # a tenant has left cursors behind in slot 0
+    before = recompiles.counts()
+    eng.submit(np.arange(1, 8, dtype=np.int32), max_new_tokens=3,
+               temperature=0.9, seed=seed)
+    eng._admit()
+    # nothing new is traced: the one program the warm-up compiled
+    assert recompiles.since(before) == {}
+    want = {"slot": "serve.enter_slot", "paged": "serve.seed_slot"}[mode]
+    assert recompiles.counts()[want] >= 1
+    rngs = np.asarray(eng._rngs)
+    assert (rngs[0] == np.asarray(jax.random.PRNGKey(seed))).all()
+    if mode == "slot":
+        assert all(int(c[0]) == 0 for c in _cursors(eng))
+    # the same programs, called alone: other rows are left as they were
+    base = jnp.arange(8, dtype=jnp.uint32).reshape(4, 2)
+    out = np.asarray(_seed_slot(base + 0, np.int32(2), np.int64(seed)))
+    assert (out[2] == np.asarray(jax.random.PRNGKey(seed))).all()
+    assert (np.delete(out, 2, 0) == np.delete(np.asarray(base), 2, 0)).all()
+    eng.drain()
 
 
 # -- late-EOS on the pipeline boundary ---------------------------------------
@@ -277,7 +545,8 @@ def test_flight_records_overlap_fields():
                and "pipeline_depth" in s and "overrun_tokens" in s
                for s in snaps)
     assert max(s["pipeline_depth"] for s in snaps) >= 1
-    assert sum(s["overrun_tokens"] for s in snaps) >= 1
+    # every finish here is by length, which the plan knows: none overruns
+    assert sum(s["overrun_tokens"] for s in snaps) == 0
     p_sync = es.flight.percentile("device_wait_ms", 50)
     p_pipe = ep.flight.percentile("device_wait_ms", 50)
     assert p_sync is not None and p_pipe is not None

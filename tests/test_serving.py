@@ -101,13 +101,19 @@ def test_parity_with_eos_gqa_int8_rope():
     assert reqs[0].stream.finish_reason == "eos"
 
 
-def test_eos_frees_slot_same_tick():
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_eos_frees_slot_same_tick(pipeline):
     """When a request samples its eos, its slot is refilled from the
     queue in the same step() call — the replacement's prompt chunk rides
     the very next tick, so the tick count for two back-to-back requests
     is the sum of their stream lengths plus exactly one prefill-chunk
     tick each (both prompts fit one default chunk), with no idle tick
-    between."""
+    between. That is the strictly alternating loop (``pipeline=False``).
+    The loop that runs a tick ahead reads the eos behind the next
+    tick's dispatch: that tick's token for the row is dropped, the slot
+    refills on the step after, and the eos costs one tick more. The
+    replacement finishes by length, which the plan knows: no tick is
+    spent on it."""
     model, params = _model_and_params()
     rng = np.random.default_rng(2)
     p1, p2 = (rng.integers(0, 64, size=6).astype(np.int32)
@@ -118,22 +124,25 @@ def test_eos_frees_slot_same_tick():
     want2 = _solo(model, params, p2, max_new_tokens=5)
     assert len(want1) == 4
 
-    eng = ServingEngine(model, params, slots=1)
+    eng = ServingEngine(model, params, slots=1, pipeline=pipeline)
     r1 = eng.submit(p1, max_new_tokens=10, eos_id=eos)
     r2 = eng.submit(p2, max_new_tokens=5)
     saw_refill_tick = None
     while eng.step():
         if saw_refill_tick is None and r1.done_t is not None:
             # the step that completed r1 must already have admitted r2
+            # (a tick ahead: the slot is free, the next step admits)
             saw_refill_tick = eng.ticks
-            assert eng.slot_requests == [r2.rid]
+            assert eng.slot_requests == ([None] if pipeline else [r2.rid])
     # r1: 1 chunk tick + 4 decode ticks, eos on the 5th
     assert saw_refill_tick == 1 + len(want1)
     assert r1.stream.tokens(timeout=10) == want1
     assert r2.stream.tokens(timeout=10) == want2
     # no idle ticks: every tick either fed a prompt chunk or emitted a
-    # token for exactly one request
-    assert eng.ticks == (1 + len(want1)) + (1 + len(want2))
+    # token for exactly one request (and, a tick ahead, the one whose
+    # token for the finished row was dropped)
+    assert eng.ticks == (1 + len(want1)) + (1 + len(want2)) + pipeline
+    assert eng.stats()["overrun_tokens"] == int(pipeline)
 
 
 def test_queue_backpressure_and_deadline():

@@ -32,13 +32,17 @@ PHASES = ("ctrl", "admit", "plan", "upload", "dispatch", "wait", "stream",
 # every tick path of the engine, by the constructor arguments that
 # select it
 PATHS = {
-    "sync_mixed": dict(prefill_chunk=4),
+    "pipelined": dict(prefill_chunk=4),  # the constructor's default loop
     "decode_only": dict(prefill_chunk=None),
-    "pipelined": dict(prefill_chunk=4, pipeline=True),
     "multi_step": dict(prefill_chunk=4, multi_step_k=4),
     "spec": dict(prefill_chunk=4, draft="ngram", spec_k=3),
     "paged": dict(prefill_chunk=4, paged=True, block_size=8),
 }
+# ... and each under the strictly alternating loop (a speculative engine
+# reads before it plans in both)
+PATHS.update({"sync_" + name: dict(kw, pipeline=False)
+              for name, kw in list(PATHS.items()) if name != "spec"})
+PATHS["sync_mixed"] = PATHS.pop("sync_pipelined")
 
 
 @pytest.fixture(scope="module")
@@ -76,7 +80,7 @@ def test_phase_fields_sum_to_the_period(lm, path):
     eng.drain()
     ticks = _ticks(eng)
     assert len(ticks) >= 12
-    if path == "multi_step":
+    if path.endswith("multi_step"):
         assert any("multi_k" in t for t in ticks)
     if path == "spec":
         assert any("draft_tokens" in t for t in ticks)
@@ -92,7 +96,7 @@ def test_phase_fields_sum_to_the_period(lm, path):
     gaps = []
     for t, nxt in zip(ticks[1:], ticks[2:] + [None]):
         own = t
-        if path == "pipelined":
+        if eng.pipeline and not eng.spec:
             # tick N+1 is planned, uploaded and dispatched inside tick
             # N's period, before N is read back
             if nxt is None or nxt["tick"] != t["tick"] + 1:

@@ -445,9 +445,13 @@ def test_tier_telemetry_and_flight(model_and_params, tmp_path, capsys):
     model, params = model_and_params
     _, trace = _churn_trace()
     # restore_budget=1: a multi-block restore spans ticks, so the
-    # RESTORING slot state is actually observable in snapshots
+    # RESTORING slot state is actually observable in snapshots — of the
+    # strictly alternating loop: a tick ahead, a tick's snapshot is
+    # taken behind the next tick's plan, which has issued the next
+    # restore already
     eng = _engine(model, params, host_blocks=32, num_blocks=12,
-                  scheduler=FIFOScheduler(restore_budget=1))
+                  scheduler=FIFOScheduler(restore_budget=1),
+                  pipeline=False)
     _serve(eng, trace)
     s = eng.stats()
     assert s["block_demotions"] > 0 and s["block_restores"] > 0
