@@ -80,6 +80,9 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
     expert_tile: int = 128
+    # one loop over the held experts in place of one loop an expert
+    # (``ops/moe.py . dropless_held_experts``): less program to compile
+    rolled: bool = False
 
     @nn.compact
     def __call__(self, u, live):
@@ -108,7 +111,7 @@ class RoutedExperts(nn.Module):
                 x.astype(self.dtype), experts, gates, live.reshape(B * T),
                 w_gate.astype(self.dtype), w_up.astype(self.dtype),
                 w_down.astype(self.dtype), self.expert_rank * held,
-                self.expert_tile)
+                self.expert_tile, self.rolled)
         for name, value in counts.items():
             self.sow("counters", name, value, reduce_fn=jnp.add,
                      init_fn=lambda: jnp.zeros((), jnp.int32))

@@ -133,7 +133,8 @@ def group_limited_route(scores, bias, n_group: int, topk_group: int,
 
 
 def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
-                          first: int, tile: int = 128):
+                          first: int, tile: int = 128,
+                          rolled: bool = False):
     """The part of a routed layer's result that the experts held here
     give: no capacity, no token dropped, no one-hot dispatch.
 
@@ -150,7 +151,11 @@ def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
     ``routed_here`` (pairs of live tokens sent to held experts),
     ``routed_total`` (all pairs of live tokens) and
     ``expert_rows_computed`` (rows the tiles ran over, padding
-    included), int32 scalars."""
+    included), int32 scalars. ``rolled`` walks all tiles in one loop
+    that takes each tile's expert's weights by a dynamic slice, where
+    the default unrolls one loop an expert: the same tiles in the same
+    order, and ``E_l`` times less program to compile (20 experts in
+    each of 8 layers were half of a tick program's compile)."""
     N, D = x.shape
     k = experts.shape[1]
     E_l = w_gate.shape[0]
@@ -167,8 +172,11 @@ def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
     starts = jnp.cumsum(sizes) - sizes
     tiles = (sizes + tile - 1) // tile
     y = jnp.zeros((N, D), jnp.float32)
-    for e in range(E_l):
-        def rows(i, y, e=e):
+
+    def rows_of(e):
+        """Tile ``i`` of expert ``e`` (a Python int, or traced where
+        the loop is rolled), added into ``y``."""
+        def rows(i, y):
             at = starts[e] + i * tile
             mine = i * tile + jnp.arange(tile) < sizes[e]
             ids = jnp.where(mine, jax.lax.dynamic_slice(tok, (at,), (tile,)),
@@ -183,7 +191,23 @@ def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
                           preferred_element_type=jnp.float32)
             return y.at[ids].add(out * g[:, None], mode="drop")
 
-        y = jax.lax.fori_loop(0, tiles[e], rows, y)
+        return rows
+
+    if rolled:
+        # one loop over all tiles, expert by expert: tile j belongs to
+        # the expert whose run of tiles it falls in (a loop an expert
+        # inside a loop over experts would hand each expert's weights to
+        # the inner loop as a copy)
+        ends = jnp.cumsum(tiles)
+
+        def flat(j, y):
+            e = jnp.searchsorted(ends, j, side="right").astype(jnp.int32)
+            return rows_of(e)(j - (ends[e] - tiles[e]), y)
+
+        y = jax.lax.fori_loop(0, ends[-1], flat, y)
+    else:
+        for e in range(E_l):
+            y = jax.lax.fori_loop(0, tiles[e], rows_of(e), y)
     counts = {
         "routed_here": held.sum(dtype=jnp.int32),
         "routed_total": live.sum(dtype=jnp.int32) * k,

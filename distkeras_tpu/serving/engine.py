@@ -399,7 +399,8 @@ def _paged_prefill_fn(dm_paged, ctx: Optional[_ShardCtx] = None):
 # a tick's work that only some models have (see ServingEngine.stats)
 _MODEL_WORK = ("index_positions_scored", "keys_selected", "routed_here",
                "routed_total", "expert_rows_computed", "full_key_positions",
-               "window_key_positions")
+               "window_key_positions", "state_rows_stepped",
+               "chunk_positions_live", "chunk_positions_computed")
 
 
 def _counter_sums(sown, names):
@@ -1379,6 +1380,23 @@ class ServingEngine:
     ``packs_live_tokens``. It refuses ``paged``, ``draft``, ``mesh``,
     ``multi_step_k > 1``, ``prefill_chunk=None``, a chunk the ring
     cannot hold behind the window, and ``cache_dtype != "model"``.
+
+    ``solar_open2_lm`` has a cache leaf that is not indexed by position:
+    beside its GQA layers' ``[S, L, Hk * 128]`` K and V, each KDA layer
+    keeps a recurrent state ``[S, H, 128, 128]`` float32 and the last
+    three inputs of its short convolutions ``[S, 3, 3 * H * 128]``. The
+    engine pools, donates and parks them as any leaf (parking zeroes the
+    ``[S]`` cursors alone: the layer reads state and tail as zero where
+    the row's cursor is 0, and leaves both untouched for a row dealt
+    nothing). It asks the model nothing new: ``kv_positions_by_kind``
+    counts ``state_rows_stepped``, ``chunk_positions_live`` and
+    ``chunk_positions_computed`` beside ``full_key_positions``,
+    ``cache_bytes_by_kind`` gives ``cache_bytes_state``. A token a tick
+    ran ahead past an eos enters the state and cannot be rewound; it is
+    harmless because that request is finished and its slot re-entered
+    at cursor 0. It refuses ``paged``, ``draft``, ``mesh``,
+    ``multi_step_k > 1``, ``prefill_chunk=None`` and ``cache_dtype !=
+    "model"``.
 
     Drive it with :meth:`step` (one admit→tick→complete→refill cycle,
     e.g. from a test) or :meth:`serve_forever` (the TCP front-end's
@@ -4313,7 +4331,10 @@ class ServingEngine:
             # whose layers differ in kind: full_key_positions_total,
             # window_key_positions_total (K/V positions the attends of
             # each kind copied in, summed over the kind's layers) and
-            # cache_bytes_full, cache_bytes_window
+            # cache_bytes_full, cache_bytes_window; for a model with
+            # recurrent-state layers: state_rows_stepped_total,
+            # chunk_positions_live_total over
+            # chunk_positions_computed_total, cache_bytes_state
             **{f"{name}_total": total
                for name, total in self.model_work_totals.items()},
             **self._cache_bytes,

@@ -429,7 +429,7 @@ def report_flight(path: str, last: Optional[int] = None,
             + (f" ({100 * here / rows:.1f}% useful)" if rows else "")
             + "\n"
         )
-    kinds = [r for r in ticks if "full_key_positions" in r]
+    kinds = [r for r in ticks if "window_key_positions" in r]
     if kinds:
         # layers of two kinds: K/V positions the attends of each kind
         # copied in (summed over the kind's layers), and what each
@@ -442,6 +442,26 @@ def report_flight(path: str, last: Optional[int] = None,
             f"cache_bytes_full: {kinds[-1]['cache_bytes_full'] / 1e9:.3f} "
             f"GB  cache_bytes_window: "
             f"{kinds[-1]['cache_bytes_window'] / 1e9:.3f} GB\n"
+        )
+    states = [r for r in ticks if "state_rows_stepped" in r]
+    if states:
+        # recurrent-state layers beside full attention layers: rows
+        # whose state took the one-token step, the chunk form's live
+        # tokens over the positions it ran (both summed over the state
+        # layers), the full layers' K/V walk, and each kind's leaves
+        live = sum(r["chunk_positions_live"] for r in states)
+        ran = sum(r["chunk_positions_computed"] for r in states)
+        out.write(
+            f"state_rows_stepped: "
+            f"{sum(r['state_rows_stepped'] for r in states)}  "
+            f"chunk_positions_live/computed: {live}/{ran}"
+            + (f" ({100 * live / ran:.1f}% useful)" if ran else "")
+            + f"  full_key_positions: "
+            f"{sum(r['full_key_positions'] for r in states)}  "
+            f"cache_bytes_state: "
+            f"{states[-1]['cache_bytes_state'] / 1e9:.3f} GB  "
+            f"cache_bytes_full: "
+            f"{states[-1]['cache_bytes_full'] / 1e9:.3f} GB\n"
         )
     waits = [float(r["device_wait_ms"]) for r in ticks
              if "device_wait_ms" in r]

@@ -171,6 +171,31 @@ def test_full_attend_at_the_published_widths(for_chip, T, name):
                    for line in text.splitlines())
 
 
+@pytest.mark.parametrize("T,name", [
+    (64, "%full_attend"),        # a mixed tick's chunk attend
+    (1, "%full_decode_attend"),  # a decode tick's attend
+])
+def test_full_attend_at_the_gqa_layers_widths_of_solar_open2(for_chip, T,
+                                                             name):
+    """chipbench's ``solar-open2-250b-serve`` (64 slots of 6144
+    positions, 64 query heads of 128 over 8 KV heads, keys and values
+    alike, the heads of a position side by side in the leaf's 1024
+    lanes, a KV tile of 256): the chip's compiler takes the 128-lane
+    slices of eight heads and the 512-row chunk tile a KV head."""
+    assert hybrid_attend.supports(T, 8, 128, 128, 6144, 8)
+    assert splash_prefill.choose_kv_block(6144) == 256
+    text = for_chip(
+        hybrid_attend.full_attention,
+        ((64, T, 64, 128), BF16), ((64, 6144, 8 * 128), BF16),
+        ((64, 6144, 8 * 128), BF16), ((64,), I32), ((64,), I32))
+    assert "tpu_custom_call" in text and name in text
+    other = {"%full_attend", "%full_decode_attend"} - {name}
+    assert not any(o in text for o in other)
+    # the pool stays where it lies: no copy of a cache leaf about the call
+    assert not any(" copy(" in line and "[64,6144," in line.split(" copy(")[0]
+                   for line in text.splitlines())
+
+
 # (kernel, T, H, Hk, hd): what supports() says must be what the compiler
 # says. The refusals are VMEM: the tiles hold all Hk heads of a chunk.
 GATE_CASES = [

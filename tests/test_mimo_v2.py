@@ -467,7 +467,16 @@ PARENT = {
         "mixed_8_8": "978dca6c49254a5ea430d9024c0600098ebab1960cf4422ded6aa7"
                      "f9ebcd1ae7",
         "mixed_1_None": "20f6a0e27c447583c1b040ff3f49f643361647ef01a3327b78d"
-                        "2f1768ed2a3a8"}}
+                        "2f1768ed2a3a8"},
+    # this model's own, at commit 8c19c14, before solar_open2_lm came to
+    # share RoutedExperts, the live packing and the full attend with it
+    "mimo_v2_lm": {
+        "params": "c4302266a3b30edc927227c931ecc0593b0d89d337e16db7ac593ca61"
+                  "e9c75e7",
+        "mixed_8_8": "b4053b9a6638fbc7ca888ee7c7b878beb4c1283c0ed8dbc8b429d6"
+                     "79d09ca983",
+        "mixed_1_None": "84176e4e11c0640d9f5e3815d7e64f20189bbba522de03e3ffa"
+                        "82248a5c6a67a"}}
 TINY = {
     "deepseek_v32_lm": dict(
         vocab_size=64, d_model=32, num_layers=3, first_k_dense=1,
@@ -480,7 +489,8 @@ TINY = {
         expert_tile=8, dtype=jnp.float32),
     "transformer_lm": dict(
         vocab_size=64, d_model=32, num_heads=4, num_layers=2, max_len=64,
-        dtype=jnp.float32, attention="dense")}
+        dtype=jnp.float32, attention="dense"),
+    "mimo_v2_lm": dict(SMALL, dtype=jnp.float32)}
 
 
 def _sha(text: str) -> str:
