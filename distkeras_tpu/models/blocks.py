@@ -1,10 +1,11 @@
 """Blocks more than one language model is built from: RMSNorm, the
 SwiGLU feed-forward, the expert layer of which a chip holds a share
-(``deepseek_v32_lm``, ``mimo_v2_lm``), and the packing of a mixed
-tick's live tokens (``transformer_lm``, ``mimo_v2_lm``). One home, so
-that an optimisation of one is an optimisation of every model that
-runs it, and the benchmark's cells of the others catch what it costs
-them."""
+(``mimo_v2_lm``, ``solar_open2_lm``; ``deepseek_v32_lm`` takes it a
+part at a time), and the packing of a mixed tick's live tokens
+(``transformer_lm``, ``mimo_v2_lm``, ``solar_open2_lm`` to one compiled
+count; ``deepseek_v32_lm`` to the blocks in use). One home, so that an
+optimisation of one is an optimisation of every model that runs it, and
+the benchmark's cells of the others catch what it costs them."""
 
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from distkeras_tpu.ops.moe import dropless_held_experts, group_limited_route
 
@@ -123,6 +125,70 @@ class RoutedExperts(nn.Module):
         return shared + y.reshape(B, T, d)
 
 
+class RoutedExpertsByPart(RoutedExperts):
+    """:class:`RoutedExperts`' parameters and arithmetic with the
+    parts callable one by one, for a model whose mixed tick runs the
+    router and the shared expert over the blocks of packed rows in use
+    (:func:`map_live_blocks`) and the held experts once over the packed
+    array: their grouped matmul already sizes its work from the rows
+    routed, and a call a block would read every expert's weights again
+    for a quarter of the rows. Called whole it is ``RoutedExperts``."""
+    d_model: int = 0
+
+    def setup(self):
+        d, E, held = self.d_model, self.n_routed_experts, self.experts_held
+        pd = self.param_dtype
+        self.router = self.param("router", _normal(), (d, E), pd)
+        self.bias = self.param("e_score_correction_bias",
+                               nn.initializers.zeros, (E,), jnp.float32)
+        self.w_gate = self.param("w_gate", _normal(), (held, d, self.width),
+                                 pd)
+        self.w_up = self.param("w_up", _normal(), (held, d, self.width), pd)
+        self.w_down = self.param("w_down", _normal(), (held, self.width, d),
+                                 pd)
+        self.shared = (SwiGLU(self.width * self.n_shared_experts, self.dtype,
+                              pd) if self.n_shared_experts else None)
+
+    def route(self, x):
+        """``x [n, d]`` float32 -> ``(experts, gates) [n, k]`` over all
+        experts."""
+        with jax.named_scope("moe_route"):
+            # float32 scores at full precision: a routing decision is
+            # discrete, and rounding here sends a token elsewhere
+            scores = jax.nn.sigmoid(jnp.dot(
+                x, self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            return group_limited_route(
+                scores, self.bias.astype(jnp.float32), self.n_group,
+                self.topk_group, self.num_experts_per_tok,
+                self.routed_scaling_factor)
+
+    def held(self, x, experts, gates, live):
+        """What the experts held here give the ``live [n]`` of the
+        tokens ``x [n, d]``: ``[n, d]`` float32, the layer's counters
+        sown."""
+        with jax.named_scope("moe_experts"):
+            y, counts = dropless_held_experts(
+                x.astype(self.dtype), experts, gates, live,
+                self.w_gate.astype(self.dtype), self.w_up.astype(self.dtype),
+                self.w_down.astype(self.dtype),
+                self.expert_rank * self.experts_held, self.expert_tile,
+                self.rolled)
+        for name, value in counts.items():
+            self.sow("counters", name, value, reduce_fn=jnp.add,
+                     init_fn=lambda: jnp.zeros((), jnp.int32))
+        return y
+
+    def __call__(self, u, live):
+        B, T, d = u.shape
+        x = u.reshape(B * T, d)
+        y = self.held(x, *self.route(x), live.reshape(B * T))
+        if self.shared is None:
+            return y.reshape(B, T, d)
+        with jax.named_scope("moe_shared"):
+            return self.shared(u) + y.reshape(B, T, d)
+
+
 class LivePacking(NamedTuple):
     """Where a mixed tick's live tokens lie once packed (traced; see
     ``TransformerLM.__call__``, ``live_tokens``)."""
@@ -153,3 +219,61 @@ def unpack_live(t, packing: LivePacking):
 def pack_live(t, packing: LivePacking):
     """``[S, C, ...]`` -> the ``[1, N, ...]`` packed rows."""
     return t.reshape((-1,) + t.shape[2:])[packing.idx][None]
+
+
+# Rows a block of packed live tokens holds where a model runs its
+# per-token layers over the blocks in use: past the chip's ridge (~240
+# rows a weight byte on a v5e), so a block's matmuls are bound by their
+# arithmetic and reading the weights again for the next block costs no
+# more than that block's own multiplications
+LIVE_BLOCK = 512
+
+
+def live_block_rows(positions: int) -> int:
+    """Rows a block of a tick of ``positions`` = ``S * C`` holds:
+    :data:`LIVE_BLOCK` where that divides the tick, else the whole tick
+    is one block (the tiny engines of the tests)."""
+    return LIVE_BLOCK if positions % LIVE_BLOCK == 0 else positions
+
+
+def map_live_blocks(mdl, fn, xs, like, n_blocks, tail: int = 0,
+                    over=None):
+    """``fn(mdl, *blocks)`` over the first ``n_blocks`` blocks of
+    :func:`live_block_rows` packed rows of the ``[1, N, ...]`` arrays
+    ``xs``, in ONE loop whose trip count is the traced ``n_blocks``
+    (``ceil(live / block)``, found on the device): one compiled body,
+    run as often as blocks hold a token. ``fn`` returns a tuple of ``[1,
+    block, ...]`` arrays, ``like`` gives each one's trailing shape and
+    dtype; the results come back ``[1, N + tail, ...]``, zeros in the
+    blocks that were not run and in the ``tail`` rows beyond them (room
+    for a consumer that slices a fixed number of rows from any token
+    on). ``over`` is a tuple of arrays of the results' shapes to write
+    over instead of fresh zeros (the same stage's results in the layer
+    before: the blocks run there cover the blocks run here, and
+    nothing reads a row of a block that was not run). ``mdl`` is the
+    module whose parameters ``fn`` reads (lifted into the loop:
+    read-only there, so ``fn`` declares no variable and sows nothing)."""
+    N = xs[0].shape[1]
+    block = live_block_rows(N)
+
+    def body(m, carry):
+        b, outs = carry
+        at = b * block
+        # behind a barrier: a layout the body's matmuls want is given
+        # to the block, not to all N rows in a copy of their own
+        ys = fn(m, *jax.lax.optimization_barrier(tuple(
+            jax.lax.dynamic_slice_in_dim(x, at, block, 1) for x in xs)))
+        # rows major, said aloud: left to itself the compiler writes a
+        # wide result rows-minor (the projection's own layout), gives
+        # that layout to all N rows, and copies them for the reader
+        ys = tuple(with_layout_constraint(
+            y.astype(o.dtype), Layout(major_to_minor=tuple(range(y.ndim))))
+            for o, y in zip(outs, ys))
+        return b + 1, tuple(
+            jax.lax.dynamic_update_slice_in_dim(o, y, at, 1)
+            for o, y in zip(outs, ys))
+
+    init = over or tuple(jnp.zeros((1, N + tail) + tuple(shape), dtype)
+                         for shape, dtype in like)
+    return nn.while_loop(lambda _, carry: carry[0] < n_blocks, body, mdl,
+                         (jnp.zeros((), jnp.int32), init))[1]

@@ -39,9 +39,22 @@ rope is applied at each row's own cursor. The residual stream and the
 norms are float32; matmul operands are ``dtype`` with float32
 accumulation; router scores and logits are float32.
 
-The serving engine reads two things off the class besides the module
+- **The packed mixed tick** (``live_tokens = S * C``): a ``[S, C]``
+  tick's live tokens are packed to the front
+  (:func:`~distkeras_tpu.models.blocks.live_packing`) and every
+  per-token layer runs over the blocks of 512 packed rows that hold one
+  (:func:`~distkeras_tpu.models.blocks.map_live_blocks`: a loop whose
+  trip count the device derives from ``valid_lens``). The modules'
+  parameters are declared in ``setup`` so that the per-token halves are
+  callable a block at a time; the walk reads its queries where they lie
+  packed and leaves its results there. Called without ``live_tokens``
+  (the ``[S, 1]`` tick, a full forward) the model is the program it was.
+
+The serving engine reads four things off the class besides the module
 fields: ``tick_counters`` (names sown into the ``counters`` collection,
-returned with a tick's tokens) and :meth:`serving_refusals`.
+returned with a tick's tokens), ``packs_live_tokens`` with
+``live_block_rows`` (the packed tick, by blocks) and
+:meth:`serving_refusals`.
 """
 
 from __future__ import annotations
@@ -54,13 +67,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.models.blocks import (  # noqa: F401 (re-exported)
-    RoutedExperts, SwiGLU, _dot, _normal, rms_norm)
+    RoutedExperts, RoutedExpertsByPart, SwiGLU, _dot, _normal,
+    live_block_rows, live_packing, map_live_blocks, pack_live, rms_norm,
+    unpack_live)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import mla
 
 
 class LatentAttention(nn.Module):
-    """MLA with the lightning indexer; see the module docstring."""
+    """MLA with the lightning indexer; see the module docstring. The
+    per-token halves (:meth:`project` before the attend, :meth:`output`
+    after it) are callable apart from the walk over the cache
+    (:meth:`attend`), so that a packed mixed tick can run them a block
+    of live rows at a time."""
+    d_model: int
     num_heads: int
     q_lora_rank: int
     kv_lora_rank: int
@@ -79,77 +99,113 @@ class LatentAttention(nn.Module):
     cache_len: int = 0
     kv_tile: int = 512
 
+    def setup(self):
+        d, H, R = self.d_model, self.num_heads, self.kv_lora_rank
+        rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+        J, Di, pd = self.index_n_heads, self.index_head_dim, self.param_dtype
+        ones, zeros = nn.initializers.ones, nn.initializers.zeros
+        self.wq_a = self.param("wq_a", _normal(), (d, self.q_lora_rank), pd)
+        self.q_norm = self.param("q_norm", ones, (self.q_lora_rank,), pd)
+        self.wq_b = self.param("wq_b", _normal(),
+                               (self.q_lora_rank, H, nope + rope), pd)
+        self.wkv_a = self.param("wkv_a", _normal(), (d, R + rope), pd)
+        self.kv_norm = self.param("kv_norm", ones, (R,), pd)
+        self.wkv_b = self.param("wkv_b", _normal(),
+                                (R, H, nope + self.v_head_dim), pd)
+        self.wo = self.param("wo", _normal(2), (H, self.v_head_dim, d), pd)
+        self.iq_b = self.param("index_wq_b", _normal(),
+                               (self.q_lora_rank, J, Di), pd)
+        self.ik_w = self.param("index_wk", _normal(), (d, Di), pd)
+        self.ik_scale = self.param("index_k_norm_scale", ones, (Di,), pd)
+        self.ik_bias = self.param("index_k_norm_bias", zeros, (Di,), pd)
+        self.iw = self.param("index_weights_proj", _normal(), (d, J), pd)
+
     @nn.compact
-    def __call__(self, u, valid_lens=None):
-        B, T, d = u.shape
-        H, R, rope = self.num_heads, self.kv_lora_rank, self.qk_rope_head_dim
-        nope, J, Di = (self.qk_nope_head_dim, self.index_n_heads,
-                       self.index_head_dim)
-        pd, dt = self.param_dtype, self.dtype
-        wq_a = self.param("wq_a", _normal(), (d, self.q_lora_rank), pd)
-        q_norm = self.param("q_norm", nn.initializers.ones,
-                            (self.q_lora_rank,), pd)
-        wq_b = self.param("wq_b", _normal(),
-                          (self.q_lora_rank, H, nope + rope), pd)
-        wkv_a = self.param("wkv_a", _normal(), (d, R + rope), pd)
-        kv_norm = self.param("kv_norm", nn.initializers.ones, (R,), pd)
-        wkv_b = self.param("wkv_b", _normal(),
-                           (R, H, nope + self.v_head_dim), pd)
-        wo = self.param("wo", _normal(2), (H, self.v_head_dim, d), pd)
-        iq_b = self.param("index_wq_b", _normal(),
-                          (self.q_lora_rank, J, Di), pd)
-        ik_w = self.param("index_wk", _normal(), (d, Di), pd)
-        ik_scale = self.param("index_k_norm_scale", nn.initializers.ones,
-                              (Di,), pd)
-        ik_bias = self.param("index_k_norm_bias", nn.initializers.zeros,
-                             (Di,), pd)
-        iw = self.param("index_weights_proj", _normal(), (d, J), pd)
+    def cache(self, B: int):
+        """The decode cache's three leaves for ``B`` rows."""
+        L, dt = self.cache_len, self.dtype
+        return (
+            self.variable("cache", "latent", jnp.zeros,
+                          (B, L, self.kv_lora_rank + self.qk_rope_head_dim),
+                          dt),
+            self.variable("cache", "index_key", jnp.zeros,
+                          (B, L, self.index_head_dim), dt),
+            self.variable("cache", "cache_index",
+                          lambda: jnp.zeros((B,), jnp.int32)))
 
-        if self.decode:
-            L = self.cache_len
-            latent = self.variable("cache", "latent", jnp.zeros,
-                                   (B, L, R + rope), dt)
-            index_key = self.variable("cache", "index_key", jnp.zeros,
-                                      (B, L, Di), dt)
-            cursor = self.variable("cache", "cache_index",
-                                   lambda: jnp.zeros((B,), jnp.int32))
-            starts = cursor.value
-        else:
-            starts = jnp.zeros((B,), jnp.int32)
-        pos = starts[:, None] + jnp.arange(T)[None]  # [B, T]
+    def _starts(self, B: int):
+        return (self.cache(B)[2].value if self.decode
+                else jnp.zeros((B,), jnp.int32))
+
+    def positions(self, B: int, T: int):
+        """``[B, T]``: the absolute position of each token of a ``[B,
+        T]`` call, from each row's own cursor."""
+        return self._starts(B)[:, None] + jnp.arange(T)[None]
+
+    def projected(self):
+        """Trailing shape and dtype of each of :meth:`project`'s five
+        results."""
+        D = self.kv_lora_rank + self.qk_rope_head_dim
+        J, dt = self.index_n_heads, self.dtype
+        return (((self.num_heads, D), dt), ((D,), dt),
+                ((J, self.index_head_dim), dt),
+                ((self.index_head_dim,), dt), ((J,), jnp.float32))
+
+    def project(self, u, pos):
+        """Per token (``u [..., T, d]`` at ``pos [..., T]``): the
+        absorbed query, the latent entry, the index query, the index key
+        and the index heads' weights."""
+        R, rope, nope = (self.kv_lora_rank, self.qk_rope_head_dim,
+                         self.qk_nope_head_dim)
+        J, Di, dt = self.index_n_heads, self.index_head_dim, self.dtype
         inv_freq = np.asarray(self.inv_freq, np.float32)
-
         with jax.named_scope("mla_project"):
-            cq = rms_norm(_dot(u, wq_a, dt), q_norm, self.rms_eps)
-            q = _dot(cq, wq_b, dt).astype(dt)  # [B, T, H, nope + rope]
+            cq = rms_norm(_dot(u, self.wq_a, dt), self.q_norm, self.rms_eps)
+            q = _dot(cq, self.wq_b, dt).astype(dt)  # [..., H, nope + rope]
             q_rope = mla.rope_half(q[..., nope:], pos, inv_freq)
             # the key half of W_ukv folded into the query
-            q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :nope],
-                               wkv_b[..., :nope].astype(dt),
+            q_lat = jnp.einsum("...hn,rhn->...hr", q[..., :nope],
+                               self.wkv_b[..., :nope].astype(dt),
                                preferred_element_type=jnp.float32)
             q_full = jnp.concatenate([q_lat.astype(dt), q_rope], axis=-1)
-            kv = _dot(u, wkv_a, dt)
+            kv = _dot(u, self.wkv_a, dt)
             entry = jnp.concatenate(
-                [rms_norm(kv[..., :R], kv_norm, self.rms_eps),
+                [rms_norm(kv[..., :R], self.kv_norm, self.rms_eps),
                  mla.rope_half(kv[..., R:], pos, inv_freq)],
-                axis=-1).astype(dt)  # [B, T, R + rope]
+                axis=-1).astype(dt)  # [..., R + rope]
             # the indexer: rope on the first `rope` channels of both
-            qi = _dot(cq, iq_b, dt).astype(dt)
+            qi = _dot(cq, self.iq_b, dt).astype(dt)
             qi = jnp.concatenate(
                 [mla.rope_half(qi[..., :rope], pos, inv_freq),
                  qi[..., rope:]], axis=-1)
-            ki = _dot(u, ik_w, dt)
+            ki = _dot(u, self.ik_w, dt)
             mean = ki.mean(axis=-1, keepdims=True)
             ki = (ki - mean) * jax.lax.rsqrt(
                 jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
-                + self.rms_eps) * ik_scale.astype(jnp.float32) \
-                + ik_bias.astype(jnp.float32)
+                + self.rms_eps) * self.ik_scale.astype(jnp.float32) \
+                + self.ik_bias.astype(jnp.float32)
             ki = jnp.concatenate(
                 [mla.rope_half(ki[..., :rope], pos, inv_freq),
                  ki[..., rope:]], axis=-1).astype(dt)
-            w = _dot(u, iw, dt) * (J ** -0.5 * Di ** -0.5)
+            w = _dot(u, self.iw, dt) * (J ** -0.5 * Di ** -0.5)
+        return q_full, entry, qi, ki, w
 
+    def attend(self, q_full, entry, qi, ki, w, pos, valid_lens=None,
+               offsets=None, out=None):
+        """The ``[B, T]`` tokens' entries (``entry``, ``ki``) written at
+        their rows' cursors (``pos``: :meth:`positions`), then every
+        query attended over what the indexer selects of its row:
+        ``[B, T, H, rank]`` float32. With ``offsets`` the queries
+        (``q_full`` as rows of ``[H * D]``, ``qi``, ``w``) are PACKED
+        rows, row ``b``'s tokens from ``offsets[b]`` on, and so is the
+        result, rows of ``[H * rank]`` in the compute dtype, written
+        over ``out`` if given
+        (:func:`mla.sparse_latent_attention_packed`)."""
+        B, T = entry.shape[:2]
+        starts = self._starts(B)
         if self.decode:
+            latent, index_key, cursor = self.cache(B)
+            L = self.cache_len
             with jax.named_scope("cache_update"):
                 # each row's valid tokens land at its cursor; a chunk's
                 # padding is pushed past the cache and dropped
@@ -170,41 +226,128 @@ class LatentAttention(nn.Module):
             pad = (-T) % tile
             held = jnp.pad(entry, ((0, 0), (0, pad), (0, 0)))
             keys = jnp.pad(ki, ((0, 0), (0, pad), (0, 0)))
-        out = mla.sparse_latent_attention(
-            q_full, qi, w, held, keys, starts, valid_lens,
-            topk=self.index_topk, tile=tile, scale=self.softmax_scale,
-            rank=R)
+        walk = dict(topk=self.index_topk, tile=tile,
+                    scale=self.softmax_scale, rank=self.kv_lora_rank)
+        if offsets is None:
+            return mla.sparse_latent_attention(
+                q_full, qi, w, held, keys, starts, valid_lens, **walk)
+        return mla.sparse_latent_attention_packed(
+            q_full, qi, w, held, keys, starts, valid_lens, offsets, T, out,
+            **walk)
+
+    def output(self, out):
+        """Per token: the attend's ``[..., H, rank]`` through the value
+        half of W_ukv (folded into the output) and ``wo``; float32."""
+        dt, nope = self.dtype, self.qk_nope_head_dim
         with jax.named_scope("mla_project"):
-            # the value half of W_ukv folded into the output
-            o = jnp.einsum("bthr,rhv->bthv", out.astype(dt),
-                           wkv_b[..., nope:].astype(dt),
+            o = jnp.einsum("...hr,rhv->...hv", out.astype(dt),
+                           self.wkv_b[..., nope:].astype(dt),
                            preferred_element_type=jnp.float32)
             return jax.lax.dot_general(
-                o.astype(dt), wo.astype(dt),
-                (((2, 3), (0, 1)), ((), ())),
+                o.astype(dt), self.wo.astype(dt),
+                (((o.ndim - 2, o.ndim - 1), (0, 1)), ((), ())),
                 preferred_element_type=jnp.float32)
 
 
 class DecoderLayer(nn.Module):
-    attn: tuple  # LatentAttention's fields as sorted items (hashable)
-    ffn: tuple   # SwiGLU's, or RoutedExperts' where the layer is not dense
+    """Pre-norm attention and feed-forward, each added to the residual
+    stream. Everything but the attend is per token: :meth:`before` and
+    :meth:`after` are those two halves, which a packed mixed tick
+    (``packing``) runs over the ``n_blocks`` blocks of packed rows that
+    hold a token and a full-width call runs once over ``[B, T]``."""
+    d_model: int
+    attn_kw: tuple  # LatentAttention's fields as sorted items (hashable)
+    ffn_kw: tuple   # SwiGLU's, or the expert layer's where not dense
     dense: bool
     rms_eps: float
+    dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
-    @nn.compact
-    def __call__(self, x, live, valid_lens=None):
-        d = x.shape[-1]
-        n1 = self.param("attn_norm", nn.initializers.ones, (d,),
-                        self.param_dtype)
-        n2 = self.param("ffn_norm", nn.initializers.ones, (d,),
-                        self.param_dtype)
-        x = x + LatentAttention(**dict(self.attn), name="attn")(
-            rms_norm(x, n1, self.rms_eps), valid_lens)
-        u = rms_norm(x, n2, self.rms_eps)
+    def setup(self):
+        ones = nn.initializers.ones
+        self.attn_norm = self.param("attn_norm", ones, (self.d_model,),
+                                    self.param_dtype)
+        self.ffn_norm = self.param("ffn_norm", ones, (self.d_model,),
+                                   self.param_dtype)
+        self.attn = LatentAttention(**dict(self.attn_kw))
         if self.dense:
-            return x + SwiGLU(**dict(self.ffn), name="mlp")(u)
-        return x + RoutedExperts(**dict(self.ffn), name="moe")(u, live)
+            self.mlp = SwiGLU(**dict(self.ffn_kw))
+        else:
+            self.moe = RoutedExpertsByPart(**dict(self.ffn_kw))
+
+    def before(self, x, pos):
+        return self.attn.project(
+            rms_norm(x, self.attn_norm, self.rms_eps), pos)
+
+    def after(self, x, out):
+        """The residual stream past the attention, then the dense
+        feed-forward added, or (expert layer) what the held experts
+        still need: the shared expert's result, the normed input in the
+        compute dtype and the router's choice."""
+        x = x + self.attn.output(out)
+        u = rms_norm(x, self.ffn_norm, self.rms_eps)
+        if self.dense:
+            return (x + self.mlp(u),)
+        experts, gates = self.moe.route(u[0])
+        with jax.named_scope("moe_shared"):
+            shared = self.moe.shared(u)
+        return x, shared, u.astype(self.dtype), experts[None], gates[None]
+
+    def __call__(self, x, live, valid_lens=None, packing=None,
+                 n_blocks=None, scratch=None):
+        """``scratch`` (packed ticks): the arrays the layer before left
+        its stages' results in, by stage, to write this layer's over in
+        place of 0.9 GB of fresh zeros a layer; replaced by this
+        layer's."""
+        if packing is None:
+            u = rms_norm(x, self.attn_norm, self.rms_eps)
+            pos = self.attn.positions(*x.shape[:2])
+            x = x + self.attn.output(self.attn.attend(
+                *self.attn.project(u, pos), pos, valid_lens))
+            u = rms_norm(x, self.ffn_norm, self.rms_eps)
+            return x + (self.mlp(u) if self.dense else self.moe(u, live))
+        S, C = packing.inv.shape
+        pos = self.attn.positions(S, C)
+        ((H, D), dt), *small = self.attn.projected()
+        R = self.attn.kv_lora_rank
+
+        # the two wide arrays (a token's queries, 128 x 576, and its
+        # attend's results, 128 x 512) are carried as rows of features:
+        # with heads an axis of their own the compiler lays all N rows
+        # out for one consumer and copies them for the next
+        def before(m, x, pos):
+            q, *rest = m.before(x, pos)
+            return (q.reshape(q.shape[:2] + (H * D,)), *rest)
+
+        def after(m, x, out):
+            return m.after(x, out.reshape(out.shape[:2] + (H, R)))
+
+        def stage(name, fn, xs, like, tail=0):
+            scratch[name] = map_live_blocks(self, fn, xs, like, n_blocks,
+                                            tail, scratch.get(name))
+            return scratch[name]
+
+        q, entry, qi, ki, w = stage(
+            "before", before, (x, pack_live(pos, packing)),
+            (((H * D,), dt), *small), C)
+        # the cache write keeps [S, C]; the walk takes each row's
+        # queries where they lie packed and leaves its results there
+        scratch["attend"] = out = self.attn.attend(
+            q[0], unpack_live(entry, packing), qi[0],
+            unpack_live(ki, packing), w[0], pos, valid_lens,
+            jnp.cumsum(valid_lens) - valid_lens, scratch.get("attend"))
+        stream = ((self.d_model,), jnp.float32)
+        if self.dense:
+            return stage("dense", after, (x, out[None]), (stream,))[0]
+        k = (self.moe.num_experts_per_tok,)
+        x, shared, u, experts, gates = stage(
+            "experts", after, (x, out[None]),
+            (stream, stream, ((self.d_model,), self.dtype), (k, jnp.int32),
+             (k, jnp.float32)))
+        # one call over the packed array: the grouped matmul sizes its
+        # work from the rows routed here
+        return x + (shared + self.moe.held(u[0], experts[0], gates[0],
+                                           live[0])[None])
 
 
 @register_model("deepseek_v32_lm")
@@ -260,6 +403,13 @@ class DeepseekV32LM(nn.Module):
     # sown into the "counters" collection by every expert layer; the
     # serving tick returns their sums with the tick's tokens
     tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+    # a decode apply takes ``live_tokens`` (the dropless experts give
+    # each token what they would give it alone), and not one compiled
+    # count of rows but blocks: handed ``live_tokens = S * C`` the model
+    # runs its per-token layers over as many blocks of this many packed
+    # rows as hold a token, counted on the device
+    packs_live_tokens = True
+    live_block_rows = staticmethod(live_block_rows)
 
     def serving_refusals(self, **options):
         """Raise for each :class:`ServingEngine` option this model does
@@ -293,7 +443,16 @@ class DeepseekV32LM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, block_tables=None,
-                 seq_lens=None, valid_lens=None):
+                 seq_lens=None, valid_lens=None,
+                 live_tokens: Optional[int] = None):
+        """``live_tokens`` (``S * C``, with ``valid_lens`` on a decode
+        module) is the packed form of a mixed ``[S, C]`` tick: the live
+        tokens are packed to the front and every per-token layer runs
+        over the ``ceil(live / block)`` blocks of :meth:`live_block_rows`
+        rows that hold one, a loop whose trip count the device derives
+        from ``valid_lens``; the attend and the cache write keep ``[S,
+        C]``. The result is ``[S, 1, vocab]``, each row's last valid
+        token's logits."""
         if block_tables is not None or seq_lens is not None:
             raise ValueError("deepseek_v32_lm has no paged cache")
         if self.cache_dtype != "model":
@@ -307,11 +466,16 @@ class DeepseekV32LM(nn.Module):
         if self.decode and self.max_len % min(self.kv_tile, self.max_len):
             raise ValueError(f"max_len={self.max_len} must be a multiple "
                              f"of kv_tile={self.kv_tile} (or shorter)")
+        if live_tokens is not None and (
+                valid_lens is None or not self.decode
+                or live_tokens != tokens.size):
+            raise ValueError("live_tokens (the packed mixed tick) is S * C, "
+                             "with valid_lens on a decode module")
         B, T = tokens.shape
         held = (self.n_routed_experts if self.experts_held is None
                 else self.experts_held)
         attn = dict(
-            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            d_model=self.d_model, num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
             kv_lora_rank=self.kv_lora_rank,
             qk_nope_head_dim=self.qk_nope_head_dim,
             qk_rope_head_dim=self.qk_rope_head_dim,
@@ -330,7 +494,8 @@ class DeepseekV32LM(nn.Module):
             cache_len=self.max_len if self.decode else 0,
             kv_tile=self.kv_tile)
         moe = dict(
-            n_routed_experts=self.n_routed_experts, experts_held=held,
+            d_model=self.d_model, n_routed_experts=self.n_routed_experts,
+            experts_held=held,
             expert_rank=self.expert_rank,
             num_experts_per_tok=self.num_experts_per_tok,
             n_group=self.n_group, topk_group=self.topk_group,
@@ -340,18 +505,32 @@ class DeepseekV32LM(nn.Module):
             param_dtype=self.param_dtype, expert_tile=self.expert_tile)
         mlp = dict(width=self.intermediate_size, dtype=self.dtype,
                    param_dtype=self.param_dtype)
-        live = (jnp.ones((B, T), bool) if valid_lens is None
-                else jnp.arange(T)[None, :] < valid_lens[:, None])
+        packing = n_blocks = None
+        scratch = {}
+        if live_tokens is not None:
+            packing = live_packing(valid_lens, T, live_tokens)
+            tokens = tokens.reshape(-1)[packing.idx][None]  # [1, S * C]
+            dealt = valid_lens.sum()
+            live = (jnp.arange(live_tokens) < dealt)[None]
+            n_blocks = -(-dealt // self.live_block_rows(live_tokens))
+        else:
+            live = (jnp.ones((B, T), bool) if valid_lens is None
+                    else jnp.arange(T)[None, :] < valid_lens[:, None])
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                      param_dtype=self.param_dtype,
                      name="embed")(tokens).astype(jnp.float32)
         for i in range(self.num_layers):
             dense = i < self.first_k_dense
-            x = DecoderLayer(tuple(sorted(attn.items())),
+            x = DecoderLayer(self.d_model, tuple(sorted(attn.items())),
                              tuple(sorted((mlp if dense else moe).items())),
-                             dense,
-                             self.rms_eps, self.param_dtype,
-                             name=f"layers_{i}")(x, live, valid_lens)
+                             dense, self.rms_eps, self.dtype,
+                             self.param_dtype, name=f"layers_{i}")(
+                                 x, live, valid_lens, packing, n_blocks,
+                                 scratch)
+        if packing is not None:
+            # [S, 1, d]: each row's last valid token, where its packed
+            # run ends
+            x = x[0][jnp.maximum(jnp.cumsum(valid_lens) - 1, 0)][:, None]
         norm = self.param("norm", nn.initializers.ones,
                           (self.d_model,), self.param_dtype)
         head = self.param("head", _normal(),

@@ -221,6 +221,62 @@ def sparse_latent_attention(q, qi, w, latent, index_keys, starts,
     return jax.lax.map(one, (q, qi, w, starts, valid, latent, index_keys))
 
 
+def sparse_latent_attention_packed(q, qi, w, latent, index_keys, starts,
+                                   valid_lens, offsets, chunk: int,
+                                   out=None, *, topk: int, tile: int,
+                                   scale: float, rank: int):
+    """:func:`sparse_latent_attention` over PACKED queries, for a mixed
+    tick whose per-token layers run over the live tokens packed to the
+    front: ``q [N, H * D]`` (a token's heads side by side), ``qi [N, J,
+    Di]``, ``w [N, J]`` hold row ``s``'s ``valid_lens[s]`` tokens at
+    ``offsets[s]`` on (``offsets`` = the exclusive cumulative sum of
+    ``valid_lens``), and ``N`` leaves ``chunk`` rows beyond the last
+    token, so that a row's slice of ``chunk`` rows always fits. Returns
+    ``[N, H * rank]`` in ``q``'s dtype with each row's results where its
+    queries were. Rows are walked in order and each writes its whole
+    slice: what lies beyond a row's valid tokens is overwritten by the
+    rows after it, and beyond the last token nothing reads; ``out`` is
+    an array of the result's shape to write over (another layer's
+    result: every row a reader looks at is written here first), zeros
+    if none. Neither the
+    queries (302 MB a layer at ``[32, 64]``) nor the results are ever
+    laid out ``[S, chunk]``; both are rows of features, two axes, so
+    that the layouts the walk's and the projections' matmuls want are
+    given to a row's slice or a block, not to all ``N`` rows in a copy."""
+    N = q.shape[0]
+    D = latent.shape[-1]
+    H = q.shape[1] // D
+    if latent.shape[1] % tile:
+        raise ValueError(f"cache length {latent.shape[1]} is no multiple of "
+                         f"the walk's tile {tile}")
+    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale,
+                             rank=rank)
+
+    def one(out, args):
+        offset, start, fed, lat, keys = args
+        n = jnp.where(fed > 0, start + fed, 0)
+
+        def rows(c):
+            # the row's slice, behind a barrier: folded into the walk,
+            # the layout its matmuls want becomes a copy of all N rows,
+            # made again for every row
+            qr, qir, wr = jax.lax.optimization_barrier(tuple(
+                jax.lax.dynamic_slice_in_dim(t, offset, c)
+                for t in (q, qi, w)))
+            res = walk(qr.reshape(c, H, D), qir, wr, lat, keys, start, n)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, res.astype(out.dtype).reshape(c, H * rank), offset, 0)
+
+        # a row that feeds at most one token takes, walks and writes one
+        return jax.lax.cond(fed <= 1, lambda _: rows(1),
+                            lambda _: rows(chunk), None), None
+
+    if out is None:
+        out = jnp.zeros((N, H * rank), q.dtype)
+    return jax.lax.scan(
+        one, out, (offsets, starts, valid_lens, latent, index_keys))[0]
+
+
 def fetched_positions(starts, valid, tile: int) -> int:
     """Cache positions the walks of one tick read, all rows of it: each
     row's tiles up to its last valid token, none for a row that feeds

@@ -3121,10 +3121,16 @@ class ServingEngine:
         ``packs_live_tokens``, where packing would leave nothing out (a
         ``[S, 1]`` tick; a budget that covers every row's chunk), and
         where this plan overran the count (a scheduler of the user's
-        that deals more than its ``tick_token_budget``)."""
+        that deals more than its ``tick_token_budget``). A module that
+        packs by blocks (``live_block_rows``) is handed ``S * C``
+        whatever the budget: it finds the blocks in use on the device."""
+        dm = self._layout.dm
+        if not getattr(dm, "packs_live_tokens", False):
+            return None
+        if hasattr(dm, "live_block_rows"):
+            return self.slots * C if C > 1 else None
         budget = getattr(self.scheduler, "tick_token_budget", None)
-        if budget is None or not getattr(
-                self._layout.dm, "packs_live_tokens", False):
+        if budget is None:
             return None
         N = _packed_count(budget, self.slots, C)
         return N if N < self.slots * C and dealt <= N else None
@@ -3247,7 +3253,8 @@ class ServingEngine:
             # where it decodes, its chunk where it prefills, 0 otherwise
             packed = self._layout.pack(self, (fed, valid, sample_mask),
                                        advance=valid)
-        live = self._live_count(C, n_dec + fed_tokens)
+        dealt = n_dec + fed_tokens
+        live = self._live_count(C, dealt)
         work = {"attended_tokens": attended,
                 "key_positions": key_positions,
                 "key_positions_fetched": self._kv_fetched(starts, valid, C),
@@ -3256,6 +3263,11 @@ class ServingEngine:
                 # attend's beside them: they differ on a packed tick
                 "query_positions": live or S * C,
                 "attend_query_positions": S * C}
+        block = getattr(self._layout.dm, "live_block_rows", None)
+        if live is not None and block is not None:
+            # a module that packs by blocks runs those that hold a token
+            work["live_blocks"] = -(-dealt // block(live))
+            work["query_positions"] = work["live_blocks"] * block(live)
         by_kind = getattr(self.model, "kv_positions_by_kind", None)
         if by_kind is not None:
             # layers of more than one kind: what each kind's attends

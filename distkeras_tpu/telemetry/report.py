@@ -393,6 +393,16 @@ def report_flight(path: str, last: Optional[int] = None,
             f"per-token layers over {packed[-1]['query_positions']} of "
             f"{packed[-1]['attend_query_positions']} query positions\n"
         )
+    blocks = [r["live_blocks"] for r in ticks if "live_blocks" in r]
+    if blocks:
+        # a model that packs by blocks: those of a chunk tick's packed
+        # rows that held a token, which its per-token layers ran over
+        out.write(
+            f"blocks in use a tick: p50 {_percentile(blocks, 50):.0f}  "
+            f"p95 {_percentile(blocks, 95):.0f}  (of the "
+            f"{len(blocks)} chunk ticks; query_positions / live_blocks "
+            f"rows a block)\n"
+        )
     if any("pipeline_depth" in r for r in ticks):
         # the loop that runs a tick ahead: tokens it sampled for rows
         # that had finished in the unread tick, dropped at

@@ -194,6 +194,153 @@ def test_the_engine_counts_what_the_model_selects_and_routes(small,
     assert "expert_rows_computed:" in out
 
 
+# -- the packed mixed tick: per-token layers over the blocks in use -------------
+
+# 6 rows x 256 = three blocks of 512 packed rows
+WIDE = dict(SMALL, max_len=512, kv_tile=64)
+BLOCK_TICKS = {
+    # valid lens of one [6, 256] tick -> the blocks its live tokens fill
+    "one_block": ([40, 1, 1, 200, 1, 1], 1),
+    "two_blocks": ([256, 256, 1, 0, 100, 7], 2),
+    "all_blocks": ([256] * 6, 3),
+    "a_chunk_beside_single_tokens": ([1, 1, 256, 1, 1, 1], 1),
+    "an_idle_row": ([256, 0, 1, 256, 0, 30], 2),
+}
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The decode module at [6, 256] with every row's cursor somewhere
+    else, and its two compiled mixed ticks: full width and packed."""
+    cfg = _config(**WIDE)
+    params = ref.make_params(cfg, 7)["params"]
+    dm = get_model("deepseek_v32_lm", **WIDE, dtype=jnp.float32).clone(
+        decode=True, slot_cursor=True, parent=None)
+    S, C = 6, 256
+
+    def tick(live):
+        def fn(cache, tokens, valid):
+            return dm.apply({"params": params, "cache": cache}, tokens,
+                            valid_lens=valid, mutable=["cache", "counters"],
+                            **({"live_tokens": live} if live else {}))
+        return jax.jit(fn)
+
+    full, packed = tick(None), tick(S * C)
+    cache = dm.init(jax.random.PRNGKey(0),
+                    jnp.zeros((S, 1), jnp.int32))["cache"]
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, SMALL["vocab_size"], (S, C)), jnp.int32)
+    _, grown = full(cache, tokens, jnp.asarray([100, 3, 0, 50, 1, 200]))
+    return dm, full, packed, grown["cache"], tokens
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_TICKS))
+def test_the_packed_tick_equals_the_full_width_tick(wide, case):
+    """Logits at each row's last valid token, every cache leaf and
+    cursor, and the experts' counters, whichever blocks hold a token."""
+    dm, full, packed, cache, tokens = wide
+    lens, blocks = BLOCK_TICKS[case]
+    assert -(-sum(lens) // dm.live_block_rows(tokens.size)) == blocks
+    valid = jnp.asarray(lens, jnp.int32)
+    want, left = full(cache, tokens, valid)
+    got, kept = packed(cache, tokens, valid)
+    assert got.shape == (6, 1, SMALL["vocab_size"])
+    fed = np.asarray(lens) > 0
+    last = np.maximum(np.asarray(lens) - 1, 0)
+    np.testing.assert_allclose(
+        np.asarray(got)[fed, 0], np.asarray(want)[np.arange(6), last][fed],
+        atol=1e-5)
+    for a, b in zip(jax.tree.leaves(kept["cache"]),
+                    jax.tree.leaves(left["cache"])):
+        if a.dtype == jnp.int32:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, atol=1e-6)
+    assert jax.tree.map(int, kept["counters"]) == jax.tree.map(
+        int, left["counters"])
+
+
+def test_live_tokens_is_the_whole_tick_on_a_decode_module(wide):
+    dm, _, _, cache, tokens = wide
+    params = ref.make_params(_config(**WIDE), 7)["params"]
+    with pytest.raises(ValueError, match="is S \\* C"):
+        dm.apply({"params": params, "cache": cache}, tokens,
+                 valid_lens=jnp.ones((6,), jnp.int32), live_tokens=512,
+                 mutable=["cache", "counters"])
+
+
+@pytest.fixture(scope="module")
+def served_by_blocks():
+    """Five requests through four slots in chunks of 256 (a [4, 256]
+    tick is two blocks of 512), by the packed program and, the same
+    requests again, by the full-width one."""
+    cfg = _config(**WIDE)
+    params = ref.make_params(cfg, 7)
+    model = get_model("deepseek_v32_lm", **WIDE, dtype=jnp.float32)
+    prompts = [_tokens(n, i) for i, n in enumerate((300, 40, 270, 5, 130))]
+    news = [6, 10, 5, 8, 7]
+    engine = dict(slots=4, max_len=512, prefill_chunk=256,
+                  scheduler={"tick_token_budget": 1024})
+    packed = _serve(model, params, prompts, news, **engine)
+    eng = ServingEngine(model, params, **engine)
+    eng._live_count = lambda C, dealt: None
+    for p, n in zip(prompts, news):
+        eng.submit(p, n)
+    eng.drain()
+    return cfg, params, prompts, news, packed, eng
+
+
+def test_chunked_prefill_by_blocks_agrees_with_the_reference(
+        served_by_blocks):
+    cfg, params, prompts, news, (eng, reqs, seen), _ = served_by_blocks
+    assert eng.stats()["packed_ticks_total"] > 0
+    for r, p, n in zip(reqs, prompts, news):
+        toks = r.stream.tokens(timeout=10)
+        assert len(toks) == n and r.stream.finish_reason == "length"
+        seq = np.concatenate([p, np.asarray(toks, np.int32)])
+        want = ref.forward_logits(cfg, params, seq, np.arange(len(seq)),
+                                  "f32", 512)
+        at = sorted(pos for rid, pos in seen if rid == r.rid)
+        assert at[0] == len(p) - 1 and len(at) >= n
+        for pos in at:
+            assert np.abs(seen[(r.rid, pos)] - want[pos]).max() < TOL
+        assert toks == want[len(p) - 1:len(seq) - 1].argmax(-1).tolist()
+
+
+def test_a_tick_counts_the_blocks_it_ran(served_by_blocks, tmp_path,
+                                         capsys):
+    """``query_positions`` is 512 x ceil(dealt / 512) on a chunk tick,
+    and what the model selects and routes is what the full-width
+    program counts."""
+    _, _, _, _, (eng, _, _), full = served_by_blocks
+    ticks = [t for t in eng.flight.snapshots() if t.get("kind") == "tick"]
+    chunked = [t for t in ticks if t["chunk"] == 256]
+    assert chunked and {t["attend_query_positions"] for t in chunked} == {
+        1024}
+    for t in chunked:
+        dealt = t["decode_tokens"] + t["prefill_tokens"]
+        assert t["query_positions"] == 512 * -(-dealt // 512)
+        assert t["live_blocks"] == -(-dealt // 512)
+    assert {t["query_positions"] for t in chunked} == {512, 1024}
+    assert all(t["query_positions"] == 4 and "live_blocks" not in t
+               for t in ticks if t["chunk"] == 1)
+    st, was = eng.stats(), full.stats()
+    assert st["packed_ticks_total"] == sum(
+        t["query_positions"] == 512 for t in chunked)
+    assert was["packed_ticks_total"] == 0
+    assert st["query_positions_total"] < was["query_positions_total"]
+    for name in ("routed_here_total", "routed_total_total",
+                 "expert_rows_computed_total", "keys_selected_total",
+                 "index_positions_scored_total", "attended_tokens_total",
+                 "useful_query_tokens_total"):
+        assert st[name] == was[name], name
+    path = tmp_path / "flight.jsonl"
+    eng.flight.dump(str(path), reason="manual")
+    telemetry_report.main(["--flight", str(path)])
+    out = capsys.readouterr().out
+    assert "packed ticks:" in out and "blocks in use a tick: p50 " in out
+
+
 @pytest.mark.parametrize("option,what", [
     (dict(paged=True), "paged"),
     (dict(draft="ngram"), "draft"),

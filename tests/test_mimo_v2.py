@@ -457,8 +457,11 @@ PARENT = {
     "deepseek_v32_lm": {
         "params": "a902f0f6869c63f47dd1a845795e2bfb1d8a4fc6e659c923c8be26f7"
                   "207a14ce",
-        "mixed_8_None": "3fe1ad9b9a35dcaf38bef3f16370adb0eb6b0fd6f06d5dfed67"
-                        "bb2d18d21875a",
+        # PR 38: its [3, 8] tick packs by blocks (one block of 24 rows
+        # here), a deliberate change; the full-width program was
+        # 3fe1ad9b...21875a and the [3, 1] tick is the parent's still
+        "mixed_8_24": "e239c0ed08077838f58739d1bcdc3bc03e77358b3bbf2ab8c79ab"
+                      "0cd0144c41c",
         "mixed_1_None": "1f1b0d20c5954b1b3be9abbaae318fa96935464a34c97672a70"
                         "b660132690d97"},
     "transformer_lm": {

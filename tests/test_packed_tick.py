@@ -2,9 +2,13 @@
 tick fewer tokens than it holds, the per-token layers run over the live
 tokens packed to ONE compiled count ``N`` and only the attend sees ``[S,
 C]``. Same tokens, logits, cache bytes and cursors as the full-width
-program; one program for every live count; models whose result would
-change (routed experts) or that bring their own module keep the
-full-width program."""
+program; one program for every live count; a model whose result would change (a
+capacity sized from the tokens given) keeps the full-width program, and
+a model that packs by blocks (``deepseek_v32_lm``, PR 38) is handed ``S
+* C`` and finds the blocks in use itself, while the models of one count
+keep theirs and their programs, text for text."""
+
+import hashlib
 
 import numpy as np
 import pytest
@@ -198,22 +202,20 @@ DSV32_KW = dict(
     kv_tile=16, expert_tile=8, dtype=jnp.float32)
 
 
-def _full_width_model(name):
-    if name == "moe_lm":
-        model = get_model("moe_lm", **KW, moe_experts=4)
-    else:
-        model = get_model("deepseek_v32_lm", **DSV32_KW)
+def _model(name):
+    kw = {"moe_lm": dict(KW, moe_experts=4), "transformer_lm": KW,
+          "deepseek_v32_lm": DSV32_KW, "solar_open2_lm": SOLAR_KW}[name]
+    model = get_model(name, **kw)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
     return model, {"params": params["params"]}
 
 
-@pytest.mark.parametrize("name", ["moe_lm", "deepseek_v32_lm"])
-def test_models_that_do_not_pack_keep_their_program(name, monkeypatch):
+def test_a_model_that_does_not_pack_keeps_its_program(monkeypatch):
     """Routed experts size their capacity from the tokens they are
-    given, and the latent model is its own module: under a budget that
-    would pack a dense model both are dispatched the builder's
-    full-width program, the one call the parent made."""
-    eng = _engine(_full_width_model(name), slots=3, chunk=8, budget=6)
+    given: under a budget that would pack a dense model ``moe_lm`` is
+    dispatched the builder's full-width program, the one call the
+    parent made."""
+    eng = _engine(_model("moe_lm"), slots=3, chunk=8, budget=6)
     assert engine_mod._packed_count(6, 3, 8) < 3 * 8
     built, builder = [], engine_mod._mixed_tick_fn
 
@@ -238,6 +240,64 @@ def test_models_that_do_not_pack_keep_their_program(name, monkeypatch):
         eng._params_only, eng._cache, eng._last_logits, eng._rngs,
         jnp.asarray(packed)).as_text()
     assert "tensor<3x8x64xf32>" in text
+
+
+SOLAR_KW = dict(
+    vocab_size=97, d_model=64, num_layers=4, num_heads=8, head_dim=16,
+    num_kv_heads=2, gqa_layers=[0, 3], kda_num_heads=4, kda_head_dim=16,
+    kda_gate_rank=8, moe_intermediate_size=32, n_routed_experts=16,
+    num_experts_per_tok=4, experts_held=8, expert_rank=0, max_len=64,
+    expert_tile=8, dtype=jnp.float32)
+# sha256 of the lowered text of the [3, 8] mixed tick of a tiny engine
+# under a budget of 6 (packed to 8 rows) at commit 67f0b59, before
+# deepseek_v32_lm came to pack by blocks; tests/test_mimo_v2.py pins
+# mimo_v2_lm's and a second transformer_lm the same way
+PARENT_PROGRAMS = {
+    "solar_open2_lm": "771076a64b1004c6051f3483ee1a28e842dc44bdb4db42d96b330"
+                      "736aa63698a",
+    "transformer_lm": "978dca6c49254a5ea430d9024c0600098ebab1960cf4422ded6aa"
+                      "7f9ebcd1ae7"}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))
+def test_the_models_of_one_count_keep_it_and_their_programs(name):
+    """The engine hands them the one compiled ``N`` as before, and the
+    program built for it is text for text the parent's."""
+    eng = _engine(_model(name), slots=3, chunk=8, budget=6)
+    assert not hasattr(eng._layout.dm, "live_block_rows")
+    assert [eng._live_count(8, dealt) for dealt in (1, 4, 8, 9)] == [
+        8, 8, 8, None]
+    assert eng._live_count(1, 3) is None
+    packed = eng._layout.pack(eng, (np.zeros((3, 8), np.int32),
+                                    np.zeros(3, np.int32),
+                                    np.zeros(3, np.int32)))
+    text = engine_mod._mixed_tick_fn(
+        eng._layout, (engine_mod._IDLE_CFG,) * 3, 8, None, 8).lower(
+            eng._params_only, eng._cache, eng._last_logits, eng._rngs,
+            jnp.asarray(packed)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[name]
+
+
+def test_a_model_that_packs_by_blocks_is_handed_the_whole_tick():
+    """``deepseek_v32_lm`` declares ``live_block_rows``: whatever was
+    dealt, a chunk tick's count is ``S * C`` (the
+    module finds the blocks in use on the device), the ``[S, 1]`` tick
+    stays full width, and the positions its per-token layers ran over
+    are whole blocks: one block of 24 rows at this size."""
+    eng = _engine(_model("deepseek_v32_lm"), slots=3, chunk=8, budget=6)
+    assert eng._layout.dm.live_block_rows(3 * 8) == 24
+    assert eng._layout.dm.live_block_rows(32 * 64) == 512
+    assert [eng._live_count(8, dealt) for dealt in (1, 6, 24)] == [24] * 3
+    assert eng._live_count(1, 3) is None
+    for i, p in enumerate(_prompts([13, 7, 10])):
+        eng.submit(p, max_new_tokens=3, seed=i)
+    eng.drain()
+    chunked = [t for t in _ticks(eng) if t["chunk"] == 8]
+    assert chunked and all(
+        t["query_positions"] == 24 and t["live_blocks"] == 1
+        for t in chunked)
+    # nothing was left out, so none counts as a packed tick
+    assert eng.stats()["packed_ticks_total"] == 0
 
 
 # -- (4) one program for every live count ----------------------------------------
