@@ -67,9 +67,10 @@ TTFT, per-token latency, per-stream inter-token latency
 ``serving_itl_ms``, decode-stall count, prefill fraction) into a
 :class:`~distkeras_tpu.telemetry.MetricRegistry` — scrapeable over the
 msgpack ``stats``/``trace_dump`` ops and the HTTP endpoint. The
-per-tick/per-request JSONL records still ride
+per-tick/per-request JSONL records ride a
 :class:`~distkeras_tpu.utils.metrics.MetricsWriter` for offline
-analysis. The engine also keeps a black box: a per-tick
+analysis where the caller hands one (``metrics=``). The engine also
+keeps a black box: a per-tick
 :class:`~distkeras_tpu.telemetry.FlightRecorder` snapshot (slot states,
 budget split, phase-decomposed latency) dumped to a postmortem JSONL on
 crash or stall, plus runtime introspection — jit recompile counting
@@ -115,7 +116,7 @@ from distkeras_tpu.serving.scheduler import (
     FIFOScheduler,
     Request,
 )
-from distkeras_tpu.utils.metrics import MetricsWriter
+from distkeras_tpu.utils.metrics import MetricsWriter, percentiles
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
@@ -1072,6 +1073,20 @@ class _InflightTick:
     n_forced: Optional[np.ndarray] = None
     granted: Optional[np.ndarray] = None
     spec_set: Optional[set] = None
+    # the device clock's stamps (:class:`_DeviceClock`, perf_counter
+    # seconds): the jitted call began and returned, the read began, and
+    # the last moment the tokens were seen not ready / the first they
+    # were seen ready (``ready_hi`` None until then; a read that blocked
+    # sets both ends to the moment it returned)
+    program: str = "decode"         # "decode" | "mixed" | "multi" | "spec"
+    dispatching_t: float = 0.0
+    dispatched_t: float = 0.0
+    read_t: float = 0.0
+    ready_lo: float = 0.0
+    ready_hi: Optional[float] = None
+    dozed: bool = False             # an idle phase since the dispatch before
+    epoch: int = 0                  # the clock's mark this tick counts under
+    first: bool = False             # the first dispatch after a mark
 
 
 class _Phase:
@@ -1092,8 +1107,10 @@ class _Phase:
     def __exit__(self, *exc):
         self.ms = (time.perf_counter() - self._t0) * 1e3
         self._span.__exit__(*exc)
-        acc = self._clock.acc
+        clock = self._clock
+        acc = clock.acc
         acc[self._name] = acc.get(self._name, 0.0) + self.ms
+        clock.boundary(self._name)
         return False
 
 
@@ -1108,9 +1125,12 @@ class _PhaseClock:
     one's; :meth:`take` closes it and hands out what each phase took
     in it, with the period's own length as ``loop``."""
 
-    def __init__(self, prefix: str):
+    def __init__(self, prefix: str, boundary):
         self.prefix = prefix
         self.acc: dict = {}
+        # called with the phase's name as each bracket closes: where the
+        # device clock probes the unread tick
+        self.boundary = boundary
         self._period_t0 = time.perf_counter()
 
     def __call__(self, name: str, **args) -> _Phase:
@@ -1122,6 +1142,194 @@ class _PhaseClock:
         out["loop"] = (now - self._period_t0) * 1e3
         self._period_t0 = now
         return out
+
+
+class _ClockTotals(NamedTuple):
+    """What :class:`_DeviceClock` has summed since its mark
+    (milliseconds; ``by_program``: program -> (sum of tick ms, ticks),
+    a new dict a tick, never written to once it is here)."""
+
+    busy_ms: float = 0.0
+    starved_ms: float = 0.0
+    unasked_ms: float = 0.0
+    err_ms: float = 0.0
+    ticks: int = 0
+    exact: int = 0
+    by_program: dict = {}
+    # the first counted tick's start and the last one's read, seconds on
+    # the clock: the span the three sums add up to
+    origin_t: Optional[float] = None
+    last_t: Optional[float] = None
+
+
+class _DeviceClock:
+    """The device's time a tick, with no profiler running: when each
+    tick's tokens became ready (the tokens of a TPU program become
+    ready when the program ends), from one non-blocking ``is_ready()``
+    at every phase boundary of the engine thread while a tick is unread
+    and not yet seen ready, or from the read itself where it blocked.
+
+    Per tick N, once read: ``start = max(dispatching_t(N),
+    ready(N-1))``, ``device_tick_ms = ready(N) - start``, and the gap
+    ``max(0, dispatching_t(N) - ready(N-1))`` in which the device had
+    nothing queued is ``device_starved_ms`` -- or ``device_unasked_ms``
+    where the loop dozed in it (an ``idle`` phase: nothing to run). The
+    hand-over is stamped where the jitted call BEGINS: the call
+    enqueues the program at its head (an idle v5e took a tick up 5-19 %
+    into the ``engine.dispatch`` span) and beside a server's threads
+    returns 4-10 ms later, when it has the interpreter lock back, by
+    which time the tick may have ended. A readiness seen at a boundary
+    is known to the interval between that boundary and the one before;
+    the values use its midpoint and ``device_clock_err_ms`` is half the
+    widths of the two intervals used, and, where the device was free
+    before the call returned, what of the call (``dispatching_t`` to
+    ``dispatched_t``) it was free for: the start lies somewhere in
+    that. 0 is ``exact``: both reads blocked and the device was still
+    busy when the call returned. The alternating loop gets every host
+    millisecond between two ticks as starved, which is what it does.
+
+    Pure arithmetic over the stamps on :class:`_InflightTick` (anything
+    with ``toks.is_ready()`` and those fields does), the clock handed
+    in: a test drives it with a made-up one. Everything but
+    :meth:`mark` and :meth:`stats` belongs to the engine thread."""
+
+    ZERO = _ClockTotals()
+
+    def __init__(self, now=time.perf_counter):
+        self._now = now
+        self._unread: deque = deque()   # dispatched, not yet read
+        self._prev: Optional[tuple] = None  # (lo, hi) of the last read tick
+        self._dozed = False
+        self._began = 0.0               # the dispatch call under way
+        self._epoch = 0
+        self._mark_asked = False
+        self._totals = self.ZERO
+
+    # -- stamps -------------------------------------------------------------
+
+    def boundary(self, phase: str):
+        """A phase of the loop closed: note a doze, and probe the oldest
+        unread tick not yet seen ready (ticks end in order: where that
+        one is not ready, none after it is)."""
+        if phase == "idle":
+            self._dozed = True
+        for rec in self._unread:
+            if rec.ready_hi is None:
+                t = self._now()
+                if rec.toks.is_ready():
+                    rec.ready_hi = self._now()
+                else:
+                    rec.ready_lo = t
+                return
+
+    def dispatching(self):
+        """The jitted call of the next tick begins."""
+        self._began = self._now()
+
+    def dispatched(self, rec):
+        """The jitted call of ``rec`` has returned."""
+        rec.dispatching_t = rec.ready_lo = self._began
+        rec.dispatched_t = self._now()
+        rec.dozed, self._dozed = self._dozed, False
+        if self._mark_asked:
+            # the mark takes effect here, on the engine thread: ticks
+            # dispatched from now on count, the tick in flight does not
+            self._epoch += 1
+            self._totals = self.ZERO
+            self._mark_asked = False
+            rec.first = True
+        rec.epoch = self._epoch
+        self._unread.append(rec)
+
+    def read_begins(self, rec):
+        """The ``wait`` phase begins: a last probe says whether the read
+        will block."""
+        rec.read_t = self._now()
+        if rec.ready_hi is None:
+            if rec.toks.is_ready():
+                rec.ready_hi = self._now()
+            else:
+                rec.ready_lo = rec.read_t
+
+    def read_ends(self, rec) -> tuple:
+        """The tokens of ``rec`` are on the host. Returns
+        ``(device_tick_ms, device_starved_ms, device_unasked_ms,
+        device_clock_err_ms)`` and adds them to the totals."""
+        if rec.ready_hi is None:        # the read blocked: ready now
+            rec.ready_lo = rec.ready_hi = self._now()
+        self._unread.remove(rec)
+        lo, hi = rec.ready_lo, rec.ready_hi
+        prev, self._prev = self._prev, (lo, hi)
+        d = rec.dispatching_t
+        if prev is None:
+            start, gap, err = d, 0.0, 0.0
+        else:
+            # N ends after N-1 does: what N-1 was last seen not ready
+            # at bounds N from below too
+            lo = max(lo, prev[0])
+            before = (prev[0] + prev[1]) / 2
+            start, gap = max(d, before), max(0.0, d - before)
+            err = (prev[1] - prev[0]) / 2
+        # ... plus what of the call the device was free for
+        err += (hi - lo) / 2 + max(0.0, rec.dispatched_t - start)
+        tick = max(0.0, (lo + hi) / 2 - start)
+        out = (tick * 1e3, 0.0 if rec.dozed else gap * 1e3,
+               gap * 1e3 if rec.dozed else 0.0, err * 1e3)
+        if rec.epoch == self._epoch and not self._mark_asked:
+            self._add(rec, out, start, hi)
+        return out
+
+    def _add(self, rec, out, start, hi):
+        tick, starved, unasked, err = out
+        t = self._totals
+        origin = t.origin_t
+        if rec.first or origin is None:
+            # the sums run from the first dispatch after the mark (or
+            # the end of the tick the device was still running then):
+            # the gap before it is the time before the mark's
+            origin, starved, unasked = start, 0.0, 0.0
+        by = dict(t.by_program)
+        total, n = by.get(rec.program, (0.0, 0))
+        by[rec.program] = (total + tick, n + 1)
+        self._totals = _ClockTotals(
+            t.busy_ms + tick, t.starved_ms + starved,
+            t.unasked_ms + unasked, t.err_ms + err, t.ticks + 1,
+            t.exact + (err == 0.0), by, origin, hi)
+
+    # -- any thread ---------------------------------------------------------
+
+    def mark(self):
+        """Count from the next dispatch on (``mark_steady``)."""
+        self._mark_asked = True
+
+    def stats(self) -> dict:
+        """The ``device_*`` keys of :meth:`ServingEngine.stats`."""
+        t = self.ZERO if self._mark_asked else self._totals
+        whole = t.busy_ms + t.starved_ms + t.unasked_ms
+
+        def mean(program):
+            total, n = t.by_program.get(program, (0.0, 0))
+            return total / n if n else None
+
+        return {
+            "device_busy_ms": t.busy_ms,
+            "device_starved_ms": t.starved_ms,
+            "device_unasked_ms": t.unasked_ms,
+            "device_clock_err_ms": t.err_ms,
+            "device_starved_pct": (100.0 * t.starved_ms / whole
+                                   if whole else None),
+            "device_unasked_pct": (100.0 * t.unasked_ms / whole
+                                   if whole else None),
+            **{f"device_{p}_tick_ms": mean(p)
+               for p in ("decode", "mixed", "multi", "spec")},
+            "device_clock_exact_pct": (100.0 * t.exact / t.ticks
+                                       if t.ticks else None),
+            # first dispatch after the mark to the last read: what the
+            # three sums add up to, within device_clock_err_ms
+            "device_clock_span_ms": ((t.last_t - t.origin_t) * 1e3
+                                     if t.ticks else 0.0),
+            "device_clock_ticks": t.ticks,
+        }
 
 
 class ServingEngine:
@@ -1160,8 +1368,11 @@ class ServingEngine:
         want ``S x C`` dealt. A ``dict`` is taken as
         :class:`FIFOScheduler`'s arguments, for callers that build the
         engine from a file.
-      metrics: a :class:`MetricsWriter`; an in-memory one is created if
-        omitted (so :meth:`stats` always works).
+      metrics: a :class:`MetricsWriter` for the per-tick and per-request
+        JSONL rows. Omitted, none are written and the engine keeps no
+        list that grows with its ticks; :meth:`stats` reads its
+        ``ttft_ms`` and ``token_ms`` percentiles off the latest
+        ``STATS_RECENT`` observations either way.
       registry: the :class:`~distkeras_tpu.telemetry.MetricRegistry` the
         engine publishes into; defaults to the process-global one. Pass
         a fresh instance to isolate a run (benchmarks, tests).
@@ -1570,7 +1781,8 @@ class ServingEngine:
             )
         self._device = device if device is not None else jax.local_devices()[0]
         self._recompile_mark = recompiles.mark()
-        self._phase = _PhaseClock("engine.")
+        self._clock = _DeviceClock()
+        self._phase = _PhaseClock("engine.", self._clock.boundary)
         # what the mixed ticks computed against what they were dealt
         self.attended_tokens_total = 0
         self.query_positions_total = 0
@@ -1609,7 +1821,13 @@ class ServingEngine:
         self.scheduler.registry = self.registry
         self.scheduler._wire_metrics()
         self._wire_metrics()
-        self.metrics = metrics or MetricsWriter()
+        # the per-tick and per-request JSONL rows go to a writer the
+        # caller handed, and nowhere otherwise: a replica holds no list
+        # that grows with its ticks. stats()' two percentile keys read
+        # the latest observations
+        self.metrics = metrics
+        self._ttft_recent: deque = deque(maxlen=self.STATS_RECENT)
+        self._token_ms_recent: deque = deque(maxlen=self.STATS_RECENT)
         self._params_only = {"params": params["params"]}
         # what update_weights holds a push to: structure, shapes and
         # dtypes as handed, whatever the engine holds after its cast
@@ -2085,10 +2303,6 @@ class ServingEngine:
             "tokens emitted per tick dispatch (multi-step windows "
             "amortize the host round trip over up to k tokens)",
             buckets=(0, 1, 2, 3, 4, 6, 8, 12, 16, 24, 32))
-        self._m_multi_k = reg.gauge(
-            "serving_multi_step_k",
-            "window width of the latest reconciled dispatch (1 = "
-            "ordinary tick: multi-step off or fallen back)")
         self._m_multi_fallbacks = reg.counter(
             "serving_multi_step_fallbacks_total",
             "planned ticks that fell back to k=1, by the "
@@ -2470,8 +2684,10 @@ class ServingEngine:
         counts. Any nonzero :meth:`recompiles_since_mark` afterwards
         means a jitted serving function re-traced in steady state — a
         latency bug (``serve_bench --smoke`` asserts the dict is
-        empty)."""
+        empty). The device clock's sums (``stats()["device_*"]``) count
+        from the next dispatch on; the flight ring is left alone."""
         self._recompile_mark = recompiles.mark()
+        self._clock.mark()
 
     def recompiles_since_mark(self) -> dict:
         """Per-function jit traces since :meth:`mark_steady` (or engine
@@ -2506,15 +2722,16 @@ class ServingEngine:
         admitted, expired = self.scheduler.pop_admissible(
             len(free), admissible=admissible
         )
-        for req in expired:
-            # span chain, finish-reason counter, and the stream sentinel
-            # are recorded by the scheduler (expiry is visible in trace
-            # dumps even if no engine ever pops); the engine adds only
-            # its per-request JSONL summary
-            self.metrics.summary(
-                "request", rid=req.rid, reason="expired", tokens=0,
-                queued_ms=round((req.done_t - req.submit_t) * 1e3, 3),
-            )
+        if self.metrics is not None:
+            for req in expired:
+                # span chain, finish-reason counter, and the stream
+                # sentinel are recorded by the scheduler (expiry is
+                # visible in trace dumps even if no engine ever pops);
+                # the engine adds only its per-request JSONL summary
+                self.metrics.summary(
+                    "request", rid=req.rid, reason="expired", tokens=0,
+                    queued_ms=round((req.done_t - req.submit_t) * 1e3, 3),
+                )
         for req in admitted:
             self._prefill_into(free.pop(0), req)
         return len(admitted)
@@ -3319,6 +3536,7 @@ class ServingEngine:
             if spec_rows is not None and self.draft_kind != "model":
                 operands.append(jnp.asarray(drafts))
         with self._phase("dispatch", tick=tick_no, **span) as dispatch:
+            self._clock.dispatching()
             if spec_rows is not None:
                 if self.draft_kind == "model":
                     q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
@@ -3344,12 +3562,18 @@ class ServingEngine:
                 self._params_only, self._cache, self._last_logits,
                 self._rngs, *operands,
             )
-        return _InflightTick(
+        rec = _InflightTick(
             toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
             upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
             n_dec=n_dec, fed_tokens=fed_tokens, chunk=chunk, work=work,
-            multi_k=multi_k, acc=acc[0] if acc else None, **spec_rec,
+            multi_k=multi_k, acc=acc[0] if acc else None,
+            program=("spec" if spec_rows is not None
+                     else "multi" if multi_k is not None
+                     else "mixed" if fed_tokens else "decode"),
+            **spec_rec,
         )
+        self._clock.dispatched(rec)
+        return rec
 
     def _reconcile(self, rec: _InflightTick):
         """Materialize one dispatched tick and settle the host side:
@@ -3360,8 +3584,10 @@ class ServingEngine:
         an earlier reconcile, and record telemetry + the flight
         snapshot."""
         with self._phase("wait", tick=rec.tick) as wait:
+            self._clock.read_begins(rec)
             # forces completion of the tick
             toks_host = np.asarray(rec.toks)
+            clock = self._clock.read_ends(rec)
             counts_host = (np.asarray(rec.acc) if rec.multi_k is not None
                            else None)
             counters = getattr(self.model, "tick_counters", ())
@@ -3380,14 +3606,19 @@ class ServingEngine:
             occupancy = sum(st is not None for st in self._slots)
             self._occ_sum += occupancy
             now = time.monotonic()
+            # what the flight record has called device_ms since the
+            # alternating loop: the dispatch call plus what the overlap
+            # left of the read. The device's own time for the tick is
+            # the clock's
             device_ms = rec.dispatch_ms + wait_ms
+            device_tick_ms = clock[0]
             k = rec.multi_k or 1
             # multi-step windows: one readback carries up to k tokens per
             # row, each produced one scan step apart — attribute per-token
             # timestamps across the window's device span so the per-tier
-            # ITL histograms see k gaps of ~device_ms/k, not one lump and
-            # k-1 zeros (no k-wide ITL spikes in the QoS stats)
-            step_s = (device_ms / 1e3) / k
+            # ITL histograms see k gaps of ~device_tick_ms/k, not one lump
+            # and k-1 zeros (no k-wide ITL spikes in the QoS stats)
+            step_s = (device_tick_ms / 1e3) / k
             window_t0 = now - (k - 1) * step_s
             emitted = 0
             overrun = 0
@@ -3445,32 +3676,43 @@ class ServingEngine:
             self._m_ticks.inc()
             self._m_tokens.inc(emitted)
             self._m_occupancy.set(sum(st is not None for st in self._slots))
-            # serving_token_ms stays a PER-TOKEN series: a k-step window's
-            # device span covers k sampled tokens per live row
-            self._m_tick_ms.observe(device_ms / k)
             self._m_device_wait.observe(wait_ms)
             self.dispatches += 1
             self._m_dispatches.inc()
             self._m_tokens_per_dispatch.observe(emitted)
-            self._m_multi_k.set(k)
             if rec.chunk is not None and rec.fed_tokens + rec.n_dec > 0:
                 self._m_prefill_frac.observe(
                     rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
-            if device_ms > 0:
-                self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
-            log_kw = ({"prefill_tokens": rec.fed_tokens}
-                      if rec.chunk is not None else {})
-            self.metrics.log(
-                step=self.ticks, occupancy=occupancy,
-                queue_depth=queue_depth,
-                token_ms=round(device_ms / k, 3), **log_kw,
-            )
+            # serving_token_ms stays a PER-TOKEN series: a k-step window's
+            # device span covers k sampled tokens per live row
+            self._observe_token_ms(
+                device_tick_ms, k, emitted, occupancy, queue_depth,
+                **({"prefill_tokens": rec.fed_tokens}
+                   if rec.chunk is not None else {}))
         self._record_tick(
-            rec, device_ms=device_ms, stream_ms=stream.ms,
+            rec, device_ms=device_ms, clock=clock, stream_ms=stream.ms,
             emitted=emitted, occupancy=occupancy,
             queue_depth=queue_depth, device_wait_ms=wait_ms,
             overrun=overrun,
         )
+
+    def _observe_token_ms(self, device_tick_ms: float, k: int, emitted: int,
+                          occupancy: int, queue_depth: int, **log_kw):
+        """One tick's device time (the device clock's) a token, ``k``
+        of them a row in a multi-step window: into the registry, the
+        latest tick's token rate, what :meth:`stats` keeps, and the
+        per-tick row of a writer the caller handed."""
+        token_ms = device_tick_ms / k
+        self._m_tick_ms.observe(token_ms)
+        if device_tick_ms > 0:
+            self._m_decode_tps.set(
+                round(emitted / (device_tick_ms / 1e3), 3))
+        self._token_ms_recent.append(round(token_ms, 3))
+        if self.metrics is not None:
+            self.metrics.log(
+                step=self.ticks, occupancy=occupancy,
+                queue_depth=queue_depth, token_ms=round(token_ms, 3),
+                **log_kw)
 
     def _stream_row(self, s: int, st: _SlotState, toks_row, now,
                     defer: Optional[list] = None, times=None):
@@ -3725,8 +3967,10 @@ class ServingEngine:
         settles here."""
         k = self.spec_k
         with self._phase("wait", tick=rec.tick) as wait:
+            self._clock.read_begins(rec)
             # forces completion of the tick
             toks_host = np.asarray(rec.toks)
+            clock = self._clock.read_ends(rec)
             acc_host = np.asarray(rec.acc)
             rec.toks = rec.acc = None  # freed here, as in _reconcile
         wait_ms = wait.ms
@@ -3799,7 +4043,6 @@ class ServingEngine:
             self._m_ticks.inc()
             self._m_tokens.inc(emitted)
             self._m_occupancy.set(sum(st is not None for st in self._slots))
-            self._m_tick_ms.observe(device_ms)
             self._m_device_wait.observe(wait_ms)
             self.dispatches += 1
             self._m_dispatches.inc()
@@ -3807,17 +4050,12 @@ class ServingEngine:
             if rec.fed_tokens + rec.n_dec > 0:
                 self._m_prefill_frac.observe(
                     rec.fed_tokens / (rec.fed_tokens + rec.n_dec))
-            if device_ms > 0:
-                self._m_decode_tps.set(round(emitted / (device_ms / 1e3), 3))
-            self.metrics.log(
-                step=self.ticks, occupancy=occupancy,
-                queue_depth=queue_depth,
-                token_ms=round(device_ms, 3),
+            self._observe_token_ms(
+                clock[0], 1, emitted, occupancy, queue_depth,
                 prefill_tokens=rec.fed_tokens,
-                draft_tokens=proposed, accepted_tokens=accepted,
-            )
+                draft_tokens=proposed, accepted_tokens=accepted)
         self._record_tick(
-            rec, device_ms=device_ms, stream_ms=stream.ms,
+            rec, device_ms=device_ms, clock=clock, stream_ms=stream.ms,
             emitted=emitted, occupancy=occupancy,
             queue_depth=queue_depth, device_wait_ms=wait_ms,
             draft_tokens=proposed, accepted_tokens=accepted,
@@ -4001,11 +4239,14 @@ class ServingEngine:
             self._m_qos_critical.labels(tier=req.tier, phase=ph).observe(ms)
         self._m_requests.labels(reason=reason).inc()
         req.stream._finish(reason)
-        self.metrics.summary(
-            "request", rid=req.rid, reason=reason, tokens=req.n_emitted,
-            ttft_ms=round((req.first_token_t - req.submit_t) * 1e3, 3),
-            total_ms=round((req.done_t - req.submit_t) * 1e3, 3),
-        )
+        ttft_ms = round((req.first_token_t - req.submit_t) * 1e3, 3)
+        self._ttft_recent.append(ttft_ms)
+        if self.metrics is not None:
+            self.metrics.summary(
+                "request", rid=req.rid, reason=reason,
+                tokens=req.n_emitted, ttft_ms=ttft_ms,
+                total_ms=round((req.done_t - req.submit_t) * 1e3, 3),
+            )
 
     def _release_blocks(self, st: _SlotState):
         """Finish-time block bookkeeping: register the prompt's full
@@ -4033,6 +4274,7 @@ class ServingEngine:
     # -- observability ------------------------------------------------------
 
     MEM_SAMPLE_EVERY = 32  # ticks between /proc + device-allocator reads
+    STATS_RECENT = 4096    # observations stats()' percentiles are over
 
     def _slot_snaps(self) -> list:
         """Per-slot state for the flight snapshot: None (idle) or a
@@ -4076,7 +4318,8 @@ class ServingEngine:
         return self._mem.summary()
 
     def _record_tick(self, rec: _InflightTick, *, device_ms: float,
-                     stream_ms: float, emitted: int, occupancy: int,
+                     clock: tuple, stream_ms: float, emitted: int,
+                     occupancy: int,
                      queue_depth: int, device_wait_ms: float,
                      draft_tokens: Optional[int] = None,
                      accepted_tokens: Optional[int] = None,
@@ -4088,7 +4331,13 @@ class ServingEngine:
         ``stats()["flight"]["overhead_frac"]`` is that ratio, and
         ``serve_bench --smoke`` asserts it stays under 5%."""
         plan_ms = rec.plan_ms
-        with self._phase("record", tick=rec.tick):
+        device_tick_ms, starved_ms, unasked_ms, clock_err_ms = clock
+        # the clock's values ride the span: each estimate lies on the
+        # device trace's clock beside the operations it describes
+        with self._phase("record", tick=rec.tick, program=rec.program,
+                         device_tick_ms=device_tick_ms,
+                         device_starved_ms=starved_ms,
+                         device_unasked_ms=unasked_ms):
             self._tick_ns += int((plan_ms + device_ms + stream_ms) * 1e6)
             # runtime introspection runs with or without a recorder (the
             # gauges are its output); only the snapshot build + ring append
@@ -4110,10 +4359,10 @@ class ServingEngine:
             # into the critical-path "device" phase (a finished row freed
             # earlier in this step misses its final share; attribution,
             # not accounting)
-            if device_ms > 0.0:
+            if device_tick_ms > 0.0:
                 live = [st for st in self._slots if st is not None]
                 if live:
-                    share = device_ms / len(live)
+                    share = device_tick_ms / len(live)
                     for st in live:
                         st.req.device_ms_accum += share
             snap = None
@@ -4128,6 +4377,14 @@ class ServingEngine:
                     "tick_ms": plan_ms + device_ms + stream_ms,
                     "plan_ms": plan_ms, "device_ms": device_ms,
                     "stream_ms": stream_ms,
+                    # the device clock: which program ran, the device's
+                    # own time for it, and how long the device had
+                    # nothing queued before it (late host / nobody asked)
+                    "program": rec.program,
+                    "device_tick_ms": device_tick_ms,
+                    "device_starved_ms": starved_ms,
+                    "device_unasked_ms": unasked_ms,
+                    "device_clock_err_ms": clock_err_ms,
                     "occupancy": occupancy, "queue_depth": queue_depth,
                     "queue_oldest_wait_s": oldest,
                     # per-tier backlog: a postmortem can show the batch
@@ -4270,8 +4527,13 @@ class ServingEngine:
             "mean_occupancy": (
                 round(self._occ_sum / self.ticks, 3) if self.ticks else 0.0
             ),
-            "ttft_ms": self.metrics.percentiles("ttft_ms"),
-            "token_ms": self.metrics.percentiles("token_ms"),
+            # over the latest STATS_RECENT finished requests / ticks
+            "ttft_ms": percentiles(list(self._ttft_recent)),
+            "token_ms": percentiles(list(self._token_ms_recent)),
+            # the device clock since mark_steady(): the device's time by
+            # program and the time it had nothing queued (see
+            # _DeviceClock)
+            **self._clock.stats(),
             # bucket-interpolated stream-gap percentiles; None until two
             # tokens of one stream have been emitted (the registry
             # histogram keeps the full distribution)

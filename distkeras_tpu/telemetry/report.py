@@ -368,6 +368,30 @@ def report_flight(path: str, last: Optional[int] = None,
                        for k, v in by_phase.items())
             + "\n"
         )
+    clocked = [r for r in ticks if "device_tick_ms" in r]
+    if clocked:
+        # the device clock: the device's own time for a tick by
+        # program, and the share of it all in which the device had
+        # nothing queued because the host was late (starved) or nobody
+        # asked (unasked); exact = both reads blocked, error 0
+        by_program: dict = {}
+        for r in clocked:
+            by_program.setdefault(r.get("program", "?"), []).append(
+                float(r["device_tick_ms"]))
+        starved = sum(float(r["device_starved_ms"]) for r in clocked)
+        unasked = sum(float(r["device_unasked_ms"]) for r in clocked)
+        whole = (sum(map(sum, by_program.values())) + starved
+                 + unasked) or 1e-9
+        exact = sum(not r["device_clock_err_ms"] for r in clocked)
+        out.write(
+            "device tick: "
+            + ", ".join(f"{prog} p50 {_percentile(ms, 50):.2f} "
+                        f"p95 {_percentile(ms, 95):.2f}"
+                        for prog, ms in sorted(by_program.items()))
+            + f"; starved {100 * starved / whole:.1f} %, "
+            f"unasked {100 * unasked / whole:.1f} %, "
+            f"exact {100 * exact / len(clocked):.1f} %\n"
+        )
     walked = [r for r in ticks if r.get("cache_positions")]
     if walked:
         # mixed ticks: K/V positions the attend copied in (every row's

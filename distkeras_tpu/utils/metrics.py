@@ -16,6 +16,24 @@ import time
 from typing import Dict, List, Optional
 
 
+def percentiles(values, ps=(50, 90, 99)) -> Optional[Dict[str, float]]:
+    """``{"p50": ..., "p90": ..., "p99": ...}`` of ``values`` (linear
+    interpolation, numpy convention) over the finite ones; None where
+    there is none."""
+    vals = sorted(v for v in map(float, values) if math.isfinite(v))
+    if not vals:
+        return None
+    out: Dict[str, float] = {}
+    for p in ps:
+        rank = (len(vals) - 1) * p / 100.0
+        lo = int(rank)
+        hi = min(lo + 1, len(vals) - 1)
+        out[f"p{p}"] = round(
+            vals[lo] + (vals[hi] - vals[lo]) * (rank - lo), 6
+        )
+    return out
+
+
 class MetricsWriter:
     """Append-only JSONL metrics sink with wall-clock and throughput
     bookkeeping. Thread-safe: async trainers share one writer across N
@@ -70,21 +88,8 @@ class MetricsWriter:
         serving ITL report depends on None for scenarios that produced
         no decode ticks)."""
         with self._lock:
-            vals = sorted(
-                v for r in self._records if key in r
-                for v in (float(r[key]),) if math.isfinite(v)
-            )
-        if not vals:
-            return None
-        out: Dict[str, float] = {}
-        for p in ps:
-            rank = (len(vals) - 1) * p / 100.0
-            lo = int(rank)
-            hi = min(lo + 1, len(vals) - 1)
-            out[f"p{p}"] = round(
-                vals[lo] + (vals[hi] - vals[lo]) * (rank - lo), 6
-            )
-        return out
+            values = [r[key] for r in self._records if key in r]
+        return percentiles(values, ps)
 
     def throughput(self) -> Optional[float]:
         """Overall samples/sec across logged records (None without samples)."""

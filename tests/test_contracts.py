@@ -339,7 +339,7 @@ def test_hostsync_tainted_int_cast_in_plan_body(tmp_path):
     """int() of a value produced by the dispatched tick is a
     one-element sync; int() of host state (lengths, numpy lookups like
     the n-gram drafter's) stays legal — the real engine is clean."""
-    anchor = ("        return _InflightTick(\n"
+    anchor = ("        rec = _InflightTick(\n"
               "            toks=toks, rows=rows, tick=tick_no, "
               "plan_ms=plan.ms,\n")
     e = _mutate(tmp_path, ENGINE, anchor,
