@@ -9,11 +9,11 @@ deepseek_v32.py`` follows them in plain float32; this module is the
 program. What it does differently from the plain form, with the same
 mathematics:
 
-- **Absorbed attention.** The cache holds per position one latent ``[c_kv
-  | rope(k_r)]`` (``kv_lora_rank + qk_rope_head_dim`` wide) and one
-  index key; ``W_ukv``'s key half is folded into the query and its
-  value half into the output, so every tick kind attends the latent
-  itself: one shared 576-wide key under all heads
+- **Absorbed attention.** The cache holds per position one latent entry
+  ``[c_kv | rope(k_r)]`` (``kv_lora_rank + qk_rope_head_dim`` values,
+  in two leaves) and one index key; ``W_ukv``'s key half is folded into
+  the query and its value half into the output, so every tick kind
+  attends the latent itself: one shared 576-wide key under all heads
   (:mod:`distkeras_tpu.ops.mla`).
 - **Selection by threshold.** The positions a query may attend are
   those whose index score is at least the query's ``index_topk``-th
@@ -34,8 +34,11 @@ published checkpoint interleaves MLA's pairs: a fixed permutation of
 prediction module.
 
 Cache leaves a layer (collection ``cache``, decode mode): ``latent [S,
-L, 576]``, ``index_key [S, L, 128]`` and the cursor ``cache_index [S]``;
-rope is applied at each row's own cursor. The residual stream and the
+L, 512]`` (the compressed values), ``rope_key [S, 64, L]`` (the rotated
+key half, positions minor), ``index_key [S, L, 128]`` and the cursor
+``cache_index [S]``; every minor axis is whole 128-lane groups, which
+the chip stores as declared (see :meth:`LatentAttention.cache`). Rope
+is applied at each row's own cursor. The residual stream and the
 norms are float32; matmul operands are ``dtype`` with float32
 accumulation; router scores and logits are float32.
 
@@ -122,19 +125,27 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def cache(self, B: int):
-        """The decode cache's three leaves for ``B`` rows."""
+        """The decode cache's four leaves for ``B`` rows. A position's
+        latent entry lies in two, each with whole 128-lane groups minor:
+        the chip pads no minor axis, so one leaf of 512 + 64 it stored
+        positions-minor, and every layer's walk copied the pool into the
+        layout it reads and back. The 64 rope channels are stored
+        positions-minor on purpose: a tile of them is the score's ``[d,
+        t]`` operand as it lies (:func:`mla.write_positions_minor`
+        writes it)."""
         L, dt = self.cache_len, self.dtype
         return (
             self.variable("cache", "latent", jnp.zeros,
-                          (B, L, self.kv_lora_rank + self.qk_rope_head_dim),
-                          dt),
+                          (B, L, self.kv_lora_rank), dt),
+            self.variable("cache", "rope_key", jnp.zeros,
+                          (B, self.qk_rope_head_dim, L), dt),
             self.variable("cache", "index_key", jnp.zeros,
                           (B, L, self.index_head_dim), dt),
             self.variable("cache", "cache_index",
                           lambda: jnp.zeros((B,), jnp.int32)))
 
     def _starts(self, B: int):
-        return (self.cache(B)[2].value if self.decode
+        return (self.cache(B)[-1].value if self.decode
                 else jnp.zeros((B,), jnp.int32))
 
     def positions(self, B: int, T: int):
@@ -202,9 +213,10 @@ class LatentAttention(nn.Module):
         over ``out`` if given
         (:func:`mla.sparse_latent_attention_packed`)."""
         B, T = entry.shape[:2]
+        R = self.kv_lora_rank
         starts = self._starts(B)
         if self.decode:
-            latent, index_key, cursor = self.cache(B)
+            latent, rope_key, index_key, cursor = self.cache(B)
             L = self.cache_len
             with jax.named_scope("cache_update"):
                 # each row's valid tokens land at its cursor; a chunk's
@@ -213,27 +225,30 @@ class LatentAttention(nn.Module):
                        else valid_lens)
                 at = jnp.where(jnp.arange(T)[None, :] < fed[:, None], pos, L)
                 rows = jnp.arange(B)[:, None]
-                latent.value = latent.value.at[rows, at].set(entry,
-                                                             mode="drop")
-                index_key.value = index_key.value.at[rows, at].set(
-                    ki, mode="drop")
+                for leaf, new in ((latent, entry[..., :R]), (index_key, ki)):
+                    leaf.value = leaf.value.at[rows, at].set(
+                        new, mode="drop")
+                rope_key.value = mla.write_positions_minor(
+                    rope_key.value, entry[..., R:], starts, fed)
                 cursor.value = starts + fed
-            held, keys = latent.value, index_key.value
+            held, rot, keys = latent.value, rope_key.value, index_key.value
             tile = min(self.kv_tile, L)
         else:
             # no cache: the sequence itself, padded to whole tiles
             tile = min(self.kv_tile, T)
             pad = (-T) % tile
-            held = jnp.pad(entry, ((0, 0), (0, pad), (0, 0)))
-            keys = jnp.pad(ki, ((0, 0), (0, pad), (0, 0)))
+            held, rot, keys = (
+                jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                for x in (entry[..., :R], entry[..., R:], ki))
+            rot = rot.swapaxes(1, 2)
         walk = dict(topk=self.index_topk, tile=tile,
-                    scale=self.softmax_scale, rank=self.kv_lora_rank)
+                    scale=self.softmax_scale)
         if offsets is None:
             return mla.sparse_latent_attention(
-                q_full, qi, w, held, keys, starts, valid_lens, **walk)
+                q_full, qi, w, held, rot, keys, starts, valid_lens, **walk)
         return mla.sparse_latent_attention_packed(
-            q_full, qi, w, held, keys, starts, valid_lens, offsets, T, out,
-            **walk)
+            q_full, qi, w, held, rot, keys, starts, valid_lens, offsets, T,
+            out, **walk)
 
     def output(self, out):
         """Per token: the attend's ``[..., H, rank]`` through the value

@@ -5,10 +5,15 @@ absorbed attend itself (one shared 576-wide "KV head" under all query
 heads), each walking a row's cache in tiles up to the row's cursor and
 no further.
 
-A row of the slot cache holds, per position, one latent ``[c_kv |
-rope(k_r)]`` (``kv_lora_rank + rope`` wide) and one index key. For a
-chunk of ``C`` queries of row ``r`` at absolute positions ``start ..
-start + C - 1``:
+A row of the slot cache holds, per position, one latent entry ``[c_kv |
+rope(k_r)]`` and one index key. The entry lies in two leaves, each with
+a minor axis of whole 128-lane groups (PR 40): ``latent [L, kv_lora_rank]``
+(512) and ``rope_keys [rope, L]`` (positions minor: a tile of it is the
+score's ``[d, t]`` operand as it lies). One leaf 576 wide the chip
+stores positions-minor rather than pad it, and every layer's walk then
+copied the 453 MB pool into the layout it reads and back. For a chunk
+of ``C`` queries of row ``r`` at absolute positions ``start .. start +
+C - 1``:
 
 1. ``index_score``: ``I[t, s] = sum_j w[t, j] * relu(q_i[t, j] .
    k_i[s])`` for the positions ``s`` the row holds (scope
@@ -18,9 +23,9 @@ start + C - 1``:
    no sort, no approximation; scope ``index_select``). A query with no
    more than ``topk`` positions gets threshold 0 and sees them all;
 3. ``mla_attend``: an online-softmax walk of the latent tiles with the
-   mask ``key >= threshold and s <= t`` (scope ``mla_attend``). Values
-   are the first ``kv_lora_rank`` channels of the same tile, so a tile
-   is read once.
+   mask ``key >= threshold and s <= t`` (scope ``mla_attend``). The
+   score is ``q_lat . c_kv + q_rope . rope(k_r)``, two products summed
+   in float32; values are the latent tile itself, so it is read once.
 
 Everything is plain ``jax.numpy`` under ``lax`` loops with trip counts
 read from the cursors: XLA compiles one program for every context
@@ -50,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG = -1e30
+LANES = 128  # a TPU register's minor axis: what a leaf's minor axis fills
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
@@ -119,15 +125,18 @@ def kth_largest_key(keys, k: int):
                              jnp.zeros(keys.shape[:1], jnp.uint32))
 
 
-def _row_walk(q, qi, w, latent, index_keys, start, n, *, topk: int,
-              tile: int, scale: float, rank: int):
-    """One row's chunk: ``q [C, H, D]`` absorbed queries, ``qi [C, J,
-    Di]`` index queries, ``w [C, J]`` float32 head weights, against the
-    row's own ``latent [L, D]`` and ``index_keys [L, Di]`` up to position
-    ``n`` (exclusive); query ``c`` sits at ``start + c``. Returns ``[C,
-    H, rank]`` float32 (zeros where the row holds nothing)."""
-    C, H, D = q.shape
+def _row_walk(q, qi, w, latent, rope_keys, index_keys, start, n, *,
+              topk: int, tile: int, scale: float):
+    """One row's chunk: ``q [C, H, rank + rope]`` absorbed queries, ``qi
+    [C, J, Di]`` index queries, ``w [C, J]`` float32 head weights,
+    against the row's own ``latent [L, rank]``, ``rope_keys [rope, L]``
+    (positions minor) and ``index_keys [L, Di]`` up to position ``n``
+    (exclusive); query ``c`` sits at ``start + c``. Returns ``[C, H,
+    rank]`` float32 (zeros where the row holds nothing)."""
+    C, H, _ = q.shape
     L, Di = index_keys.shape
+    rank, rope = latent.shape[1], rope_keys.shape[0]
+    q_lat, q_rope = q[..., :rank], q[..., rank:]
     qpos = start + jnp.arange(C)
     tiles = (n + tile - 1) // tile
 
@@ -150,21 +159,28 @@ def _row_walk(q, qi, w, latent, index_keys, start, n, *, topk: int,
     with jax.named_scope("mla_attend"):
         def attend(i, carry):
             m, l, acc = carry
-            kt = jax.lax.dynamic_slice(latent, (i * tile, 0), (tile, D))
+            kt = jax.lax.dynamic_slice(latent, (i * tile, 0), (tile, rank))
+            rt = jax.lax.dynamic_slice(rope_keys, (0, i * tile),
+                                       (rope, tile))
             ok = (jax.lax.dynamic_slice(keys, (0, i * tile), (C, tile))
                   >= threshold[:, None]) & causal(i)
-            # the tile is transposed here, behind a barrier: folded into
-            # the matmul, the transpose becomes a layout that the
-            # compiler gives the whole cache and copies every tick
-            s = jnp.einsum("chd,dt->cht", q,
-                           jax.lax.optimization_barrier(kt.T),
-                           preferred_element_type=jnp.float32) * scale
+            # the latent tile is transposed here, behind a barrier:
+            # folded into the matmul, the transpose becomes a layout
+            # that the compiler gives the whole cache and copies every
+            # tick (the rope tile lies transposed in its leaf). The
+            # score is the 576-wide product in its two halves, both
+            # accumulated in float32
+            s = (jnp.einsum("chd,dt->cht", q_lat,
+                            jax.lax.optimization_barrier(kt.T),
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("chd,dt->cht", q_rope, rt,
+                              preferred_element_type=jnp.float32)) * scale
             s = jnp.where(ok[:, None, :], s, NEG)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.where(ok[:, None, :], jnp.exp(s - m_new[..., None]), 0.0)
             fade = jnp.exp(m - m_new)
             acc = acc * fade[..., None] + jnp.einsum(
-                "cht,tv->chv", p.astype(kt.dtype), kt[:, :rank],
+                "cht,tv->chv", p.astype(kt.dtype), kt,
                 preferred_element_type=jnp.float32)
             return m_new, l * fade + p.sum(axis=-1), acc
 
@@ -176,55 +192,86 @@ def _row_walk(q, qi, w, latent, index_keys, start, n, *, topk: int,
         return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
-def sparse_latent_attention(q, qi, w, latent, index_keys, starts,
-                            valid_lens, *, topk: int, tile: int,
-                            scale: float, rank: int):
+def write_positions_minor(leaf, new, starts, fed):
+    """``leaf [S, D, L]`` (positions minor) with the first ``fed [S]``
+    of each row's ``new [S, T, D]`` entries written at positions
+    ``starts [S]`` on; what would land past ``L`` is dropped.
+
+    A row's write is one window of whole 128-lane groups about its
+    cursor, read, overlaid and written back in place, a row at a time:
+    a scatter of single positions wants them major, and a gather of the
+    rows' windows wants a layout of its own, so that either makes the
+    compiler copy the whole leaf in and out, every layer and tick."""
+    S, D, L = leaf.shape
+    T = new.shape[1]
+    W = min(L, -(-(T + LANES - 1) // LANES) * LANES)
+    first = jnp.clip(starts // LANES * LANES, 0, L - W)
+    # the window's lane j takes entry j - (cursor - first) where fed
+    c = jnp.arange(W)[None, :] - (starts - first)[:, None]  # [S, W]
+    live = (c >= 0) & (c < fed[:, None])
+    placed = jnp.take_along_axis(
+        new, jnp.clip(c, 0, T - 1)[:, :, None], axis=1).swapaxes(1, 2)
+
+    def row(s, leaf):
+        at = (s, 0, first[s])
+        old = jax.lax.dynamic_slice(leaf, at, (1, D, W))
+        return jax.lax.dynamic_update_slice(
+            leaf, jnp.where(live[s], placed[s], old[0])[None], at)
+
+    return jax.lax.fori_loop(0, S, row, leaf)
+
+
+def sparse_latent_attention(q, qi, w, latent, rope_keys, index_keys,
+                            starts, valid_lens, *, topk: int, tile: int,
+                            scale: float):
     """``[S, C, H, rank]``: every row's chunk of queries attended over
     the positions the indexer selects among those the row holds.
 
     ``q [S, C, H, D]`` (absorbed: ``D = rank + rope``), ``qi [S, C, J,
-    Di]``, ``w [S, C, J]``; ``latent [S, L, D]`` and ``index_keys [S, L,
-    Di]`` already hold this chunk's own entries; ``starts [S]`` are the
+    Di]``, ``w [S, C, J]``; ``latent [S, L, rank]``, ``rope_keys [S,
+    rope, L]`` and ``index_keys [S, L, Di]`` already hold this chunk's
+    own entries; ``starts [S]`` are the
     cursors before the chunk and ``valid_lens [S]`` how many of its
     ``C`` tokens each row feeds (``None``: all). ``L`` is a multiple of
     ``tile``. A row that feeds at most one token walks with its first
     query alone; the other outputs of such a row are zeros, which
     nothing reads."""
-    S, C, H, D = q.shape
+    S, C, H, _ = q.shape
+    rank = latent.shape[-1]
     if latent.shape[1] % tile:
         raise ValueError(f"cache length {latent.shape[1]} is no multiple of "
                          f"the walk's tile {tile}")
     valid = (jnp.full((S,), C, jnp.int32) if valid_lens is None
              else valid_lens)
-    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale,
-                             rank=rank)
+    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale)
 
     def one(args):
         # the row's own slices of the cache (the scan hands them out): the
         # walk's loops then hold a row, not the pool
-        qr, qir, wr, start, fed, lat, keys = args
+        qr, qir, wr, start, fed, lat, rot, keys = args
         # a row that feeds nothing (idle, or starved of budget) walks no
         # tile, wherever its cursor was left
         n = jnp.where(fed > 0, start + fed, 0)
 
         def chunk(_):
-            return walk(qr, qir, wr, lat, keys, start, n)
+            return walk(qr, qir, wr, lat, rot, keys, start, n)
 
         def single(_):
-            first = walk(qr[:1], qir[:1], wr[:1], lat, keys, start, n)
+            first = walk(qr[:1], qir[:1], wr[:1], lat, rot, keys, start, n)
             return jnp.zeros((C, H, rank), jnp.float32).at[:1].set(first)
 
         if C == 1:
             return chunk(None)
         return jax.lax.cond(fed <= 1, single, chunk, None)
 
-    return jax.lax.map(one, (q, qi, w, starts, valid, latent, index_keys))
+    return jax.lax.map(one, (q, qi, w, starts, valid, latent, rope_keys,
+                             index_keys))
 
 
-def sparse_latent_attention_packed(q, qi, w, latent, index_keys, starts,
-                                   valid_lens, offsets, chunk: int,
+def sparse_latent_attention_packed(q, qi, w, latent, rope_keys, index_keys,
+                                   starts, valid_lens, offsets, chunk: int,
                                    out=None, *, topk: int, tile: int,
-                                   scale: float, rank: int):
+                                   scale: float):
     """:func:`sparse_latent_attention` over PACKED queries, for a mixed
     tick whose per-token layers run over the live tokens packed to the
     front: ``q [N, H * D]`` (a token's heads side by side), ``qi [N, J,
@@ -244,16 +291,16 @@ def sparse_latent_attention_packed(q, qi, w, latent, index_keys, starts,
     that the layouts the walk's and the projections' matmuls want are
     given to a row's slice or a block, not to all ``N`` rows in a copy."""
     N = q.shape[0]
-    D = latent.shape[-1]
+    rank = latent.shape[-1]
+    D = rank + rope_keys.shape[1]
     H = q.shape[1] // D
     if latent.shape[1] % tile:
         raise ValueError(f"cache length {latent.shape[1]} is no multiple of "
                          f"the walk's tile {tile}")
-    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale,
-                             rank=rank)
+    walk = functools.partial(_row_walk, topk=topk, tile=tile, scale=scale)
 
     def one(out, args):
-        offset, start, fed, lat, keys = args
+        offset, start, fed, lat, rot, keys = args
         n = jnp.where(fed > 0, start + fed, 0)
 
         def rows(c):
@@ -263,7 +310,8 @@ def sparse_latent_attention_packed(q, qi, w, latent, index_keys, starts,
             qr, qir, wr = jax.lax.optimization_barrier(tuple(
                 jax.lax.dynamic_slice_in_dim(t, offset, c)
                 for t in (q, qi, w)))
-            res = walk(qr.reshape(c, H, D), qir, wr, lat, keys, start, n)
+            res = walk(qr.reshape(c, H, D), qir, wr, lat, rot, keys, start,
+                       n)
             return jax.lax.dynamic_update_slice_in_dim(
                 out, res.astype(out.dtype).reshape(c, H * rank), offset, 0)
 
@@ -274,7 +322,8 @@ def sparse_latent_attention_packed(q, qi, w, latent, index_keys, starts,
     if out is None:
         out = jnp.zeros((N, H * rank), q.dtype)
     return jax.lax.scan(
-        one, out, (offsets, starts, valid_lens, latent, index_keys))[0]
+        one, out, (offsets, starts, valid_lens, latent, rope_keys,
+                   index_keys))[0]
 
 
 def fetched_positions(starts, valid, tile: int) -> int:

@@ -14,6 +14,8 @@ may make the call.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +84,12 @@ def paged_shapes(B, T, H, Hk, hd, bs, quant, pages=512, max_blocks=64):
 def splash_shapes(B, T, H, Hk, hd, L):
     kv = ((B, L, Hk, hd), BF16)
     return [((B, T, H, hd), BF16), kv, kv, ((B,), I32)]
+
+
+def _abstract(tree, sharding):
+    """``tree``'s shapes and dtypes as arguments placed by ``sharding``."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
 
 
 def grad_of(attend):
@@ -273,14 +281,10 @@ def test_the_packed_tick_at_the_benchmarks_shapes(for_chip, monkeypatch,
     held = jax.eval_shape(lambda p: compute_params(model, p),
                           {"params": shapes["params"]})
 
-    def abstract(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    args = abstract((held, shapes["cache"],
-                     jax.ShapeDtypeStruct((S, V), F32),
-                     jax.ShapeDtypeStruct((S, 2), jnp.uint32),
-                     jax.ShapeDtypeStruct((S * C + 2 * S,), I32)))
+    args = _abstract((held, shapes["cache"],
+                      jax.ShapeDtypeStruct((S, V), F32),
+                      jax.ShapeDtypeStruct((S, 2), jnp.uint32),
+                      jax.ShapeDtypeStruct((S * C + 2 * S,), I32)), one_chip)
     cfgs = ((0.0, None, None),) * S
 
     def matmul_rows(live):
@@ -296,3 +300,119 @@ def test_the_packed_tick_at_the_benchmarks_shapes(for_chip, monkeypatch,
                               "16x1x50257"}
     assert matmul_rows(None) == {"16x64x6144", "16x64x2048", "16x64x8192",
                                  "16x64x50257"}
+
+
+# -- deepseek-v3.2-exp-serve: what the compiled ticks do to the cache ---------
+
+_CALLS = re.compile(r"(?:calls|body|condition|to_apply|true_computation|"
+                    r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_MOVE = re.compile(r"(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                   r"\{([\d,]*)[^ ]* (copy|transpose)\(")
+_LEAF = re.compile(r"%?([\w.\-]+) = bf16\[([\d,]*)\]\{([\d,]*)[^ ]* "
+                   r"parameter\(\d+\).*op_name=\"cache\[\\'layers_\d+\\'\]"
+                   r"\[\\'attn\\'\]\[\\'(\w+)\\'\]\"")
+
+
+def _moves(text):
+    """``(entry parameters of the cache, copies and transposes)`` of a
+    compiled module's text: each cache leaf as ``(leaf name, dims, minor
+    to major)``, each ``copy`` or ``transpose`` as ``(instruction, dims,
+    bytes, in a loop's body)``, a fusion's or a call's instructions
+    counted under the loop that reaches them."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        if line[:1] not in ("", " ", "}") and line.rstrip().endswith("{"):
+            head = re.match(r"(ENTRY\s+)?%?([\w.\-]+)", line)
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line.strip())
+    looped = set()
+    todo = [body for lines in comps.values() for line in lines
+            for body in re.findall(r" while\(.*body=%?([\w.\-]+)", line)]
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in looped:
+            looped.add(c)
+            for line in comps[c]:
+                todo += _CALLS.findall(line)
+                for group in _BRANCHES.findall(line):
+                    todo += [b.strip().lstrip("%") for b in group.split(",")]
+    leaves = [(m.group(4), _dims(m.group(2)), m.group(3))
+              for m in map(_LEAF.match, comps[entry]) if m]
+    size = {"bf16": 2, "pred": 1, "s8": 1, "u8": 1}  # else 4 bytes
+    moves = []
+    for c, lines in comps.items():
+        for m in filter(None, map(_MOVE.match, lines)):
+            dims = _dims(m.group(3))
+            moves.append((f"{m.group(5)} %{m.group(1)} {m.group(2)}"
+                          f"[{m.group(3)}]{{{m.group(4)}}} in %{c}", dims,
+                          math.prod(dims) * size.get(m.group(2), 4),
+                          c in looped))
+    return leaves, moves
+
+
+def _dims(text):
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+@pytest.mark.parametrize("C,temp_gb", [
+    (64, 1.7),   # the mixed tick, packed by blocks (live = 32 * 64)
+    (1, None),   # the decode tick
+])
+def test_the_latent_cache_is_read_where_it_lies(for_chip, one_chip, C,
+                                                temp_gb):
+    """chipbench's ``deepseek-v3.2-exp-serve`` at its real size (32
+    slots of 12 288 positions, five layers), both tick programs as the
+    engine builds them, from shapes alone: the chip stores every cache
+    leaf as the model declares it (a minor axis of whole 128-lane
+    groups), so no layer copies the pool into another layout and back.
+    One leaf ``[32, 12288, 576]`` was stored positions-minor, and ten
+    copies of 453 MB a tick were a fifth of the device's time (PR 40)."""
+    from chipbench.harness import spec
+    from distkeras_tpu.models import get_model
+    from distkeras_tpu.serving import engine
+
+    cfg = spec.load("configs", "deepseek-v3.2-exp-serve")
+    S, V = cfg["engine"]["slots"], cfg["model"]["vocab_size"]
+    L, m = cfg["engine"]["max_len"], cfg["model"]
+    model = get_model(spec.model_name(cfg), **m,
+                      dtype=jnp.dtype(cfg["compute_dtype"]))
+    dm = model.clone(decode=True, slot_cursor=True, parent=None)
+    cache = jax.eval_shape(dm.init, jax.random.PRNGKey(0),
+                           jnp.zeros((S, 1), I32))["cache"]
+    params = jax.eval_shape(
+        lambda: spec.reference(cfg).make_params(cfg, 0))["params"]
+    # the cache is what the configuration's `slots` line states
+    per_position = sum(math.prod(x.shape) * x.dtype.itemsize
+                       for x in jax.tree.leaves(cache) if x.ndim == 3)
+    assert per_position == S * L * 7040
+    args = _abstract(({"params": params}, cache,
+                      jax.ShapeDtypeStruct((S, V), F32),
+                      jax.ShapeDtypeStruct((S, 2), jnp.uint32),
+                      jax.ShapeDtypeStruct((S * C + 2 * S,), I32)), one_chip)
+    compiled = engine._mixed_tick_fn(
+        engine._CacheLayout(dm), (engine._IDLE_CFG,) * S, C, None,
+        S * C if C > 1 else None).lower(*args).compile()
+    leaves, moves = _moves(compiled.as_text())
+
+    # nothing the size of the 512-wide leaf (403 MB) or larger is moved
+    pool = S * L * m["kv_lora_rank"] * 2
+    assert [what for what, _, size, _ in moves if size >= pool] == []
+    # every cache leaf enters in the layout its shape declares
+    assert {name for name, _, _ in leaves} >= {"latent", "index_key"}
+    assert [leaf for leaf in leaves if leaf[2] != "2,1,0"] == []
+    # of the cache, at most the small leaf is moved, once in and out a layer
+    shapes = {tuple(sorted(dims)): name for name, dims, _ in leaves}
+    moved = [(shapes[tuple(sorted(dims))], what)
+             for what, dims, _, _ in moves if tuple(sorted(dims)) in shapes]
+    assert [x for x in moved if x[0] != "rope_key"] == []
+    assert len(moved) <= 10, moved
+    # the weights' relayouts are made once a tick, not once a block
+    weights = {x.shape for x in jax.tree.leaves(params) if x.size >= 1 << 20}
+    assert [what for what, dims, _, looped in moves
+            if looped and dims in weights] == []
+    if temp_gb is not None:
+        assert compiled.memory_analysis().temp_size_in_bytes <= temp_gb * 1e9

@@ -573,3 +573,56 @@ def test_the_walk_reads_whole_tiles_up_to_the_cursor_and_no_further():
     # rows at 0 (idle), 5 + 8 fed, 40 + 1 fed, 33 starved (feeds nothing)
     assert mla.fetched_positions([0, 5, 40, 33], [0, 8, 1, 0], 16) == (
         0 + 16 + 48 + 0)
+
+
+# -- the latent entry in two leaves of whole lanes ----------------------------
+
+
+def test_the_split_score_is_the_576_wide_score():
+    """The walk over ``latent [S, L, 512]`` and ``rope_key [S, 64, L]``
+    against one product over the 576-wide entries they were cut from:
+    the same products, summed in float32 in another order. ``topk`` is
+    the cache's length, so the selection lets every position through."""
+    S, C, H, R, rope, L, Di, J = 3, 8, 4, 512, 64, 128, 16, 2
+    rng = np.random.default_rng(40)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    q, entries = 0.1 * normal(S, C, H, R + rope), normal(S, L, R + rope)
+    qi, ki, w = normal(S, C, J, Di), normal(S, L, Di), normal(S, C, J)
+    starts = jnp.asarray([0, 37, L - C], jnp.int32)
+    got = mla.sparse_latent_attention(
+        q, qi, w, entries[..., :R], entries[..., R:].swapaxes(1, 2), ki,
+        starts, None, topk=L, tile=32, scale=0.3)
+    score = jnp.einsum("schd,std->scht", q, entries,
+                       preferred_element_type=jnp.float32) * 0.3
+    seen = (jnp.arange(L)[None, None, :]
+            <= (starts[:, None] + jnp.arange(C)[None])[:, :, None])
+    p = jax.nn.softmax(jnp.where(seen[:, :, None, :], score, -jnp.inf), -1)
+    want = jnp.einsum("scht,stv->schv", p, entries[..., :R])
+    assert got.shape == (S, C, H, R)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert float(jnp.abs(want).max()) > 0.5
+
+
+@pytest.mark.parametrize("L,T", [(64, 1), (64, 8), (512, 1), (512, 200)])
+def test_the_positions_minor_write_is_the_scatter_at_the_cursor(L, T):
+    """``mla.write_positions_minor`` (a window of whole lanes a row)
+    against the scatter the other leaves are written by: a row at 0,
+    one that feeds nothing, one mid-cache, one that ends at the cache's
+    last position and one that would run past it (dropped there)."""
+    D = 8
+    rng = np.random.default_rng(L + T)
+    starts = np.asarray([0, 17, L // 2 + 3, L - T, L - 1], np.int32)
+    fed = np.asarray([T, 0, max(T - 1, 1), T, T], np.int32)
+    S = len(starts)
+    leaf = jnp.asarray(rng.normal(size=(S, L, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(S, T, D)), jnp.float32)
+    at = np.where(np.arange(T)[None] < fed[:, None],
+                  starts[:, None] + np.arange(T)[None], L)
+    want = leaf.at[np.arange(S)[:, None], at].set(new, mode="drop")
+    got = jax.jit(mla.write_positions_minor)(
+        leaf.swapaxes(1, 2), new, jnp.asarray(starts), jnp.asarray(fed))
+    np.testing.assert_array_equal(got.swapaxes(1, 2), want)
+    assert not np.array_equal(want, leaf)

@@ -459,11 +459,14 @@ PARENT = {
                   "207a14ce",
         # PR 38: its [3, 8] tick packs by blocks (one block of 24 rows
         # here), a deliberate change; the full-width program was
-        # 3fe1ad9b...21875a and the [3, 1] tick is the parent's still
-        "mixed_8_24": "e239c0ed08077838f58739d1bcdc3bc03e77358b3bbf2ab8c79ab"
-                      "0cd0144c41c",
-        "mixed_1_None": "1f1b0d20c5954b1b3be9abbaae318fa96935464a34c97672a70"
-                        "b660132690d97"},
+        # 3fe1ad9b...21875a. PR 40: the latent entry lies in two cache
+        # leaves (latent [S, L, 16] and rope_key [S, 8, L] here), a
+        # deliberate change to both ticks; they were e239c0ed...44c41c
+        # and 1f1b0d20...690d97 (the [3, 1] tick's since 7ce0293)
+        "mixed_8_24": "c2d4df761bcae4b01f1d29b0db54254eb70f3c85027e31db1d49a"
+                      "7e18ce58d3d",
+        "mixed_1_None": "20e82ef3c0633748702ba3f7c8ffe397735dc6ac8fbdbd5ccea"
+                        "f67706b741664"},
     "transformer_lm": {
         "params": "c01fb3e08f7b1c7d216c5ce24b4a4ef04b8e65fb722a7ae887ffa156"
                   "8870f503",
