@@ -433,9 +433,11 @@ class DeepseekV32LM(nn.Module):
         lacks = {
             "paged": "a paged (block-pooled) latent cache: serving/"
                      "kvpool.py allocates [blocks, block, Hk, hd] K and V",
-            "draft": "speculative decoding: no draft path reads the latent "
-                     "cache, and the multi-token-prediction module that "
-                     "would draft is not built",
+            "draft": "speculative decoding: its multi-token-prediction "
+                     "module is not built, and a draft's window would "
+                     "have to pass the indexer's selection (the verify "
+                     "tick that reads a latent cache, draft='mtp', walks "
+                     "every position)",
             "mesh": "tensor parallelism: the latent is shared by all "
                     "heads, so heads are not split; replicas take batches",
             "multi_step": "multi-step decode windows: the expert layers' "

@@ -27,6 +27,12 @@ C - 1``:
    score is ``q_lat . c_kv + q_rope . rope(k_r)``, two products summed
    in float32; values are the latent tile itself, so it is read once.
 
+A model with NO selection (``glm4_moe_lite_lm``: every position up to
+the query's is attended, keys 192 + 64 against values 256 folded into
+the same 512 + 64 latent) takes :func:`dense_latent_attention`: step 3
+alone, with the rows that feed a token or a verify window walking
+together and the prompt chunks one by one.
+
 Everything is plain ``jax.numpy`` under ``lax`` loops with trip counts
 read from the cursors: XLA compiles one program for every context
 length, and a row that holds a third of ``max_len`` costs a third. No
@@ -324,6 +330,142 @@ def sparse_latent_attention_packed(q, qi, w, latent, rope_keys, index_keys,
     return jax.lax.scan(
         one, out, (offsets, starts, valid_lens, latent, rope_keys,
                    index_keys))[0]
+
+
+def _dense_tiles(q, latent, rope_keys, qpos, n, *, tile: int, scale: float):
+    """The absorbed attend with no selection, for ``B`` rows walking
+    together: ``q [B, Q, H, rank + rope]`` at positions ``qpos [B, Q]``
+    against ``latent [B, L, rank]`` and ``rope_keys [B, rope, L]``
+    (positions minor), each row over its positions below ``n [B]`` and
+    no later than the query's own. One loop over the tiles up to the
+    longest row's ``n``; a row that holds less is masked in the tiles
+    beyond it. Returns ``[B, Q, H, rank]`` float32 (zeros for a row
+    with ``n`` 0)."""
+    B, Q, H, _ = q.shape
+    rank, rope = latent.shape[-1], rope_keys.shape[1]
+    q_lat, q_rope = q[..., :rank], q[..., rank:]
+
+    def step(i, carry):
+        m, l, acc = carry
+        kt = jax.lax.dynamic_slice(latent, (0, i * tile, 0),
+                                   (B, tile, rank))
+        rt = jax.lax.dynamic_slice(rope_keys, (0, 0, i * tile),
+                                   (B, rope, tile))
+        at = (i * tile + jnp.arange(tile))[None, None, :]
+        ok = ((at <= qpos[:, :, None]) & (at < n[:, None, None]))[:, :, None]
+        # the latent tile transposed behind a barrier, the rope tile as
+        # it lies: see ``_row_walk``
+        s = (jnp.einsum("bqhd,bdt->bqht", q_lat,
+                        jax.lax.optimization_barrier(kt.swapaxes(1, 2)),
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhd,bdt->bqht", q_rope, rt,
+                          preferred_element_type=jnp.float32)) * scale
+        s = jnp.where(ok, s, NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        fade = jnp.exp(m - m_new)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bqht,btv->bqhv", p.astype(kt.dtype), kt,
+            preferred_element_type=jnp.float32)
+        return m_new, l * fade + p.sum(axis=-1), acc
+
+    with jax.named_scope("mla_attend"):
+        _, l, acc = jax.lax.fori_loop(
+            0, (jnp.max(n) + tile - 1) // tile, step,
+            (jnp.full((B, Q, H), NEG, jnp.float32),
+             jnp.zeros((B, Q, H), jnp.float32),
+             jnp.zeros((B, Q, H, rank), jnp.float32)))
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def dense_latent_attention(q, latent, rope_keys, starts, fed, offsets,
+                           chunk: int, *, small: int, tile: int,
+                           scale: float):
+    """The absorbed attend over a latent cache with NO selection: every
+    query sees every position its row holds up to its own. For a chunk,
+    for one query and for a verify window of ``small`` queries.
+
+    ``q [N + chunk, H * D]`` holds the tick's queries as rows of
+    features (``D = rank + rope``), row ``s``'s ``fed[s]`` of them from
+    ``offsets[s]`` on: ``offsets = s * chunk`` for a tick laid out ``[S,
+    chunk]``, the exclusive cumulative sum of ``fed`` where the live
+    tokens are packed; the ``chunk`` rows of tail let any row's slice of
+    ``chunk`` fit. ``latent [S, L, rank]`` and ``rope_keys [S, rope, L]``
+    already hold the fed tokens' own entries; ``starts [S]`` are the
+    cursors before them. Returns ``[N, H * rank]`` in ``q``'s dtype,
+    each token's result where its query was; rows no token lies on are
+    zeros or stale and nothing reads them.
+
+    The rows that feed at most ``small`` tokens (decoding rows, verify
+    windows) walk TOGETHER, ``small`` queries each, one loop over the
+    tiles up to the longest of them: a step reads the tile of every row
+    in one slice and multiplies it in one batched product, where a walk
+    a row is a dozen small kernels a step, a thousand steps a tick. The
+    rows that feed more (prompt chunks, a few a tick) walk one by one
+    with all ``chunk`` queries. A tick no wider than ``small`` is the
+    first kind alone."""
+    S, L, rank = latent.shape
+    D = rank + rope_keys.shape[1]
+    H = q.shape[1] // D
+    N = q.shape[0] - chunk
+    if L % tile:
+        raise ValueError(f"cache length {L} is no multiple of the walk's "
+                         f"tile {tile}")
+    walk = functools.partial(_dense_tiles, tile=tile, scale=scale)
+    ends = jnp.where(fed > 0, starts + fed, 0)
+    if chunk <= small:
+        if N != S * chunk:
+            raise ValueError("a tick no wider than the verify window is "
+                             "laid out [S, chunk], not packed")
+        # laid out [S, chunk]: the queries are the rows as they lie
+        res = walk(q[:N].reshape(S, chunk, H, D), latent, rope_keys,
+                   starts[:, None] + jnp.arange(chunk)[None], ends)
+        return res.astype(q.dtype).reshape(N, H * rank)
+    few = (fed > 0) & (fed <= small)
+    cols = jnp.arange(small)[None]
+    idx = offsets[:, None] + cols  # [S, small] rows of q
+    res = walk(q[idx].reshape(S, small, H, D), latent, rope_keys,
+               starts[:, None] + cols, jnp.where(few, ends, 0))
+    out = jnp.zeros((N + chunk, H * rank), q.dtype).at[
+        jnp.where(few[:, None] & (cols < fed[:, None]), idx,
+                  N + chunk).reshape(-1)].set(
+        res.astype(q.dtype).reshape(S * small, H * rank), mode="drop")
+    many = fed > small
+    order = jnp.argsort(~many, stable=True)
+    live = jnp.arange(chunk)[:, None]
+
+    def one(i, out):
+        r = order[i]
+        at = offsets[r]
+        # the row's queries and its cache behind a barrier: folded into
+        # the walk, the layouts its products want are given to all N
+        # rows and to the pool, in copies made again for every row
+        qr, lat, rot = jax.lax.optimization_barrier((
+            jax.lax.dynamic_slice_in_dim(q, at, chunk),
+            jax.lax.dynamic_slice_in_dim(latent, r, 1),
+            jax.lax.dynamic_slice_in_dim(rope_keys, r, 1)))
+        res = walk(qr.reshape(1, chunk, H, D), lat, rot,
+                   (starts[r] + jnp.arange(chunk))[None], ends[r][None])
+        old = jax.lax.dynamic_slice_in_dim(out, at, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(live < fed[r],
+                           res.astype(q.dtype).reshape(chunk, H * rank),
+                           old), at, 0)
+
+    return jax.lax.fori_loop(0, many.sum(), one, out)[:N]
+
+
+def dense_fetched_positions(starts, valid, tile: int, small: int) -> int:
+    """Cache positions one layer's :func:`dense_latent_attention` reads
+    in a tick, all rows of it: the rows that feed at most ``small``
+    tokens walk together to the longest of them (every row's tile is
+    read in every step), the others their own tiles (host arithmetic,
+    for the engine's ``key_positions_fetched``)."""
+    valid = np.asarray(valid, np.int64)
+    n = np.where(valid > 0, np.asarray(starts, np.int64) + valid, 0)
+    few = (valid > 0) & (valid <= small)
+    together = -(-int(n[few].max(initial=0)) // tile) * tile * len(n)
+    return together + int((-(-n[valid > small] // tile) * tile).sum())
 
 
 def fetched_positions(starts, valid, tile: int) -> int:

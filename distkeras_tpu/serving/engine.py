@@ -401,7 +401,8 @@ def _paged_prefill_fn(dm_paged, ctx: Optional[_ShardCtx] = None):
 _MODEL_WORK = ("index_positions_scored", "keys_selected", "routed_here",
                "routed_total", "expert_rows_computed", "full_key_positions",
                "window_key_positions", "state_rows_stepped",
-               "chunk_positions_live", "chunk_positions_computed")
+               "chunk_positions_live", "chunk_positions_computed",
+               "window_positions", "mtp_positions_fed")
 
 
 def _counter_sums(sown, names):
@@ -603,7 +604,9 @@ def _multi_tick_fn(layout, cfgs, k, ctx: Optional[_ShardCtx] = None):
 # is never fed — it becomes next tick's host-known pending token, which
 # is what lets the host (or the draft model) propose the next window
 # before the dispatch. Greedy rows accept a draft iff it IS the argmax,
-# so greedy streams are bit-identical to the non-speculative engine;
+# so greedy streams are the non-speculative engine's wherever a window
+# position's logits are the single tick's bit for bit (float32; in
+# bfloat16 they differ by rounding and the streams part at near-ties);
 # sampled rows are distributionally exact by the standard
 # rejection-sampling argument (Leviathan et al.). Rollback of rejected
 # suffixes is a cursor rewind only — rejected K/V bytes sit beyond the
@@ -643,68 +646,77 @@ def _spec_accept(cfgs, k, onehot_q, full, rngs, valid, n_forced,
     for rows that actually sampled (``sample_mask``) — prefill/idle
     rows keep their chains untouched.
 
+    The greedy rows are taken TOGETHER, in one batched comparison (a
+    loop a row unrolled ``S`` argmaxes over the vocabulary into the
+    program: 24 s of compile at 64 slots of a 150 k vocabulary); the
+    rows that sample go through :func:`_accept_sampled_row` one by one.
+
     Returns ``(out_toks [S, k+1], acc [S], new_last [S, V],
     new_rngs)``: out_toks rows are [accepted drafts..., z, 0 pad];
     new_last is uniformly ``full[s, n_forced + acc]`` — for prefill
     rows (acc 0, n_forced = valid) that is exactly the
     logits-at-last-valid-token rule of the mixed tick."""
+    S = len(cfgs)
+    cols = jnp.arange(k)[None]
+    n_draft = valid - n_forced
+    at = n_forced[:, None] + cols  # [S, k]: window position of draft i
+    # (a position past the window is masked by n_draft)
+    best = jnp.argmax(jnp.take_along_axis(full, at[..., None], axis=1),
+                      axis=-1).astype(jnp.int32)
+    ok = (draft_toks == best) & (cols < n_draft[:, None])
+    acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
+    new_last = jnp.take_along_axis(
+        full, (n_forced + acc)[:, None, None], axis=1)[:, 0]
+    z = jnp.argmax(new_last, axis=-1).astype(jnp.int32)
+    chains = jax.vmap(jax.random.split)(rngs)  # [S, 2]: the chain, the draw
+    for s, cfg in enumerate(cfgs):
+        if cfg[0] == 0.0:
+            continue
+        row = _accept_sampled_row(
+            cfg, k, full[s], chains[s, 1], n_draft[s], n_forced[s],
+            draft_toks[s], None if onehot_q else q_probs[s])
+        acc, z, new_last = (held.at[s].set(new) for held, new in zip(
+            (acc, z, new_last), row))
+    pos = jnp.arange(k + 1)[None]
+    out = jnp.where(
+        pos < acc[:, None],
+        jnp.concatenate([draft_toks, jnp.zeros((S, 1), jnp.int32)], axis=1),
+        jnp.where(pos == acc[:, None], z[:, None], 0))
+    return out, acc, new_last, jnp.where(sample_mask[:, None],
+                                         chains[:, 0], rngs)
+
+
+def _accept_sampled_row(cfg, k, full, key, n_draft, n_forced, d, q):
+    """One sampling row of :func:`_spec_accept`: ``full`` [W+1, V], its
+    drafts ``d`` [k] and their distributions ``q`` [k, V] (``None``: a
+    deterministic drafter). Returns ``(acc, z, z_logits)``."""
+    temp, top_k, top_p = cfg
     V = full.shape[-1]
-    out_toks, accs, new_last, new_rngs = [], [], [], []
-    pos = jnp.arange(k + 1)
-    for s, (temp, top_k, top_p) in enumerate(cfgs):
-        n_draft = valid[s] - n_forced[s]
-        j = n_forced[s] + jnp.arange(k)  # window position of draft i
-        pd = jnp.take(full[s], j, axis=0)  # [k, V] (OOB clipped, masked)
-        d = draft_toks[s]
-        rng, sub = jax.random.split(rngs[s])
-        u_key, z_key = jax.random.split(sub)
-        if temp == 0.0:
-            ok = d == jnp.argmax(pd, axis=-1).astype(jnp.int32)
-        else:
-            p_prob = jax.nn.softmax(
-                filter_logits(pd, temp, top_k, top_p), axis=-1)
-            p_at_d = jnp.take_along_axis(p_prob, d[:, None], axis=-1)[:, 0]
-            if onehot_q:
-                ratio = p_at_d
-            else:
-                q_at_d = jnp.take_along_axis(
-                    q_probs[s], d[:, None], axis=-1)[:, 0]
-                ratio = p_at_d / jnp.maximum(q_at_d, 1e-30)
-            u = jax.random.uniform(u_key, (k,))
-            ok = u < jnp.minimum(ratio, 1.0)
-        ok = ok & (jnp.arange(k) < n_draft)
-        acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32)))
-        z_logits = jnp.take(full[s], n_forced[s] + acc, axis=0)
-        if temp == 0.0:
-            z = jnp.argmax(z_logits).astype(jnp.int32)
-        else:
-            p_z = jax.nn.softmax(filter_logits(z_logits, temp,
-                                               top_k, top_p))
-            a_clip = jnp.minimum(acc, k - 1)  # the first-rejected draft
-            if onehot_q:
-                q_z = jax.nn.one_hot(jnp.take(d, a_clip), V,
-                                     dtype=p_z.dtype)
-            else:
-                q_z = jnp.take(q_probs[s], a_clip, axis=0)
-            resid = jnp.maximum(p_z - q_z, 0.0)
-            dist = jnp.where(acc >= n_draft, p_z, resid)
-            tot = jnp.sum(dist)
-            # p == q exactly makes the residual vanish; rejection then
-            # had probability 0, so the fallback is never drawn — it
-            # only keeps the categorical finite
-            dist = jnp.where(tot > 0, dist / jnp.maximum(tot, 1e-30),
-                             p_z)
-            z = jax.random.categorical(
-                z_key, jnp.log(jnp.maximum(dist, 1e-38))
-            ).astype(jnp.int32)
-        dp = jnp.concatenate([d, jnp.zeros((1,), jnp.int32)])
-        out_toks.append(
-            jnp.where(pos < acc, dp, jnp.where(pos == acc, z, 0)))
-        accs.append(acc)
-        new_last.append(z_logits)
-        new_rngs.append(jnp.where(sample_mask[s], rng, rngs[s]))
-    return (jnp.stack(out_toks), jnp.stack(accs),
-            jnp.stack(new_last), jnp.stack(new_rngs))
+    u_key, z_key = jax.random.split(key)
+    # (a position past the window is clipped, and masked by n_draft)
+    pd = jnp.take(full, n_forced + jnp.arange(k), axis=0)
+    p_prob = jax.nn.softmax(filter_logits(pd, temp, top_k, top_p), axis=-1)
+    ratio = jnp.take_along_axis(p_prob, d[:, None], axis=-1)[:, 0]
+    if q is not None:
+        ratio = ratio / jnp.maximum(
+            jnp.take_along_axis(q, d[:, None], axis=-1)[:, 0], 1e-30)
+    ok = jax.random.uniform(u_key, (k,)) < jnp.minimum(ratio, 1.0)
+    ok = ok & (jnp.arange(k) < n_draft)
+    acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32)))
+    z_logits = jnp.take(full, n_forced + acc, axis=0)
+    p_z = jax.nn.softmax(filter_logits(z_logits, temp, top_k, top_p))
+    a_clip = jnp.minimum(acc, k - 1)  # the first-rejected draft
+    q_z = (jax.nn.one_hot(jnp.take(d, a_clip), V, dtype=p_z.dtype)
+           if q is None else jnp.take(q, a_clip, axis=0))
+    dist = jnp.where(acc >= n_draft, p_z, jnp.maximum(p_z - q_z, 0.0))
+    tot = jnp.sum(dist)
+    # p == q exactly makes the residual vanish; rejection then had
+    # probability 0, so the fallback is never drawn — it only keeps the
+    # categorical finite
+    dist = jnp.where(tot > 0, dist / jnp.maximum(tot, 1e-30), p_z)
+    z = jax.random.categorical(
+        z_key, jnp.log(jnp.maximum(dist, 1e-38))).astype(jnp.int32)
+    return acc, z, z_logits
 
 
 def _merge_drafts(fed, valid, n_forced, draft_toks, k):
@@ -772,6 +784,129 @@ def _spec_verify_fn(layout, cfgs, W, k, onehot_q,
             new_cache = _rewind_cursors(new_cache,
                                         valid - (n_forced + acc))
         return new_cache, new_last, out_toks, acc, new_rngs
+
+    return tick
+
+
+def _draft_rows(cfgs, logits, rngs, advance):
+    """One proposal per slot from the drafter's ``[S, vocab]``
+    ``logits`` (traced): a greedy row its best token, any other a draw
+    from its own filtered distribution, which is the ``q`` the verify
+    tick's accept ratio divides by (zeros for a greedy row: nothing
+    reads them). The draft chains are apart from the emission chains
+    and move only where ``advance`` [S]. Returns ``(tokens [S], q [S,
+    vocab], chains [S, 2])``."""
+    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    q = jnp.zeros(logits.shape, jnp.float32)
+    for s, (temp, top_k, top_p) in enumerate(cfgs):
+        if temp == 0.0:
+            continue
+        rng, sub = jax.random.split(rngs[s])
+        f = filter_logits(logits[s], temp, top_k, top_p)
+        toks = toks.at[s].set(
+            jax.random.categorical(sub, f).astype(jnp.int32))
+        q = q.at[s].set(jax.nn.softmax(f))
+        rngs = rngs.at[s].set(jnp.where(advance[s], rng, rngs[s]))
+    return toks, q, rngs
+
+
+@functools.lru_cache(maxsize=256)
+def _mtp_verify_fn(layout, cfgs, W, ctx: Optional[_ShardCtx] = None,
+                   live: Optional[int] = None):
+    """Compiled verify tick of a model that drafts with ITS OWN
+    multi-token-prediction module (``draft="mtp"``; one draft a row a
+    tick): :func:`_spec_verify_fn`'s window, acceptance and rewind, with
+    three differences the module forces.
+
+    **The drafter runs here, behind the acceptance.** The module's row
+    at position ``t`` reads the main model's hidden state at ``t``
+    beside the embedding of the token at ``t + 1``, and which token that
+    is at a window's last kept position is known only once the window
+    is accepted. So the one dispatch is: the main layers over the ``[S,
+    W]`` window (``dm.apply(..., head_at=)``: the hidden states of every
+    row, the logits at the window's ``2`` positions alone: a chunk's
+    64 positions of a 150 k vocabulary are never multiplied),
+    :func:`_spec_accept`, then ``dm.draft`` over the same positions with
+    each one's next token (the window shifted left; at the last kept
+    position the token just sampled, or for a prompt chunk the prompt's
+    next token, which the host sends), then ONE rewind of every cursor,
+    the module's cache leaf among them, past the rejected draft. The
+    module's logits at the last kept position give the next tick's
+    draft.
+
+    **Its state stays on the device**: ``state`` = the row's pending
+    token (sampled last tick, not yet in the cache), its draft, the
+    draft's distribution (for a sampled row's accept ratio) and the
+    draft chains. The next tick's window is built from them here
+    (``use_pending`` says for which rows), so no token crosses the host
+    between an acceptance and the next draft.
+
+    **A prompt's last chunk samples the row's first token** (the host
+    sets its ``sample_mask``) in place of a transition tick: the module
+    needs that token beside the chunk's last hidden state.
+
+    ``live`` packs the per-token layers as in :func:`_mixed_tick_fn`.
+    The model's ``tick_counters`` ride behind the accepted lengths,
+    ``[S + len(counters)]``."""
+    counters = tuple(getattr(layout.dm, "tick_counters", ()))
+    mutable = ["cache", "counters"] if counters else ["cache"]
+
+    @functools.partial(_compile, ctx=ctx, in_kinds="pcrrrr",
+                       out_kinds="crrrrr", donate=(1, 2, 3))
+    def tick(params_only, cache, last_logits, rngs, packed, state):
+        recompiles.note(layout.tag("mtp_tick"))
+        pending, draft_toks, q_probs, draft_rngs = state
+        S = rngs.shape[0]
+        where, (fed, valid, n_forced, smask, next_tok, use_pending) = (
+            layout.unpack(packed, S,
+                          ((S, W), (S,), (S,), (S,), (S,), (S,))))
+        if live is not None:
+            where = {**where, "live_tokens": live}
+        sample_mask = smask != 0
+        fed = fed.at[:, 0].set(
+            jnp.where(use_pending != 0, pending, fed[:, 0]))
+        merged = _merge_drafts(fed, valid, n_forced, draft_toks, 1)
+        # the window's two positions: the last forced token's column and
+        # the draft's (a row that feeds nothing reads neither)
+        head_at = jnp.clip(n_forced[:, None] - 1 + jnp.arange(2)[None],
+                           0, W - 1)
+        (hidden, logits), vs = layout.dm.apply(
+            {**params_only, "cache": cache}, merged, valid_lens=valid,
+            head_at=head_at, mutable=mutable, **where)
+        # _spec_accept reads window position j at full[:, j]: here that
+        # is last_logits, then the two head positions, so a row that
+        # forced any tokens counts as having forced one
+        forced = jnp.minimum(n_forced, 1)
+        out_toks, acc, new_last, new_rngs = _spec_accept(
+            cfgs, 1, False,
+            jnp.concatenate([last_logits[:, None],
+                             logits.astype(jnp.float32)], axis=1),
+            rngs, valid - n_forced + forced, forced, sample_mask,
+            draft_toks, q_probs)
+        kept = n_forced + acc
+        z = jnp.take_along_axis(out_toks, acc[:, None], axis=1)[:, 0]
+        after = jnp.where(sample_mask, z, next_tok)
+        following = jnp.where(
+            jnp.arange(W)[None] == kept[:, None] - 1, after[:, None],
+            jnp.roll(merged, -1, axis=1))
+        drafted, vs2 = layout.dm.apply(
+            {**params_only, "cache": vs["cache"]}, hidden, following,
+            valid_lens=valid, draft_at=jnp.maximum(kept - 1, 0),
+            mutable=mutable, method="draft", **where)
+        new_cache = _rewind_cursors(vs2["cache"], valid - kept)
+        tok, q, draft_rngs = _draft_rows(
+            cfgs, drafted.astype(jnp.float32), draft_rngs, sample_mask)
+        # a row that sampled nothing (a chunk mid-prompt, a held row)
+        # keeps the pending token and the draft it had
+        state = (jnp.where(sample_mask, z, pending),
+                 jnp.where(sample_mask[:, None], tok[:, None], draft_toks),
+                 jnp.where(sample_mask[:, None, None], q[:, None], q_probs),
+                 draft_rngs)
+        if counters:
+            acc = jnp.concatenate([acc, sum(
+                _counter_sums(v.get("counters", {}), counters)
+                for v in (vs, vs2))])
+        return new_cache, new_last, out_toks, acc, state, new_rngs
 
     return tick
 
@@ -1013,7 +1148,9 @@ class _SlotState:
     admit_seq: int = 0  # admission order: prefill budget is dealt FIFO
     admit_t: float = 0.0  # monotonic admission time (prefill span)
     # speculative decoding (engine.spec): the row's emitted-but-unfed
-    # token (None until the transition tick samples the first one), the
+    # token (None until the transition tick samples the first one; with
+    # draft="mtp" the device holds it, and -1 here says so until the
+    # tick that sampled it is read), the
     # prompt+emitted history the n-gram drafter matches against, the
     # queue of true tokens the draft model hasn't consumed yet, and the
     # draft-cursor overshoot (rejected proposals) to rewind at its next
@@ -1068,7 +1205,9 @@ class _InflightTick:
     # = ordinary one-token tick); ``acc`` doubles as its device [S]
     # per-row emitted counts
     multi_k: Optional[int] = None
-    # speculative extras (depth-1 pipeline: emissions defer, plans don't)
+    # speculative extras (the n-gram and draft-model drafters run a
+    # depth-1 pipeline: emissions defer, plans don't; draft="mtp" runs
+    # a tick ahead like a non-speculative engine)
     acc: Any = None                 # device [S] accepted-prefix lengths
     n_forced: Optional[np.ndarray] = None
     granted: Optional[np.ndarray] = None
@@ -1289,8 +1428,14 @@ class _DeviceClock:
             # the gap before it is the time before the mark's
             origin, starved, unasked = start, 0.0, 0.0
         by = dict(t.by_program)
-        total, n = by.get(rec.program, (0.0, 0))
-        by[rec.program] = (total + tick, n + 1)
+        # a verify tick is ``spec`` whether or not a prompt chunk rode
+        # in it; those that fed prompt tokens are summed apart as well
+        # (the wider program of the two)
+        fed = rec.program == "spec" and getattr(rec, "fed_tokens", 0)
+        for program in (rec.program, "spec_chunk") if fed else (
+                rec.program,):
+            total, n = by.get(program, (0.0, 0))
+            by[program] = (total + tick, n + 1)
         self._totals = _ClockTotals(
             t.busy_ms + tick, t.starved_ms + starved,
             t.unasked_ms + unasked, t.err_ms + err, t.ticks + 1,
@@ -1321,7 +1466,7 @@ class _DeviceClock:
             "device_unasked_pct": (100.0 * t.unasked_ms / whole
                                    if whole else None),
             **{f"device_{p}_tick_ms": mean(p)
-               for p in ("decode", "mixed", "multi", "spec")},
+               for p in ("decode", "mixed", "multi", "spec", "spec_chunk")},
             "device_clock_exact_pct": (100.0 * t.exact / t.ticks
                                        if t.ticks else None),
             # first dispatch after the mark to the last read: what the
@@ -1484,15 +1629,25 @@ class ServingEngine:
         history. The flagship verifies every window in ONE fused
         ``[S, k+1]`` dispatch (the mixed tick's ``valid_lens``
         machinery) and accepts a per-row prefix by rejection sampling:
-        greedy streams stay bit-identical to the non-speculative
-        engine, sampled streams are distributionally exact. Verify
+        greedy streams are the non-speculative engine's token for
+        token where the arithmetic is exact (float32: the tests hold
+        it; a reduced precision rounds a ``[S, k+1]`` window and a
+        ``[S, 1]`` tick differently, and the two streams part at
+        near-ties, each as close to the float32 model: PERF.md §6, PR
+        41), sampled streams are distributionally exact. Verify
         tokens are charged against the scheduler's
         ``tick_token_budget`` (decodes reserve 1 each, prompt chunks
         are dealt next, leftover widens the windows), so chunked
         prefill and speculation coexist. Rejected suffixes roll back
         as cursor rewinds on both cache layouts; acceptance-length
         variation never changes a compiled shape (fixed ``spec_k``
-        padding — zero steady-state recompiles).
+        padding — zero steady-state recompiles). ``"mtp"`` drafts with
+        the served model's OWN multi-token-prediction module (a model
+        that declares ``mtp_depth``; ``spec_k`` is that depth): no
+        second copy of any weight, the module's cache a leaf of the
+        model's own, the draft made on the device behind the
+        acceptance, and the loop a tick ahead (see ``glm4_moe_lite_lm``
+        below and :func:`_mtp_verify_fn`).
       draft_params: the draft model's trained variables.
       spec_k: draft tokens proposed per row per tick (default 4).
       ngram_max: longest suffix n-gram the ``"ngram"`` drafter matches
@@ -1571,8 +1726,9 @@ class ServingEngine:
     ``serving_refusals``. For that model the constructor **refuses**,
     with the model's reason: ``paged=True`` (``kvpool`` allocates
     ``[blocks, block, Hk, hd]`` K and V; no latent block exists),
-    ``draft=`` of either kind (no draft path reads a latent cache and
-    the multi-token-prediction module is not built), ``mesh=`` (all
+    ``draft=`` of any kind (its multi-token-prediction module is not
+    built, and a draft's window would have to pass the indexer's
+    selection), ``mesh=`` (all
     heads share one latent: heads are not split), ``multi_step_k > 1``
     (the counters return once a tick), ``prefill_chunk=None`` (the walk
     holds a chunk's scores, not a prompt's) and a model cloned with
@@ -1606,6 +1762,24 @@ class ServingEngine:
     ran ahead past an eos enters the state and cannot be rewound; it is
     harmless because that request is finished and its slot re-entered
     at cursor 0. It refuses ``paged``, ``draft``, ``mesh``,
+    ``multi_step_k > 1``, ``prefill_chunk=None`` and ``cache_dtype !=
+    "model"``.
+
+    ``glm4_moe_lite_lm`` brings its drafter with it: a
+    multi-token-prediction module behind the last layer, whose
+    parameters are leaves of the tree the engine holds and whose cache
+    is one more latent layer (``latent [S, L, 512]``, ``rope_key [S, 64,
+    L]``, a cursor) among the model's own cache leaves. With
+    ``draft="mtp"`` every tick is :func:`_mtp_verify_fn`'s: the main
+    layers over the window, the acceptance, the module over the same
+    positions with each one's next token, one rewind of every cursor,
+    the module's among them. The engine asks the model ``mtp_depth``,
+    ``__call__(..., head_at=)``, ``draft(...)`` and clones its decode
+    module with ``verify_window = spec_k + 1``; what a tick leaves for
+    the next (pending token, draft, the draft's distribution, the draft
+    chains) is ``_mtp_state``, on the device, so this drafter runs a
+    tick ahead (a row is held against the most an unread tick can emit).
+    It refuses ``paged``, a drafter other than its module, ``mesh``,
     ``multi_step_k > 1``, ``prefill_chunk=None`` and ``cache_dtype !=
     "model"``.
 
@@ -1656,6 +1830,8 @@ class ServingEngine:
         refusals = getattr(model, "serving_refusals", None)
         if refusals is not None:
             refusals(paged=paged, draft=draft is not None,
+                     draft_kind=(draft if isinstance(draft, str)
+                                 else None if draft is None else "model"),
                      mesh=mesh is not None, multi_step=multi_step_k > 1,
                      monolithic_prefill=prefill_chunk is None,
                      prefill_chunk=prefill_chunk)
@@ -1706,18 +1882,37 @@ class ServingEngine:
             if spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1; got {spec_k}")
             if isinstance(draft, str):
-                if draft != "ngram":
+                if draft not in ("ngram", "mtp"):
                     raise ValueError(
                         f"Unknown draft '{draft}'. Known: 'ngram' "
-                        f"(self-speculative n-gram lookup), or a small "
+                        f"(self-speculative n-gram lookup), 'mtp' (the "
+                        f"served model's own multi-token-prediction "
+                        f"module), or a small "
                         f"TransformerLM plus draft_params"
                     )
                 if draft_params is not None:
                     raise ValueError(
-                        "draft='ngram' takes no draft_params (it "
-                        "proposes from the stream's own history)"
+                        f"draft='{draft}' takes no draft_params (it "
+                        f"proposes from the stream's own history, or "
+                        f"with leaves of the served model's own tree)"
                     )
-                self.draft_kind = "ngram"
+                if draft == "mtp":
+                    depth = getattr(model, "mtp_depth", 0)
+                    if not depth:
+                        raise ValueError(
+                            f"draft='mtp' drafts with the served model's "
+                            f"own multi-token-prediction module, and "
+                            f"{type(model).__name__} carries none (no "
+                            f"mtp_depth): use draft='ngram' or a draft "
+                            f"model"
+                        )
+                    if spec_k != depth or paged or mesh is not None:
+                        raise ValueError(
+                            f"draft='mtp' makes one draft a module a tick "
+                            f"(spec_k={depth} for this model; got "
+                            f"{spec_k}), on the slot cache of one chip"
+                        )
+                self.draft_kind = draft
             else:
                 if draft_params is None:
                     raise ValueError(
@@ -1934,6 +2129,11 @@ class ServingEngine:
             self.host = None
             tp_kw = ({"tp_size": self.tp, "tp_axis": tp_axis}
                      if mesh is not None else {})
+            if self.draft_kind == "mtp":
+                # rows that feed a pending token and its draft walk the
+                # cache with the decoding rows, not as chunks (no mesh
+                # here: the constructor refused one above)
+                tp_kw = {"verify_window": spec_k + 1}
             self._dm_slot = self.model.clone(
                 decode=True, slot_cursor=True,
                 prefill_kernel=prefill_kernel, parent=None, **tp_kw
@@ -1991,6 +2191,17 @@ class ServingEngine:
             )
             self._draft_tp = draft_tp
         self._draft_rngs = jnp.zeros((slots, 2), jnp.uint32)
+        # draft="mtp": what the verify tick leaves on the device for the
+        # next one (each row's pending token, its draft, the draft's
+        # distribution, the draft chains; see _mtp_verify_fn)
+        self._mtp_state = None
+        if self.draft_kind == "mtp":
+            self._mtp_state = (
+                jnp.zeros((slots,), jnp.int32),
+                jnp.zeros((slots, spec_k), jnp.int32),
+                jnp.zeros((slots, spec_k, self.model.vocab_size),
+                          jnp.float32),
+                self._draft_rngs)
         self._last_logits = jnp.zeros(
             (slots, self.model.vocab_size), jnp.float32
         )
@@ -2006,6 +2217,8 @@ class ServingEngine:
             self._last_logits = jax.device_put(self._last_logits, device)
             self._rngs = jax.device_put(self._rngs, device)
             self._draft_rngs = jax.device_put(self._draft_rngs, device)
+            if self._mtp_state is not None:
+                self._mtp_state = jax.device_put(self._mtp_state, device)
             if self._dm_draft is not None:
                 self._draft_params_only = jax.device_put(
                     self._draft_params_only, device)
@@ -2467,8 +2680,12 @@ class ServingEngine:
         token, n-gram history), so reconciliation runs first, but token
         emission and telemetry are deferred until after the next
         dispatch — the device computes tick N+1 while the host streams
-        tick N."""
-        if self.spec:
+        tick N. An engine that drafts with the served model's own module
+        (``draft="mtp"``) runs depth-2 like a non-speculative one: the
+        pending token and the draft are the device's, so nothing of tick
+        N is needed to plan N+1 but how many tokens it may emit, and a
+        row is held against the most it can (every draft accepted)."""
+        if self.spec and self.draft_kind != "mtp":
             defer: list = []
             while self._pending:
                 self._reconcile_spec(self._pending.popleft(), defer)
@@ -2495,6 +2712,8 @@ class ServingEngine:
             k = self._multi_gate()
             if k > 1:
                 rec = self._plan_dispatch_multi(k)
+            elif self.spec:
+                rec = self._plan_dispatch_spec()
             elif self.prefill_chunk is not None:
                 rec = self._plan_dispatch_mixed()
             else:
@@ -2505,7 +2724,11 @@ class ServingEngine:
         # last streams always complete
         keep = 1 if ahead else 0
         while len(self._pending) > keep:
-            self._reconcile(self._pending.popleft())
+            rec = self._pending.popleft()
+            if self.spec:
+                self._reconcile_spec(rec, None)
+            else:
+                self._reconcile(rec)
         return (occupied or self.scheduler.depth() > 0
                 or bool(self._pending))
 
@@ -3029,6 +3252,14 @@ class ServingEngine:
             # draft model's private cache has seen it)
             if self.draft_kind == "ngram":
                 st.history = np.asarray(req.prompt, np.int32).copy()
+            elif self.draft_kind == "mtp":
+                # the module's cache is a leaf of the cache _enter_slot
+                # just parked; only a sampled row's draft chain is new
+                if req.temperature != 0.0:
+                    *held, chains = self._mtp_state
+                    self._mtp_state = (*held, chains.at[slot].set(
+                        jax.random.fold_in(
+                            jax.random.PRNGKey(req.seed), 1)))
             else:
                 st.draft_queue = np.asarray(req.prompt, np.int32).copy()
                 self._draft_cache = _reset_slot_cursors(
@@ -3318,7 +3549,9 @@ class ServingEngine:
         layout's gathered view. Host arithmetic on cursors the plan
         already holds."""
         m = self.model
-        fetched = getattr(m, "kv_positions_fetched", None)
+        # (the decode module: it knows the verify window it was cloned
+        # with)
+        fetched = getattr(self._layout.dm, "kv_positions_fetched", None)
         if fetched is not None:  # the model's own walk over its cache
             return fetched(starts, valid, C)
         L = m.max_len
@@ -3533,11 +3766,17 @@ class ServingEngine:
             span.update(work)
         with self._phase("upload", tick=tick_no) as upload:
             operands = [] if packed is None else [self._upload(packed)]
-            if spec_rows is not None and self.draft_kind != "model":
+            if spec_rows is not None and self.draft_kind == "ngram":
                 operands.append(jnp.asarray(drafts))
         with self._phase("dispatch", tick=tick_no, **span) as dispatch:
             self._clock.dispatching()
-            if spec_rows is not None:
+            if spec_rows is not None and self.draft_kind == "mtp":
+                # the drafts are on the device, where the last verify
+                # tick's module left them
+                operands.append(self._mtp_state)
+                tick = _mtp_verify_fn(self._layout, cfgs, chunk, self._ctx,
+                                      live)
+            elif spec_rows is not None:
                 if self.draft_kind == "model":
                     q_probs, draft_dev = self._run_draft(cfgs, spec_rows)
                     operands += [draft_dev, q_probs]
@@ -3562,6 +3801,9 @@ class ServingEngine:
                 self._params_only, self._cache, self._last_logits,
                 self._rngs, *operands,
             )
+            if spec_rows is not None and self.draft_kind == "mtp":
+                # what the module left on the device for the next tick
+                self._mtp_state = acc.pop()
         rec = _InflightTick(
             toks=toks, rows=rows, tick=tick_no, plan_ms=plan.ms,
             upload_ms=upload.ms, dispatch_ms=upload.ms + dispatch.ms,
@@ -3882,23 +4124,29 @@ class ServingEngine:
                  if st and not st.decoding and st.restoring is None),
                 key=lambda p: p[1].admit_seq,
             )
+            mtp = self.draft_kind == "mtp"
+            # (a tick ahead: a row whose budget the unread tick may use
+            # up, every draft of it accepted, is held)
             dec = [(s, st) for s, st in enumerate(self._slots)
-                   if st and st.decoding]
-            # rows eligible to speculate: a host-known pending token, room
-            # for at least one draft, and a drafter able to propose (the
-            # n-gram index found a match / the draft model is caught up)
+                   if st and st.decoding and st.unplanned > 0]
+            # rows eligible to speculate: a pending token (host-known, or
+            # with the module's draft on the device), room for at least
+            # one draft, and a drafter able to propose (the n-gram index
+            # found a match / the draft model is caught up / the module
+            # drafted when the pending token was sampled)
             spec_rows, want = [], []
             ngram_toks = {}
             for s, st in dec:
                 if st.pending_tok is None:
                     continue  # transition row: samples its first token
-                w = min(k, st.remaining - 1)
+                w = min(k, st.unplanned - 1)
                 if self.draft_kind == "ngram":
                     toks, found = _ngram_propose(st.history, k,
                                                  self.ngram_max)
                     ngram_toks[s] = toks
                     w = min(w, found)
-                elif st.draft_queue is not None and st.draft_queue.size > 2:
+                elif (not mtp and st.draft_queue is not None
+                      and st.draft_queue.size > 2):
                     w = 0  # draft still consuming the prompt
                 if w > 0:
                     spec_rows.append((s, st))
@@ -3917,7 +4165,14 @@ class ServingEngine:
             sample_mask = np.zeros((S,), np.int32)
             draft_np = np.zeros((S, k), np.int32)
             granted = np.zeros((S,), np.int32)
+            # draft="mtp": the prompt's next token behind a chunk (the
+            # module's input at the chunk's last position), and the rows
+            # whose pending token is the device's
+            next_tok = np.zeros((S,), np.int32)
+            use_pending = np.zeros((S,), np.int32)
             rows: List[Optional[tuple]] = [None] * S
+            starts = np.fromiter((st.cursor if st else 0
+                                  for st in self._slots), np.int64, S)
             for s, st in dec:
                 sample_mask[s] = 1
                 rows[s] = ("dec", st)
@@ -3925,6 +4180,7 @@ class ServingEngine:
                     fed[s, 0] = st.pending_tok
                     n_forced[s] = 1
                     valid[s] = 1
+                    use_pending[s] = mtp
             for (s, st), w in zip(spec_rows, widths):
                 valid[s] = 1 + w
                 granted[s] = w
@@ -3940,20 +4196,76 @@ class ServingEngine:
                     if st.pending.size == 0:
                         # last chunk dealt: the next tick is this row's
                         # transition tick (samples its first token, which
-                        # becomes the pending token)
+                        # becomes the pending token); with the module
+                        # drafting, this tick samples it, since the
+                        # module's row at the prompt's last position
+                        # needs it
                         st.decoding = True
                         flipped = True
+                        if mtp:
+                            sample_mask[s] = 1
+                            st.pending_tok = -1  # the device holds it
+                    elif mtp:
+                        next_tok[s] = st.pending[0]
                 rows[s] = ("pre", st, take, flipped)
+            for s, row in enumerate(rows):
+                if row is None:
+                    continue
+                if sample_mask[s]:
+                    # the tokens the unread ticks may still sample for
+                    # the row, every draft accepted: what the next plan
+                    # holds its budget against
+                    row[1].inflight += 1 + int(granted[s])
+                if mtp:
+                    # what is certain of the row's advance (the work
+                    # counters read it); an accepted draft is added when
+                    # the tick is read
+                    row[1].cursor += int(n_forced[s])
             # host-owned cursors hold here: how far a row advances is
             # known only when its accepted length is read back
             # (_reconcile_spec)
+            fields = (fed, valid, n_forced, sample_mask)
             packed = self._layout.pack(
-                self, (fed, valid, n_forced, sample_mask))
+                self, fields + (next_tok, use_pending) if mtp else fields)
+        work = live = None
+        if mtp:
+            live = self._live_count(W, int(valid.sum()))
+            work = self._window_work(starts, valid, W, live)
+            work["draft_tokens"] = int(granted.sum())
         return self._dispatch(tick_no, plan, cfgs, packed, rows,
                               n_dec=len(dec), fed_tokens=fed_tokens,
-                              chunk=W, drafts=draft_np,
+                              chunk=W, live=live, work=work,
+                              drafts=draft_np,
                               spec_rows=spec_rows, n_forced=n_forced,
                               granted=granted, spec_set=spec_set)
+
+    def _window_work(self, starts, valid, W: int,
+                     live: Optional[int]) -> dict:
+        """What a verify tick of a model that drafts for itself computes
+        against what it was dealt, as :meth:`_plan_dispatch_mixed`
+        counts a mixed tick: a window's draft position is a query like
+        any other (``window_positions``: every position the tick ran,
+        forced or drafted; the module runs the same,
+        ``mtp_positions_fed``), and whether it was worth running shows
+        in ``decode_tokens``, which a verify tick's record counts as the
+        tokens emitted. ``starts`` are the host's cursors: behind the
+        device's by the drafts an unread tick accepts."""
+        S = self.slots
+        live_rows = valid > 0
+        ends = (starts + valid)[live_rows]
+        first = starts[live_rows]
+        return {
+            # query i of a row's run attends the cached span and the run
+            # up to itself
+            "attended_tokens": int(
+                (ends * (ends + 1) - first * (first + 1)).sum() // 2),
+            "key_positions": int(ends.sum()),
+            "key_positions_fetched": self._kv_fetched(starts, valid, W),
+            "cache_positions": S * self.model.max_len,
+            "query_positions": live or S * W,
+            "attend_query_positions": S * W,
+            "window_positions": int(valid.sum()),
+            "mtp_positions_fed": int(valid.sum())}
 
     def _reconcile_spec(self, rec: _InflightTick,
                         defer: Optional[list]):
@@ -3972,6 +4284,13 @@ class ServingEngine:
             toks_host = np.asarray(rec.toks)
             clock = self._clock.read_ends(rec)
             acc_host = np.asarray(rec.acc)
+            counters = getattr(self.model, "tick_counters", ())
+            if counters and rec.work is not None:
+                # what the model counted on the device rides behind the
+                # S accepted lengths (see _mtp_verify_fn)
+                rec.work.update(zip(
+                    counters, acc_host[self.slots:].tolist()))
+                acc_host = acc_host[:self.slots]
             rec.toks = rec.acc = None  # freed here, as in _reconcile
         wait_ms = wait.ms
         with self._phase("stream", tick=rec.tick) as stream:
@@ -3984,14 +4303,28 @@ class ServingEngine:
             self._occ_sum += occupancy
             now = time.monotonic()
             emitted = 0
+            overrun = 0
             proposed = int(rec.granted.sum())
             accepted = 0
+            mtp = self.draft_kind == "mtp"
             for s, row in enumerate(rec.rows):
                 if row is None:
                     continue
                 st = row[1]
+                # a prompt's last chunk samples the row's first token
+                # where the model's own module drafts
+                samples = row[0] == "dec" or (mtp and row[3])
+                a = int(acc_host[s]) if samples else 0
                 if self._slots[s] is not st:
-                    continue  # late finish (cannot happen at depth 1)
+                    # late finish: the row's request ended (an eos) in
+                    # the tick before this one, read after this one was
+                    # dispatched. Only the loop a tick ahead gets here
+                    if samples:
+                        overrun += a + 1
+                    continue
+                if samples:
+                    st.inflight -= 1 + int(rec.granted[s])
+                    st.cursor += a
                 if row[0] == "pre":
                     if row[3]:
                         req = st.req
@@ -4006,8 +4339,8 @@ class ServingEngine:
                             wv=self.weight_version,
                         )
                         self._m_prefill_ms.observe(prefill_ms)
-                    continue
-                a = int(acc_host[s])
+                    if not samples:
+                        continue
                 if rec.granted[s] > 0:
                     accepted += a
                     self._m_accept_len.observe(a)
@@ -4038,6 +4371,9 @@ class ServingEngine:
             self.draft_tokens_accepted += accepted
             self._m_draft_tokens.inc(proposed)
             self._m_accepted_tokens.inc(accepted)
+            if overrun:
+                self.overrun_tokens += overrun
+                self._m_overrun.inc(overrun)
             queue_depth = self.scheduler.depth()
             device_ms = rec.dispatch_ms + wait_ms
             self._m_ticks.inc()
@@ -4059,6 +4395,7 @@ class ServingEngine:
             emitted=emitted, occupancy=occupancy,
             queue_depth=queue_depth, device_wait_ms=wait_ms,
             draft_tokens=proposed, accepted_tokens=accepted,
+            overrun=overrun,
         )
 
     def _decode_tick(self):
@@ -4392,7 +4729,10 @@ class ServingEngine:
                     # shallow (the QoS degradation order, as it happened)
                     "qos_depth": self.scheduler.depth_by_tier(),
                     "budget_limit": self.scheduler.tick_token_budget,
-                    "decode_tokens": rec.n_dec,
+                    # a verify tick's are the tokens it emitted, not the
+                    # positions it verified: a rejected draft is waste
+                    "decode_tokens": (rec.n_dec if draft_tokens is None
+                                      else emitted),
                     "prefill_tokens": rec.fed_tokens, "chunk": rec.chunk,
                     "emitted": emitted,
                     "slots": self._slot_snaps(),
@@ -4463,7 +4803,8 @@ class ServingEngine:
             self.key_positions_fetched_total += rec.work[
                 "key_positions_fetched"]
             self.cache_positions_total += rec.work["cache_positions"]
-            self.useful_query_tokens_total += rec.n_dec + rec.fed_tokens
+            self.useful_query_tokens_total += rec.fed_tokens + (
+                rec.n_dec if draft_tokens is None else emitted)
             for name in _MODEL_WORK:
                 if name in rec.work:
                     self.model_work_totals[name] = (
@@ -4485,7 +4826,7 @@ class ServingEngine:
                 idle_ms=period.get("idle", 0.0),
                 loop_ms=period["loop"],
             )
-            if self.pipeline and self.spec:
+            if self.pipeline and self.spec and self.draft_kind != "mtp":
                 # the previous tick's tokens reached their consumers
                 # inside this period, behind this tick's dispatch
                 snap["deferred_stream_ms"] = (
@@ -4650,6 +4991,12 @@ class ServingEngine:
                           / self.draft_tokens_proposed, 4)
                     if self.draft_tokens_proposed else 0.0
                 ),
+                # the same two, named as the other per-tick sums are,
+                # and their ratio unrounded
+                "draft_tokens_total": self.draft_tokens_proposed,
+                "accepted_tokens_total": self.draft_tokens_accepted,
+                "spec_accept_pct": 100.0 * self.draft_tokens_accepted
+                / max(self.draft_tokens_proposed, 1),
             })
         if self.flight is not None:
             out["flight"] = {

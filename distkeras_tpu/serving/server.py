@@ -688,6 +688,7 @@ class ServingClient:
         self._call_lock = threading.Lock()
         self._send_lock = threading.Lock()
         self._acks: _queue.Queue = _queue.Queue()
+        self._acks_owed = 0  # calls that timed out; under _call_lock
         self._streams: Dict[int, _queue.Queue] = {}
         self._streams_lock = threading.Lock()
         self._trace_ids: Dict[int, int] = {}  # rid -> telemetry trace id
@@ -774,8 +775,16 @@ class ServingClient:
                     f"failed: {e}"
                 ) from e
             try:
-                reply = self._acks.get(timeout=timeout)
+                while True:
+                    reply = self._acks.get(timeout=timeout)
+                    if not self._acks_owed or reply.get("_disconnected"):
+                        break
+                    # the reply to a call that gave up waiting: replies
+                    # come in the order of the calls, and this one must
+                    # not be taken for the next call's
+                    self._acks_owed -= 1
             except _queue.Empty:
+                self._acks_owed += 1
                 raise TimeoutError(
                     f"no reply to op {msg.get('op')!r} within {timeout}s"
                 ) from None

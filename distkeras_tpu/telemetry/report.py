@@ -437,6 +437,22 @@ def report_flight(path: str, last: Optional[int] = None,
             f"overrun_pct: {100 * dropped / max(sampled, 1):.2f} "
             f"({dropped} of {sampled} sampled tokens dropped)\n"
         )
+    drafted = [r for r in ticks if "draft_tokens" in r]
+    if drafted:
+        # verify ticks: drafts put into windows, those the model kept,
+        # and (a model that drafts with its own module) the positions
+        # the windows ran over beside the positions the module was fed
+        drafts = sum(int(r["draft_tokens"]) for r in drafted)
+        kept = sum(int(r.get("accepted_tokens") or 0) for r in drafted)
+        line = (f"drafts: {drafts}  accepted: {kept}  rate "
+                f"{100 * kept / max(drafts, 1):.2f} %  (over "
+                f"{len(drafted)} verify ticks")
+        if any("window_positions" in r for r in drafted):
+            line += (f"; window positions "
+                     f"{sum(r.get('window_positions', 0) for r in drafted)}"
+                     f", module positions fed "
+                     f"{sum(r.get('mtp_positions_fed', 0) for r in drafted)}")
+        out.write(line + ")\n")
     chosen = [r for r in ticks if r.get("index_positions_scored")]
     if chosen:
         # a learned selection over the cache: positions the indexer

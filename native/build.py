@@ -15,6 +15,7 @@ import os
 import shutil
 import subprocess
 import sys
+import threading
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -41,8 +42,10 @@ def build_lib(lib_name: str, quiet: bool = False) -> str:
     src = os.path.join(HERE, LIBS[lib_name])
     out = os.path.join(HERE, lib_name)
     # build beside the target and rename into place: several processes
-    # (test workers) may find the library stale at the same moment
-    tmp = f"{out}.{os.getpid()}.tmp"
+    # (test workers) and several threads of one (a server's and its
+    # client's first frames) may find the library stale at the same moment
+    mine = f"{out}.{os.getpid()}.{threading.get_ident()}"
+    tmp = mine + ".tmp"
     cmd = [_cc(), "-O2", "-shared", "-fPIC", "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=quiet)
@@ -50,7 +53,7 @@ def build_lib(lib_name: str, quiet: bool = False) -> str:
     finally:
         if os.path.exists(tmp):
             os.remove(tmp)
-    with open(f"{out}.{os.getpid()}.stamp", "w") as fh:
+    with open(mine + ".stamp", "w") as fh:
         fh.write(_source_hash(lib_name))
     os.replace(fh.name, out + ".sha256")
     return out

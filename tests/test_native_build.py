@@ -6,6 +6,7 @@ directory."""
 import importlib.util
 import os
 import shutil
+import threading
 
 import jax
 import pytest
@@ -46,6 +47,42 @@ def test_ensure_lib_rebuilds_a_library_without_a_stamp(build, tmp_path):
     lib.write_bytes(b"not a library")
     build.ensure_lib("libdk_transport.so")
     assert lib.read_bytes()[:4] == b"\x7fELF"
+
+
+def test_two_threads_that_find_the_library_missing_both_get_it(
+        build, tmp_path, monkeypatch):
+    """A server's and its client's first frames load the transport at
+    the same moment (a fresh checkout's first serving run): with one
+    temporary name a process the second rename found nothing, and that
+    run carried its frames in Python."""
+    both_compiled = threading.Barrier(2, timeout=60)
+    run = build.subprocess.run
+
+    def compile_then_meet(*args, **kw):
+        out = run(*args, **kw)
+        both_compiled.wait()
+        return out
+
+    monkeypatch.setattr(build.subprocess, "run", compile_then_meet)
+    got = []
+
+    def load():
+        try:
+            got.append(build.ensure_lib("libdk_transport.so"))
+        except Exception as e:
+            got.append(e)
+
+    threads = [threading.Thread(target=load) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    lib = tmp_path / "libdk_transport.so"
+    assert got == [str(lib)] * 2
+    assert lib.read_bytes()[:4] == b"\x7fELF"
+    assert sorted(os.listdir(tmp_path)) == sorted(
+        ["build.py", "dk_dataio.c", "dk_transport.c", "libdk_transport.so",
+         "libdk_transport.so.sha256"])
 
 
 def test_a_failed_build_raises(build, monkeypatch):

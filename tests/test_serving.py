@@ -222,6 +222,43 @@ def test_client_request_timeout_names_request():
         server.stop()
 
 
+def test_a_reply_that_comes_after_its_call_gave_up_is_not_the_next_calls():
+    """Acks carry no id: the reply to a call that timed out (a profile's
+    export held every thread for longer than the wait, PR 41) used to be
+    taken for the next call's, and ``flight()`` then found a generate
+    ack. A peer that answers its first frame late, then at once."""
+    import socket
+
+    from distkeras_tpu.networking import recv_msg, send_msg
+
+    listener = socket.create_server(("127.0.0.1", 0))
+    late = threading.Event()
+
+    def peer():
+        conn, _ = listener.accept()
+        with conn:
+            recv_msg(conn)
+            late.wait(10)
+            send_msg(conn, {"ok": 1, "id": 7, "trace": None})
+            while recv_msg(conn) is not None:
+                send_msg(conn, {"ok": 1, "stats": {"mine": 1}})
+
+    t = threading.Thread(target=peer, daemon=True)
+    t.start()
+    client = ServingClient("127.0.0.1", listener.getsockname()[1],
+                           request_timeout=0.2)
+    try:
+        with pytest.raises(TimeoutError, match="'generate'"):
+            client.generate([1, 2], 3)
+        late.set()
+        assert client.stats() == {"mine": 1}
+        assert client.stats() == {"mine": 1}
+    finally:
+        client.close()
+        listener.close()
+        t.join(5)
+
+
 def test_submit_validation():
     model, params = _model_and_params()
     eng = ServingEngine(model, params, slots=1)
