@@ -302,11 +302,13 @@ def _extract_server(src: SourceFile, cls: ast.ClassDef) -> ServerModel:
                     code = _const_str(v)
                     if code is not None:
                         model.error_codes.add(code)
-    # stream frames: dict literals the pump pushes (no "ok" key)
+    # stream frames: dict literals the pump pushes, or gathers in a
+    # list for one write (no "ok" key)
     for item in cls.body:
         if (isinstance(item, (ast.FunctionDef, ast.AsyncFunctionDef))
                 and item.name == "_pump"):
-            for d in _reply_dicts(item.body):
+            for d in _reply_dicts(item.body, send_attrs=(
+                    "_send", "_send_entry", "append")):
                 ok, keys, _ = _classify_reply(d)
                 if ok is None and "ok" not in keys:
                     model.stream_keys |= keys

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from distkeras_tpu.ops.moe import dropless_held_experts, group_limited_route
+from distkeras_tpu.ops.moe import (
+    dropless_held_experts, expert_bytes, group_limited_route)
 
 
 def _normal(fan_in_axes: int = 1):
@@ -64,6 +65,15 @@ class SwiGLU(nn.Module):
         return _dot(h, w_down, self.dtype)
 
 
+def expert_counter_units(model) -> dict:
+    """``tick_counter_units`` of a model whose expert layers are
+    :class:`RoutedExperts`: the device counts ``experts_read`` (a tick's
+    bytes pass an int32, its experts do not) and the host keeps
+    ``expert_weight_bytes``, that many times one expert's matrices."""
+    return {"experts_read": ("expert_weight_bytes", expert_bytes(
+        model.d_model, model.moe_intermediate_size, model.dtype))}
+
+
 class RoutedExperts(nn.Module):
     """The expert layer: sigmoid router over all experts with
     group-limited top-k (``n_group = topk_group = 1``: no limit), this
@@ -82,9 +92,6 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
     expert_tile: int = 128
-    # one loop over the held experts in place of one loop an expert
-    # (``ops/moe.py . dropless_held_experts``): less program to compile
-    rolled: bool = False
 
     @nn.compact
     def __call__(self, u, live):
@@ -113,7 +120,7 @@ class RoutedExperts(nn.Module):
                 x.astype(self.dtype), experts, gates, live.reshape(B * T),
                 w_gate.astype(self.dtype), w_up.astype(self.dtype),
                 w_down.astype(self.dtype), self.expert_rank * held,
-                self.expert_tile, self.rolled)
+                self.expert_tile)
         for name, value in counts.items():
             self.sow("counters", name, value, reduce_fn=jnp.add,
                      init_fn=lambda: jnp.zeros((), jnp.int32))
@@ -172,8 +179,7 @@ class RoutedExpertsByPart(RoutedExperts):
                 x.astype(self.dtype), experts, gates, live,
                 self.w_gate.astype(self.dtype), self.w_up.astype(self.dtype),
                 self.w_down.astype(self.dtype),
-                self.expert_rank * self.experts_held, self.expert_tile,
-                self.rolled)
+                self.expert_rank * self.experts_held, self.expert_tile)
         for name, value in counts.items():
             self.sow("counters", name, value, reduce_fn=jnp.add,
                      init_fn=lambda: jnp.zeros((), jnp.int32))

@@ -71,8 +71,8 @@ import numpy as np
 
 from distkeras_tpu.models.blocks import (  # noqa: F401 (re-exported)
     RoutedExperts, RoutedExpertsByPart, SwiGLU, _dot, _normal,
-    live_block_rows, live_packing, map_live_blocks, pack_live, rms_norm,
-    unpack_live)
+    expert_counter_units, live_block_rows, live_packing, map_live_blocks,
+    pack_live, rms_norm, unpack_live)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import mla
 
@@ -417,7 +417,10 @@ class DeepseekV32LM(nn.Module):
 
     # sown into the "counters" collection by every expert layer; the
     # serving tick returns their sums with the tick's tokens
-    tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+    tick_counters = ("routed_here", "routed_total", "expert_rows_computed",
+                     "experts_read")
+    # name -> (the name the host keeps it under, times what)
+    tick_counter_units = property(expert_counter_units)
     # a decode apply takes ``live_tokens`` (the dropless experts give
     # each token what they would give it alone), and not one compiled
     # count of rows but blocks: handed ``live_tokens = S * C`` the model

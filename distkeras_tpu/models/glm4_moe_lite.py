@@ -57,8 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.models.blocks import (
-    LivePacking, RoutedExperts, SwiGLU, _dot, _normal, live_packing,
-    rms_norm)
+    LivePacking, RoutedExperts, SwiGLU, _dot, _normal, expert_counter_units,
+    live_packing, rms_norm)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import mla
 
@@ -335,7 +335,10 @@ class Glm4MoeLiteLM(nn.Module):
 
     # sown into the "counters" collection by every expert layer, the
     # module's too; the serving tick returns their sums with its tokens
-    tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+    tick_counters = ("routed_here", "routed_total", "expert_rows_computed",
+                     "experts_read")
+    # name -> (the name the host keeps it under, times what)
+    tick_counter_units = property(expert_counter_units)
     # a decode apply takes ``live_tokens``: the dropless experts give
     # each token what they would give it alone
     packs_live_tokens = True
@@ -408,8 +411,7 @@ class Glm4MoeLiteLM(nn.Module):
                 routed_scaling_factor=self.routed_scaling_factor,
                 width=self.moe_intermediate_size,
                 n_shared_experts=self.n_shared_experts, dtype=self.dtype,
-                param_dtype=self.param_dtype, expert_tile=self.expert_tile,
-                rolled=True)
+                param_dtype=self.param_dtype, expert_tile=self.expert_tile)
         return dict(attn_kw=tuple(sorted(attn.items())),
                     ffn_kw=tuple(sorted(ffn.items())), dense=dense,
                     rms_eps=self.rms_eps, param_dtype=self.param_dtype)

@@ -56,8 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.models.blocks import (
-    RoutedExperts, SwiGLU, _dot, _normal, live_packing, pack_live, rms_norm,
-    unpack_live)
+    RoutedExperts, SwiGLU, _dot, _normal, expert_counter_units, live_packing,
+    pack_live, rms_norm, unpack_live)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import hybrid_attend, splash_prefill
 from distkeras_tpu.ops.mla import rope_half
@@ -257,7 +257,10 @@ class MiMoV2LM(nn.Module):
 
     # sown into the "counters" collection by every expert layer; the
     # serving tick returns their sums with the tick's tokens
-    tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+    tick_counters = ("routed_here", "routed_total", "expert_rows_computed",
+                     "experts_read")
+    # name -> (the name the host keeps it under, times what)
+    tick_counter_units = property(expert_counter_units)
     # a decode apply takes ``live_tokens``: the dropless experts give
     # each token what they would give it alone, so leaving a tick's
     # padding out changes no result

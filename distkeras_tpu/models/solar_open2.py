@@ -62,8 +62,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu.models.blocks import (
-    RoutedExperts, _dot, _normal, live_packing, pack_live, rms_norm,
-    unpack_live)
+    RoutedExperts, _dot, _normal, expert_counter_units, live_packing,
+    pack_live, rms_norm, unpack_live)
 from distkeras_tpu.models.registry import register_model
 from distkeras_tpu.ops import delta_rule, hybrid_attend, splash_prefill
 
@@ -352,7 +352,10 @@ class SolarOpen2LM(nn.Module):
 
     # sown into the "counters" collection by every expert layer; the
     # serving tick returns their sums with the tick's tokens
-    tick_counters = ("routed_here", "routed_total", "expert_rows_computed")
+    tick_counters = ("routed_here", "routed_total", "expert_rows_computed",
+                     "experts_read")
+    # name -> (the name the host keeps it under, times what)
+    tick_counter_units = property(expert_counter_units)
     # a decode apply takes ``live_tokens``: experts and delta rule give
     # each token what they would give it alone in its row's run, so
     # leaving a tick's padding out changes no result
@@ -498,7 +501,7 @@ class SolarOpen2LM(nn.Module):
             width=self.moe_intermediate_size,
             n_shared_experts=self.n_shared_experts,
             dtype=self.dtype, param_dtype=self.param_dtype,
-            expert_tile=self.expert_tile, rolled=True)
+            expert_tile=self.expert_tile)
         packing = None
         if live_tokens is not None:
             packing = live_packing(valid_lens, C, live_tokens)

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu.ops.grouped_experts import grouped_experts
+
 
 def switch_moe(
     x: jnp.ndarray,          # [S, D] local tokens
@@ -132,9 +134,14 @@ def group_limited_route(scores, bias, n_group: int, topk_group: int,
     return experts.astype(jnp.int32), gates
 
 
+def _on_tpu() -> bool:
+    """The grouped matmul's kernels are written for the TPU; elsewhere
+    the same steps run as plain XLA."""
+    return jax.default_backend() == "tpu"
+
+
 def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
-                          first: int, tile: int = 128,
-                          rolled: bool = False):
+                          first: int, tile: int = 128):
     """The part of a routed layer's result that the experts held here
     give: no capacity, no token dropped, no one-hot dispatch.
 
@@ -143,77 +150,68 @@ def dropless_held_experts(x, experts, gates, live, w_gate, w_up, w_down,
     reads (a mixed tick's padding is not routed); this chip holds the
     ``E_l`` experts ``first .. first + E_l - 1`` as ``w_gate``/``w_up
     [E_l, D, F]`` and ``w_down [E_l, F, D]`` (SwiGLU). The (token,
-    expert) pairs sent to held experts are sorted by expert and each
-    expert's rows run through its weights ``tile`` rows at a time, as
-    many tiles as it was sent rows (a grouped matmul whose trip counts
-    come from the routing), gathered from ``x`` and scatter-added into
-    the result. Returns ``(y [N, D] float32, counts)`` with ``counts`` =
-    ``routed_here`` (pairs of live tokens sent to held experts),
-    ``routed_total`` (all pairs of live tokens) and
+    expert) pairs are sorted by expert, pairs sent to no held expert
+    last, and a grouped matmul runs over each expert's rows ``tile`` at
+    a time: one gather of the rows, each touched expert's weights read
+    once a tile, one combine into the tokens' order. On a TPU that is
+    :func:`distkeras_tpu.ops.grouped_experts.grouped_experts` (two
+    Pallas launches that walk the row tiles expert by expert, as many
+    steps as the routing made; it refuses widths its launches cannot
+    take); elsewhere the same three steps in plain XLA around
+    ``jax.lax.ragged_dot``. Returns ``(y [N, D] float32,
+    counts)`` with ``counts`` = ``routed_here`` (pairs of live tokens
+    sent to held experts), ``routed_total`` (all pairs of live tokens),
     ``expert_rows_computed`` (rows the tiles ran over, padding
-    included), int32 scalars. ``rolled`` walks all tiles in one loop
-    that takes each tile's expert's weights by a dynamic slice, where
-    the default unrolls one loop an expert: the same tiles in the same
-    order, and ``E_l`` times less program to compile (20 experts in
-    each of 8 layers were half of a tick program's compile)."""
+    included: ``tile`` for every ``tile`` rows or part of them that an
+    expert was sent) and ``experts_read`` (held experts that were sent
+    a row: times :func:`expert_bytes` what the layer has to read, which
+    the host works out, since the layers of one tick take the bytes
+    past an int32), int32 scalars."""
     N, D = x.shape
     k = experts.shape[1]
-    E_l = w_gate.shape[0]
+    E_l, _, F = w_gate.shape
     local = experts - first
     held = (local >= 0) & (local < E_l) & live[:, None]
     key = jnp.where(held, local, E_l).reshape(N * k)
     order = jnp.argsort(key, stable=True)
-    # a tile that starts near the end reads past the pairs: padding
-    # keeps the slice from being clamped back onto other rows
-    tok = jnp.pad((order // k).astype(jnp.int32), (0, tile),
-                  constant_values=N)
-    gate = jnp.pad(gates.reshape(N * k)[order], (0, tile))
-    sizes = jnp.bincount(key, length=E_l + 1)[:E_l].astype(jnp.int32)
-    starts = jnp.cumsum(sizes) - sizes
-    tiles = (sizes + tile - 1) // tile
-    y = jnp.zeros((N, D), jnp.float32)
-
-    def rows_of(e):
-        """Tile ``i`` of expert ``e`` (a Python int, or traced where
-        the loop is rolled), added into ``y``."""
-        def rows(i, y):
-            at = starts[e] + i * tile
-            mine = i * tile + jnp.arange(tile) < sizes[e]
-            ids = jnp.where(mine, jax.lax.dynamic_slice(tok, (at,), (tile,)),
-                            N)
-            g = jnp.where(mine, jax.lax.dynamic_slice(gate, (at,), (tile,)),
-                          0.0)
-            xt = jnp.take(x, ids, axis=0, mode="fill", fill_value=0)
-            h = jax.nn.silu(jnp.dot(
-                xt, w_gate[e], preferred_element_type=jnp.float32)
-            ) * jnp.dot(xt, w_up[e], preferred_element_type=jnp.float32)
-            out = jnp.dot(h.astype(x.dtype), w_down[e],
-                          preferred_element_type=jnp.float32)
-            return y.at[ids].add(out * g[:, None], mode="drop")
-
-        return rows
-
-    if rolled:
-        # one loop over all tiles, expert by expert: tile j belongs to
-        # the expert whose run of tiles it falls in (a loop an expert
-        # inside a loop over experts would hand each expert's weights to
-        # the inner loop as a copy)
-        ends = jnp.cumsum(tiles)
-
-        def flat(j, y):
-            e = jnp.searchsorted(ends, j, side="right").astype(jnp.int32)
-            return rows_of(e)(j - (ends[e] - tiles[e]), y)
-
-        y = jax.lax.fori_loop(0, ends[-1], flat, y)
+    sizes = (key[:, None] == jnp.arange(E_l)).sum(0, dtype=jnp.int32)
+    tok = (order // k).astype(jnp.int32)
+    gate = gates.reshape(N * k)[order].astype(jnp.float32)
+    if _on_tpu():
+        y = grouped_experts(x, tok, gate, sizes, w_gate, w_up, w_down,
+                            tile=tile, interpret=False)
     else:
-        for e in range(E_l):
-            y = jax.lax.fori_loop(0, tiles[e], rows_of(e), y)
+        y = _grouped_xla(x, tok, gate, sizes, w_gate, w_up, w_down)
     counts = {
         "routed_here": held.sum(dtype=jnp.int32),
         "routed_total": live.sum(dtype=jnp.int32) * k,
-        "expert_rows_computed": tiles.sum(dtype=jnp.int32) * tile,
+        "expert_rows_computed": ((sizes + tile - 1) // tile).sum(
+            dtype=jnp.int32) * tile,
+        "experts_read": (sizes > 0).sum(dtype=jnp.int32),
     }
     return y, counts
+
+
+def expert_bytes(d: int, width: int, dtype) -> int:
+    """Bytes of one SwiGLU expert's three matrices as a tick reads them
+    (``[d, width]`` twice, ``[width, d]``, in the compute dtype)."""
+    return 3 * d * width * jnp.dtype(dtype).itemsize
+
+
+def _grouped_xla(x, tok, gate, sizes, w_gate, w_up, w_down):
+    """:func:`~distkeras_tpu.ops.grouped_experts.grouped_experts` in
+    plain XLA, for the backends its kernels are not written for: the
+    sorted rows gathered, three ragged matmuls (a row past the last
+    expert's run comes out zero), one scatter-add."""
+    xs = x[tok]
+
+    def dot(rows, bank):
+        return jax.lax.ragged_dot(rows, bank, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    out = dot(h.astype(x.dtype), w_down)
+    return jnp.zeros(x.shape, jnp.float32).at[tok].add(out * gate[:, None])
 
 
 class SwitchMoE(nn.Module):

@@ -399,7 +399,8 @@ def _paged_prefill_fn(dm_paged, ctx: Optional[_ShardCtx] = None):
 
 # a tick's work that only some models have (see ServingEngine.stats)
 _MODEL_WORK = ("index_positions_scored", "keys_selected", "routed_here",
-               "routed_total", "expert_rows_computed", "full_key_positions",
+               "routed_total", "expert_rows_computed", "expert_weight_bytes",
+               "full_key_positions",
                "window_key_positions", "state_rows_stepped",
                "chunk_positions_live", "chunk_positions_computed",
                "window_positions", "mtp_positions_fed")
@@ -413,6 +414,19 @@ def _counter_sums(sown, names):
         sum((v for path, v in flat.items() if path[-1] == name),
             jnp.zeros((), jnp.int32)).astype(jnp.int32)
         for name in names])
+
+
+def _counter_work(model, words) -> dict:
+    """A tick's ``model.tick_counters`` as the host keeps them: a
+    counter the model names in ``tick_counter_units`` under the name
+    and times the unit given there (what the device can only count in
+    whole units of, an int32 like the rest)."""
+    units = getattr(model, "tick_counter_units", {})
+    work = {}
+    for name, n in zip(model.tick_counters, words):
+        kept, unit = units.get(name, (name, 1))
+        work[kept] = n * unit
+    return work
 
 
 def _packed_count(budget: int, slots: int, chunk: int) -> int:
@@ -1720,7 +1734,10 @@ class ServingEngine:
     the dtype they are handed (bf16 there). The engine asks such a
     model three things: ``tick_counters`` (sums returned with a tick's
     tokens: ``routed_here``, ``routed_total``,
-    ``expert_rows_computed``), ``kv_positions_fetched`` /
+    ``expert_rows_computed``, ``experts_read``; its
+    ``tick_counter_units`` says which of them the host keeps under
+    another name and times what: the last as ``expert_weight_bytes``),
+    ``kv_positions_fetched`` /
     ``index_topk`` (the host's counts ``key_positions_fetched``,
     ``index_positions_scored``, ``keys_selected``) and
     ``serving_refusals``. For that model the constructor **refuses**,
@@ -1989,7 +2006,8 @@ class ServingEngine:
         # what only a model with a learned selection over its cache or
         # with routed experts counts (index_positions_scored,
         # keys_selected; routed_here, routed_total,
-        # expert_rows_computed from the device): name -> total
+        # expert_rows_computed from the device, expert_weight_bytes
+        # from its experts_read): name -> total
         self.model_work_totals: dict = {}
         self._flight_ns = 0  # time spent building/recording snapshots
         self._tick_ns = 0    # total tick wall time (plan+device+stream)
@@ -3836,8 +3854,8 @@ class ServingEngine:
             if counters and rec.work is not None:
                 # what the model counted on the device rides behind the
                 # S tokens (see _mixed_tick_fn)
-                rec.work.update(zip(
-                    counters, toks_host[self.slots:].tolist()))
+                rec.work.update(_counter_work(
+                    self.model, toks_host[self.slots:].tolist()))
             # the device buffers are freed here, on the read's side of
             # the boundary (a millisecond of this thread's time on a
             # v5e, PR 25), not wherever the record happens to die
@@ -4288,8 +4306,8 @@ class ServingEngine:
             if counters and rec.work is not None:
                 # what the model counted on the device rides behind the
                 # S accepted lengths (see _mtp_verify_fn)
-                rec.work.update(zip(
-                    counters, acc_host[self.slots:].tolist()))
+                rec.work.update(_counter_work(
+                    self.model, acc_host[self.slots:].tolist()))
                 acc_host = acc_host[:self.slots]
             rec.toks = rec.acc = None  # freed here, as in _reconcile
         wait_ms = wait.ms
@@ -4670,11 +4688,18 @@ class ServingEngine:
         plan_ms = rec.plan_ms
         device_tick_ms, starved_ms, unasked_ms, clock_err_ms = clock
         # the clock's values ride the span: each estimate lies on the
-        # device trace's clock beside the operations it describes
+        # device trace's clock beside the operations it describes; so do
+        # the counters the model keeps in a unit of its own (the bytes
+        # of expert weights the tick had to read: over the seconds under
+        # moe_experts in the same window, the layer's share of the
+        # chip's memory rate)
+        work = rec.work or {}
+        read = {kept: work[kept] for kept, _ in getattr(
+            self.model, "tick_counter_units", {}).values() if kept in work}
         with self._phase("record", tick=rec.tick, program=rec.program,
                          device_tick_ms=device_tick_ms,
                          device_starved_ms=starved_ms,
-                         device_unasked_ms=unasked_ms):
+                         device_unasked_ms=unasked_ms, **read):
             self._tick_ns += int((plan_ms + device_ms + stream_ms) * 1e6)
             # runtime introspection runs with or without a recorder (the
             # gauges are its output); only the snapshot build + ring append
@@ -4942,7 +4967,9 @@ class ServingEngine:
             # experts: index_positions_scored_total, keys_selected_total
             # (beside attended_tokens_total: the pairs a dense attend
             # would have been allowed), routed_here_total over
-            # routed_total_total, expert_rows_computed_total; for a model
+            # routed_total_total, expert_rows_computed_total,
+            # expert_weight_bytes_total (the banks of the held experts
+            # that were sent a row, every apply); for a model
             # whose layers differ in kind: full_key_positions_total,
             # window_key_positions_total (K/V positions the attends of
             # each kind copied in, summed over the kind's layers) and

@@ -91,32 +91,67 @@ class TokenStream:
     def __init__(self):
         self._q: _queue.Queue = _queue.Queue()
         self.finish_reason: Optional[str] = None
+        # where forward() sends the frames in place of the queue
+        self._sink: Optional[Callable] = None
+        self._lock = threading.Lock()
 
     # engine side -----------------------------------------------------------
 
     def _put(self, tok: int):
-        self._q.put(("tok", tok))
+        with self._lock:
+            if self._sink is None:
+                self._q.put(("tok", tok))
+            else:
+                self._sink("tok", tok)
 
     def _finish(self, reason: str):
-        self._q.put(("end", reason))
+        with self._lock:
+            if self._sink is None:
+                self._q.put(("end", reason))
+            else:
+                self.finish_reason = reason
+                self._sink("end", reason)
 
     # consumer side ---------------------------------------------------------
 
     def __iter__(self):
         while True:
-            kind, val = self._q.get()
+            # a Queue locks itself; _lock only orders the engine's puts
+            # against forward()'s drain
+            kind, val = self._q.get()  # analysis: unguarded-ok
             if kind == "end":
+                # analysis: unguarded-ok (the one consumer's, at the end)
                 self.finish_reason = val
                 return
             yield val
+
+    def forward(self, sink: Callable):
+        """Hand every frame to ``sink(kind, value)`` in place of the
+        queue: those already queued first, then each as it is emitted,
+        on the emitting thread (so ``sink`` must not block). One
+        consumer of many streams takes them this way, with no thread a
+        stream waiting on a queue (``LMServer``'s sender a
+        connection)."""
+        with self._lock:
+            while True:
+                try:
+                    kind, val = self._q.get_nowait()
+                except _queue.Empty:
+                    break
+                if kind == "end":
+                    self.finish_reason = val
+                sink(kind, val)
+            self._sink = sink
 
     def tokens(self, timeout: Optional[float] = 60.0) -> List[int]:
         """Drain the stream to completion (bounded wait per token so a
         dead engine raises ``queue.Empty`` instead of hanging)."""
         out: List[int] = []
         while True:
+            # analysis: unguarded-ok (as in __iter__)
             kind, val = self._q.get(timeout=timeout)
             if kind == "end":
+                # analysis: unguarded-ok (as in __iter__)
                 self.finish_reason = val
                 return out
             out.append(val)

@@ -113,9 +113,10 @@ ui.perfetto.dev.
 
 Tokens stream as the engine emits them — a connection may hold many
 in-flight requests, so frames are tagged with the request id and the
-client demultiplexes. Token pushes run in per-request pump threads fed by
-the request's :class:`TokenStream`, so a slow client never stalls the
-engine loop; a per-connection lock keeps frames whole.
+client demultiplexes. The engine's loop only queues what a request's
+:class:`TokenStream` emits for the connection's one sender thread, so a
+slow client never stalls it; the sender writes all that stands queued
+(a tick's tokens) at once, and a per-connection lock keeps frames whole.
 """
 
 from __future__ import annotations
@@ -123,9 +124,11 @@ from __future__ import annotations
 import queue as _queue
 import socket
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from distkeras_tpu.networking import connect, recv_msg, send_msg
+from distkeras_tpu.networking import (
+    MsgReader, connect, recv_msg, send_msg, send_msgs)
 from distkeras_tpu.serving.engine import ServingEngine
 from distkeras_tpu.serving.scheduler import DrainingError, QueueFullError
 from distkeras_tpu.serving.weights import (
@@ -243,7 +246,7 @@ class LMServer:
         self._conns: List[socket.socket] = []
         self._conns_lock = threading.Lock()
         # critical-path "stream" phase: the delivery tail after the
-        # engine finished decoding — observed per request by the pump,
+        # engine finished decoding — observed per request by the sender,
         # into the same family the engine fills its phases into
         self._m_cp_stream = engine.registry.histogram(
             "serving_request_critical_path_ms",
@@ -285,8 +288,8 @@ class LMServer:
         for t in self._threads:
             t.join(timeout)
         # the loop has stopped and emits no more: end the streams of
-        # the requests it still held, or their pump threads wait on
-        # them for ever and keep this server, the engine and its cache
+        # the requests it still held, or their connections' sender
+        # threads wait for ever and keep this server, the engine and its cache
         # (gigabytes of device memory) alive after stop()
         if not self._loop_thread.is_alive():
             self.engine.abandon_streams()
@@ -319,50 +322,75 @@ class LMServer:
         with lock:
             send_msg(conn, msg)
 
-    def _pump(self, conn, lock, req):
-        """Forward one request's token stream to the client."""
-        import time
-
-        n = 0
-        t0 = time.monotonic()
-        try:
-            for tok in req.stream:
-                self._send(conn, lock, {"id": req.rid, "t": int(tok)})
-                n += 1
-            # span before the done frame (same discipline as
-            # _notify_finish): a client that saw "done" can immediately
-            # trace_dump and find the stream span in the chain
-            end = time.monotonic()
-            self.engine.tracer.record(
-                req.trace_id, "stream", t0, (end - t0) * 1e3, tokens=n,
-            )
-            # delivery tail: how long the pump kept running after the
-            # engine finished the request (done_t is set before the
-            # stream's end sentinel, so it is visible here)
-            self._m_cp_stream.observe(
-                max(0.0, (end - req.done_t) * 1e3)
-                if req.done_t is not None else 0.0
-            )
-            self._send(conn, lock, {
-                "id": req.rid, "done": 1,
-                "reason": req.stream.finish_reason, "n": n,
-            })
-        except (ConnectionError, OSError):
-            # client went away mid-stream: drain silently (the engine
-            # finishes the request; its tokens are simply dropped). A
-            # stream whose end was already read (the "done" frame is
-            # what failed to send) holds nothing more to wait for
-            if req.stream.finish_reason is None:
-                for _ in req.stream:
-                    pass
-            self.engine.tracer.record(
-                req.trace_id, "stream", t0,
-                (time.monotonic() - t0) * 1e3, tokens=n, aborted=1,
-            )
+    def _pump(self, conn, lock, outbox):
+        """Forward this connection's token streams to the client: every
+        frame that stands in ``outbox`` in one write, so a tick's tokens
+        (the engine emits them together) cost one wake-up and one
+        system call, however many requests they belong to. ``outbox``
+        holds ``(request, kind, value)``: ``"open"`` with the time when
+        the request was accepted, then what its stream emits
+        (``TokenStream.forward``); ``None`` says the handler has gone,
+        and the thread ends with the last open stream. (A pump thread a
+        request wrote each token as two small segments under the send
+        lock, and the client made two ``recv`` a frame: once an engine
+        made more frames a second than that reader took, the connection
+        fell to five frames a second: PERF.md §6, PR 43.)"""
+        live = {}      # rid -> [accepted at, tokens forwarded]
+        gone = False   # the client went away: later frames are dropped
+        closing = False
+        while live or not closing:
+            batch = [outbox.get()]
+            try:
+                while True:
+                    batch.append(outbox.get_nowait())
+            except _queue.Empty:
+                pass
+            frames = []
+            for item in batch:
+                if item is None:
+                    closing = True
+                    continue
+                req, kind, val = item
+                if kind == "open":
+                    live[req.rid] = [val, 0]
+                elif kind == "tok":
+                    live[req.rid][1] += 1
+                    frames.append({"id": req.rid, "t": int(val)})
+                else:
+                    t0, n = live.pop(req.rid)
+                    # span before the done frame (same discipline as
+                    # _notify_finish): a client that saw "done" can
+                    # immediately trace_dump and find the stream span
+                    # in the chain; a client that went away mid-stream
+                    # (the engine finished the request, its tokens were
+                    # dropped) leaves an aborted one
+                    end = time.monotonic()
+                    self.engine.tracer.record(
+                        req.trace_id, "stream", t0, (end - t0) * 1e3,
+                        tokens=n, **({"aborted": 1} if gone else {}))
+                    if gone:
+                        continue
+                    # delivery tail: how long after the engine finished
+                    # the request its last frame leaves
+                    self._m_cp_stream.observe(
+                        max(0.0, (end - req.done_t) * 1e3)
+                        if req.done_t is not None else 0.0
+                    )
+                    frames.append({"id": req.rid, "done": 1,
+                                   "reason": val, "n": n})
+            if frames and not gone:
+                try:
+                    with lock:
+                        send_msgs(conn, frames)
+                except (ConnectionError, OSError):
+                    gone = True
 
     def _handle(self, conn: socket.socket):
         lock = threading.Lock()
-        pumps: List[threading.Thread] = []
+        # every stream of this connection reaches the client through
+        # one thread (started with the first request)
+        outbox: _queue.SimpleQueue = _queue.SimpleQueue()
+        sender: Optional[threading.Thread] = None
         # push_weights chunk reassembly, per connection (chunks of one
         # push always ride one connection, in order)
         push_buf: dict = {}
@@ -406,16 +434,20 @@ class LMServer:
                                 None if msg.get("parent_span") is None
                                 else str(msg["parent_span"])),
                         )
-                        # ack BEFORE the pump starts so the acceptance
-                        # frame always precedes the first token frame
+                        # ack BEFORE the stream is forwarded so the
+                        # acceptance frame always precedes the first
+                        # token frame
                         self._send(conn, lock, {"ok": 1, "id": req.rid,
                                                 "trace": req.trace_id})
-                        t = threading.Thread(
-                            target=self._pump, args=(conn, lock, req),
-                            daemon=True,
-                        )
-                        t.start()
-                        pumps.append(t)
+                        if sender is None:
+                            sender = threading.Thread(
+                                target=self._pump,
+                                args=(conn, lock, outbox), daemon=True)
+                            sender.start()
+                        outbox.put((req, "open", time.monotonic()))
+                        req.stream.forward(
+                            lambda kind, val, req=req: outbox.put(
+                                (req, kind, val)))
                     elif op == "stats":
                         self._send(conn, lock,
                                    {"ok": 1, "stats": self.engine.stats()})
@@ -583,8 +615,9 @@ class LMServer:
         except (ConnectionError, OSError):
             return
         finally:
-            for t in pumps:
-                t.join(timeout=5.0)
+            outbox.put(None)
+            if sender is not None:
+                sender.join(timeout=5.0)
             conn.close()
             with self._conns_lock:
                 if conn in self._conns:
@@ -724,21 +757,26 @@ class ServingClient:
 
     def _read_loop(self):
         reason = "closed by client"
+        # every whole frame a recv holds at once: a tick's tokens come
+        # together, and a system call a frame (with a wait for the
+        # interpreter lock after each) is what a busy process cannot pay
+        reader = MsgReader(self._sock, max_bytes=self.max_frame_bytes)
         try:
             while True:
-                msg = recv_msg(self._sock,
-                               max_bytes=self.max_frame_bytes)
-                if msg is None:
+                msgs = reader.recv_msgs()
+                if msgs is None:
                     reason = "server closed the connection"
                     break
-                if "t" in msg:
-                    self._stream_q(int(msg["id"])).put(("tok", int(msg["t"])))
-                elif "done" in msg:
-                    self._stream_q(int(msg["id"])).put(
-                        ("end", str(msg.get("reason")))
-                    )
-                else:
-                    self._acks.put(msg)
+                for msg in msgs:
+                    if "t" in msg:
+                        self._stream_q(int(msg["id"])).put(
+                            ("tok", int(msg["t"])))
+                    elif "done" in msg:
+                        self._stream_q(int(msg["id"])).put(
+                            ("end", str(msg.get("reason")))
+                        )
+                    else:
+                        self._acks.put(msg)
         except (ConnectionError, OSError) as e:
             if not self._closed:  # a local close() races the recv error
                 reason = f"connection lost ({type(e).__name__}: {e})"

@@ -477,6 +477,9 @@ def report_flight(path: str, last: Optional[int] = None,
             f"{sum(r['routed_total'] for r in routed)}  "
             f"expert_rows_computed: {rows}"
             + (f" ({100 * here / rows:.1f}% useful)" if rows else "")
+            + (f"  expert_weight_bytes: "
+               f"{sum(r['expert_weight_bytes'] for r in routed)}"
+               if "expert_weight_bytes" in routed[0] else "")
             + "\n"
         )
     kinds = [r for r in ticks if "window_key_positions" in r]

@@ -22,8 +22,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distkeras_tpu.ops import (hybrid_attend, paged_attention,
-                               pallas_attention, pallas_pair, splash_prefill)
+from distkeras_tpu.ops import (grouped_experts, hybrid_attend, moe,
+                               paged_attention, pallas_attention,
+                               pallas_pair, splash_prefill)
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +56,7 @@ def for_chip(monkeypatch, one_chip):
     for mod in (pallas_attention, pallas_pair, paged_attention,
                 splash_prefill, hybrid_attend):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -300,6 +302,92 @@ def test_the_packed_tick_at_the_benchmarks_shapes(for_chip, monkeypatch,
                               "16x1x50257"}
     assert matmul_rows(None) == {"16x64x6144", "16x64x2048", "16x64x8192",
                                  "16x64x50257"}
+
+
+# -- the held experts' grouped matmul ------------------------------------------
+
+@pytest.mark.parametrize("name,N,k,D,F,held", [
+    ("glm-4.7-flash-serve [64, 2]", 128, 4, 2048, 1536, 64),
+    ("glm-4.7-flash-serve packed", 256, 4, 2048, 1536, 64),
+    ("mimo-v2.5-serve packed", 512, 8, 4096, 2048, 16),
+    ("solar-open2-250b-serve packed", 256, 8, 4096, 1280, 20),
+    ("deepseek-v3.2-exp-serve by blocks", 2048, 8, 7168, 2048, 16),
+])
+def test_grouped_experts_at_the_four_configurations_widths(for_chip, name, N,
+                                                           k, D, F, held):
+    """Both launches at the rows and widths of each expert
+    configuration's widest tick (and glm's verify tick): Mosaic's block
+    rules, the row copies' alignment, the scalar memory the pair lists
+    take and the VMEM of the weight blocks, all met before any chip
+    time."""
+    text = for_chip(
+        functools.partial(grouped_experts.grouped_experts, tile=128,
+                          interpret=False),
+        ((N, D), BF16), ((N * k,), I32), ((N * k,), F32), ((held,), I32),
+        ((held, D, F), BF16), ((held, D, F), BF16), ((held, F, D), BF16))
+    assert "moe_gate_up" in text and "moe_down" in text
+    assert text.count("tpu_custom_call") >= 2
+    assert grouped_experts.supports(D, F, 128)
+
+
+def test_the_chip_has_one_form_of_the_grouped_matmul(monkeypatch):
+    """A width the launches cannot take is refused where they would be
+    built, by name: on a chip the layer does not fall back to the XLA
+    form the other backends run (the tests' tiny widths run the kernels
+    in interpret mode, or plain XLA off the chip)."""
+    assert not grouped_experts.supports(64, 32, 8)
+    x = jnp.zeros((8, 64), BF16)
+    bank = jnp.zeros((2, 64, 32), BF16)
+    args = (x, jnp.zeros((8, 2), I32), jnp.ones((8, 2), F32),
+            jnp.ones((8,), bool), bank, bank, bank.transpose(0, 2, 1), 0, 8)
+    y, _ = moe.dropless_held_experts(*args)
+    assert y.shape == (8, 64)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="d_model in whole 1024s.*64, 32, 8"):
+        moe.dropless_held_experts(*args)
+
+
+def test_the_expert_banks_are_read_where_they_lie(for_chip, one_chip):
+    """chipbench's ``glm-4.7-flash-serve`` at its real size, the ``[64,
+    2]`` verify tick as the engine builds it, from shapes alone: the
+    held experts' layers are the two launches a layer (five layers and
+    the module), nothing under ``moe_experts`` is a loop, and no bank
+    (1.2 GB a layer) is copied on its way to them."""
+    from chipbench.harness import spec
+    from distkeras_tpu.models import get_model
+    from distkeras_tpu.serving import engine
+
+    cfg = spec.load("configs", "glm-4.7-flash-serve")
+    S, V, m = cfg["engine"]["slots"], cfg["model"]["vocab_size"], cfg["model"]
+    model = get_model(spec.model_name(cfg), **m,
+                      dtype=jnp.dtype(cfg["compute_dtype"]))
+    dm = model.clone(decode=True, slot_cursor=True, parent=None,
+                     verify_window=2)
+    cache = jax.eval_shape(dm.init, jax.random.PRNGKey(0),
+                           jnp.zeros((S, 1), I32))["cache"]
+    params = jax.eval_shape(
+        lambda: spec.reference(cfg).make_params(cfg, 0))["params"]
+    sds = jax.ShapeDtypeStruct
+    state = (sds((S,), I32), sds((S, 1), I32), sds((S, 1, V), F32),
+             sds((S, 2), jnp.uint32))
+    args = _abstract(({"params": params}, cache, sds((S, V), F32),
+                      sds((S, 2), jnp.uint32), sds((S * 2 + 5 * S,), I32),
+                      state), one_chip)
+    text = engine._mtp_verify_fn(
+        engine._CacheLayout(dm), (engine._IDLE_CFG,) * S, 2, None,
+        None).lower(*args).compile().as_text()
+    applies = m["num_layers"] - m["first_k_dense"] + 1
+    for launch in ("moe_gate_up", "moe_down"):
+        calls = re.findall(rf"^\s*%?{launch}[.\d]* = .*custom-call\(.*"
+                           rf'custom_call_target="tpu_custom_call"', text,
+                           re.M)
+        assert len(calls) == applies, (launch, len(calls))
+    assert [line for line in text.splitlines()
+            if " while(" in line and "moe_experts" in line] == []
+    bank = (m["n_routed_experts"] * m["d_model"]
+            * m["moe_intermediate_size"] * 2)
+    _, moves = _moves(text)
+    assert [what for what, _, size, _ in moves if size >= bank] == []
 
 
 # -- deepseek-v3.2-exp-serve: what the compiled ticks do to the cache ---------

@@ -13,6 +13,8 @@ against whole rows, grouped rows against every expert over every token):
 measured, and a hundredth of what either control moves them by.
 """
 
+import contextlib
+import functools
 import gc
 import threading
 import time
@@ -161,9 +163,18 @@ def test_chunked_prefill_then_decode_agrees_with_the_reference(small):
 
 def test_the_engine_counts_what_the_model_selects_and_routes(small,
                                                              tmp_path,
-                                                             capsys):
+                                                             capsys,
+                                                             monkeypatch):
     cfg, params, model = small
     prompts = [_tokens(n, i) for i, n in enumerate((30, 11, 21))]
+    spans = []
+
+    class Span(contextlib.nullcontext):
+        def __init__(self, name, **args):
+            super().__init__()
+            spans.append((name, args))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
     eng, reqs, _ = _serve(model, params, prompts, [6, 6, 6], slots=2,
                           max_len=64, prefill_chunk=8)
     st = eng.stats()
@@ -176,6 +187,12 @@ def test_the_engine_counts_what_the_model_selects_and_routes(small,
     assert 0 < st["routed_here_total"] < st["routed_total_total"]
     rows = st["expert_rows_computed_total"]
     assert rows >= st["routed_here_total"] and rows % SMALL["expert_tile"] == 0
+    # the banks the ticks had to read: whole experts (three matrices of
+    # d_model x moe_intermediate_size in float32 here), at least one and
+    # at most the four held a layer a tick, the same sum on the ring
+    bank = 3 * SMALL["d_model"] * SMALL["moe_intermediate_size"] * 4
+    read = st["expert_weight_bytes_total"]
+    assert read % bank == 0 and 0 < read <= eng.ticks * 2 * 4 * bank
     # a query at position t scores t + 1 positions and may attend
     # min(t + 1, 16) of them
     assert st["index_positions_scored_total"] == st["attended_tokens_total"]
@@ -185,6 +202,13 @@ def test_the_engine_counts_what_the_model_selects_and_routes(small,
     assert st["key_positions_fetched_total"] < st["cache_positions_total"]
     ticks = [t for t in eng.flight.snapshots() if t.get("kind") == "tick"]
     assert all("routed_here" in t and "keys_selected" in t for t in ticks)
+    assert sum(t["expert_weight_bytes"] for t in ticks) == read
+    # and each tick's on its engine.record span, beside the device
+    # clock's values: on a profile's clock, under the same window as
+    # the seconds of the scope moe_experts
+    recorded = {a["tick"]: a for name, a in spans if name == "engine.record"}
+    assert {t["tick"]: t["expert_weight_bytes"] for t in ticks} == {
+        n: a["expert_weight_bytes"] for n, a in recorded.items()}
     path = tmp_path / "flight.jsonl"
     eng.flight.dump(str(path), reason="manual")
     telemetry_report.main(["--flight", str(path)])
@@ -192,6 +216,7 @@ def test_the_engine_counts_what_the_model_selects_and_routes(small,
     assert "index_positions_scored:" in out and "keys_selected:" in out
     assert "routed_here/routed_total:" in out
     assert "expert_rows_computed:" in out
+    assert f"expert_weight_bytes: {read}" in out
 
 
 # -- the packed mixed tick: per-token layers over the blocks in use -------------
@@ -330,7 +355,8 @@ def test_a_tick_counts_the_blocks_it_ran(served_by_blocks, tmp_path,
     assert was["packed_ticks_total"] == 0
     assert st["query_positions_total"] < was["query_positions_total"]
     for name in ("routed_here_total", "routed_total_total",
-                 "expert_rows_computed_total", "keys_selected_total",
+                 "expert_rows_computed_total", "expert_weight_bytes_total",
+                 "keys_selected_total",
                  "index_positions_scored_total", "attended_tokens_total",
                  "useful_query_tokens_total"):
         assert st[name] == was[name], name
@@ -510,30 +536,125 @@ def test_group_limited_routing_agrees_with_the_reference(seed):
             <= 2).all()
 
 
-def test_the_grouped_matmul_gathers_scatters_and_counts():
-    rng = np.random.default_rng(0)
-    N, D, F, k = 19, 8, 6, 2
-    x = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
-    experts = jnp.asarray(rng.integers(0, 6, size=(N, k)), jnp.int32)
+# name: N, k, experts in all, held, first, D, F, tile, dtype, share of
+# the tokens live, experts the router ever chooses (None: any)
+_GROUPED = {
+    # the case the tile loop was held to before PR 43 (a token may name
+    # one expert twice)
+    "the_tile_loop_s_case": (19, 2, 6, 3, 2, 8, 6, 4, "float32", 0.8, None),
+    "a_held_subset": (24, 3, 12, 4, 4, 16, 32, 8, "float32", 1.0, None),
+    "the_whole_bank": (16, 4, 8, 8, 0, 16, 32, 8, "float32", 1.0, None),
+    "experts_sent_no_row": (12, 2, 16, 8, 8, 16, 32, 8, "float32", 1.0,
+                            (8, 11, 3)),
+    "more_rows_than_a_tile": (40, 2, 4, 2, 1, 16, 32, 8, "float32", 1.0,
+                              (1, 2)),
+    "dead_rows": (20, 2, 6, 6, 0, 16, 32, 8, "float32", 0.3, None),
+    "every_row_dead": (9, 2, 6, 6, 0, 16, 32, 8, "float32", 0.0, None),
+    "pairs_not_whole_tiles": (19, 3, 8, 5, 2, 16, 32, 8, "float32", 0.9,
+                              None),
+    "a_tile_of_128": (70, 4, 4, 3, 1, 128, 256, 128, "float32", 0.9, None),
+    "bfloat16": (24, 3, 12, 4, 4, 128, 256, 8, "bfloat16", 0.9, None),
+}
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("case", sorted(_GROUPED))
+def test_the_grouped_matmul_gathers_combines_and_counts(case, form,
+                                                        monkeypatch):
+    """Both forms of the grouped matmul (the Pallas launches in
+    interpret mode; the plain XLA the models take off the chip) against
+    the per-token loop, the four counters against counts made by
+    hand."""
+    from distkeras_tpu.ops import grouped_experts as ge, moe
+
+    N, k, E, E_l, first, D, F, tile, dtype, live_share, chosen = _GROUPED[
+        case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.normal(size=(N, D)), jnp.float32).astype(dt)
+    experts = jnp.asarray(rng.choice(chosen or E, size=(N, k)), jnp.int32)
     gates = jnp.asarray(rng.uniform(size=(N, k)), jnp.float32)
-    w = [jnp.asarray(rng.normal(size=s), jnp.float32)
-         for s in ((3, D, F), (3, D, F), (3, F, D))]
-    live = jnp.asarray(rng.uniform(size=N) < 0.8)
-    y, counts = dropless_held_experts(x, experts, gates, live, *w, first=2,
-                                      tile=4)
+    w = [jnp.asarray(rng.normal(size=s) / np.sqrt(s[1]), jnp.float32
+                     ).astype(dt)
+         for s in ((E_l, D, F), (E_l, D, F), (E_l, F, D))]
+    live = jnp.asarray(rng.uniform(size=N) < live_share)
+    if form == "kernel":
+        monkeypatch.setattr(
+            moe, "_grouped_xla", functools.partial(
+                ge.grouped_experts, tile=tile, interpret=True))
+    y, counts = dropless_held_experts(x, experts, gates, live, *w,
+                                      first=first, tile=tile)
     want = np.zeros((N, D), np.float32)
-    here = 0
+    sent = np.zeros(E_l, int)
+    f32 = [np.asarray(m, np.float32) for m in w]
     for t in range(N):
         for j in range(k):
-            e = int(experts[t, j]) - 2
-            if live[t] and 0 <= e < 3:
-                h = jax.nn.silu(x[t] @ w[0][e]) * (x[t] @ w[1][e])
-                want[t] += gates[t, j] * np.asarray(h @ w[2][e])
-                here += 1
-    assert np.abs(np.asarray(y) - want).max() < 1e-4
-    assert int(counts["routed_here"]) == here
+            e = int(experts[t, j]) - first
+            if live[t] and 0 <= e < E_l:
+                xt = np.asarray(x[t], np.float32)
+                h = jax.nn.silu(xt @ f32[0][e]) * (xt @ f32[1][e])
+                h = np.asarray(jnp.asarray(h).astype(dt), np.float32)
+                want[t] += float(gates[t, j]) * (h @ f32[2][e])
+                sent[e] += 1
+    tol = 1e-4 if dtype == "float32" else 2e-2
+    assert y.dtype == jnp.float32 and y.shape == (N, D)
+    assert np.abs(np.asarray(y) - want).max() < tol
+    assert int(counts["routed_here"]) == sent.sum()
     assert int(counts["routed_total"]) == int(live.sum()) * k
-    assert int(counts["expert_rows_computed"]) % 4 == 0
+    assert int(counts["expert_rows_computed"]) == sum(
+        -(-n // tile) * tile for n in sent)
+    assert int(counts["experts_read"]) == (sent > 0).sum()
+    assert all(c.dtype == jnp.int32 for c in counts.values())
+    if case == "experts_sent_no_row":
+        assert (sent > 0).sum() == 2 < E_l
+    if case == "more_rows_than_a_tile":
+        assert sent.max() > tile
+    if case == "every_row_dead":
+        assert not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("sizes,tile", [
+    ((3, 0, 9, 8, 0), 8), ((0, 0, 0), 8), ((130, 1), 128), ((5,), 4)])
+def test_the_walk_of_the_sorted_pairs_visits_each_tile_of_each_run(
+        sizes, tile):
+    from distkeras_tpu.ops.grouped_experts import visits
+
+    pairs = sum(sizes) + 5
+    expert, base, rows, n = (np.asarray(a) for a in visits(
+        jnp.asarray(sizes, jnp.int32), tile, pairs))
+    assert len(expert) == -(-pairs // tile) + len(sizes)
+    want, at = [], 0
+    for e, size in enumerate(sizes):
+        want += [(e, at + i, min(tile, size - i))
+                 for i in range(0, size, tile)]
+        at += size
+    assert int(n) == len(want)
+    assert list(zip(expert[:n], base[:n], rows[:n])) == want
+    # a visit past the count has no row and names rows that exist
+    assert not rows[n:].any() and (base <= pairs).all()
+    assert ((0 <= expert) & (expert < len(sizes))).all()
+
+
+def test_the_host_keeps_the_experts_read_as_bytes_past_an_int32():
+    """Six applies of glm-4.7-flash's whole bank are 7.25 GB a tick:
+    the device counts the experts that were sent a row, an int32 like
+    the other counters behind the tick's tokens, and the host keeps the
+    bytes of their matrices, a count made by hand here."""
+    from distkeras_tpu.models import get_model
+    from distkeras_tpu.serving import engine
+
+    model = get_model("glm4_moe_lite_lm", dtype=jnp.bfloat16)
+    assert model.tick_counters[-1] == "experts_read"
+    sown = {f"layers_{i}": {"moe": {
+        "experts_read": jnp.int32(64), "routed_here": jnp.int32(512)}}
+        for i in range(6)}
+    words = engine._counter_sums(sown, model.tick_counters)
+    assert words.dtype == jnp.int32 and words.shape == (4,)
+    bank = 64 * 3 * 2048 * 1536 * 2
+    assert engine._counter_work(model, np.asarray(words).tolist()) == {
+        "routed_here": 6 * 512, "routed_total": 0, "expert_rows_computed": 0,
+        "expert_weight_bytes": 6 * bank}
+    assert 6 * bank > 2 ** 32
 
 
 # -- the selection and the rope -----------------------------------------------

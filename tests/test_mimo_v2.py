@@ -462,11 +462,16 @@ PARENT = {
         # 3fe1ad9b...21875a. PR 40: the latent entry lies in two cache
         # leaves (latent [S, L, 16] and rope_key [S, 8, L] here), a
         # deliberate change to both ticks; they were e239c0ed...44c41c
-        # and 1f1b0d20...690d97 (the [3, 1] tick's since 7ce0293)
-        "mixed_8_24": "c2d4df761bcae4b01f1d29b0db54254eb70f3c85027e31db1d49a"
-                      "7e18ce58d3d",
-        "mixed_1_None": "20e82ef3c0633748702ba3f7c8ffe397735dc6ac8fbdbd5ccea"
-                        "f67706b741664"},
+        # and 1f1b0d20...690d97 (the [3, 1] tick's since 7ce0293).
+        # PR 43: the held experts' grouped matmul is a gather, three
+        # ragged matmuls and a scatter-add off the chip (two Pallas
+        # launches on it) where it was a loop of tiles, a deliberate
+        # change to both ticks of every expert model; they were
+        # c2d4df76...e58d3d and 20e82ef3...741664
+        "mixed_8_24": "5288050ef4b8d2bafe89d1e58f986bd8682a68e18081ad04aafed"
+                      "5476006a3fc",
+        "mixed_1_None": "2a20d9af8fb2e013525787f572752d778f914b6aa55c667c794"
+                        "04f3963c1b1bd"},
     "transformer_lm": {
         "params": "c01fb3e08f7b1c7d216c5ce24b4a4ef04b8e65fb722a7ae887ffa156"
                   "8870f503",
@@ -475,14 +480,16 @@ PARENT = {
         "mixed_1_None": "20f6a0e27c447583c1b040ff3f49f643361647ef01a3327b78d"
                         "2f1768ed2a3a8"},
     # this model's own, at commit 8c19c14, before solar_open2_lm came to
-    # share RoutedExperts, the live packing and the full attend with it
+    # share RoutedExperts, the live packing and the full attend with it.
+    # PR 43: the grouped matmul's new form (above), a deliberate change
+    # to both ticks; they were b4053b9a...9ca983 and 84176e4e...c6a67a
     "mimo_v2_lm": {
         "params": "c4302266a3b30edc927227c931ecc0593b0d89d337e16db7ac593ca61"
                   "e9c75e7",
-        "mixed_8_8": "b4053b9a6638fbc7ca888ee7c7b878beb4c1283c0ed8dbc8b429d6"
-                     "79d09ca983",
-        "mixed_1_None": "84176e4e11c0640d9f5e3815d7e64f20189bbba522de03e3ffa"
-                        "82248a5c6a67a"}}
+        "mixed_8_8": "eb83d328046d1c9a6179a0206ebc97237c0d40bb77c4d6b3d8ed93"
+                     "93edbe8a95",
+        "mixed_1_None": "a787ee7246980d1758c970db789f40c9f3d985d95a4e85f6d4e"
+                        "20008e4ec9880"}}
 TINY = {
     "deepseek_v32_lm": dict(
         vocab_size=64, d_model=32, num_layers=3, first_k_dense=1,

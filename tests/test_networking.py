@@ -166,3 +166,106 @@ def test_oversized_frame_rejected():
         cli.close()
         conn.close()
         srv.close()
+
+
+def _drip(sock, data, sizes):
+    """``data`` in writes of the given sizes (the last takes the rest)."""
+    at = 0
+    for n in list(sizes) + [len(data)]:
+        if at >= len(data):
+            break
+        sock.sendall(data[at:at + n])
+        at += n
+
+
+@pytest.mark.parametrize("case", [
+    "one_write", "split_in_a_header", "split_in_a_payload", "a_large_frame",
+    "eof_before_a_header", "eof_in_a_frame", "over_max_bytes",
+    "a_fault_rule_counts_frames"])
+def test_messages_sent_and_read_in_bulk(case):
+    """``send_msgs`` writes the frames ``send_msg`` would, back to back,
+    and ``MsgReader`` hands out every whole frame a ``recv`` holds: the
+    same messages in the same order wherever the stream is cut, and the
+    contract of ``recv_msg`` at its ends (``None`` on a clean EOF, a
+    ``FrameError`` for an EOF inside a frame or a frame over the
+    limit); under an armed fault injector both go frame by frame, so
+    that a rule's ``nth`` still counts frames."""
+    import struct
+
+    from flax import serialization
+
+    msgs = [{"id": i, "t": 1000 + i} for i in range(40)] + [{"ok": 1}]
+    cli, srv = _loopback_pair()
+    try:
+        reader = net.MsgReader(srv, max_bytes=1 << 22)
+        if case == "one_write":
+            before = net._SENT_FRAMES.value
+            net.send_msgs(cli, msgs)
+            assert net._SENT_FRAMES.value == before + len(msgs)
+            got = reader.recv_msgs()
+            # what one recv held came out together
+            assert len(got) > 1
+            while len(got) < len(msgs):
+                got += reader.recv_msgs()
+            assert got == msgs
+            # a reader of single frames reads the same bytes
+            net.send_msgs(cli, msgs[:3])
+            assert [net.recv_msg(srv) for _ in range(3)] == msgs[:3]
+        elif case in ("split_in_a_header", "split_in_a_payload"):
+            data = b"".join(
+                struct.pack(">Q", len(p)) + p
+                for p in map(serialization.msgpack_serialize, msgs))
+            first = len(serialization.msgpack_serialize(msgs[0])) + 8
+            cut = first + 3 if case == "split_in_a_header" else first + 11
+            t = threading.Thread(target=_drip, args=(cli, data, [cut, 1, 2]))
+            t.start()
+            got = []
+            while len(got) < len(msgs):
+                got += reader.recv_msgs()
+            t.join()
+            assert got == msgs
+        elif case == "a_large_frame":
+            big = {"blob": np.random.bytes(3 << 20)}
+            t = threading.Thread(target=net.send_msgs,
+                                 args=(cli, [msgs[0], big, msgs[1]]))
+            t.start()
+            got = []
+            while len(got) < 3:
+                got += reader.recv_msgs()
+            t.join()
+            assert got[0] == msgs[0] and got[2] == msgs[1]
+            assert got[1]["blob"] == big["blob"]
+        elif case == "eof_before_a_header":
+            net.send_msgs(cli, msgs[:2])
+            cli.close()
+            got = []
+            while len(got) < 2:
+                got += reader.recv_msgs()
+            assert got == msgs[:2] and reader.recv_msgs() is None
+        elif case == "eof_in_a_frame":
+            cli.sendall(struct.pack(">Q", 100) + b"half")
+            cli.close()
+            with pytest.raises(net.FrameError, match="truncated"):
+                reader.recv_msgs()
+        elif case == "over_max_bytes":
+            cli.sendall(struct.pack(">Q", 1 << 40))
+            with pytest.raises(net.FrameError, match="exceeds") as e:
+                reader.recv_msgs()
+            assert e.value.limit == 1 << 22
+        else:
+            injector = net.FaultInjector()
+            rule = injector.rule("drop", direction="send", nth=3)
+            net.install_fault_injector(injector)
+            try:
+                net.send_msgs(cli, msgs[:5])
+                got = []
+                while len(got) < 4:
+                    part = reader.recv_msgs()
+                    assert len(part) == 1  # frame by frame while armed
+                    got += part
+            finally:
+                net.uninstall_fault_injector()
+            assert got == msgs[:2] + msgs[3:5] and rule.fired == 1
+    finally:
+        cli.close()
+        srv.close()

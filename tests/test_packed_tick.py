@@ -251,10 +251,13 @@ SOLAR_KW = dict(
 # sha256 of the lowered text of the [3, 8] mixed tick of a tiny engine
 # under a budget of 6 (packed to 8 rows) at commit 67f0b59, before
 # deepseek_v32_lm came to pack by blocks; tests/test_mimo_v2.py pins
-# mimo_v2_lm's and a second transformer_lm the same way
+# mimo_v2_lm's and a second transformer_lm the same way. PR 43:
+# solar_open2_lm's expert layers take the grouped matmul's new form (a
+# gather, three ragged matmuls, a scatter-add off the chip), a
+# deliberate change; its tick was 771076a6...63698a
 PARENT_PROGRAMS = {
-    "solar_open2_lm": "771076a64b1004c6051f3483ee1a28e842dc44bdb4db42d96b330"
-                      "736aa63698a",
+    "solar_open2_lm": "49e32ae5ba65ae8d15a5145ecfc7594c764d8079c5fc3a4573fa7"
+                      "01987df548f",
     "transformer_lm": "978dca6c49254a5ea430d9024c0600098ebab1960cf4422ded6aa"
                       "7f9ebcd1ae7"}
 

@@ -487,3 +487,127 @@ def test_lockorder_detector_is_armed_in_this_suite():
     assert type(probe).__name__ == "_TrackedLock"
     with probe:
         pass
+
+
+@pytest.mark.parametrize("case", [
+    "queued_before", "emitted_after", "ended_before", "iterated_as_before"])
+def test_a_stream_forwarded_to_one_consumer(case):
+    """``TokenStream.forward(sink)``: what the engine queued before the
+    consumer came reaches the sink first and in order, what it emits
+    after goes straight to the sink on the emitting thread, the end
+    sets ``finish_reason`` either way; a stream nobody forwards is
+    iterated as it always was."""
+    from distkeras_tpu.serving.scheduler import TokenStream
+
+    s, got = TokenStream(), []
+    sink = lambda kind, val: got.append(  # noqa: E731
+        (kind, val, threading.current_thread().name))
+    me = threading.current_thread().name
+    if case == "queued_before":
+        for t in (5, 6, 7):
+            s._put(t)
+        s.forward(sink)
+        assert got == [("tok", t, me) for t in (5, 6, 7)]
+        assert s.finish_reason is None and s._q.empty()
+    elif case == "emitted_after":
+        s._put(5)
+        s.forward(sink)
+        t = threading.Thread(target=lambda: (s._put(6), s._finish("eos")),
+                             name="engine-loop")
+        t.start()
+        t.join()
+        assert got == [("tok", 5, me), ("tok", 6, "engine-loop"),
+                       ("end", "eos", "engine-loop")]
+        assert s.finish_reason == "eos" and s._q.empty()
+    elif case == "ended_before":
+        s._put(5)
+        s._finish("length")
+        s.forward(sink)
+        assert [g[:2] for g in got] == [("tok", 5), ("end", "length")]
+        assert s.finish_reason == "length"
+    else:
+        s._put(5)
+        s._finish("length")
+        assert list(s) == [5] and s.finish_reason == "length"
+        s2 = TokenStream()
+        s2._put(9)
+        s2._finish("eos")
+        assert s2.tokens(timeout=1.0) == [9] and s2.finish_reason == "eos"
+
+
+def _pump_threads():
+    return [t for t in threading.enumerate()
+            if getattr(getattr(t, "_target", None), "__name__", "") == "_pump"]
+
+
+@pytest.mark.parametrize("case", ["many_requests", "client_goes_away"])
+def test_one_thread_a_connection_forwards_its_streams(case):
+    """Every request of a connection reaches the client through ONE
+    sender thread, whatever their number (a thread a request, each
+    taking the send lock for a frame, stopped delivering at 2 400
+    tokens a second on the chip's machine: PR 43): the ack first, then
+    the request's tokens in order, then ``done`` with their count and
+    the stream span already recorded; a client that goes away
+    mid-stream leaves the engine running, the span marked aborted and
+    no thread behind."""
+    from distkeras_tpu import networking as net, telemetry
+
+    model, params = _model_and_params()
+    eng = ServingEngine(model, params, slots=3,
+                        registry=telemetry.MetricRegistry(),
+                        tracer=telemetry.Tracer())
+    server = LMServer(eng).start()
+    before = len(_pump_threads())
+    sock = net.connect("127.0.0.1", server.port)
+    try:
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, 64, size=n).astype(np.int32)
+                   for n in (5, 7, 4, 6, 5, 8, 3)]
+        new = 12 if case == "many_requests" else 40
+        acks, toks, done = {}, {}, {}
+        reader = net.MsgReader(sock)
+        for p in prompts:
+            net.send_msg(sock, {"op": "generate", "prompt": p.tolist(),
+                                "max_new_tokens": new})
+        while len(done) < len(prompts):
+            for msg in reader.recv_msgs():
+                if "ok" in msg:
+                    acks[msg["id"]] = msg["trace"]
+                    toks[msg["id"]] = []
+                elif "t" in msg:
+                    # never before its ack, never after its done
+                    assert msg["id"] in acks and msg["id"] not in done
+                    toks[msg["id"]].append(msg["t"])
+                else:
+                    done[msg["id"]] = msg
+                    spans = [s for s in eng.tracer.dump(
+                        trace=acks[msg["id"]]) if s["span"] == "stream"]
+                    assert [s["tokens"] for s in spans] == [msg["n"]]
+            if any(toks.values()):
+                assert len(_pump_threads()) == before + 1
+                if case == "client_goes_away":
+                    break
+        if case == "many_requests":
+            rids = sorted(acks)  # rids count up in the order submitted
+            for rid, p in zip(rids, prompts):
+                assert toks[rid] == _solo(model, params, p,
+                                          max_new_tokens=new)
+                assert done[rid]["n"] == new
+                assert done[rid]["reason"] == "length"
+            return
+        sock.close()
+        deadline = time.monotonic() + 30.0
+        aborted = []
+        while time.monotonic() < deadline:
+            aborted = [s for tid in acks.values()
+                       for s in eng.tracer.dump(trace=tid)
+                       if s["span"] == "stream" and s.get("aborted")]
+            if len(_pump_threads()) == before and aborted:
+                break
+            time.sleep(0.02)
+        assert aborted and len(_pump_threads()) == before
+        # the engine finished them all; their tokens were dropped
+        assert eng.stats()["requests_completed"] >= len(prompts) - len(done)
+    finally:
+        sock.close()
+        server.stop()
