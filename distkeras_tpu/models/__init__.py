@@ -16,3 +16,4 @@ from distkeras_tpu.models.deepseek_v32 import DeepseekV32LM  # noqa: F401
 from distkeras_tpu.models.mimo_v2 import MiMoV2LM  # noqa: F401
 from distkeras_tpu.models.solar_open2 import SolarOpen2LM  # noqa: F401
 from distkeras_tpu.models.glm4_moe_lite import Glm4MoeLiteLM  # noqa: F401
+from distkeras_tpu.models.afmoe import AfmoeLM  # noqa: F401

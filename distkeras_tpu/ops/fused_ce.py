@@ -216,9 +216,13 @@ def lm_head_loss(features, head_params, targets, mask,
     side; single-device callers divide directly.
     """
     B, T, D = features.shape
+    kernel = head_params["kernel"]
+    bias = head_params.get("bias")
+    if bias is None:
+        # typed as the kernel is, so that the scan's carries agree
+        bias = jnp.zeros((kernel.shape[1],), jnp.float32) + _vma_zero(kernel)
     s = fused_linear_softmax_ce(
-        features.reshape(B * T, D),
-        head_params["kernel"], head_params["bias"],
+        features.reshape(B * T, D), kernel, bias,
         targets.reshape(B * T).astype(jnp.int32),
         mask.reshape(B * T).astype(jnp.float32),
         chunk,

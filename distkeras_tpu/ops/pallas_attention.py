@@ -88,8 +88,35 @@ def _out_struct(shape, dtype, like):
 # ---------------------------------------------------------------------------
 
 
+def _band(T: int, block: int, window):
+    """k blocks a query block's walk holds: all of them under the causal
+    wedge alone, else as many as a band of ``window`` keys can touch
+    (the diagonal block, the whole blocks below it, and the one the
+    band's lower edge cuts)."""
+    nq = T // block
+    if window is None:
+        return nq
+    return min(nq, (window + block - 2) // block + 1)
+
+
+def _k_block(i, step, walk: int, window):
+    """The k block a query block ``i`` meets at ``step`` of its walk:
+    under a window the walk starts ``walk - 1`` blocks below the
+    diagonal (at block 0 for the first query blocks)."""
+    if window is None:
+        return step
+    return jnp.maximum(i - (walk - 1), 0) + step
+
+
+def _live(q_pos, k_pos, window):
+    """The causal wedge, bounded from below by the window's band."""
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (k_pos > q_pos - window)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
-                *, block: int, scale: float):
+                *, block: int, scale: float, window=None, walk: int = 0):
     i = pl.program_id(1)
     j = pl.program_id(2)
     bq = block
@@ -100,6 +127,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
+    # from here on j is the k block itself (a band's walk starts below
+    # the diagonal, not at block 0)
+    j = _k_block(i, j, walk, window)
+
     @pl.when(j <= i)
     def _():
         q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
@@ -109,7 +140,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         )  # [bq, bq]
         q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        # a row whose keys in this tile are all outside the band adds
+        # exp(0) terms at the -1e30 floor; the first live key's
+        # correction exp(-1e30 - m) = 0 wipes them
+        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
         m_old = m_s[:]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_old - m_new)
@@ -138,16 +172,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         )
 
 
-def _fwd(q3, k3, v3, block: int, scale: float):
+def _kv_head(b, group: int):
+    """The folded K/V row that the folded query row ``b`` reads: query
+    head ``h`` of a batch row reads KV head ``h // group``, and ``(B *
+    H + h) // group`` is that row of ``[B * Hk, T, hd]``: the group
+    shares K and V through the index map, nothing is repeated in HBM."""
+    return b if group == 1 else b // group
+
+
+def _fwd(q3, k3, v3, block: int, scale: float, window=None):
     BH, T, hd = q3.shape
     nq = T // block
+    group = BH // k3.shape[0]
+    walk = _band(T, block, window)
 
     def kv_idx(b, i, j):
-        return (b, jnp.minimum(i, j), 0)
+        return (_kv_head(b, group),
+                jnp.minimum(i, _k_block(i, j, walk, window)), 0)
 
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, block=block, scale=scale),
-        grid=(BH, nq, nq),
+        functools.partial(_fwd_kernel, block=block, scale=scale,
+                          window=window, walk=walk),
+        grid=(BH, nq, walk),
         in_specs=[
             pl.BlockSpec((1, block, hd), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
@@ -182,7 +228,8 @@ def _fwd(q3, k3, v3, block: int, scale: float):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-               dq_acc, *, block: int, scale: float):
+               dq_acc, *, block: int, scale: float, window=None,
+               walk: int = 0):
     i = pl.program_id(1)
     j = pl.program_id(2)
     bq = block
@@ -190,6 +237,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     @pl.when(j == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    j = _k_block(i, j, walk, window)  # the k block, as in _fwd_kernel
 
     @pl.when(j <= i)
     def _():
@@ -211,7 +260,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         )
         q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
         p = jnp.exp(s - lse)  # exact probabilities via saved logsumexp
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
@@ -228,20 +277,34 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
+def _q_block(j, step, walk: int, group: int, window):
+    """``(member, i)``: the query head of the group and the query block
+    that the k block ``j`` meets at ``step`` of its walk. The walk
+    passes the group's heads one after the other (their sum is the KV
+    head's gradient); under a window a head's walk starts at the
+    diagonal and ends ``walk - 1`` blocks above it, which may lie past
+    the last query block."""
+    member, step = (0, step) if group == 1 else (step // walk, step % walk)
+    return member, step if window is None else j + step
+
+
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, block: int,
-                scale: float):
+                scale: float, window=None, walk: int = 0, group: int = 1,
+                nq: int = 0):
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    step = pl.program_id(2)
     ni = pl.num_programs(2)
     bq = block
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i >= j)
+    i = _q_block(j, step, walk, group, window)[1]  # the query block
+
+    @pl.when(i >= j if window is None else i < nq)
     def _():
         q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
         kb = k_ref[0]
@@ -258,7 +321,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         )
         q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
         p = jnp.exp(s - lse)
         pc = p.astype(do.dtype)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
@@ -277,25 +340,30 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(i == ni - 1)
+    @pl.when(step == ni - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, out, lse, do3, block: int, scale: float):
+def _bwd(q3, k3, v3, out, lse, do3, block: int, scale: float,
+         window=None):
     BH, T, hd = q3.shape
     nq = T // block
+    group = BH // k3.shape[0]
+    walk = _band(T, block, window)
 
     def kv_row_idx(b, i, j):  # dq grid: kv blocks clamp to the diagonal
-        return (b, jnp.minimum(i, j), 0)
+        return (_kv_head(b, group),
+                jnp.minimum(i, _k_block(i, j, walk, window)), 0)
 
     def q_row_idx(b, i, j):  # q/do/o/lse tiles follow the q block
         return (b, i, 0)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block=block, scale=scale),
-        grid=(BH, nq, nq),
+        functools.partial(_dq_kernel, block=block, scale=scale,
+                          window=window, walk=walk),
+        grid=(BH, nq, walk),
         in_specs=[
             pl.BlockSpec((1, block, hd), q_row_idx,
                          memory_space=pltpu.VMEM),
@@ -319,11 +387,15 @@ def _bwd(q3, k3, v3, out, lse, do3, block: int, scale: float):
     )(q3, k3, v3, do3, out, lse)
 
     def q_col_idx(b, j, i):  # dkv grid: q/do/o/lse blocks clamp to diag
-        return (b, jnp.maximum(i, j), 0)
+        member, i = _q_block(j, i, walk, group, window)
+        return (b if group == 1 else b * group + member,
+                jnp.maximum(i, j) if window is None
+                else jnp.minimum(i, nq - 1), 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block=block, scale=scale),
-        grid=(BH, nq, nq),
+        functools.partial(_dkv_kernel, block=block, scale=scale,
+                          window=window, walk=walk, group=group, nq=nq),
+        grid=(BH // group, nq, group * walk),
         in_specs=[
             pl.BlockSpec((1, block, hd), q_col_idx,
                          memory_space=pltpu.VMEM),
@@ -345,8 +417,8 @@ def _bwd(q3, k3, v3, out, lse, do3, block: int, scale: float):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _out_struct((BH, T, hd), k3.dtype, k3),
-            _out_struct((BH, T, hd), v3.dtype, v3),
+            _out_struct(k3.shape, k3.dtype, k3),
+            _out_struct(v3.shape, v3.dtype, v3),
         ],
         scratch_shapes=[
             pltpu.VMEM((block, hd), jnp.float32),
@@ -447,19 +519,34 @@ def preferred(T: int, hd: int, batch_heads: int | None = None,
     return choose_block(T, hd, itemsize=itemsize) is not None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def pallas_causal_attention(q, k, v, block: int = DEFAULT_BLOCK):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def pallas_causal_attention(q, k, v, block: int = DEFAULT_BLOCK,
+                            window: int | None = None):
     """Causal flash attention, [B, T, H, hd] -> [B, T, H, hd].
 
     ``softmax(q k^T / sqrt(hd) + causal mask) v`` with causal tile
     skipping on TPU (interpret mode elsewhere). See :func:`supports`.
+
+    ``k`` and ``v`` may hold fewer heads, ``[B, T, Hk, hd]`` with ``H %
+    Hk == 0``: query head ``h`` reads KV head ``h // (H // Hk)``.
+    ``window`` bounds the walk from below as the causal wedge bounds it
+    from above: position ``t`` attends ``max(0, t - window + 1) .. t``,
+    and tiles outside that band cost nothing, forward or backward.
     """
-    out, _ = _fwd_res(q, k, v, block)
+    out, _ = _fwd_res(q, k, v, block, window)
     return out
 
 
-def _fwd_res(q, k, v, block):
+def _fwd_res(q, k, v, block, window=None):
     B, T, H, hd = q.shape
+    Hk = k.shape[2]
+    if H % Hk or v.shape[2] != Hk:
+        raise ValueError(
+            f"pallas attention shares a KV head among a whole group of "
+            f"query heads: got {H} query heads over {Hk} K and "
+            f"{v.shape[2]} V heads")
+    if window is not None and window < 1:
+        raise ValueError(f"pallas attention: window={window}")
     b = min(block, T)
     # the strictest (smallest) operand itemsize sets the sublane need: a
     # bf16 k/v/do tile mis-tiles even when an f32 q would be fine
@@ -473,25 +560,26 @@ def _fwd_res(q, k, v, block):
         )
     scale = 1.0 / math.sqrt(hd)
     q3, k3, v3 = _to_bh(q), _to_bh(k), _to_bh(v)
-    out3, lse = _fwd(q3, k3, v3, b, scale)
+    out3, lse = _fwd(q3, k3, v3, b, scale, window)
     return _from_bh(out3, B, H), (q3, k3, v3, out3, lse, B, H, b)
 
 
-def _vjp_fwd(q, k, v, block):
-    out, res = _fwd_res(q, k, v, block)
+def _vjp_fwd(q, k, v, block, window):
+    out, res = _fwd_res(q, k, v, block, window)
     return out, res
 
 
-def _vjp_bwd(block, res, g):
+def _vjp_bwd(block, window, res, g):
     q3, k3, v3, out3, lse, B, H, b = res
     scale = 1.0 / math.sqrt(q3.shape[-1])
     do3 = _to_bh(g)
-    dq3, dk3, dv3 = _bwd(q3, k3, v3, out3, lse, do3, b, scale)
+    dq3, dk3, dv3 = _bwd(q3, k3, v3, out3, lse, do3, b, scale, window)
+    Hk = k3.shape[0] // B
     # each gradient in its PRIMAL's dtype (ADVICE r3 #2 — casting all to
     # g.dtype returned wrong-dtyped cotangents under mixed q/k/v dtypes)
     return (_from_bh(dq3, B, H).astype(q3.dtype),
-            _from_bh(dk3, B, H).astype(k3.dtype),
-            _from_bh(dv3, B, H).astype(v3.dtype))
+            _from_bh(dk3, B, Hk).astype(k3.dtype),
+            _from_bh(dv3, B, Hk).astype(v3.dtype))
 
 
 pallas_causal_attention.defvjp(_vjp_fwd, _vjp_bwd)

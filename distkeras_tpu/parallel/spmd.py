@@ -211,6 +211,34 @@ def lm_state_shardings(optimizer, mesh: Mesh, params,
     return named(pspec), named(ospec)
 
 
+def lm_step_model(model, params=None):
+    """The features-only copy of ``model`` that the LM step applies, or a
+    ``ValueError`` that names what ``model`` lacks of what the step
+    needs (see :func:`make_lm_train_step`), in place of a ``KeyError``
+    deep inside the traced step."""
+    name = type(model).__name__
+    if not hasattr(model, "features_only"):
+        raise ValueError(
+            f"{name} cannot be trained by the LM step: it has no "
+            f"features_only field (the step applies model.copy("
+            f"features_only=True) for the final norm's [B, T, D] output "
+            f"and runs the head inside the fused loss)")
+    if params is not None and "kernel" not in params.get("params", {}).get(
+            "head", {}):
+        raise ValueError(
+            f"{name} cannot be trained by the LM step: its parameters "
+            f"have no head subtree with a kernel [D, V] (the fused loss "
+            f"reads params['params']['head']); found "
+            f"{sorted(params.get('params', {}))}")
+    if getattr(model, "step_counters", ()):
+        for method in ("rule_update", "step_metrics"):
+            if not callable(getattr(model, method, None)):
+                raise ValueError(
+                    f"{name} names step_counters and has no {method}("
+                    f"params, counters)")
+    return model.copy(features_only=True)
+
+
 def make_lm_train_step(model, optimizer, mesh: Mesh,
                        dp_axis: str = "dp", sp_axis: str = "sp",
                        tp_axis: Optional[str] = None,
@@ -221,9 +249,20 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
     (x tensor, optionally).
 
     ``tokens`` is ``[B, T]`` with B sharded over ``dp_axis`` and T over
-    ``sp_axis``. The model must be a :class:`TransformerLM` constructed with
-    ``attention='ring'`` and ``seq_axis=sp_axis`` so attention is exact over
-    the full sequence while each device holds only ``T/sp`` of it.
+    ``sp_axis``. What the model has to offer (:func:`lm_step_model`
+    says which is missing): ``model.copy(features_only=True)`` whose
+    apply returns the final norm's ``[B, T, D]`` output, and a ``head``
+    subtree of its parameters (``kernel [D, V]``, a ``bias [V]`` or
+    none) for the fused loss; for ``sp > 1`` ring attention over
+    ``seq_axis=sp_axis`` (:class:`TransformerLM` with
+    ``attention='ring'``), so attention is exact over the full sequence
+    while each device holds only ``T/sp`` of it. Optionally
+    ``step_counters``: names the model sows into the ``"counters"``
+    collection every apply. The step then sums them over the mesh,
+    calls ``model.rule_update(params, counters)`` after the optimizer
+    update (state that a rule moves and no gradient does: a router's
+    selection bias) and returns ``model.step_metrics(params, counters)``
+    beside the loss: ``loss`` is then a dict ``{"loss": ..., **metrics}``.
 
     With ``tp_axis`` given (and a ``params_template`` for spec inference),
     the model must also be built with ``tp_size == mesh tp size``: its
@@ -274,7 +313,11 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
         pspec = lm_param_specs(params_template, tp_axis=tp_axis)
         ospec = opt_state_specs(optimizer, params_template, pspec)
 
-    feat_model = model.copy(features_only=True) if fused_ce else None
+    feat_model = lm_step_model(model, params_template) if fused_ce else None
+    counted = bool(getattr(model, "step_counters", ()))
+    if counted and not fused_ce:
+        raise ValueError("a model with step_counters trains with the "
+                         "fused loss (fused_ce=True)")
 
     def batch_update(params, opt_state, tokens):
         B_l, T_l = tokens.shape
@@ -289,10 +332,16 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
         mask = (local_pos < total_T - 1).astype(jnp.float32)[None, :]
 
         def objective(p):
+            counters = None
             if fused_ce:
                 from distkeras_tpu.ops.fused_ce import lm_head_loss
 
-                feats = feat_model.apply(p, tokens)
+                if counted:
+                    feats, state = feat_model.apply(p, tokens,
+                                                    mutable=["counters"])
+                    counters = state["counters"]
+                else:
+                    feats = feat_model.apply(p, tokens)
                 # pcast the replicated head params to device-varying HERE,
                 # where the axes are known: the fused op's custom VJP
                 # returns varying head grads, and the transpose of this
@@ -324,13 +373,25 @@ def make_lm_train_step(model, optimizer, mesh: Mesh,
             global_cnt = jax.lax.psum(local_cnt, (dp_axis, sp_axis))
             # objective sums to the global mean across all shards: the
             # autodiff psum over (dp, sp) then yields the exact global grad
+            if counted:
+                return local_sum / global_cnt, counters
             return local_sum / global_cnt
 
-        local_obj, grads = jax.value_and_grad(objective)(params)
+        if counted:
+            (local_obj, counters), grads = jax.value_and_grad(
+                objective, has_aux=True)(params)
+        else:
+            local_obj, grads = jax.value_and_grad(objective)(params)
         with jax.named_scope("optimizer_update"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         loss = jax.lax.psum(local_obj, (dp_axis, sp_axis))
+        if counted:
+            # the step's own counts, over every shard's tokens; the rule
+            # runs inside the step: no host round trip between steps
+            counters = jax.lax.psum(counters, (dp_axis, sp_axis))
+            params = model.rule_update(params, counters)
+            loss = {"loss": loss, **model.step_metrics(params, counters)}
         return params, opt_state, loss
 
     if not window:

@@ -1558,9 +1558,31 @@ class DataParallelTrainer(Trainer):
 
 
 class LMTrainer(Trainer):
-    """Flagship long-context path as a Trainer: a :class:`TransformerLM`
-    trained over a dp x sp (x tp) mesh with the SPMD LM step
+    """Flagship long-context path as a Trainer: a language model trained
+    over a dp x sp (x tp) mesh with the SPMD LM step
     (:func:`distkeras_tpu.parallel.spmd.make_lm_train_step`).
+
+    What the model has to offer (``transformer_lm`` and ``afmoe_lm``
+    do; one that lacks a piece is refused with a message that names it,
+    :func:`distkeras_tpu.parallel.spmd.lm_step_model`): a
+    ``features_only`` field (the step applies the copy that returns the
+    final norm's output and runs the head inside the fused loss), a
+    ``head`` subtree of its parameters (``kernel [D, V]``, with or
+    without a ``bias``), ``remat`` as a field if activations are to be
+    recomputed, ``attention`` / ``tp_size`` / ``ep_size`` where it can
+    be sharded (read with defaults: absent means one chip's model) and
+    ``training_refusals(axes)`` where it cannot. Optionally state that a
+    rule updates and per-step counters: ``step_counters`` sown into
+    ``"counters"``, ``rule_update(params, counters)`` run inside the
+    dispatched window after each optimizer step, and
+    ``step_metrics(params, counters)``, whose scalars come back with
+    the ``[W]`` losses and join each step's metrics row and history
+    entry. Rows of a model without counters hold ``loss`` alone.
+
+    While ``train()`` runs, the loop's tree is the only copy of the
+    parameters the trainer keeps on the device (``self.params`` is None
+    until the run ends; a caller who handed a tree and wants it back
+    after a failure keeps a reference of their own).
 
     No reference counterpart (the reference has no sequence models); this
     folds the framework's headline capability — ring-attention sequence
@@ -1606,6 +1628,10 @@ class LMTrainer(Trainer):
                 "microbatches only applies to pipeline training — set "
                 "axes={'pp': ..., 'dp': ...} (or drop microbatches)"
             )
+        # while the window loop runs the trainer holds no tree but the
+        # loop's own (see _train): whether a run that raised took a tree
+        # the caller had given
+        self._params_lost = False
 
     def _coerce_dataset(self, dataset):
         return dataset  # both LM paths stream ShardedDatasets natively
@@ -1716,7 +1742,7 @@ class LMTrainer(Trainer):
         from distkeras_tpu.models.registry import model_spec
 
         if (getattr(self.model, "tp_size", 1) == 1
-                and self.model.attention != "ring"
+                and getattr(self.model, "attention", None) != "ring"
                 and getattr(self.model, "ep_size", 1) == 1):
             return self.model
         spec = model_spec(self.model)
@@ -1731,11 +1757,21 @@ class LMTrainer(Trainer):
         slices any tp/ep-sharded leaves onto the mesh."""
         if self.params is not None:
             return self.params
+        if self._params_lost:
+            raise RuntimeError(
+                "the train() call that raised held this trainer's only "
+                "copy of the parameters it was given (the window step "
+                "donates its state, and no second copy stays on the "
+                "device beside it): set trainer.params again"
+            )
         T_local = tokens.shape[1] // sp
-        self.params = self._single_chip_twin().init(
+        variables = self._single_chip_twin().init(
             jax.random.PRNGKey(self.seed),
             jnp.asarray(tokens[:1, :T_local], jnp.int32),
         )
+        # what a model counts while it runs is no state of the trainer's
+        self.params = {k: v for k, v in variables.items()
+                       if k != "counters"}
         return self.params
 
     def _train(self, dataset: PartitionedDataset, shuffle: bool = False) -> Model:
@@ -1743,6 +1779,7 @@ class LMTrainer(Trainer):
         from distkeras_tpu.parallel.mesh import make_mesh
         from distkeras_tpu.parallel.spmd import (
             lm_state_shardings,
+            lm_step_model,
             make_lm_train_step,
         )
         from jax.sharding import NamedSharding
@@ -1754,6 +1791,9 @@ class LMTrainer(Trainer):
         if shuffle and not sharded:
             dataset = dataset.shuffle(seed=self.seed)
         axes = dict(self.axes) if self.axes else {"dp": len(jax.devices())}
+        refusals = getattr(self.model, "training_refusals", None)
+        if refusals is not None:
+            refusals(axes)
         if axes.get("pp", 1) > 1:
             return self._train_pp(dataset, shuffle)
         # an MoE model (ep_size > 1) trains on a (dp, ep) mesh via the
@@ -1783,7 +1823,7 @@ class LMTrainer(Trainer):
             mesh = make_mesh(axes)
             sp = axes.get("sp", 1)
             tp = axes.get("tp", 1)
-            if sp > 1 and self.model.attention != "ring":
+            if sp > 1 and getattr(self.model, "attention", None) != "ring":
                 raise ValueError(
                     "sp > 1 needs the model built with attention='ring' "
                     "(seq_axis='sp')"
@@ -1825,6 +1865,7 @@ class LMTrainer(Trainer):
             raise ValueError(
                 f"sequence length {T} not divisible by sp={sp}"
             )
+        seeded = self.params is None  # else given, or an earlier run's
         if sharded:
             first = dataset.read_shard(0)[self.tokens_col]
             self._init_params(np.ascontiguousarray(first[:1], np.int32), sp)
@@ -1841,6 +1882,7 @@ class LMTrainer(Trainer):
                 window=True,
             )
         else:
+            lm_step_model(self.model, self.params)  # or say what it lacks
             step = make_lm_train_step(
                 self.model, optimizer, mesh,
                 tp_axis="tp" if tp > 1 else None,
@@ -1969,6 +2011,15 @@ class LMTrainer(Trainer):
         )
         params = jax.device_put(params, p_sh, may_alias=False)
         opt_state = jax.device_put(opt_state, o_sh, may_alias=False)
+        # ... and the only ones: the trainer's own hold on the tree it
+        # started from kept every parameter on the device a second time
+        # for the whole run (2.0 GB of 16 at 504 M parameters, the room
+        # a second 8k row of afmoe_lm needs). A caller who wants that
+        # tree afterwards keeps a reference; a run that raises leaves
+        # self.params None, and the next train() starts from the seed
+        # again or, where the tree was given, asks for it
+        self._params_lost = not seeded
+        self.params = None
         history: History = []
         # the loop's phases as spans on the profiler's clock (what
         # Trainer(profile_dir=) shows beside the device's operations);
@@ -1988,8 +2039,14 @@ class LMTrainer(Trainer):
                 epoch_losses.append(losses)
             with span("lm_trainer.drain", epoch=epoch):
                 for losses in epoch_losses:
-                    for loss in np.atleast_1d(np.asarray(losses)):
-                        row = {"loss": float(loss)}
+                    # a model with step counters hands its steps'
+                    # metrics back beside the losses, [W] each
+                    if not isinstance(losses, dict):
+                        losses = {"loss": losses}
+                    cols = {k: np.atleast_1d(np.asarray(v))
+                            for k, v in losses.items()}
+                    for i in range(len(cols["loss"])):
+                        row = {k: float(v[i]) for k, v in cols.items()}
                         history.append(row)
                         if self.metrics_writer is not None:
                             self.metrics_writer.log(
@@ -2004,6 +2061,7 @@ class LMTrainer(Trainer):
                         force=(epoch + 1 == self.num_epoch),
                     )
         self.params = jax.tree.map(np.asarray, params)
+        self._params_lost = False
         self.history = history
         self.executor_histories = [history]
         return Model(self._single_chip_twin(), self.params)

@@ -330,6 +330,41 @@ def test_grouped_experts_at_the_four_configurations_widths(for_chip, name, N,
     assert grouped_experts.supports(D, F, 128)
 
 
+def test_grouped_experts_backward_at_the_training_cells_shapes(for_chip):
+    """``train-moe-seq8k``'s expert layer, a batch row at a time: 8192
+    tokens, 8 of 128 experts held, 8 a token. The forward's two
+    launches, the hidden rows' and the banks' backward launches and the
+    down launch again over the transposed banks, for the real compiler
+    (a row's 65 536 pairs fill the launches' scalar memory: two rows
+    at once do not fit its 1 MB)."""
+    N, k, D, F, held = 8192, 8, 2048, 1024, 8
+
+    def loss(x, tok, gate, sizes, wg, wu, wd):
+        return jnp.sum(grouped_experts.grouped_experts(
+            x, tok, gate, sizes, wg, wu, wd, tile=128, interpret=False))
+
+    text = for_chip(
+        jax.value_and_grad(loss, argnums=(0, 2, 4, 5, 6)),
+        ((N, D), BF16), ((N * k,), I32), ((N * k,), F32), ((held,), I32),
+        ((held, D, F), BF16), ((held, D, F), BF16), ((held, F, D), BF16))
+    for launch in ("moe_gate_up", "moe_down", "moe_bwd_hidden",
+                   "moe_bwd_weights"):
+        assert launch in text
+    assert text.count("tpu_custom_call") >= 5
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_banded_attention_at_the_training_cells_shapes(for_chip, window):
+    """``train-moe-seq8k``'s attends, forward, dq and dk/dv: 32 query
+    heads over 4 KV heads of 128, two rows of 8192 positions, under the
+    window of 2048 and under the causal wedge alone."""
+    q, kv = ((2, 8192, 32, 128), BF16), ((2, 8192, 4, 128), BF16)
+    text = for_chip(grad_of(functools.partial(
+        pallas_attention.pallas_causal_attention, block=512,
+        window=window)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_the_chip_has_one_form_of_the_grouped_matmul(monkeypatch):
     """A width the launches cannot take is refused where they would be
     built, by name: on a chip the layer does not fall back to the XLA

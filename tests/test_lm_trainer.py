@@ -180,3 +180,59 @@ def test_donation_leaves_caller_params_alive():
     t.train(ds)
     out = model.apply(params, jnp.zeros((2, 32), jnp.int32))
     assert np.isfinite(np.asarray(out)).all()
+
+
+class _Stop(Exception):
+    pass
+
+
+def _raise_at_first_row(trainer):
+    """Make the loop raise once its first window's losses are drained:
+    the state is donated by then, as in any run that dies midway."""
+    class Writer:
+        records = ()
+
+        def log(self, **row):
+            # what the loop holds while it runs: no tree of the trainer's
+            assert trainer.params is None
+            raise _Stop()
+
+        def throughput(self):
+            return None
+
+        def close(self):
+            pass
+
+    trainer.record_training_start = lambda: setattr(
+        trainer, "metrics_writer", Writer())
+
+
+@pytest.mark.parametrize("given", [False, True])
+def test_the_loop_holds_the_only_tree_and_a_failed_run_says_so(given):
+    """``_train`` keeps no second copy of the parameters on the device:
+    while the loop runs ``trainer.params`` is None. A run that raises
+    leaves it so; the next ``train()`` starts from the seed again, or,
+    where the tree was given, refuses with a message instead of
+    training other weights in silence."""
+    ds = token_dataset()
+    model = get_model("transformer_lm", attention="standard", **LM_KW)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 32), jnp.int32)) if given else None
+    t = LMTrainer(model, params=params, axes={"dp": 1}, batch_size=8,
+                  num_epoch=1, worker_optimizer="adam", learning_rate=1e-3)
+    start, end = t.record_training_start, t.record_training_end
+    _raise_at_first_row(t)
+    t.record_training_end = lambda: None
+    with pytest.raises(_Stop):
+        t.train(ds)
+    assert t.params is None
+    t.record_training_start, t.record_training_end = start, end
+    t.metrics_writer = None
+    if given:
+        with pytest.raises(RuntimeError, match="set trainer.params again"):
+            t.train(ds)
+        t.params = params  # the caller's own reference is whole
+    t.train(ds)
+    assert t.params is not None and len(t.history) == 64 // 8
+    t.train(ds)  # and a trained tree is a start like a given one
+    assert np.isfinite(t.history[-1]["loss"])
