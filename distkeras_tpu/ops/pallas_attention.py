@@ -1,38 +1,70 @@
-"""Pallas TPU causal flash attention with causal tile SKIPPING.
+"""Pallas TPU causal flash attention for training: one forward launch
+and two backward launches (dq; dk with dv) that visit only the tiles of
+the causal wedge, or of a window's band inside it.
 
-The pure-JAX blocked kernel (:mod:`distkeras_tpu.ops.flash_attention`)
-streams KV blocks but computes every (q, k) tile and masks the upper
-triangle — half the attention FLOPs are thrown away. Here the KV walk is
-a third GRID dimension with the causal wedge enforced by ``pl.when``:
-for query block i only k blocks j <= i do work, skipped tiles cost
-nothing (their KV index map clamps to the diagonal block, so the
-pipeline doesn't even re-fetch), and the online-softmax state lives in
-VMEM scratch carried across the inner grid steps. Per-block KV DMA means
-NO full-sequence VMEM residency — T=8192+ runs where a whole-KV design
-exceeds the ~16 MB budget. The per-query logsumexp/delta scalars stream
-the same way, as lane-replicated ``(block, 8)`` f32 tiles riding the q
-block index (r3 held them whole-[BH, T] in VMEM, which capped B*H*T;
-VERDICT r3 weak #4), so neither T nor B*H has a VMEM ceiling. The
-backward pass is the Dao recompute scheme split into a dq kernel (rows,
-k <= q) and a dk/dv kernel (columns, q >= k), each walking only its
-causal wedge the same way.
+**The walk.** Heads are folded into the batch (``[B*H, T, hd]``), the
+sequence is cut into square ``block x block`` tiles, and a launch's grid
+is ``(B*H, steps)``: the steps are enumerated on the host into
+scalar-prefetched index vectors (:func:`_row_walk`, :func:`_col_walk`),
+one query block's walk after the other (forward, dq: its k blocks
+ascending to the diagonal) or one k block's (dk/dv: the query blocks
+from the diagonal up, the heads of a KV group in turn into one
+accumulator). Only tiles that hold a live pair are steps: none above the
+diagonal, none below a window's band. Where the blocks pair up a step
+holds TWO tiles side by side (:func:`_span`): a step's fixed cost (DMA
+issue and waits, ~0.4 us on a v5e against ~0.75 us of work a tile) is
+paid once for both; the tile of a pair that lies outside the walk is
+skipped by ``pl.when``. Per-block DMA means no whole-sequence VMEM
+residency: neither T nor B*H has a ceiling.
 
-Layout: attention heads are folded into the batch ([B*H, T, hd]) so every
-tile is a clean 2-D (block, head_dim) VMEM tile — hd is a multiple of 128
-(the lane width) by construction of the flagship models.
+**A tile** is one body whatever its place: scores, the band's mask
+(two iotas, compares, a select), the softmax update or the recompute,
+the products. A second body without the mask for the tiles that lie
+whole inside the band (all but the diagonal and the tile a band's lower
+edge cuts: :func:`_edge`) was built and measured no faster on the chip,
+forward or backward, so it is not here; :func:`tile_census` still
+counts those tiles, the ones the mask cuts and the steps, from the
+vectors and rules the launches use.
 
-Numerics match the dense/blocked kernels: bf16 matmul operands, f32
-accumulation (``preferred_element_type``), f32 online softmax state.
+**What is one number a query.** The online softmax's running max and
+sum are held over all 128 lanes of their vreg, every lane the row's
+value (:func:`_state_lanes`): kept as ``[block, 1]`` columns, each
+tile's max -> exp -> sum chain waited on a cross-lane broadcast a vreg,
+and that, not the mask and not the matmuls, was what held the forward
+at a quarter of its roofline (PERF.md, PR 45). The backward's numbers
+(the saved logsumexp, ``delta = sum(do * o)``) hang on no such chain:
+the dq launch reads them as columns (over the lanes it measured a
+little slower), computes delta once a walk and hands it on; the dk/dv
+launch holds its scores transposed (keys in rows, queries in lanes),
+where a query's number is a row that spreads over sublanes for nothing
+and both accumulations (``p^T @ do``, ``ds^T @ q``) are plain products
+(with queries in rows each took a transpose of the tile). ``q`` is
+scaled a tile (a ``[block, hd]`` pass beside a ``[block, block]`` one;
+scaling it once a walk into scratch measured nothing).
 
-Requires T divisible by the (clamped) block and head_dim % 128 == 0 —
+**Traced once.** ``pallas_call`` traces its kernel at every call and
+keeps no cache, so a model of L layers traced these bodies 3 L times
+and more (seconds of Python a set-up on the benchmark's host). The two
+entry points :func:`_fwd_launch` and :func:`_bwd_launches` are
+``jax.jit(inline=True)`` (:func:`_traced_once`): one trace a shape,
+inlined, so the caller's program is what it would be without.
+
+Numerics: bf16 matmul operands, f32 accumulation
+(``preferred_element_type``), f32 online-softmax state, exact ``exp``.
+K and V may hold fewer heads than q (grouped through the index map,
+nothing repeated in HBM). The backward is the Dao recompute scheme from
+the saved logsumexp.
+
+Requires T divisible by the (clamped) block and head_dim % 128 == 0 -
 :func:`supports` is the gate, and the wrapper RAISES on unsupported
 shapes; falling back is the caller's job (models.transformer keeps
 'blocked' for shapes this kernel won't serve).
 
-Measured on v5e vs the blocked kernel (value+grad, B·H=64→16, hd=256):
-1.58× @T=2048, 2.17× @T=4096, 2.36× @T=8192; the flagship training step
-gains +39% at T=2048 and +60% at T=4096, and T=8192 trains at 33.8k
-tokens/sec where the whole-KV design could not compile.
+Measured: PERF.md (section 6, PR 44 and PR 45: the kernel alone at the
+benchmark's two training shapes, head 128) and the ledger. Older
+numbers, builder's and from before the benchmark (v5e, value+grad,
+head 256, against the blocked pure-JAX kernel): 1.58x at T=2048, 2.17x
+at 4096, 2.36x at 8192.
 """
 
 from __future__ import annotations
@@ -84,7 +116,7 @@ def _out_struct(shape, dtype, like):
 
 
 # ---------------------------------------------------------------------------
-# forward: grid (BH, nq, nk), online softmax state in scratch
+# the walk: which tiles a launch visits, in which grid steps
 # ---------------------------------------------------------------------------
 
 
@@ -99,77 +131,182 @@ def _band(T: int, block: int, window):
     return min(nq, (window + block - 2) // block + 1)
 
 
-def _k_block(i, step, walk: int, window):
-    """The k block a query block ``i`` meets at ``step`` of its walk:
-    under a window the walk starts ``walk - 1`` blocks below the
-    diagonal (at block 0 for the first query blocks)."""
+def _span(nq: int) -> int:
+    """Tiles a grid step holds, side by side along the walk: two where
+    the blocks pair up (a step's fixed cost, ~0.4 us of DMA issue and
+    waits, is then paid once for both), else one. Four a step measured
+    1.4-2.5 % under two on the kernel alone (PERF.md, PR 45): not taken
+    for the K/V it fetches and skips under a window."""
+    return 2 if nq % 2 == 0 else 1
+
+
+def _in_walk(i, j, nq: int, walk: int):
+    """Whether tile ``(i, j)`` holds a live pair: on or under the
+    diagonal, within ``walk`` blocks of it, inside the sequence. A
+    step's tiles that fail it (past the diagonal, before the band, past
+    the last query block) are skipped."""
+    return (j <= i) & (i - j < walk) & (i < nq)
+
+
+def _edge(i, j, block: int, window):
+    """Whether the mask cuts tile ``(i, j)`` of a walk: it cuts the
+    diagonal and, under a window, the tile that the band's lower edge
+    crosses (its first key lies at or below the last query's lower
+    bound). Every other tile a walk visits lies whole inside the band.
+    For :func:`tile_census` alone: a second body without the mask for
+    the interior tiles measured no faster on the chip, forward or
+    backward (PERF.md, PR 45), so the launches have one body."""
     if window is None:
-        return step
-    return jnp.maximum(i - (walk - 1), 0) + step
+        return j == i
+    return (j == i) | (j * block <= i * block + (block - 1 - window))
 
 
-def _live(q_pos, k_pos, window):
-    """The causal wedge, bounded from below by the window's band."""
+@functools.lru_cache(maxsize=64)
+def _row_walk(nq: int, walk: int, span: int):
+    """``i[t], J[t]``: the steps of the forward and dq launches, one
+    query block's walk after the other: the spans of ``span`` k blocks
+    that hold a tile of its walk, ascending to the diagonal's. No step
+    is empty."""
+    steps = [(i, J) for i in range(nq)
+             for J in range(max(i - walk + 1, 0) // span, i // span + 1)]
+    i, J = np.asarray(steps, np.int32).T
+    return i, J
+
+
+@functools.lru_cache(maxsize=64)
+def _col_walk(nq: int, walk: int, group: int, span: int):
+    """``member[t], I[t], j[t]``: the steps of the dk/dv launch, one k
+    block's walk after the other: the group's query heads in turn (their
+    sum is the KV head's gradient), each head's spans of ``span`` query
+    blocks ascending from the diagonal's to that of block ``min(j +
+    walk, nq) - 1``."""
+    steps = [(m, I, j) for j in range(nq) for m in range(group)
+             for I in range(j // span, (min(j + walk, nq) - 1) // span + 1)]
+    m, I, j = np.asarray(steps, np.int32).T
+    return m, I, j
+
+
+def tile_census(T: int, block: int, window=None, group: int = 1) -> dict:
+    """What the three launches visit a query head: ``{"fwd" | "dq" |
+    "dkv": {"interior", "edge", "empty", "steps"}}``: the tiles whole
+    inside the band and those the mask cuts, the grid steps that hold no
+    live tile, the grid steps. Counted from the index vectors the grids
+    are built from and the rule the kernels skip a tile by, so it cannot
+    drift from them."""
+    nq = T // block
+    walk, span = _band(T, block, window), _span(nq)
+    u = np.arange(span)[:, None]  # a step's tiles, down the first axis
+
+    def count(i, j, heads):
+        i, j = np.broadcast_arrays(i, j)
+        live = _in_walk(i, j, nq, walk)
+        edge = live & _edge(i, j, block, window)
+        return {"interior": int((live & ~edge).sum()) // heads,
+                "edge": int(edge.sum()) // heads,
+                "empty": int((~live.any(0)).sum()) // heads,
+                "steps": live.shape[1] // heads}
+
+    ri, rJ = _row_walk(nq, walk, span)
+    _, cI, cj = _col_walk(nq, walk, group, span)
+    rows = count(ri, rJ * span + u, 1)
+    return {"fwd": rows, "dq": dict(rows),
+            "dkv": count(cI * span + u, cj, group)}
+
+
+def _live(i, j, block: int, window, keys_first: bool = False):
+    """The mask of tile ``(i, j)``: the causal wedge, bounded from below
+    by the window's band; ``[queries, keys]``, or ``[keys, queries]``
+    for the dk/dv launch's transposed scores."""
+    q_shape, k_shape = ((1, block), (block, 1)) if keys_first else (
+        (block, 1), (1, block))
+    q_pos = i * block + jax.lax.broadcasted_iota(
+        jnp.int32, q_shape, int(keys_first))
+    k_pos = j * block + jax.lax.broadcasted_iota(
+        jnp.int32, k_shape, int(not keys_first))
     if window is None:
         return q_pos >= k_pos
     return (q_pos >= k_pos) & (k_pos > q_pos - window)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
-                *, block: int, scale: float, window=None, walk: int = 0):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+def _scaled(q, scale: float):
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _state_lanes(block: int) -> int:
+    """Lanes of the online softmax's running max and sum: a whole
+    vreg's 128 where the block allows, every lane the row's value, so
+    that meeting a ``[block, block]`` score tile is a plain elementwise
+    operation. Held as ``[block, 1]`` columns, each tile's max -> exp ->
+    sum chain waited on a cross-lane broadcast a vreg (that, not the
+    mask, held the forward at a quarter of its roofline: PERF.md, PR
+    45)."""
+    return 128 if block % 128 == 0 else 1
+
+
+def _rep(x, n: int):
+    """A per-query scratch's value against ``n`` columns."""
+    lanes = x.shape[-1]
+    return x if lanes in (1, n) else pltpu.repeat(x, n // lanes, 1)
+
+
+# ---------------------------------------------------------------------------
+# forward: grid (BH, the row walk's steps), online softmax state in scratch
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, o_ref, l_ref,
+                acc, m_s, l_s, *, block: int, scale: float, window=None,
+                walk: int = 0, nq: int = 0, span: int = 1):
+    t = pl.program_id(1)
+    i, J = i_ref[t], j_ref[t]
     bq = block
 
-    @pl.when(j == 0)
+    @pl.when(J == jnp.maximum(i - (walk - 1), 0) // span)  # a walk's first
     def _():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    # from here on j is the k block itself (a band's walk starts below
-    # the diagonal, not at block 0)
-    j = _k_block(i, j, walk, window)
-
-    @pl.when(j <= i)
-    def _():
-        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    def tile(j, keys):
         s = jax.lax.dot_general(
-            q, k_ref[0], (((1,), (1,)), ((), ())),
+            _scaled(q_ref[0], scale), k_ref[0, keys, :],
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bq]
-        q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
         # a row whose keys in this tile are all outside the band adds
         # exp(0) terms at the -1e30 floor; the first live key's
         # correction exp(-1e30 - m) = 0 wipes them
-        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
+        s = jnp.where(_live(i, j, block, window), s, _NEG_INF)
         m_old = m_s[:]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _rep(m_new, bq))
         l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_s[:] = m_new
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, keys, :],
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc[:] = acc[:] * corr + pv
+        acc[:] = acc[:] * _rep(corr, acc.shape[-1]) + pv
 
-    # j == i is the last tile with work for this query block: finalize
-    # (j > i iterations only clamp-fetch the diagonal KV block again)
-    @pl.when(j == i)
+    for u in range(span):  # the step's tiles that lie in the walk
+        j = J * span + u
+        pl.when(_in_walk(i, j, nq, walk))(functools.partial(
+            tile, j, slice(u * block, (u + 1) * block)))
+
+    # the diagonal's span is a walk's last step: finalize
+    @pl.when(J == i // span)
     def _():
         l_safe = jnp.maximum(l_s[:], 1e-30)
-        o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / _rep(l_safe, acc.shape[-1])).astype(o_ref.dtype)
         # per-row logsumexp of the scaled logits, for backward recompute.
         # Stored lane-replicated as a (block, LSE_LANES) tile: a (1, block)
-        # slab is an illegal TPU block shape, and a full [BH, T] VMEM
-        # resident (the r3 design) capped B*H*T — the blocked layout has
-        # no such ceiling (VERDICT r3 weak #4).
-        l_ref[0] = jnp.broadcast_to(
-            m_s[:] + jnp.log(l_safe), (bq, LSE_LANES)
-        )
+        # slab is an illegal TPU block shape, and a whole [BH, T] array in
+        # VMEM would cap B*H*T
+        lse = m_s[:] + jnp.log(l_safe)
+        l_ref[0] = (lse[:, :LSE_LANES] if lse.shape[-1] > 1
+                    else jnp.broadcast_to(lse, (bq, LSE_LANES)))
 
 
 def _kv_head(b, group: int):
@@ -180,167 +317,178 @@ def _kv_head(b, group: int):
     return b if group == 1 else b // group
 
 
+def _traced_once(launch):
+    """A launch as ``jit(inline=True)``: the kernel is traced once for a
+    shape and its statics, not once a layer (a trace of these kernels is
+    0.15-0.3 s of Python on the benchmark's host, 32 of them a
+    ``train-seq2k`` set-up), and inlining leaves the caller's program,
+    its scopes and the launches' names as they are without it. What a
+    test may patch (``_span``, ``_interpret``) is read by the caller and
+    handed in as a static."""
+    return functools.partial(jax.jit, inline=True, static_argnames=(
+        "block", "scale", "window", "span", "interpret"))(launch)
+
+
 def _fwd(q3, k3, v3, block: int, scale: float, window=None):
+    return _fwd_launch(q3, k3, v3, block=block, scale=scale, window=window,
+                       span=_span(q3.shape[1] // block),
+                       interpret=_interpret())
+
+
+@_traced_once
+def _fwd_launch(q3, k3, v3, *, block, scale, window, span, interpret):
     BH, T, hd = q3.shape
     nq = T // block
     group = BH // k3.shape[0]
-    walk = _band(T, block, window)
+    walk, lanes = _band(T, block, window), _state_lanes(block)
+    steps = _row_walk(nq, walk, span)
 
-    def kv_idx(b, i, j):
-        return (_kv_head(b, group),
-                jnp.minimum(i, _k_block(i, j, walk, window)), 0)
+    def q_idx(b, t, i_, j_):  # q, o and lse tiles follow the query block
+        return (b, i_[t], 0)
+
+    def kv_idx(b, t, i_, j_):  # a span of k blocks
+        return (_kv_head(b, group), j_[t], 0)
 
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block=block, scale=scale,
-                          window=window, walk=walk),
-        grid=(BH, nq, walk),
-        in_specs=[
-            pl.BlockSpec((1, block, hd), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), kv_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), kv_idx, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block, hd), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            # lse tile follows the q block; resident across the inner j
-            # walk, flushed once per (bh, i)
-            pl.BlockSpec((1, block, LSE_LANES), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+                          window=window, walk=walk, nq=nq, span=span),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, len(steps[0])),
+            in_specs=[
+                pl.BlockSpec((1, block, hd), q_idx),
+                pl.BlockSpec((1, span * block, hd), kv_idx),
+                pl.BlockSpec((1, span * block, hd), kv_idx),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block, hd), q_idx),
+                # resident across a walk, flushed once per (bh, i)
+                pl.BlockSpec((1, block, LSE_LANES), q_idx),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block, hd), jnp.float32),
+                pltpu.VMEM((block, lanes), jnp.float32),
+                pltpu.VMEM((block, lanes), jnp.float32),
+            ],
+        ),
         out_shape=[
             _out_struct((BH, T, hd), q3.dtype, q3),
             _out_struct((BH, T, LSE_LANES), jnp.float32, q3),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block, hd), jnp.float32),
-            pltpu.VMEM((block, 1), jnp.float32),
-            pltpu.VMEM((block, 1), jnp.float32),
-        ],
-        interpret=_interpret(),
+        interpret=interpret,
         **_call_kwargs(block),
-    )(q3, k3, v3)
+    )(*map(jnp.asarray, steps), q3, k3, v3)
 
 
 # ---------------------------------------------------------------------------
-# backward (Dao recompute): dq walks k<=q; dk/dv walk q>=k
+# backward (Dao recompute): dq walks rows as the forward does; dk/dv walk
+# columns, the group's heads in turn
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-               dq_acc, *, block: int, scale: float, window=None,
-               walk: int = 0):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    bq = block
+def _delta(do, o):
+    """delta_i = sum_d do_i * o_i, recomputed in-kernel: a block*hd VPU
+    rowsum (~1e-3 of a tile's matmul FLOPs) that replaces a whole-tensor
+    XLA pass and a materialized buffer."""
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1, keepdims=True)
 
-    @pl.when(j == 0)
+
+def _dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+               dq_ref, delta_ref, dq_acc, delta_s, *, block: int,
+               scale: float, window=None, walk: int = 0, nq: int = 0,
+               span: int = 1):
+    t = pl.program_id(1)
+    i, J = i_ref[t], j_ref[t]
+
+    # a walk's first step: delta depends on the query block alone. Kept
+    # for the walk's tiles, and handed on to the dk/dv launch as the
+    # logsumexp came from the forward: lane-replicated, riding the q
+    # block
+    @pl.when(J == jnp.maximum(i - (walk - 1), 0) // span)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        delta_s[:] = _delta(do_ref[0], o_ref[0])
+        delta_ref[0] = jnp.broadcast_to(delta_s[:], delta_ref.shape[1:])
 
-    j = _k_block(i, j, walk, window)  # the k block, as in _fwd_kernel
-
-    @pl.when(j <= i)
-    def _():
-        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
-        kb = k_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        # delta_i = sum_d do_i * o_i, recomputed in-kernel per tile — a
-        # block*hd VPU rowsum (~1e-3 of the tile's matmul FLOPs) that
-        # replaces a whole-tensor XLA pass + materialized aux buffer
-        # (measured ~3% of the flagship step)
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
+    def tile(j, keys):
+        kb = k_ref[0, keys, :]
         s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
+            _scaled(q_ref[0], scale), kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
-        p = jnp.exp(s - lse)  # exact probabilities via saved logsumexp
+        s = jnp.where(_live(i, j, block, window), s, _NEG_INF)
+        # exact probabilities via the saved logsumexp
+        p = jnp.exp(s - lse_ref[0][:, :1])
         dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+            do_ref[0], v_ref[0, keys, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta)
+        ds = p * (dp - delta_s[:])
         dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == i)
+    for u in range(span):
+        j = J * span + u
+        pl.when(_in_walk(i, j, nq, walk))(functools.partial(
+            tile, j, slice(u * block, (u + 1) * block)))
+
+    @pl.when(J == i // span)
     def _():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _q_block(j, step, walk: int, group: int, window):
-    """``(member, i)``: the query head of the group and the query block
-    that the k block ``j`` meets at ``step`` of its walk. The walk
-    passes the group's heads one after the other (their sum is the KV
-    head's gradient); under a window a head's walk starts at the
-    diagonal and ends ``walk - 1`` blocks above it, which may lie past
-    the last query block."""
-    member, step = (0, step) if group == 1 else (step // walk, step % walk)
-    return member, step if window is None else j + step
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, block: int,
+def _dkv_kernel(m_ref, i_ref, j_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, block: int,
                 scale: float, window=None, walk: int = 0, group: int = 1,
-                nq: int = 0):
-    j = pl.program_id(1)
-    step = pl.program_id(2)
-    ni = pl.num_programs(2)
-    bq = block
+                nq: int = 0, span: int = 1):
+    """Scores transposed, ``[keys, queries]``: both accumulations are
+    then plain products (``p^T @ do``, ``ds^T @ q``; with queries in
+    rows each took a transpose of the tile), and a query's logsumexp
+    and delta meet the tile as rows, spread over sublanes for nothing."""
+    t = pl.program_id(1)
+    member, I, j = m_ref[t], i_ref[t], j_ref[t]
 
-    @pl.when(step == 0)
+    @pl.when((member == 0) & (I == j // span))
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    i = _q_block(j, step, walk, group, window)[1]  # the query block
-
-    @pl.when(i >= j if window is None else i < nq)
-    def _():
-        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
-        kb = k_ref[0]
-        vb = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = jnp.sum(  # see _dq_kernel: in-kernel delta recompute
-            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
+    def tile(i, rows):
+        q = _scaled(q_ref[0, rows, :], scale)
+        do = do_ref[0, rows, :]
         s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
+            k_ref[0], q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
-        q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        k_pos = j * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
-        s = jnp.where(_live(q_pos, k_pos, window), s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        pc = p.astype(do.dtype)
+        )  # [bk, bq]
+        s = jnp.where(_live(i, j, block, window, keys_first=True), s,
+                      _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, :, rows])
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            pc, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
+            v_ref[0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = (p * (dp - delta)).astype(q.dtype)
+        ds = (p * (dp - delta_ref[0, :, rows])).astype(q.dtype)
         # no extra scale: q is already scaled, so ds^T @ q_scaled IS the
         # gradient w.r.t. the unscaled k
         dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(step == ni - 1)
+    for u in range(span):
+        i = I * span + u
+        pl.when(_in_walk(i, j, nq, walk))(functools.partial(
+            tile, i, slice(u * block, (u + 1) * block)))
+
+    @pl.when((member == group - 1)
+             & (I == jnp.minimum(j + (walk - 1), nq - 1) // span))
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -348,85 +496,103 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 def _bwd(q3, k3, v3, out, lse, do3, block: int, scale: float,
          window=None):
+    return _bwd_launches(q3, k3, v3, out, lse, do3, block=block,
+                         scale=scale, window=window,
+                         span=_span(q3.shape[1] // block),
+                         interpret=_interpret())
+
+
+@_traced_once
+def _bwd_launches(q3, k3, v3, out, lse, do3, *, block, scale, window, span,
+                  interpret):
     BH, T, hd = q3.shape
     nq = T // block
     group = BH // k3.shape[0]
     walk = _band(T, block, window)
+    rows = _row_walk(nq, walk, span)
+    cols = _col_walk(nq, walk, group, span)
+    static = dict(block=block, scale=scale, window=window, walk=walk, nq=nq,
+                  span=span)
 
-    def kv_row_idx(b, i, j):  # dq grid: kv blocks clamp to the diagonal
-        return (_kv_head(b, group),
-                jnp.minimum(i, _k_block(i, j, walk, window)), 0)
+    def q_row(b, t, i_, j_):  # q/do/o/lse/dq tiles follow the q block
+        return (b, i_[t], 0)
 
-    def q_row_idx(b, i, j):  # q/do/o/lse tiles follow the q block
-        return (b, i, 0)
+    def kv_row(b, t, i_, j_):  # a span of k blocks
+        return (_kv_head(b, group), j_[t], 0)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block=block, scale=scale,
-                          window=window, walk=walk),
-        grid=(BH, nq, walk),
-        in_specs=[
-            pl.BlockSpec((1, block, hd), q_row_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), kv_row_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), kv_row_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), q_row_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), q_row_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, LSE_LANES), q_row_idx,
-                         memory_space=pltpu.VMEM),
+    dq, delta = pl.pallas_call(
+        functools.partial(_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, len(rows[0])),
+            in_specs=[
+                pl.BlockSpec((1, block, hd), q_row),
+                pl.BlockSpec((1, span * block, hd), kv_row),
+                pl.BlockSpec((1, span * block, hd), kv_row),
+                pl.BlockSpec((1, block, hd), q_row),
+                pl.BlockSpec((1, block, hd), q_row),
+                pl.BlockSpec((1, block, LSE_LANES), q_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block, hd), q_row),
+                pl.BlockSpec((1, block, LSE_LANES), q_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block, hd), jnp.float32),
+                pltpu.VMEM((block, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            _out_struct((BH, T, hd), q3.dtype, q3),
+            _out_struct((BH, T, LSE_LANES), jnp.float32, q3),
         ],
-        out_specs=pl.BlockSpec((1, block, hd), q_row_idx,
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((BH, T, hd), q3.dtype, q3),
-        scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
         **_call_kwargs(block),
-    )(q3, k3, v3, do3, out, lse)
+    )(*map(jnp.asarray, rows), q3, k3, v3, do3, out, lse)
 
-    def q_col_idx(b, j, i):  # dkv grid: q/do/o/lse blocks clamp to diag
-        member, i = _q_block(j, i, walk, group, window)
-        return (b if group == 1 else b * group + member,
-                jnp.maximum(i, j) if window is None
-                else jnp.minimum(i, nq - 1), 0)
+    # the dk/dv launch holds keys in rows and queries in lanes, so what
+    # is one number a query (logsumexp, delta) meets its scores as a row:
+    # [BH, T, LSE_LANES] columns -> [BH, 1, T] rows, two small copies
+    lse_rows, delta_rows = (x[:, None, :, 0] for x in (lse, delta))
+
+    def q_col(b, t, m_, i_, j_):  # a span of q blocks, the group's heads
+        return (b * group + m_[t], i_[t], 0)   # in turn
+
+    def q_row_col(b, t, m_, i_, j_):  # the same span of a [BH, 1, T] row
+        return (b * group + m_[t], 0, i_[t])
+
+    def kv_col(b, t, m_, i_, j_):
+        return (b, j_[t], 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block=block, scale=scale,
-                          window=window, walk=walk, group=group, nq=nq),
-        grid=(BH // group, nq, group * walk),
-        in_specs=[
-            pl.BlockSpec((1, block, hd), q_col_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), q_col_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), q_col_idx,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, LSE_LANES), q_col_idx,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block, hd), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block, hd), lambda b, j, i: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        functools.partial(_dkv_kernel, group=group, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(BH // group, len(cols[0])),
+            in_specs=[
+                pl.BlockSpec((1, span * block, hd), q_col),
+                pl.BlockSpec((1, block, hd), kv_col),
+                pl.BlockSpec((1, block, hd), kv_col),
+                pl.BlockSpec((1, span * block, hd), q_col),
+                pl.BlockSpec((1, 1, span * block), q_row_col),
+                pl.BlockSpec((1, 1, span * block), q_row_col),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block, hd), kv_col),
+                pl.BlockSpec((1, block, hd), kv_col),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block, hd), jnp.float32),
+                pltpu.VMEM((block, hd), jnp.float32),
+            ],
+        ),
         out_shape=[
             _out_struct(k3.shape, k3.dtype, k3),
             _out_struct(v3.shape, v3.dtype, v3),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block, hd), jnp.float32),
-            pltpu.VMEM((block, hd), jnp.float32),
-        ],
-        interpret=_interpret(),
+        interpret=interpret,
         **_call_kwargs(block),
-    )(q3, k3, v3, do3, out, lse)
+    )(*map(jnp.asarray, cols), q3, k3, v3, do3, lse_rows, delta_rows)
     return dq, dk, dv
 
 
@@ -470,16 +636,17 @@ def supports(T: int, hd: int, block: int = DEFAULT_BLOCK,
     return T % b == 0 and b % sublane == 0 and hd % 128 == 0
 
 
-# auto-select candidates, in preference order, justified by the on-chip
-# sweep at the flagship attention shape (B8/H8/T2048/hd256, value+grad,
-# benchmarks/pallas_block_sweep.py → BASELINE.md): 512 = 15.80 ms/step
-# (1.38x vs blocked), 256 = 17.95, 128 = 26.44 (worse than blocked:
-# grid overhead swamps the tile skip). block=1024 measured 10.57
-# standalone (2.06x) and its old 16 MB scoped-VMEM compile-OOM is fixed
-# (_call_kwargs raises the cap for big blocks), but the FULL flagship
-# step measured ~1% SLOWER at 1024 than 512 (47,107 vs 47,559 tok/s,
-# same session) — the kernel's VMEM appetite costs the surrounding
-# program more than the bigger tiles gain — so 512 stays first.
+# auto-select candidates, in preference order. At head 128, the kernel
+# alone on a v5e at the benchmark's training shapes (PERF.md, PR 45;
+# forward + backward, ms): 512 = 20.28 under a window of 2048 at T 8192,
+# 36.14 under the whole wedge, 3.75 at 4 x 2048; 1024 = 21.66, 34.51,
+# 3.92 (its tiles waste more of a band and of a short wedge than its
+# fewer steps save); 256 = 34.03, 66.86, 5.83. The older sweep at head
+# 256 (B8/H8/T2048, builder's, before the benchmark:
+# benchmarks/pallas_block_sweep.py -> BASELINE.md) read 512 = 15.80,
+# 256 = 17.95, 128 = 26.44 (worse than the blocked kernel), and 1024
+# faster alone (10.57) but ~1 % slower in the whole step. 512 stays
+# first; _call_kwargs raises the scoped-VMEM cap for a pinned 1024.
 BLOCK_CANDIDATES = (512, 256, 128)
 
 

@@ -229,7 +229,7 @@ def _plain_attention(q, k, v, window):
         jnp.where(ok, s, -jnp.inf), -1), v)
 
 
-@pytest.mark.parametrize("H,Hk,block,window", [
+BANDS = [
     (4, 4, 16, None),   # the parent's program
     (4, 2, 16, None),   # grouped heads under the whole wedge
     (4, 2, 16, 24),     # a band that cuts tiles, grouped heads
@@ -238,23 +238,74 @@ def _plain_attention(q, k, v, window):
     (4, 1, 16, 40),     # all query heads on one KV head
     (2, 2, 32, 8),      # a band inside the diagonal tile
     (2, 2, 16, 100),    # a window longer than the sequence
-])
-def test_the_banded_kernel_against_a_plain_masked_attention(H, Hk, block,
-                                                            window):
+    # PR 45, what an off-by-one in "interior" would break: a band whose
+    # lower edge falls one key before, on and one key after a tile's
+    # edge (k * block - 1, k * block, k * block + 1) ...
+    (4, 4, 16, 15),
+    (4, 2, 16, 31),
+    (4, 2, 16, 32),
+    (8, 2, 16, 33),
+    (2, 2, 16, 47),
+    (8, 1, 16, 48),
+    (2, 2, 16, 49),
+    (2, 2, 8, 16),      # ... and with eight blocks a sequence
+    (2, 2, 8, 17),
+    (2, 2, 8, 23),
+    (4, 2, 16, 1),      # a band of the diagonal's own key alone
+    (4, 4, 16, 64),     # a window of exactly the sequence
+    (4, 4, 16, 63),     # and one key short of it
+    (2, 2, 64, None),   # one block a sequence: its only tile an edge
+    (2, 1, 64, 24),
+    (8, 1, 8, 24),      # a group of eight; walks clipped at block 0
+    (8, 1, 16, None),   # a group of eight under the whole wedge
+]
+
+
+def _through(attend, H, Hk):
     key = jax.random.PRNGKey(0)
     q, k, v, g = (jax.random.normal(jax.random.fold_in(key, i),
                                     (2, 64, h, 128), jnp.float32)
                   for i, h in enumerate((H, Hk, Hk, H)))
+    out, pull = jax.vjp(attend, q, k, v)
+    return (out,) + pull(g)  # the values, then dq, dk, dv
 
-    def through(attend):
-        out, pull = jax.vjp(attend, q, k, v)
-        return (out,) + pull(g)
 
-    got = through(lambda q, k, v: pallas_causal_attention(
-        q, k, v, block, window))
-    want = through(lambda q, k, v: _plain_attention(q, k, v, window))
-    for a, b in zip(got, want):  # the values, then dq, dk, dv
+@pytest.mark.parametrize("H,Hk,block,window", BANDS)
+def test_the_banded_kernel_against_a_plain_masked_attention(H, Hk, block,
+                                                            window):
+    got = _through(lambda q, k, v: pallas_causal_attention(
+        q, k, v, block, window), H, Hk)
+    want = _through(lambda q, k, v: _plain_attention(q, k, v, window),
+                    H, Hk)
+    for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hk,block,window", [
+    band for band in BANDS if band[2] < 64])
+def test_two_tiles_a_step_change_no_value(monkeypatch, H, Hk, block, window):
+    """A grid step holds two tiles of a walk where the blocks pair up
+    (the one that lies outside the walk skipped); one tile a step visits
+    the same tiles in the same order: values and the three gradients
+    equal bit for bit, and the census counts the same tiles in more
+    steps."""
+    from distkeras_tpu.ops import pallas_attention
+
+    def run():
+        return _through(lambda q, k, v: pallas_causal_attention(
+            q, k, v, block, window), H, Hk), pallas_attention.tile_census(
+                64, block, window, H // Hk)
+
+    assert pallas_attention._span(64 // block) == 2
+    got, paired = run()
+    monkeypatch.setattr(pallas_attention, "_span", lambda nq: 1)
+    want, single = run()
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    for launch, c in single.items():
+        assert c["steps"] == c["interior"] + c["edge"] >= paired[launch][
+            "steps"] >= c["steps"] / 2
+        assert {**c, "steps": 0} == {**paired[launch], "steps": 0}
 
 
 def test_the_kernel_refuses_heads_it_cannot_group():
@@ -364,11 +415,13 @@ def test_the_engine_refuses_the_model():
 
 # sha256 of the lowered window step of a small ``transformer_lm`` with
 # the Pallas attention (interpret mode inlines the kernels' bodies,
-# grids and index maps), taken at PR 44's parent (36ad6f2): the band,
-# the grouped heads and the step's counters are Python-level branches
-# that this model never takes. A deliberate change to that step updates
-# the hash.
-PARENT_STEP = "1e9776ceddfe280da43031a072f40c4170ba8140a1108cda03086f46c832861e"
+# grids and index maps). Taken at PR 45, which changed this step on
+# purpose (the launches' enumerated steps of two tiles, the forward's
+# state over whole vregs, the dk/dv launch keys-first); from PR 44's
+# parent (36ad6f2) to PR 45's it read 1e9776ce...: the band, the grouped
+# heads and the step's counters are Python-level branches that this
+# model never takes. A deliberate change to that step updates the hash.
+PARENT_STEP = "6dc81649b80927be1fe8f87802a635f7b138ade91f47ef57a0a98df1be02499c"
 
 
 def test_the_gpt_blocks_window_step_lowers_to_the_parents_text():

@@ -14,10 +14,12 @@ from distkeras_tpu.ops.pallas_attention import (
 )
 
 
-def dense(q, k, v):
+def dense(q, k, v, window=None):
     B, T, H, hd = q.shape
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(hd)
     mask = jnp.tril(jnp.ones((T, T), bool))
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
     s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
@@ -161,3 +163,110 @@ def test_nondefault_block_kernel_correct():
     ref = dense(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
+
+
+# -- PR 45: which tiles a launch visits, and which of them need the mask ------
+
+
+def _tiles_by_hand(T, block, window):
+    """(interior, edge) counted from the T x T matrix of live pairs: a
+    tile whose pairs are all live, and one that holds both kinds."""
+    q, k = np.arange(T)[:, None], np.arange(T)[None, :]
+    live = (q >= k) if window is None else (q >= k) & (k > q - window)
+    n = T // block
+    tiles = live.reshape(n, block, n, block).transpose(0, 2, 1, 3)
+    whole, some = tiles.all((2, 3)), tiles.any((2, 3))
+    return int(whole.sum()), int((some & ~whole).sum())
+
+
+@pytest.mark.parametrize("T,block,window,group", [
+    (64, 16, None, 1), (64, 16, 15, 1), (64, 16, 16, 2), (64, 16, 17, 4),
+    (64, 16, 31, 1), (64, 16, 32, 8), (64, 16, 33, 1), (64, 16, 1, 1),
+    (64, 16, 64, 2), (64, 16, 100, 1), (64, 64, None, 4), (64, 64, 24, 1),
+    (128, 8, 24, 8), (128, 8, 25, 1), (256, 32, 2, 1), (512, 128, 200, 4),
+    (768, 256, None, 2), (768, 256, 300, 1),  # three blocks: one a step
+    (8192, 512, 2048, 8), (8192, 512, None, 8), (2048, 512, None, 1),
+    (8192, 512, 2047, 8), (8192, 512, 2049, 8),
+])
+def test_tile_census_against_the_pairs_counted_by_hand(T, block, window,
+                                                       group):
+    """The census is read off the vectors the grids are built from and
+    the rules the kernels skip a tile and pick its body by: every tile
+    that holds a live pair is visited once, an interior tile holds no
+    dead pair, no grid step is empty."""
+    from distkeras_tpu.ops import pallas_attention as pa
+
+    nq, walk = T // block, pa._band(T, block, window)
+    span = pa._span(nq)
+    census = pa.tile_census(T, block, window, group)
+    steps = {"fwd": len(pa._row_walk(nq, walk, span)[0]),
+             "dq": len(pa._row_walk(nq, walk, span)[0]),
+             "dkv": len(pa._col_walk(nq, walk, group, span)[0]) // group}
+    interior, edge = _tiles_by_hand(T, block, window)
+    for launch, c in census.items():
+        assert c == {"interior": interior, "edge": edge, "empty": 0,
+                     "steps": steps[launch]}
+        assert interior + edge <= span * c["steps"]
+
+
+@pytest.mark.parametrize("shape,interior,edge,steps,parents_steps", [
+    # train-moe-seq8k's window layers, its full layer, train-seq2k
+    ((8192, 512, 2048, 8), 42, 28, 42, 80),
+    ((8192, 512, None, 8), 120, 16, 72, 256),
+    ((2048, 512, None, 1), 6, 4, 6, 16),
+])
+def test_tile_census_at_the_training_cells_shapes(shape, interior, edge,
+                                                  steps, parents_steps):
+    from distkeras_tpu.ops import pallas_attention as pa
+
+    T, block, window, _ = shape
+    for c in pa.tile_census(*shape).values():
+        assert c == {"interior": interior, "edge": edge, "empty": 0,
+                     "steps": steps}
+    # the rectangle of query blocks by walk steps that the grids were
+    # before PR 45 held the same tiles in more steps, some of them empty
+    assert (T // block) * pa._band(T, block, window) == parents_steps
+
+
+@pytest.mark.parametrize("T,block,window", [
+    (512, 128, None),  # four blocks: two tiles a step, state over 128 lanes
+    (384, 128, None),  # three blocks: one tile a step
+    (384, 128, 200),
+    (512, 128, 129),
+])
+def test_whole_vreg_blocks_against_dense(T, block, window):
+    """Blocks of whole 128-lane groups (what ``choose_block`` gives the
+    models: the running max and sum over all lanes of a vreg) in pairs
+    and alone: values and the three gradients against the dense form."""
+    q, k, v = qkv(B=1, T=T, seed=6)
+
+    def through(attend):
+        out, pull = jax.vjp(attend, q, k, v)
+        return (out,) + pull(jnp.cos(out))
+
+    got = through(lambda q, k, v: pallas_causal_attention(
+        q, k, v, block, window))
+    for a, b in zip(got, through(
+            lambda q, k, v: dense(q, k, v, window))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_a_second_layer_traces_no_kernel(monkeypatch):
+    """``pallas_call`` traces its kernel at every call; the launches are
+    ``jit(inline=True)`` functions, so a second layer of the same shape
+    (here a second call) reuses the first one's trace."""
+    from distkeras_tpu.ops import pallas_attention as pa
+
+    traces, kernel = [], pa._fwd_kernel
+
+    def counted(*args, **kw):
+        traces.append(1)
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(pa, "_fwd_kernel", counted)
+    q, k, v = qkv(B=1, T=640, seed=7)  # a shape no other test has traced
+    first = pallas_causal_attention(q, k, v, 128)
+    second = pallas_causal_attention(q + 1.0, k, v, 128)
+    assert len(traces) == 1
+    assert not np.array_equal(np.asarray(first), np.asarray(second))
